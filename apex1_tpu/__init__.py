@@ -34,61 +34,7 @@ survey time, so symbol anchors are the citation unit).
 __version__ = "0.1.0"
 
 
-def _install_jax_compat():
-    """Bridge the repo's newer-jax spellings onto an older runtime.
-
-    The codebase targets the current `jax.shard_map(..., check_vma=)`
-    API; on a jax that predates the top-level export (< 0.6, e.g. the
-    0.4.x CPU verify image) the same callable lives at
-    ``jax.experimental.shard_map.shard_map`` with the check kwarg named
-    ``check_rep``. Install a translating alias so ONE spelling works
-    everywhere (the alternative — try/except at 30+ call sites across
-    src/tests/examples — rots). `ops._common.out_struct` handles the
-    paired `jax.typeof`/vma gap the same way.
-    """
-    import jax
-
-    if not hasattr(jax.lax, "axis_size"):
-        # psum of a python literal is special-cased to the STATIC axis
-        # size (an int at trace time), exactly axis_size's contract
-        jax.lax.axis_size = lambda axis_name: jax.lax.psum(1, axis_name)
-
-    if not hasattr(jax.lax, "pcast"):
-        # no vma system on this jax -> re-typing a value across the
-        # varying/invariant divide is the identity
-        jax.lax.pcast = lambda x, axis_name=None, *, to=None: x
-
-    if not hasattr(jax, "set_mesh"):
-        # the legacy spelling of a default mesh is the Mesh context
-        # manager, so only the `with jax.set_mesh(mesh):` form (the one
-        # this repo uses) is bridged; the real API's statement form
-        # (global install) has no legacy equivalent — the returned mesh
-        # does nothing until entered
-        jax.set_mesh = lambda mesh: mesh
-
-    if hasattr(jax, "shard_map"):
-        return
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f=None, **kw):
-        # check_vma has no faithful translation: old check_rep is the
-        # buggier predecessor (false-positives on `cond` — its own
-        # error text says "as a temporary workaround pass
-        # check_rep=False"), and this codebase's vma annotations
-        # (pcast / out_struct vma) are identity here. Disable it; the
-        # vma discipline is enforced wherever the real API exists.
-        kw.pop("check_vma", None)
-        kw["check_rep"] = False
-        if f is None:  # partial-application form
-            return lambda g: _shard_map(g, **kw)
-        return _shard_map(f, **kw)
-
-    jax.shard_map = shard_map
-
-
-_install_jax_compat()
-
-from apex1_tpu.core import mesh, policy, loss_scale  # noqa: F401,E402
+from apex1_tpu.core import mesh, policy, loss_scale  # noqa: F401
 from apex1_tpu.core.mesh import (MeshConfig, make_hybrid_mesh,  # noqa: F401
                                  make_mesh)
 from apex1_tpu.core.policy import PrecisionPolicy, get_policy  # noqa: F401

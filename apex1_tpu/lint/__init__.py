@@ -1,8 +1,8 @@
 """graftlint — AST static analysis for this repo's JAX hazard classes.
 
 The framework's invariants (no retraces after warmup, no host syncs on
-the decode chain, use-once PRNG keys, donation discipline, one jax
-spelling through the compat bridge) are exactly the properties JAX
+the decode chain, use-once PRNG keys, donation discipline) are exactly
+the properties JAX
 never enforces statically — they regress silently and cost a TPU
 session to rediscover. graftlint walks ``apex1_tpu/``, ``tools/`` and
 ``examples/``, resolves imports well enough to know what is
@@ -113,8 +113,7 @@ class LintResult:
 def module_name_for(path: str, root: Optional[str] = None) -> str:
     """Dotted module name for a file: ``apex1_tpu/ops/rope.py`` ->
     ``apex1_tpu.ops.rope``; unknown layouts get a best-effort name
-    (only the ``apex1_tpu``-package names carry semantics — the compat
-    rule's bridge exemptions and import-runs-__init__ logic)."""
+    (only the ``apex1_tpu``-package names carry semantics)."""
     p = os.path.abspath(path)
     if root:
         try:

@@ -49,7 +49,6 @@ RULE_SLUGS: Dict[str, str] = {
     "APX102": "retrace",
     "APX103": "prng-reuse",
     "APX104": "donation",
-    "APX105": "compat-spelling",
     # APX2xx: the kernel/collective analyzer (lint/kernels/, opt-in
     # via lint_*(kernels=True) / `tools/lint.py --kernels`)
     "APX201": "sem-protocol",

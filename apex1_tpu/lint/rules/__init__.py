@@ -17,8 +17,7 @@ from typing import Callable, List, NamedTuple
 
 from apex1_tpu.lint.core import Finding
 from apex1_tpu.lint.project import Project
-from apex1_tpu.lint.rules import (compat, donation, host_sync, prng,
-                                  retrace)
+from apex1_tpu.lint.rules import donation, host_sync, prng, retrace
 
 
 class Rule(NamedTuple):
@@ -42,7 +41,4 @@ RULES = [
     Rule("APX104", "donation",
          "a donate_argnums buffer read after the donating call",
          donation.check),
-    Rule("APX105", "compat-spelling",
-         "newer-jax spelling that bypasses the _install_jax_compat "
-         "bridge", compat.check),
 ]

@@ -2,10 +2,10 @@
 banked corpus, and feed them back to the predictors.
 
 `tools/predict_perf.py` prices every bench config and kernel with an
-analytic roofline that has never been corrected against measurement:
-resnet banked 0.22x its prediction, gpt2 0.53x, and every future
-planner decision (ROADMAP item 1 — AMP-style layout pricing) would
-inherit those uncorrected errors. This module closes the loop:
+analytic roofline; uncorrected against measurement, every planner
+decision would inherit its errors. This module closes the loop (the
+repo ships NO corpus today — the planner prices "uncalibrated" until
+chip records exist):
 
 - **pairs** — every banked measurement that can be joined to its own
   prediction: on-silicon ``perf_results/bench_*.log`` records against
@@ -33,10 +33,9 @@ buffers once, not once per decode step — see predict_perf's
 "SCANNED-LOOP BLIND SPOT"), so they are excluded with that reason
 rather than silently fitted into a meaningless factor.
 
-The banked table (``perf_results/calibration.json``,
-`resilience.manifest.atomic_write_json`) is refreshed by the
-``calibrate_refresh`` entries ``tools/tpu_watch.sh`` runs after each
-bench group, so every hardware window re-fits the factors.
+The table (``perf_results/calibration.json``,
+`resilience.manifest.atomic_write_json`) is a build product of the
+corpus: re-run the CLI whenever the corpus changes.
 
 CLI::
 
@@ -67,10 +66,7 @@ EXCLUDED_STEP_CONFIGS = {
     "decode_int8": "scanned-loop blind spot (see decode)",
 }
 
-#: queue-log filename -> bench config. MUST mirror bench._BANKED_LOGS
-#: (tests/test_obs.py pins the two in sync); duplicated rather than
-#: imported because bench.py initializes jax at import and this module
-#: must stay importable by light tools.
+#: bench-log filename -> bench config
 LOG_TO_CONFIG = {
     "bench_bert.log": "bert",
     "bench_bert_drop.log": "bert_dropout",

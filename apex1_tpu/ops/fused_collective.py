@@ -60,7 +60,7 @@ decomposed PR 4 counterpart on the CPU mesh (interpret AND
 XLA-composite paths, `tests/test_fused_collective.py`), a dependence-
 mode `testing.hlo_probe` pin in tier-1, and an async-mode probe +
 Mosaic-lowering gate in `tools/aot_check.py`. `tools/bench_fused_comm.py`
-is the wall-clock A/B (queued as ``fused_comm_ab`` in tpu_watch).
+is the wall-clock A/B (never yet run on chips).
 """
 
 from __future__ import annotations
@@ -884,7 +884,7 @@ def matmul_reduce_scatter_rdma(x, w, axis_name=AXIS_TP):
         functools.partial(_mrs_rdma_kernel, n=n, axis_name=axis_name),
         grid_spec=grid_spec,
         out_shape=out_struct((chunk, N), jnp.float32, x, w),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_RDMA_COLLECTIVE_ID),
     )(cs, x, w)
     return out

@@ -25,7 +25,7 @@ op-fusion results in PAPERS.md 2502.17728 are the motivating numbers):
   entirely (``pl.when`` on the traced length — the RAGGED part).
 - :func:`fused_sample` — the sampling epilogue: logits → vocab mask →
   temperature → counter-keyed gumbel draw → argmax, one kernel per row
-  batch. The in-kernel PRNG re-derives the exact jax 0.4.x
+  batch. The in-kernel PRNG re-derives jax's exact
   threefry-2x32 stream (`_uniform_bits` — pinned bitwise against
   ``jax.random`` in ``tests/test_paged_decode.py``), so the kernel
   emits the SAME token ids as ``fold_in(key(seed), pos)`` +
@@ -151,8 +151,8 @@ def sample_token(logits, rng, *, temperature: float = 0.0,
 
 def _threefry2x32(k1, k2, x0, x1):
     """The 20-round threefry-2x32 block as pure uint32 jnp ops — runs
-    identically inside a Pallas body and in plain XLA. Reproduces jax
-    0.4.x ``jax._src.prng.threefry2x32`` op-for-op (key schedule,
+    identically inside a Pallas body and in plain XLA. Reproduces
+    ``jax._src.prng.threefry2x32`` op-for-op (key schedule,
     rotation constants, round-group injections); the bitwise match
     against ``jax.random`` is pinned in ``tests/test_paged_decode.py``
     (a silent divergence here would break the serving engine's
@@ -176,9 +176,9 @@ def _uniform_bits(k1, k2, col, n: int,
     """The uint32 draw at flat position ``col`` of an n-element
     ``jax.random`` uniform over key (k1, k2), for EITHER threefry
     stream (``partitionable`` defaults to the live
-    ``jax_threefry_partitionable`` config — the tier-1 harness runs
-    True, the jax 0.4.x default is False; the kernel must match
-    whichever stream the composite engine draws from):
+    ``jax_threefry_partitionable`` config — True by default on the
+    installed jax; the kernel must match whichever stream the
+    composite engine draws from):
 
     - partitionable: per-position 64-bit counter split into uint32
       halves — position ``col`` is the pair (0, col) for any n < 2^32,
@@ -210,7 +210,7 @@ def _uniform_bits(k1, k2, col, n: int,
 
 
 def _bits_to_gumbel(bits):
-    """uint32 → standard gumbel, op-for-op the jax 0.4.x
+    """uint32 → standard gumbel, op-for-op jax's
     ``_uniform``/``_gumbel`` pipeline (mantissa fill to [1, 2), subtract
     1, affine to [tiny, 1), −log(−log(u)))."""
     fb = (bits >> np.uint32(9)) | np.uint32(0x3F800000)

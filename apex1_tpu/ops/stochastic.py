@@ -12,13 +12,15 @@ forward/backward pair, and Megatron-style stacks fuse the
   recompute-instead-of-save trade the flash kernels already make for
   probabilities), so dropout adds zero activation memory;
 - **on TPU** the mask comes from the hardware PRNG: each kernel grid
-  step re-seeds with ``pltpu.prng_seed(seed, salt, row0, col0)`` (salt ≙
-  batch·H+head for attention, 0 for row kernels; row0/col0 are GLOBAL
-  tile offsets) and draws one ``pltpu.prng_random_bits`` tile — streams
-  are keyed on position, so the mask is independent of grid iteration
-  order and of ring-shard visiting order, and context-parallel shards
-  draw disjoint, shift-invariant streams (their global k-offset is
-  folded into the counter);
+  step re-seeds with ``pltpu.prng_seed(*_seed_words(seed, salt, row0,
+  col0))`` (salt ≙ batch·H+head for attention, 0 for row kernels;
+  row0/col0 are GLOBAL tile offsets; Mosaic takes at most two seed
+  words, so the four counters are hashed into two) and draws one
+  ``pltpu.prng_random_bits`` tile — streams are keyed on position, so
+  the mask is independent of grid iteration order and of ring-shard
+  visiting order, and context-parallel shards draw disjoint,
+  shift-invariant streams (their global k-offset is folded into the
+  counter);
 - **off TPU** (Pallas interpret mode + the XLA composites, where the
   Mosaic PRNG primitives do not lower) the same counters feed a uint32
   avalanche hash evaluated per element at its GLOBAL position — the
@@ -87,10 +89,17 @@ def hash_bits_u32(seed, salt, row, col):
     and (salt=b, row=a) would draw identical streams and per-head masks
     would be pairwise correlated across (batch·head, q-row) pairs.
     """
-    s = _mix32(jnp.asarray(seed).astype(jnp.uint32) + np.uint32(_GOLDEN))
-    s = _mix32(s ^ jnp.asarray(salt).astype(jnp.uint32) * np.uint32(_C_ROW))
-    h = _mix32(s ^ row.astype(jnp.uint32) * np.uint32(_C_ROW))
+    h = _mix32(_stream_u32(seed, salt)
+               ^ row.astype(jnp.uint32) * np.uint32(_C_ROW))
     return _mix32(h ^ col.astype(jnp.uint32) * np.uint32(_C_COL))
+
+
+def _stream_u32(seed, salt):
+    """The (seed, salt) stage of `hash_bits_u32`: one fully mixed word
+    per stream, before any position enters."""
+    s = _mix32(jnp.asarray(seed).astype(jnp.uint32) + np.uint32(_GOLDEN))
+    return _mix32(s ^ jnp.asarray(salt).astype(jnp.uint32)
+                  * np.uint32(_C_ROW))
 
 
 def threshold_u32(p: float) -> np.uint32:
@@ -122,6 +131,21 @@ def attn_keep_mask(seed, num_batch, num_heads, rows, cols, p):
     return bits >= threshold_u32(p)
 
 
+def _seed_words(seed, salt, row0, col0):
+    """Two int32 PRNG seed words from the four tile counters — Mosaic's
+    ``prng_seed`` rejects more than two. The scalar `hash_bits_u32`
+    chain, one word per stage: word 0 is the (seed, salt) stream, word 1
+    that stream hashed with the GLOBAL tile offset, so the pair is a
+    pure function of exactly the counters the forward and both backward
+    kernels share."""
+    row0, col0 = jnp.asarray(row0, jnp.int32), jnp.asarray(col0, jnp.int32)
+    # same-width integer converts wrap (Mosaic integers are signless); a
+    # scalar bitcast_convert_type does not lower ('tpu.bitcast' is
+    # vector-only)
+    return (_stream_u32(seed, salt).astype(jnp.int32),
+            hash_bits_u32(seed, salt, row0, col0).astype(jnp.int32))
+
+
 def tile_keep_mask(shape, thr, seed, salt, row0, col0, *, interp: bool):
     """(bool) keep mask for one kernel tile at GLOBAL offset (row0, col0).
 
@@ -136,7 +160,7 @@ def tile_keep_mask(shape, thr, seed, salt, row0, col0, *, interp: bool):
         col = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + col0
         bits = hash_bits_u32(seed, salt, row, col)
     else:
-        pltpu.prng_seed(seed, salt, row0, col0)
+        pltpu.prng_seed(*_seed_words(seed, salt, row0, col0))
         bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
     return bits >= thr
 

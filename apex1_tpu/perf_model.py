@@ -74,7 +74,7 @@ def flash_flops_bytes(B, Hq, Hkv, S, D, causal=True, grad=False):
         # recomputes them again then dk, dv (4) — 7 bwd matmuls
         # total, NOT the fused-backward 5 an analytic count
         # assumes (Mosaic's output-revisiting rule forces the two
-        # passes; see ops/attention.py and measured_r5.md). A
+        # passes; see ops/attention.py). A
         # perfect kernel measured against the 5-matmul roofline
         # would read as ~0.78 and be mis-flagged as a tuning
         # target.

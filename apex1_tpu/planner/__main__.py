@@ -8,8 +8,8 @@
 
 ``--smoke`` is the check_all gate (< 30s): enumerate -> price -> emit
 for the tiny shape on 8 virtual devices, pin plan determinism
-(byte-identical re-plan), price the banked gpt2 shape against the
-committed calibration table, then drive ``examples/llama_3d.py --plan
+(byte-identical re-plan), price the banked gpt2 shape (labelled
+"uncalibrated" while the repo ships no calibration table), then drive ``examples/llama_3d.py --plan
 auto`` end-to-end on the CPU mesh — the full
 search-to-training-step path with zero hardware.
 """

@@ -214,9 +214,8 @@ def calibration_factor(shape: ModelShape,
 def _sp_kernel_factor(layout: Layout,
                       results_dir: Optional[str] = None) -> dict:
     """TPU-backed kernel slowdown for the SP-boundary schedule the
-    layout selected — PR 9's A/B data once a hardware window banks it
-    (`fused_comm_ab` in the tpu_watch queue feeds the tuning tables
-    and calibration fit). Today's tables are cpu-swept, so
+    layout selected — PR 9's A/B data once a chip run banks it into
+    the tuning tables and calibration fit. Today's tables are cpu-swept, so
     `kernel_slowdown` (tpu-only by contract) returns None and the
     boundary term stays analytic — labelled as such."""
     from apex1_tpu.obs.calibrate import kernel_slowdown

@@ -80,10 +80,9 @@ class ModelShape:
                    moe_top_k=getattr(cfg, "moe_top_k", 2))
 
 
-#: The banked bench shapes the acceptance contract prices (ISSUE 12 /
-#: ROADMAP item 1): names match the calibration keys in
-#: perf_results/calibration.json (step:gpt2 1.89x, step:llama_longctx
-#: 2.79x fitted from the round-5 silicon logs), dims match the exact
+#: The bench shapes the acceptance contract prices (ISSUE 12): names
+#: are the calibration keys a banked perf_results/calibration.json
+#: would carry (step:gpt2, step:llama_longctx), dims match the exact
 #: bench.py configs (`bench_gpt2` B=16 S=1024 on v5e; `bench_llama_longctx`
 #: 16-layer 0.8B at 16k) and the 8B projection matches
 #: `tools/aot_check.py --flagship`'s Llama-3-8B step (dp2 pp2 tp4,

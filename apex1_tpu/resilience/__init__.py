@@ -9,7 +9,7 @@ story, built as four cooperating pieces (see `docs/robustness.md`):
   finite/divergence guard for ALL dtypes with a skip → rollback → abort
   escalation ladder and banked diagnostics.
 - `PreemptionHandler` / `EXIT_RESUMABLE` (`.preemption`): SIGTERM grace
-  hook → final sync checkpoint → the exit code `tools/tpu_watch.sh`
+  hook → final sync checkpoint → the exit code a job scheduler
   re-queues instead of recording a failure.
 - `retry_call` / `backoff_delays` / `TransientError` (`.retry`): the one
   bounded-exponential-backoff-with-deterministic-jitter policy, shared

@@ -45,9 +45,9 @@ match the control BIT-EXACTLY, and the episode summary is re-derived
 in the parent from the spine events alone (both processes bank into
 one obs dir) and checked against the leg's ground truth. What the
 CPU drill does NOT prove: silicon wall-clock and real multi-host
-orchestration — the ``elastic_ab`` tpu_watch queue entry (the
-``--real`` in-process form: a TPU job cannot boot a second process
-against chips it holds) carries that claim (docs/robustness.md
+orchestration — the ``--real`` in-process form (a TPU job cannot
+boot a second process against chips it holds) carries that claim
+once it runs on chips (docs/robustness.md
 § Elastic resume).
 """
 
@@ -264,7 +264,7 @@ def _resume_leg(ckpt_dir: str, work: str, n_to: int, seed: int,
         if verbose:
             print(f"[elastic drill] {msg}", flush=True)
 
-    # tiny compiles, zero cache value — and on jax 0.4.x XLA:CPU,
+    # tiny compiles, zero cache value — and on XLA:CPU,
     # RELOADING a persistent-cached executable whose device assignment
     # is a proper subset of the visible devices is unreliable
     # (segfaults reproduced on this image), which the --real in-process

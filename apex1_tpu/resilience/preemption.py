@@ -1,10 +1,9 @@
 """Preemption-safe shutdown: turn SIGTERM into a banked checkpoint and
 a machine-readable "re-queue me" exit code.
 
-The hardware this repo targets is preemptible and scarce (ROADMAP: the
-measurement queue has been armed since round 1 waiting for a window) —
-a run that dies mid-window must bank partial progress and exit in a way
-the watcher (`tools/tpu_watch.sh`) can distinguish from a real failure.
+The hardware this repo targets is preemptible and scarce — a run that
+dies mid-way must bank partial progress and exit in a way its scheduler
+can distinguish from a real failure.
 
 Contract:
 
@@ -22,9 +21,9 @@ Contract:
   is cross-signal on purpose (SIGINT then SIGTERM must escalate, not
   be swallowed as a "different" first signal).
 - `EXIT_RESUMABLE` (75, BSD ``EX_TEMPFAIL``) is the exit-code half of
-  the contract: ``tools/tpu_watch.sh`` re-queues an entry that exits 75
-  at the head of the queue instead of recording a failed round, and the
-  relaunch resumes via ``--resume auto`` / `find_restorable`.
+  the contract: a scheduler re-queues a job that exits 75 instead of
+  recording a failed run, and the relaunch resumes via
+  ``--resume auto`` / `find_restorable`.
 """
 
 from __future__ import annotations
@@ -38,7 +37,7 @@ from typing import Optional, Sequence
 
 # BSD EX_TEMPFAIL: "temporary failure, retry later" — distinct from 0
 # (done), 1 (real failure), and 124/137 (timeout kills), and stable
-# across shells. tools/tpu_watch.sh greps for exactly this value.
+# across shells.
 EXIT_RESUMABLE = 75
 
 

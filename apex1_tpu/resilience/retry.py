@@ -1,7 +1,6 @@
 """Bounded exponential backoff with deterministic jitter — the ONE
 retry policy shared by the resilient runtime (`checkpointer` backend
-writes, `runtime.RequestFeeder` backpressure, `tools/tpu_watch.sh`'s
-python helpers).
+writes, `runtime.RequestFeeder` backpressure).
 
 Deliberately jax-free (stdlib only): retry decisions run on the host
 control plane, never inside a traced program, and the chaos harness

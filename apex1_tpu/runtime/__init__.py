@@ -4,47 +4,87 @@ Reference: ``csrc/flatten_unflatten.cpp :: flatten/unflatten`` (the
 ``apex_C`` extension backing DDP bucket flattening) and
 ``examples/imagenet/main_amp.py :: data_prefetcher`` (side-stream input
 normalization + prefetch). See `_runtime.cpp` for the TPU-native design
-rationale. The library is compiled on first import with ``g++ -O3``;
-every entry point has a NumPy fallback so the package works without a
-toolchain.
+rationale. The library is compiled from ``_runtime.cpp`` on the machine
+that imports it (the artefact's name carries `_build_key`, so one built
+from other source or on another host is never loaded); every entry
+point has a NumPy fallback so the package works without a toolchain —
+a failed build warns, and `native_available` says which path is live.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import queue
 import subprocess
 import threading
 import time as _time
+import warnings
 from typing import Callable, Iterable, Iterator, Optional, Sequence
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "_runtime.cpp")
-_LIB_PATH = os.path.join(_DIR, "_runtime.so")
+_CXX = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+        "-pthread"]
 _N_THREADS = max(1, (os.cpu_count() or 4) // 2)
 
 
+def _build_key() -> str:
+    """What the artefact is a function of: the source bytes, the compile
+    command, and — because ``-march=native`` bakes in this CPU's
+    instruction set and a checkout's ignored files get copied between
+    machines — the host and its CPU flags. A ``.so`` under any other key
+    is never loaded."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX).encode())
+    h.update(platform.node().encode())
+    h.update(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            h.update(next((ln for ln in f if ln.startswith("flags")),
+                          "").encode())
+    except OSError:
+        pass
+    return h.hexdigest()[:16]
+
+
 def _build_library() -> Optional[str]:
-    if os.path.exists(_LIB_PATH) and (os.path.getmtime(_LIB_PATH)
-                                      >= os.path.getmtime(_SRC)):
-        return _LIB_PATH
-    tmp = f"{_LIB_PATH}.tmp.{os.getpid()}"  # per-pid: concurrent imports
+    lib_path = os.path.join(_DIR, f"_runtime.{_build_key()}.so")
+    if os.path.exists(lib_path):
+        return lib_path
+    tmp = f"{lib_path}.tmp.{os.getpid()}"   # per-pid: concurrent imports
     try:                                    # must not interleave writes
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-             "-pthread", _SRC, "-o", tmp],
-            check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)          # atomic publish
-        return _LIB_PATH
-    except Exception:
+        subprocess.run([*_CXX, _SRC, "-o", tmp], check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)           # atomic publish
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        # the NumPy path keeps the package usable without a toolchain,
+        # but never silently: say why the native library is absent
+        stderr = (getattr(e, "stderr", None) or b"").decode(
+            errors="replace")[-400:]
+        warnings.warn(
+            f"apex1_tpu.runtime: building _runtime.cpp failed ({e!r}) "
+            f"{stderr}; using the NumPy fallbacks", RuntimeWarning,
+            stacklevel=2)
         return None
+    for old in glob.glob(os.path.join(_DIR, "_runtime.*.so")):
+        if old != lib_path:                 # other sources / machines
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return lib_path
 
 
 def _load() -> Optional[ctypes.CDLL]:

@@ -542,15 +542,13 @@ class Engine:
                 cfg.pad_id)
             return tgt, acc, nxt, idxs + adv, pos + adv, pool
 
-        # donate the pool so XLA updates the cache in place; CPU lacks
-        # input/output aliasing for some buffers — skip there to avoid
-        # per-call warnings (semantics identical, one extra copy)
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._prefill = jax.jit(prefill, donate_argnums=donate)
+        # donate the pool so XLA updates the cache in place (every
+        # backend: the CPU tests run the same aliasing the chip does)
+        self._prefill = jax.jit(prefill, donate_argnums=1)
         if self._spec:
-            self._verify = jax.jit(verify, donate_argnums=donate)
+            self._verify = jax.jit(verify, donate_argnums=1)
         else:
-            self._decode = jax.jit(decode, donate_argnums=donate)
+            self._decode = jax.jit(decode, donate_argnums=1)
 
     def _build_paged_executables(self):
         """The paged-mode executables. Two shapes of the same contract:
@@ -828,12 +826,11 @@ class Engine:
                 cfg.pad_id)
             return tgt, acc, nxt, idxs + adv, pos + adv, pages
 
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._prefill = jax.jit(prefill, donate_argnums=donate)
+        self._prefill = jax.jit(prefill, donate_argnums=1)
         if self._spec:
-            self._verify = jax.jit(verify, donate_argnums=donate)
+            self._verify = jax.jit(verify, donate_argnums=1)
         else:
-            self._decode = jax.jit(decode, donate_argnums=donate)
+            self._decode = jax.jit(decode, donate_argnums=1)
 
     # ---- multi-tenant LoRA adapters -------------------------------------
 
@@ -1308,8 +1305,11 @@ class Engine:
             if self.kv.register_prefix(slot, pkey, length) is not None:
                 self.kv.acquire_prefix(pkey, slot)
             return
-        lane = jax.tree_util.tree_map(lambda x: x[slot:slot + 1],
-                                      self.kv.cache)
+        # the snapshot must own its buffers: the pool is DONATED to the
+        # next executable call, and on a one-slot pool x[0:1] is x itself
+        lane = jax.tree_util.tree_map(
+            lambda x: x[slot:slot + 1] if x.shape[0] > 1 else jnp.copy(x),
+            self.kv.cache)
         self.kv.put_prefix(pkey, lane, length)
         self.kv.acquire_prefix(pkey, slot)
 

@@ -41,7 +41,8 @@ from typing import Any, Mapping
 
 import numpy as np
 
-from apex1_tpu.core.capability import (detect_generation, get_capability,
+from apex1_tpu.core.capability import (CPU_PLANNING_GENERATION,
+                                       detect_generation, get_capability,
                                        vmem_budget)
 from apex1_tpu.tuning.registry import SPECS
 
@@ -83,11 +84,11 @@ def canonical_dtype(dtype) -> str:
 
 
 def canonical_generation(generation: str | None = None) -> str:
-    """Table-key generation: explicit > detected chip > 'v5e' (the same
-    conservative off-TPU default ``core.capability.get_capability``
-    plans blocks for, so CPU-validated lookups agree with the v5e
-    planning path)."""
-    return generation or detect_generation() or "v5e"
+    """Table-key generation: explicit > detected chip >
+    ``CPU_PLANNING_GENERATION`` (what ``core.capability.vmem_budget``
+    plans blocks for on the CPU backend, so CPU-validated lookups agree
+    with that planning path)."""
+    return generation or detect_generation() or CPU_PLANNING_GENERATION
 
 
 def make_key(dims: Mapping[str, int], dtype,

@@ -63,8 +63,6 @@ def cost_analysis(fn: Callable, *args, **kwargs) -> dict:
     lowered = jax.jit(fn).lower(*args, **kwargs)
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
     return dict(ca) if ca else {}
 
 

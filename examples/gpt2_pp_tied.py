@@ -20,9 +20,10 @@ import sys
 
 # direct `python examples/...` puts examples/ (not the repo root) on the
 # path; the smoke harness exec()s the source with no __file__ at all
-# (no import-time honor_jax_platforms_env here: this example calls
-# force_virtual_cpu_devices in main, which must win the first backend
-# init — an early default_backend() probe would pin 1 CPU device)
+# (nothing here may initialise a backend: under JAX_PLATFORMS=cpu this
+# example calls force_virtual_cpu_devices in main, which must win the
+# first backend init — an early default_backend() probe would pin 1 CPU
+# device)
 _root = (os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
          if "__file__" in globals() else os.getcwd())
 sys.path.insert(0, _root)
@@ -42,7 +43,10 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
-    force_virtual_cpu_devices(max(args.pp, 2))
+    # JAX_PLATFORMS=cpu builds the virtual CPU mesh; otherwise the live
+    # devices run the pipeline (--pp must equal their count)
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        force_virtual_cpu_devices(max(args.pp, 2))
 
     import jax
     import jax.numpy as jnp

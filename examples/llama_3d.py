@@ -4,9 +4,10 @@ config-4 composition (`apex1_tpu.models.llama_3d`) as a runnable loop.
 One `shard_map` train step: Megatron TP+SP blocks inside a scan+ppermute
 pipeline (optionally interleaved, ``--chunks 2``), vocab-parallel
 embedding + fused LM-head CE with embedding-group grad combination,
-fused Adam on fp32 masters. Defaults run a tiny model on the virtual
-CPU mesh; the same code compiles for a v5p-32 class topology at 8B
-(`tools/aot_check.py --flagship`).
+fused Adam on fp32 masters. ``JAX_PLATFORMS=cpu`` runs a tiny model on
+the virtual CPU mesh; without it the live devices run the step (the
+layout must cover exactly the host's chips); the same code compiles for
+a v5p-32 class topology at 8B (`tools/aot_check.py --flagship`).
 
 Two ways to pick the parallel layout:
 
@@ -49,9 +50,10 @@ import os
 import sys
 import time
 
-# (no import-time honor_jax_platforms_env here: this example calls
-# force_virtual_cpu_devices in main, which must win the first backend
-# init — an early default_backend() probe would pin 1 CPU device)
+# (nothing here may initialise a backend: under JAX_PLATFORMS=cpu this
+# example calls force_virtual_cpu_devices in main, which must win the
+# first backend init — an early default_backend() probe would pin 1 CPU
+# device)
 _root = (os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
          if "__file__" in globals() else os.getcwd())
 sys.path.insert(0, _root)
@@ -204,7 +206,11 @@ def main():
     else:
         _validate_hand_layout(args)
         n = args.dp * args.pp * args.tp * args.ep * args.cp
-    force_virtual_cpu_devices(max(n, 2))
+    # JAX_PLATFORMS=cpu builds the n-device virtual CPU mesh; otherwise
+    # the live devices run the step — on a TPU host the layout must
+    # cover exactly its chips (make_mesh raises if it does not)
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        force_virtual_cpu_devices(max(n, 2))
 
     import jax
     import jax.numpy as jnp
@@ -423,8 +429,22 @@ def main():
         if pre is not None:
             pre.uninstall()
     jax.block_until_ready(state)
+    # where the state lives, and whether every replicated copy of a
+    # shard (same index, different device) still agrees after the steps
+    homes, agree = set(), True
+    for leaf in jax.tree_util.tree_leaves(state):
+        copies = {}
+        for s in leaf.addressable_shards:
+            homes.add(s.device)
+            first = copies.setdefault(str(s.index), np.asarray(s.data))
+            agree &= np.array_equal(first, np.asarray(s.data))
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.devices()]
     print(f"done in {time.time() - t0:.1f}s "
-          f"(step counter = {int(state['step'])})")
+          f"(step counter = {int(state['step'])}); state shards on "
+          f"{len(homes)} of {jax.device_count()} "
+          f"{jax.devices()[0].platform} devices, replicated copies agree: "
+          f"{agree}, peak bytes in use per device {peaks}")
 
 
 if __name__ == "__main__":

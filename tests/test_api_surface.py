@@ -149,7 +149,7 @@ SURFACE = {
         "roofline_ms"],
     "apex1_tpu.testing": [
         "force_virtual_cpu_devices", "enable_persistent_compilation_cache",
-        "honor_jax_platforms_env", "distributed_mesh", "standalone_gpt",
+        "distributed_mesh", "standalone_gpt",
         "standalone_bert"],
     "apex1_tpu.lint": [
         "lint_paths", "lint_files", "lint_sources", "LintResult",

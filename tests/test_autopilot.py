@@ -700,10 +700,11 @@ def _mini_corpus(tmp_path, *, measured_scale=1.0):
 
 
 class TestDriftGate:
-    def test_committed_corpus_in_band(self, drift_mod):
-        """The gate must be green on the repo's own banked state —
-        that IS the check_all step."""
-        assert drift_mod.run_gate(str(_REPO / "perf_results")) == 0
+    def test_repo_without_a_table_fails_closed(self, drift_mod):
+        """The repo ships no calibration table (no chip corpus exists
+        for the current code): the gate cannot read its evidence, so
+        it must not pass — exit 2, not 0."""
+        assert drift_mod.run_gate(str(_REPO / "perf_results")) == 2
 
     def test_in_band_synthetic(self, tmp_path, drift_mod):
         assert drift_mod.run_gate(str(_mini_corpus(tmp_path))) == 0

@@ -382,8 +382,7 @@ def test_example_kill_then_elastic_relaunch(tmp_path):
     from apex1_tpu.resilience import EXIT_RESUMABLE
 
     # JAX_COMPILATION_CACHE_DIR exported EMPTY = the operator-disable
-    # form child_cache_env documents: on this image's jax 0.4.x
-    # XLA:CPU, a 4-device shard_map executable RELOADED from a warm
+    # form child_cache_env documents: on XLA:CPU, a 4-device shard_map executable RELOADED from a warm
     # persistent cache aborts (8-device reloads are fine; reproduced
     # cold-pass/warm-crash with a fresh cache dir), so the relaunch
     # children must compile cold. CPU-only; a TPU relaunch caches

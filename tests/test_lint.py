@@ -415,92 +415,6 @@ class TestDonation:
 
 
 # ---------------------------------------------------------------------------
-# APX105 compat-spelling
-# ---------------------------------------------------------------------------
-
-COMPAT_POS = """
-    import jax
-    from jax.experimental.shard_map import shard_map
-
-    def apply(mesh, specs, x):
-        f = jax.shard_map(lambda a: a, mesh=mesh, in_specs=specs,
-                          out_specs=specs, check_rep=False)
-        vma = jax.typeof(x).vma
-        return f(x), vma
-"""
-
-COMPAT_NEG = """
-    import jax
-    import apex1_tpu  # installs the compat bridge
-
-    def apply(mesh, specs, x):
-        f = jax.shard_map(lambda a: a, mesh=mesh, in_specs=specs,
-                          out_specs=specs, check_vma=False)
-        with jax.set_mesh(mesh):
-            return f(x)
-"""
-
-COMPAT_SUP = """
-    import jax
-
-    def probe(x):
-        return jax.typeof(x)  # graftlint: allow(compat-spelling) -- version probe, guarded by caller
-"""
-
-
-class TestCompatSpelling:
-    def test_positive(self):
-        res = run_lint(COMPAT_POS, path="tools/fix.py",
-                       modname="tools.fix")
-        msgs = [f.message for f in res.unsuppressed()
-                if f.rule == "APX105"]
-        assert any("legacy" in m for m in msgs), msgs
-        assert any("never imports apex1_tpu" in m for m in msgs), msgs
-        assert any("check_rep" in m for m in msgs), msgs
-        assert any("jax.typeof" in m for m in msgs), msgs
-
-    def test_negative(self):
-        res = run_lint(COMPAT_NEG, path="tools/fix.py",
-                       modname="tools.fix")
-        assert "APX105" not in codes(res), \
-            [f.render() for f in res.unsuppressed()]
-
-    def test_negative_inside_package(self):
-        # package modules get the bridge via __init__: no import needed
-        src = """
-            import jax
-
-            def apply(mesh, specs, x):
-                return jax.shard_map(lambda a: a, mesh=mesh,
-                                     in_specs=specs, out_specs=specs)(x)
-        """
-        res = run_lint(src, path="apex1_tpu/parallel/fix.py",
-                       modname="apex1_tpu.parallel.fix")
-        msgs = [f.message for f in res.unsuppressed()
-                if f.rule == "APX105"]
-        assert not msgs, msgs
-
-    def test_bridge_modules_exempt(self):
-        src = """
-            import jax
-
-            def shard_map(f=None, **kw):
-                kw.pop("check_vma", None)
-                kw["check_rep"] = False
-                return jax.experimental.shard_map.shard_map(f, **kw)
-        """
-        res = run_lint(src, path="apex1_tpu/__init__.py",
-                       modname="apex1_tpu")
-        assert "APX105" not in codes(res)
-
-    def test_suppressed(self):
-        res = run_lint(COMPAT_SUP, path="tools/fix.py",
-                       modname="tools.fix")
-        assert "APX105" not in codes(res)
-        assert codes(res, suppressed=True) == {"APX105"}
-
-
-# ---------------------------------------------------------------------------
 # suppression grammar
 # ---------------------------------------------------------------------------
 
@@ -680,7 +594,7 @@ class TestRepoSelfCheck:
 
     def test_rules_registered(self):
         assert [r.code for r in RULES] == [
-            "APX101", "APX102", "APX103", "APX104", "APX105"]
+            "APX101", "APX102", "APX103", "APX104"]
 
 
 # ---------------------------------------------------------------------------
@@ -701,25 +615,24 @@ class TestCli:
         doc = json.loads(p.stdout)
         assert doc["ok"] is True
         assert set(doc["rules"]) == {"APX101", "APX102", "APX103",
-                                     "APX104", "APX105"}
+                                     "APX104"}
 
     def test_every_rule_positive_exits_nonzero(self, tmp_path):
         """One subprocess over a directory holding every rule family's
-        positive fixture: the CLI must exit 1 and report all five
-        codes. (One spawn, not five — each CLI start pays the jax
+        positive fixture: the CLI must exit 1 and report all four
+        codes. (One spawn, not four — each CLI start pays the jax
         import; the per-rule finding behavior is covered in-memory
         above.)"""
-        d = tmp_path / "tools"      # tools/-like modname for compat
+        d = tmp_path / "tools"
         d.mkdir()
         for name, fixture in [("host.py", HOST_POS),
                               ("retrace.py", RETRACE_POS),
                               ("prng.py", PRNG_POS),
-                              ("don.py", DON_POS),
-                              ("compat.py", COMPAT_POS)]:
+                              ("don.py", DON_POS)]:
             (d / name).write_text(textwrap.dedent(fixture))
         p = self._run(str(d))
         assert p.returncode == 1, p.stdout + p.stderr
-        for rule in ("APX101", "APX102", "APX103", "APX104", "APX105"):
+        for rule in ("APX101", "APX102", "APX103", "APX104"):
             assert rule in p.stdout, (rule, p.stdout)
 
     def test_nonexistent_path_fails_closed(self):

@@ -3,6 +3,7 @@
 (gold = hand-rolled attention; norm_add variants; mask handling)."""
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -168,9 +169,9 @@ def _nonkernel_avals(jaxpr, out):
             continue
 
         def visit(val):
-            if isinstance(val, jax.core.ClosedJaxpr):
+            if isinstance(val, jax.extend.core.ClosedJaxpr):
                 _nonkernel_avals(val.jaxpr, out)
-            elif isinstance(val, jax.core.Jaxpr):
+            elif isinstance(val, jax.extend.core.Jaxpr):
                 _nonkernel_avals(val, out)
             elif isinstance(val, (tuple, list)):
                 for item in val:

@@ -52,10 +52,9 @@ class TestThreefryStream:
     @pytest.mark.parametrize("n", [6, 7, 200, 257])
     def test_uniform_bits_bitwise_vs_jax_random(self, n, partitionable):
         """The pure-jnp threefry-2x32 reimplementation must reproduce
-        jax's draw exactly under BOTH stream configs (the tier-1
-        harness runs partitionable, the jax 0.4.x default is the
-        original stream; odd counts exercise the original stream's
-        zero-padded pair-partner path)."""
+        jax's draw exactly under BOTH stream configs (partitionable is
+        the installed default; odd counts exercise the original
+        stream's zero-padded pair-partner path)."""
         key = jax.random.fold_in(jax.random.key(123), 7)
         k1, k2 = (jnp.uint32(x) for x in jax.random.key_data(key))
         col = jnp.arange(n, dtype=jnp.int32)

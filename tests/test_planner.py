@@ -169,28 +169,44 @@ class TestMemory:
 # ---------------------------------------------------------------------------
 
 class TestPricing:
-    def test_calibration_factor_from_committed_table(self):
-        # the committed calibration.json must drive the price: the
-        # calibrated/analytic ratio IS the banked step:gpt2 slowdown
-        doc = json.load(open(os.path.join(REPO, "perf_results",
-                                          "calibration.json")))
-        want = doc["factors"]["step:gpt2"]["slowdown"]
+    @staticmethod
+    def _table(tmp_path):
+        """A results dir whose calibration.json carries two tpu step
+        factors (no committed table exists: the planner's feedback is
+        pinned against a synthetic one)."""
+        doc = {"schema": "apex1-calibration-v1",
+               "factors": {"step:gpt2": {"slowdown": 1.9, "n": 2,
+                                         "backend": "tpu"},
+                           "step:bert": {"slowdown": 1.2, "n": 1,
+                                         "backend": "tpu"}},
+               "proxy_factors": {}, "excluded": [], "pairs": []}
+        (tmp_path / "calibration.json").write_text(json.dumps(doc))
+        return str(tmp_path)
+
+    def test_calibration_factor_from_table(self, tmp_path):
+        # a banked calibration.json must drive the price: the
+        # calibrated/analytic ratio IS its step:gpt2 slowdown
+        res = self._table(tmp_path)
         shape = planner.BANKED_SHAPES["gpt2"]
         lay = _layout(num_microbatches=16)
-        cal = planner.price_layout(shape, lay, generation="v5e")
+        cal = planner.price_layout(shape, lay, generation="v5e",
+                                   results_dir=res)
         raw = planner.price_layout(shape, lay, generation="v5e",
+                                   results_dir=res,
                                    use_calibration=False)
         assert cal["calibrated_step_ms"] / cal["step_ms"] == \
-            pytest.approx(want)
+            pytest.approx(1.9)
         assert raw["calibrated_step_ms"] == raw["step_ms"]
         assert "step:gpt2" in cal["calibration"]["source"]
 
-    def test_uncalibrated_shape_gets_fleet_geomean(self):
+    def test_uncalibrated_shape_gets_fleet_geomean(self, tmp_path):
         s = planner.BANKED_SHAPES["llama8b"]
         lay = _layout(dp=2, pp=2, tp=4, num_microbatches=4)
-        p = planner.price_layout(s, lay, generation="v5p")
+        p = planner.price_layout(s, lay, generation="v5p",
+                                 results_dir=self._table(tmp_path))
         assert "fleet-geomean" in p["calibration"]["source"]
-        assert p["calibrated_step_ms"] > p["step_ms"]   # slowdowns > 1
+        assert p["calibrated_step_ms"] / p["step_ms"] == \
+            pytest.approx((1.9 * 1.2) ** 0.5)
 
     def test_no_table_is_labelled_uncalibrated(self, tmp_path):
         p = planner.price_layout(
@@ -277,11 +293,18 @@ class TestPlan:
         with pytest.raises(ValueError):
             planner.load_plan(str(tmp_path / "missing.json"))
 
-    def test_plan_carries_calibration_provenance(self):
-        plan = planner.make_plan(planner.BANKED_SHAPES["gpt2"], 1)
+    def test_plan_carries_calibration_provenance(self, tmp_path):
+        res = TestPricing._table(tmp_path)
+        plan = planner.make_plan(planner.BANKED_SHAPES["gpt2"], 1,
+                                 results_dir=res)
         assert plan["provenance"]["calibration_table"] == \
             "calibration.json"
         assert plan["schema"] == planner.PLAN_SCHEMA
+        # the repo ships no table: the default plan says so
+        bare = planner.make_plan(planner.BANKED_SHAPES["gpt2"], 1)
+        assert bare["provenance"]["calibration_table"] is None
+        assert "uncalibrated" in \
+            bare["predicted"]["calibration"]["source"]
 
     def test_llama3d_config_from_plan(self):
         from apex1_tpu.core.policy import get_policy

@@ -39,13 +39,41 @@ def test_standalone_models_train_one_step(devices):
         assert all(np.all(np.isfinite(g)) for g in jax.tree.leaves(grads))
 
 
-class TestChildCacheEnv:
-    """`testing.child_cache_env` must honor the OPERATOR's exported
-    `JAX_COMPILATION_CACHE_DIR` by presence, not truthiness (exported
-    EMPTY = deliberately disabled), and always carry the min-compile
-    override (ADVICE r5)."""
+class TestCompileCachePolicy:
+    """One cache policy (`testing.enable_persistent_compilation_cache`
+    and its env-var form `child_cache_env`): an exported
+    ``JAX_COMPILATION_CACHE_DIR`` is the operator's — presence, not
+    truthiness (exported EMPTY = deliberately disabled) — and no
+    directory is set in code; unset, the cache is the fixed
+    ``<checkout>/.jax_cache``."""
 
-    def test_exported_empty_dir_is_not_reenabled(self, monkeypatch):
+    @pytest.fixture()
+    def cache_cfg(self):
+        import jax
+        keep = (jax.config.jax_compilation_cache_dir,
+                jax.config.jax_persistent_cache_min_compile_time_secs)
+        yield jax.config
+        jax.config.update("jax_compilation_cache_dir", keep[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          keep[1])
+
+    def test_env_set_leaves_config_alone(self, monkeypatch, cache_cfg):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/op_cache")
+        cache_cfg.update("jax_compilation_cache_dir", "sentinel")
+        assert testing.enable_persistent_compilation_cache() == "sentinel"
+        assert cache_cfg.jax_compilation_cache_dir == "sentinel"
+
+    def test_env_unset_uses_fixed_checkout_dir(self, monkeypatch,
+                                               cache_cfg):
+        import os
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = testing.enable_persistent_compilation_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert cache_cfg.jax_compilation_cache_dir == got
+        assert testing.REPO_CACHE_DIR == got
+
+    def test_child_exported_empty_dir_is_not_reenabled(self, monkeypatch):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
         monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
                            raising=False)
@@ -53,16 +81,12 @@ class TestChildCacheEnv:
         assert "JAX_COMPILATION_CACHE_DIR" not in out  # inherit the disable
         assert out["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0.1"
 
-    def test_disabled_path_still_lowers_min_compile_time(self, monkeypatch):
+    def test_child_unset_gets_checkout_dir(self, monkeypatch):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                           raising=False)
-        monkeypatch.setenv("APEX1_JAX_CACHE_DIR", "")  # disable convention
         out = testing.child_cache_env()
-        assert "JAX_COMPILATION_CACHE_DIR" not in out
-        assert out["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0.1"
+        assert out["JAX_COMPILATION_CACHE_DIR"] == testing.REPO_CACHE_DIR
 
-    def test_exported_dir_wins_and_is_inherited(self, monkeypatch):
+    def test_child_exported_dir_wins_and_is_inherited(self, monkeypatch):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/op_cache")
         out = testing.child_cache_env()
         # dir reaches the child via dict(os.environ); no duplicate key

@@ -137,8 +137,6 @@ def main():
                     "headline is the median of per-round ratios")
     args = ap.parse_args()
 
-    from apex1_tpu.testing import honor_jax_platforms_env
-    honor_jax_platforms_env()
     import jax
 
     from apex1_tpu.resilience import ResilientCheckpointer

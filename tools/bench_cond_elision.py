@@ -40,22 +40,6 @@ def _emit(record):
     print(json.dumps(record), flush=True)
 
 
-def _backend_is_cpu(timeout_s=120.0):
-    """Subprocess probe — see tools/bench_ring_ab.py for why the main
-    process must not initialize a backend before the mesh decision."""
-    import subprocess
-    code = ("import os, jax; p = os.environ.get('JAX_PLATFORMS'); "
-            "p and jax.config.update('jax_platforms', p); "
-            "print('BACKEND=' + jax.default_backend())")
-    try:
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True,
-                             timeout=timeout_s)
-        return "BACKEND=cpu" in out.stdout
-    except Exception:
-        return False
-
-
 def _timed(compiled, args, iters):
     import jax
     out = compiled(*args)
@@ -182,14 +166,14 @@ def main():
 
     import jax
 
-    plat = os.environ.get("JAX_PLATFORMS", "").strip()
-    on_cpu = plat == "cpu" if plat else _backend_is_cpu()
+    # JAX_PLATFORMS=cpu rehearses on the 8-device virtual mesh (the
+    # device-count flag only acts before first backend init); otherwise
+    # THIS process initialises the default backend — one process per
+    # chip, so no probing child
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
     if on_cpu:
         from apex1_tpu.testing import force_virtual_cpu_devices
         force_virtual_cpu_devices(8)
-    else:
-        from apex1_tpu.testing import honor_jax_platforms_env
-        honor_jax_platforms_env()
     from apex1_tpu.testing import enable_persistent_compilation_cache
     enable_persistent_compilation_cache()
 

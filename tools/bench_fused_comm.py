@@ -21,12 +21,10 @@ Legs, timed fwd+bwd over a tp/cp ring:
    tool also checks its output against the ppermute form and reports
    the max abs diff in the record — the hardware-window parity drill).
 
-Device requirements: a ring needs >= 2 devices; single-chip windows
-emit a skip record (rc 0 — the queue must keep moving). On CPU the
-8-device virtual mesh auto-builds and shapes shrink (command-line
-rehearsal; timings meaningless, plumbing validated). Queued as
-``fused_comm_ab`` in tools/tpu_watch.sh AHEAD of the llama_longctx
-re-bench.
+Device requirements: a ring needs >= 2 devices; a single chip emits
+a skip record. With JAX_PLATFORMS=cpu the 8-device virtual mesh builds
+and shapes shrink (command-line rehearsal; timings meaningless,
+plumbing validated).
 
 Usage: python tools/bench_fused_comm.py [--n N] [--iters K] [--rdma]
 """
@@ -45,23 +43,6 @@ def _emit(record):
     print(json.dumps(record), flush=True)
 
 
-def _backend_is_cpu(timeout_s=120.0):
-    """Subprocess backend probe — see tools/bench_ring_ab.py (the main
-    process must not initialize a backend before deciding whether to
-    build the virtual CPU mesh)."""
-    import subprocess
-    code = ("import os, jax; p = os.environ.get('JAX_PLATFORMS'); "
-            "p and jax.config.update('jax_platforms', p); "
-            "print('BACKEND=' + jax.default_backend())")
-    try:
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True,
-                             timeout=timeout_s)
-        return "BACKEND=cpu" in out.stdout
-    except Exception:
-        return False
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=None,
@@ -72,14 +53,14 @@ def main():
                          "RDMA reduce-scatter (accelerator only)")
     args = ap.parse_args()
 
-    plat = os.environ.get("JAX_PLATFORMS", "").strip()
-    on_cpu = plat == "cpu" if plat else _backend_is_cpu()
+    # JAX_PLATFORMS=cpu rehearses on the 8-device virtual mesh (the
+    # device-count flag only acts before first backend init); otherwise
+    # THIS process initialises the default backend — one process per
+    # chip, so no probing child
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
     if on_cpu:
         from apex1_tpu.testing import force_virtual_cpu_devices
         force_virtual_cpu_devices(8)
-    else:
-        from apex1_tpu.testing import honor_jax_platforms_env
-        honor_jax_platforms_env()
     from apex1_tpu.testing import enable_persistent_compilation_cache
     enable_persistent_compilation_cache()
 

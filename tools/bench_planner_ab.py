@@ -2,8 +2,7 @@
 the wall-clock form of ROADMAP item 1's acceptance contract (the
 pricing form is pinned in tier-1 by tests/test_planner.py).
 
-Two legs, banked to one log (tee this under tpu_watch as
-``planner_ab``; the queue entry writes perf_results/bench_planner_ab.log):
+Two legs:
 
 1. PRICING (runs anywhere, no devices needed): for each banked bench
    shape (gpt2, llama_longctx, the llama-8B projection) price the
@@ -34,23 +33,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def _emit(record):
     print(json.dumps(record), flush=True)
-
-
-def _backend_is_cpu(timeout_s=120.0):
-    """Subprocess backend probe (same contract as bench_ring_ab: the
-    main process must not init a backend before the virtual-mesh
-    decision)."""
-    import subprocess
-    code = ("import os, jax; p = os.environ.get('JAX_PLATFORMS'); "
-            "p and jax.config.update('jax_platforms', p); "
-            "print('BACKEND=' + jax.default_backend())")
-    try:
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True,
-                             timeout=timeout_s)
-        return "BACKEND=cpu" in out.stdout
-    except Exception:
-        return False
 
 
 #: the hand-tuned comparators the pricing leg scores against — the
@@ -216,14 +198,14 @@ def main():
         return 0 if worst <= 1.10 else 1
 
     print("== planner_ab measured (live mesh) ==", flush=True)
-    plat = os.environ.get("JAX_PLATFORMS", "").strip()
-    on_cpu = plat == "cpu" if plat else _backend_is_cpu()
+    # JAX_PLATFORMS=cpu rehearses on the 8-device virtual mesh (the
+    # device-count flag only acts before first backend init); otherwise
+    # THIS process initialises the default backend — one process per
+    # chip, so no probing child
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
     if on_cpu:
         from apex1_tpu.testing import force_virtual_cpu_devices
         force_virtual_cpu_devices(8)
-    else:
-        from apex1_tpu.testing import honor_jax_platforms_env
-        honor_jax_platforms_env()
     from apex1_tpu.testing import enable_persistent_compilation_cache
     enable_persistent_compilation_cache()
     measured_leg(args.iters or (2 if on_cpu else 6))

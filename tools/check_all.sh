@@ -24,8 +24,6 @@ echo "== graftlint protocols (APX3xx: bounded exhaustive model check of the sche
 python tools/lint.py --protocols
 echo "== tuning tables (parse + per-capability VMEM-budget validity) =="
 python tools/tune_kernels.py --validate
-echo "== drift gate (calibrated_ratio bands + re-fit drift over the banked perf_results corpus; jax-free, fail-closed) =="
-python tools/check_drift.py
 echo "== chaos smoke (injected-NaN rollback + corrupt-ckpt fallback, CPU) =="
 JAX_PLATFORMS=cpu python -m apex1_tpu.testing.chaos --smoke
 echo "== serving chaos smoke (replica-kill token parity + poison quarantine, CPU) =="

@@ -57,7 +57,7 @@ REFIT_TOL = 0.05
 
 def _import_calibrate():
     """Import ``apex1_tpu.obs.calibrate`` without executing the
-    package ``__init__`` (which imports jax for the compat bridge) —
+    package ``__init__`` (which imports jax) —
     the lint.py stub-parent recipe. ``apex1_tpu.core`` gets the same
     stub so the lazy capability lookups inside calibrate stay
     jax-free (explicit generation ⇒ no chip detection)."""

@@ -32,10 +32,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from apex1_tpu.testing import (enable_persistent_compilation_cache,
-                                   honor_jax_platforms_env)
+    from apex1_tpu.testing import enable_persistent_compilation_cache
 
-    honor_jax_platforms_env()
     enable_persistent_compilation_cache()
     backend = jax.default_backend()
     if backend == "cpu":        # smoke-test the harness only

@@ -39,7 +39,7 @@ CACHE_PATH = os.path.join(REPO, ".graftlint_cache")
 
 def _import_lint():
     """Import ``apex1_tpu.lint`` WITHOUT executing the package
-    ``__init__`` (which imports jax to install the compat bridge —
+    ``__init__`` (which imports jax —
     ~4s of startup the stdlib-ast linter doesn't need). A stub parent
     module with the real ``__path__`` lets the import machinery find
     the subpackage while skipping the parent's body. ``apex1_tpu.core``

@@ -85,8 +85,6 @@ def main():
     def analyze(name, fn, *a):
         c = jax.jit(jax.value_and_grad(fn)).lower(*a).compile()
         cost = c.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
         mem = c.memory_analysis()
         fl = float(cost.get("flops", float("nan")))
         print(f"{name:34s} flops {fl/1e9:8.2f} G   "

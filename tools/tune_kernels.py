@@ -796,14 +796,12 @@ def main():
         problems = validate(print)
         sys.exit(1 if problems else 0)
 
-    if args.backend:
-        os.environ["JAX_PLATFORMS"] = args.backend
-    from apex1_tpu.testing import (enable_persistent_compilation_cache,
-                                   honor_jax_platforms_env)
-    honor_jax_platforms_env()
-    enable_persistent_compilation_cache()
-
     import jax
+
+    if args.backend:
+        jax.config.update("jax_platforms", args.backend)
+    from apex1_tpu.testing import enable_persistent_compilation_cache
+    enable_persistent_compilation_cache()
     backend = jax.default_backend()
     names = sorted(CASES) if args.kernel == "all" else [args.kernel]
     iters = args.iters or (2 if backend == "cpu" else 20)
