@@ -40,6 +40,9 @@ from typing import Dict, List, Optional, Tuple
 from apex1_tpu.lint.project import FunctionInfo, ModuleSource, Project
 
 PALLAS_CALL = "jax.experimental.pallas.pallas_call"
+#: call sites: the primitive, and the repo's naming wrapper around it
+#: (same signature; `name=` is metadata)
+PALLAS_CALLS = (PALLAS_CALL, "apex1_tpu.ops._common.kernel_call")
 PL = "jax.experimental.pallas"
 PLTPU = "jax.experimental.pallas.tpu"
 
@@ -259,7 +262,7 @@ def pallas_sites(project: Project) -> List[PallasSite]:
         for node in ast.walk(info.node):
             if isinstance(node, ast.Call) and (
                     project.resolve_dotted(mod, node.func)
-                    == PALLAS_CALL):
+                    in PALLAS_CALLS):
                 prev = best.get(id(node))
                 if prev is None or len(info.scope) > prev[0]:
                     best[id(node)] = (len(info.scope), mod, info, node)

@@ -41,6 +41,7 @@ TRACE_ENTRIES = frozenset({
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",
     "jax.experimental.pallas.pallas_call",
+    "apex1_tpu.ops._common.kernel_call",
     "flax.linen.remat", "flax.linen.jit", "flax.linen.scan",
 })
 

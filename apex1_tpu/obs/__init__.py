@@ -1,28 +1,28 @@
-"""Observability subsystem — the measurement flywheel (ROADMAP item 5).
+"""Observability subsystem: what the program records about itself.
 
-Three cooperating layers, each usable alone:
+- `spine` — the ONE span recorder and the one run-scoped event schema.
+  `spine.span(name, req=, wait=, **counts)` appends a record (id, parent,
+  name, start/end on `spine.monotonic` in ns, request id, wait flag,
+  counts) to a bounded in-memory buffer that is always on, and enters
+  ``jax.profiler.TraceAnnotation(name)``, so the same span lies in a
+  profiler trace on the device's clock whenever one is being taken.
+  `spine.snapshot()` returns the buffer. ``APEX1_OBS_DIR`` is the one
+  sink: with it set, counters and events (`MetricsLogger`,
+  `ServingMetrics`, the resilience sentinel, the elastic episode) are
+  appended to one JSONL file per process as they happen, and the spans
+  of the buffer are written there when the run closes.
+- `xspace` — reads the ``*.xplane.pb`` traces ``jax.profiler`` writes,
+  with no dependency: per device the "XLA Ops" line alone, busy = the
+  union of its intervals and idle = window − busy, a ``custom-call``
+  keyed by its instruction name (the kernel, as `ops._common.kernel_call`
+  named it), idle gaps put down to the innermost program span over their
+  midpoint. ``tools/trace_report.py`` prints it.
+- `calibrate` — fits correction factors from banked (predicted,
+  measured) pairs; the repo ships no corpus, so every consumer prices
+  "uncalibrated" (ROADMAP D1 removes it with the model it corrects).
 
-- `spine` — ONE run-scoped telemetry schema (spans, counters, gauges,
-  events) banked as JSONL. `bench.timed_steps`, the examples' training
-  loops (via `utils.observability.MetricsLogger`), `tools/tune_kernels`
-  sweeps, `serving.ServingMetrics`, and the resilience sentinel all emit
-  through it, so one run's records JOIN across subsystems instead of
-  each inventing a JSON shape. Activated by ``APEX1_OBS_DIR``; inert
-  (zero I/O) otherwise.
-- `xspace` — dependency-free parser for the ``*.xplane.pb`` traces
-  ``jax.profiler.trace`` writes, with per-op device-time aggregation
-  and Pallas-kernel / collective / XLA-op bucketing. The engine behind
-  ``tools/trace_report.py``: any banked ``profile_artifact`` becomes a
-  per-op breakdown persisted next to the record. CPU-rehearsable —
-  ``jax.profiler.trace`` works on the CPU backend.
-- `calibrate` — fits per-config / per-kernel correction factors from
-  the accumulated (predicted, measured) pairs across banked bench logs
-  and tuning tables, and feeds them back into
-  ``bench._attach_roofline`` / ``tools/predict_perf.py`` so roofline
-  ratios price what silicon actually did (CPU-proxy pairs are labelled
-  and never applied to on-silicon predictions).
-
-See docs/observability.md for the schema and contracts.
+See docs/observability.md for the span names, the schema and how to
+read a chip trace.
 """
 
 from apex1_tpu.obs import calibrate, spine, xspace  # noqa: F401

@@ -1,14 +1,15 @@
 """``python -m apex1_tpu.obs --smoke`` — the check_all ``== obs smoke ==``
-gate: exercise the whole measurement flywheel on the CPU backend.
+gate: exercise spine, trace reader and calibration on the CPU backend.
 
-1. spine: open a run in a temp dir, emit a span/counter/event, read the
-   file back through `read_events` — schema round-trip.
+1. spine: open a run in a temp dir, emit a counter and an event, time a
+   span, read the file back through `read_events` — schema round-trip
+   (the span reaches the file when the run closes).
 2. trace -> report: capture a REAL ``jax.profiler.trace`` of one tiny
    jitted step, parse the xplane files with the dependency-free parser,
    build + persist the per-op report, assert it attributed ops.
 3. calibrate: fit factors from the repo's banked corpus (bench logs +
-   tuning tables) and assert the fit is non-empty — the flywheel stays
-   verified with no hardware attached.
+   tuning tables) and assert the fit is non-empty — verified with no
+   hardware attached.
 
 Everything runs in a few seconds; failures exit non-zero with the
 failing stage named.
@@ -27,14 +28,14 @@ def smoke() -> int:
     # -- 1. spine round-trip ----------------------------------------------
     with tempfile.TemporaryDirectory(prefix="obs_smoke_") as tmp:
         with spine.ObsRun(dir=tmp, component="obs_smoke") as run:
-            with run.span("smoke.step", iters=1):
+            with spine.span("smoke/step", iters=1):
                 pass
             run.counter("smoke.count", 2)
             run.event("smoke.note", detail="hello")
             path = run.path
         events = spine.read_events(path)
         kinds = [e["kind"] for e in events]
-        assert kinds == ["run", "span", "counter", "event"], kinds
+        assert kinds == ["run", "counter", "event", "span"], kinds
         assert events[0]["schema"] == spine.SCHEMA
         print(f"spine OK: {len(events)} events round-tripped", flush=True)
 
@@ -79,7 +80,7 @@ def smoke() -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="run the flywheel smoke (check_all gate)")
+                    help="run the obs smoke (check_all gate)")
     args = ap.parse_args(argv)
     if args.smoke:
         return smoke()

@@ -15,33 +15,41 @@ The spine fixes the SCHEMA and the SINK:
 
 - Every following line is one event::
 
-    {"kind": "span",    "name": ..., "t": <s since t0>, "dur_s": ...}
-    {"kind": "counter", "name": ..., "t": ..., "value": <cumulative>}
-    {"kind": "gauge",   "name": ..., "t": ..., "value": <sample>}
+    {"kind": "counter", "name": ..., "t": <s since t0>, "value": ...}
     {"kind": "event",   "name": ..., "t": ..., **fields}
+    {"kind": "span",    "name": ..., "t": ..., "dur_s": ..., "id": ...,
+     "parent": <id or null>, "req": ..., "wait": true, **counts}
 
   Extra keyword fields ride along verbatim (JSON-safe scalars only —
   the emitter does not fetch device arrays; callers hand host scalars).
 
-Durability contract: events are APPENDED and flushed per line, so a
-crash keeps every line that printed and at most the LAST line can be
-torn (`read_events` skips unparseable lines). Derived artifacts (trace
-reports, calibration tables) use `resilience.manifest.atomic_write_json`
-instead — those are rewritten whole, so the atomic form is the right
-one there; a streaming event log must not lose its history to a crash
-before an atomic commit point.
+Spans are the ONE way the program names a region of host time. `span`
+appends one record to a bounded in-memory buffer (`SPAN_CAPACITY`, the
+oldest dropped; always on: there is no switch) and enters
+``jax.profiler.TraceAnnotation(name)``, so the same span lies in the
+profiler's host plane, on the device trace's clock, whenever a trace is
+being taken. `snapshot` returns the buffer; a run writes the spans that
+began during it as ``span`` lines when it CLOSES (in memory first,
+written at the end — a span costs two clock reads and an append, never
+a write).
+
+Durability contract: counters and events are APPENDED and flushed per
+line, so a crash keeps every line that printed and at most the LAST
+line can be torn (`read_events` skips unparseable lines); spans reach
+the file at `close` only. Derived artifacts (trace reports, calibration
+tables) use `resilience.manifest.atomic_write_json` instead — those are
+rewritten whole.
 
 Activation: the module-level `emit`/`default_run` helpers are inert
-(no file, no I/O beyond one getenv) until ``APEX1_OBS_DIR`` is set —
-instrumented hot paths cost a dict lookup when observability is off.
-`StopWatch` is the ONE host-side wall-clock timing primitive; the
-`utils.observability.Timers` surface, `serving.metrics` wall-clock
-handling, and `bench.timed_steps` all sit on it.
+(no file, no I/O beyond one getenv) until ``APEX1_OBS_DIR`` is set.
+`StopWatch` is the ONE cumulative wall-clock timer; the
+`utils.observability.Timers` surface and `bench.timed_steps` sit on it.
 """
 
 from __future__ import annotations
 
-import contextlib
+import atexit
+import collections
 import itertools
 import json
 import os
@@ -51,12 +59,19 @@ import threading
 import time
 from typing import Any, Optional
 
+from jax.profiler import TraceAnnotation   # the package imports jax anyway
+
 SCHEMA = "apex1-obs-v1"
 
-#: event kinds the schema admits (plus the "run" header line)
-KINDS = ("span", "counter", "gauge", "event")
+#: event kinds `emit` admits; a run adds the "run" header line and, at
+#: `close`, the "span" lines of the buffer
+KINDS = ("counter", "event")
 
 monotonic = time.monotonic   # the ONE clock origin helper (see ObsRun)
+monotonic_ns = time.monotonic_ns   # the same clock, as spans stamp it
+
+#: span records the process keeps; the oldest is dropped for the newest
+SPAN_CAPACITY = 1 << 15
 
 
 def obs_dir() -> Optional[str]:
@@ -102,6 +117,77 @@ class StopWatch:
         return e
 
 
+# -- spans: the one recorder ------------------------------------------------
+
+_SPANS: collections.deque = collections.deque(maxlen=SPAN_CAPACITY)
+_SPAN_IDS = itertools.count(1)
+_OPEN = threading.local()      # .stack: this thread's open spans
+
+
+class Span:
+    """One record of the buffer, and the context manager that fills it.
+    ``parent`` is the id of the span open around it in the SAME thread;
+    ``wait`` marks time the host spent blocked on the device; ``counts``
+    may be set until the span closes (a step knows what it did at its
+    end). `start_ns`/`end_ns` are `monotonic_ns`."""
+
+    __slots__ = ("id", "parent", "name", "start_ns", "end_ns", "req",
+                 "wait", "counts", "_ann")
+
+    def __init__(self, name: str, req=None, wait: bool = False,
+                 counts: Optional[dict] = None):
+        self.id = next(_SPAN_IDS)
+        self.parent = None
+        self.name = name
+        self.start_ns = self.end_ns = 0
+        self.req = req
+        self.wait = wait
+        self.counts = counts or {}
+
+    def __enter__(self) -> "Span":
+        try:
+            stack = _OPEN.stack
+        except AttributeError:
+            stack = _OPEN.stack = []
+        if stack:
+            self.parent = stack[-1].id
+        stack.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.start_ns = monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = monotonic_ns()
+        self._ann.__exit__(*exc)
+        _OPEN.stack.pop()
+        _SPANS.append(self)
+        return False
+
+
+def span(name: str, req=None, wait: bool = False, **counts) -> Span:
+    """``with span("serving/step") as sp: ...; sp.counts["n"] = 3`` —
+    time the block as one record of the buffer and, when a profiler
+    trace is being taken, as a host event of the same name."""
+    return Span(name, req, wait, counts)
+
+
+def record_span(name: str, start_ns: int, end_ns: int, *, req=None,
+                **counts) -> Span:
+    """A span whose ends were stamped apart (the wait in a queue). It
+    has no parent and is not in the profiler's trace: nothing was open
+    around all of it."""
+    sp = Span(name, req, False, counts)
+    sp.start_ns, sp.end_ns = int(start_ns), int(end_ns)
+    _SPANS.append(sp)
+    return sp
+
+
+def snapshot() -> list:
+    """The buffer's records, in the order they closed."""
+    return list(_SPANS)
+
+
 def _component() -> str:
     base = os.path.basename(sys.argv[0] or "") or "python"
     base = re.sub(r"\.py$", "", base)
@@ -114,10 +200,10 @@ _RUN_SEQ = itertools.count()
 
 
 class ObsRun:
-    """One run's event sink. Thread-safe; every write is flushed so the
-    file tails live. Use as a context manager, or `close()` explicitly
-    (the file is also usable after the process dies mid-run — that is
-    the point)."""
+    """One run's event sink. Thread-safe; every counter and event is
+    flushed so the file tails live, and `close()` (or leaving the
+    ``with``) adds the spans that began during the run. The file is
+    usable after the process dies mid-run, less its spans."""
 
     def __init__(self, dir: Optional[str] = None, *,
                  run_id: Optional[str] = None,
@@ -136,7 +222,8 @@ class ObsRun:
             os.makedirs(d, exist_ok=True)
             path = os.path.join(d, self.run_id + ".jsonl")
         self.path = path
-        self._t0 = time.monotonic()
+        self._t0_ns = monotonic_ns()
+        self._t0 = self._t0_ns * 1e-9
         self._lock = threading.Lock()
         self._f = open(path, "a", encoding="utf-8")
         self._write({"schema": SCHEMA, "kind": "run", "run": self.run_id,
@@ -165,28 +252,30 @@ class ObsRun:
     def counter(self, name: str, value, **fields) -> None:
         self.emit("counter", name, value=value, **fields)
 
-    def gauge(self, name: str, value, **fields) -> None:
-        self.emit("gauge", name, value=value, **fields)
-
     def event(self, name: str, **fields) -> None:
         self.emit("event", name, **fields)
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, sync: Any = None, **attrs):
-        """Time the enclosed block as one span event. ``sync=tree``
-        blocks on the tree before stopping the clock (device work
-        attribution, same contract as `StopWatch.stop`)."""
-        t_rel = time.monotonic() - self._t0
-        sw = StopWatch().start()
-        try:
-            yield sw
-        finally:
-            dur = sw.stop(sync=sync)
-            self.emit("span", name, t=t_rel, dur_s=round(dur, 6), **attrs)
+    def _span_lines(self) -> str:
+        out = []
+        for sp in snapshot():
+            if sp.start_ns < self._t0_ns:
+                continue
+            rec = {"kind": "span", "name": sp.name,
+                   "t": round((sp.start_ns - self._t0_ns) * 1e-9, 6),
+                   "dur_s": round((sp.end_ns - sp.start_ns) * 1e-9, 6),
+                   "id": sp.id, "parent": sp.parent}
+            if sp.req is not None:
+                rec["req"] = sp.req
+            if sp.wait:
+                rec["wait"] = True
+            rec.update(sp.counts)
+            out.append(json.dumps(rec) + "\n")
+        return "".join(out)
 
     def close(self) -> None:
         with self._lock:
             if not self._f.closed:
+                self._f.write(self._span_lines())
                 self._f.flush()
                 try:
                     os.fsync(self._f.fileno())
@@ -227,6 +316,7 @@ def default_run() -> Optional[ObsRun]:
         if d is not None:
             try:
                 run = ObsRun(dir=d)
+                atexit.register(run.close)   # its spans are written then
             except OSError:
                 run = None
         _DEFAULT.update(run=run, key=key)

@@ -16,8 +16,8 @@ Field numbers (tensorflow/tsl/profiler/protobuf/xplane.proto)::
     XPlane:  id = 1, name = 2, lines = 3, event_metadata = 4 (map)
     XLine:   id = 1, name = 2, timestamp_ns = 3, events = 4,
              display_name = 11
-    XEvent:  metadata_id = 1, offset_ps = 2, duration_ps = 3,
-             num_occurrences = 5
+    XEvent:  metadata_id = 1, offset_ps = 2 (from the line's
+             timestamp), duration_ps = 3, num_occurrences = 5
     XEventMetadata: id = 1, name = 2
 
 Every malformed input path (truncated varint, over-long length prefix,
@@ -28,8 +28,9 @@ from the middle of a byte walker (and never a silently-empty report).
 Attribution model:
 
 - **device rows** — on TPU/GPU traces, per-op events live on device
-  planes (name contains ``/device:`` or ``TPU``) in the "XLA Ops"
-  lines. On CPU-backend traces there is no device plane; the XLA
+  planes (``/device:...``) in the "XLA Ops" line, and ONLY that line
+  counts: "XLA Modules" (one event per executed program) and "Async
+  XLA Ops" cover the same time again. On CPU-backend traces there is no device plane; the XLA
   runtime's per-op events live on the host plane's
   ``tf_XLATfrtCpuClient/...`` executor lines instead, and the report
   is labelled ``plane_class: "host-xla-proxy"`` — op *shares* are
@@ -40,10 +41,24 @@ Attribution model:
   ``pallas`` (custom-call/Mosaic kernels — the HLO cost model's blind
   spot), or ``xla`` (everything else). Name-based and best-effort, the
   rules are in `bucket_of`.
+- **keys** — a ``custom-call`` is keyed by its instruction's name
+  (``%apex1_flash_fwd.3 = ... custom-call(...)`` → ``apex1_flash_fwd``:
+  the kernel, as `ops._common.kernel_call` named it, summed over its
+  call sites in the program); every other op by its event name.
+- **busy and idle** — events keep their offsets, so per device busy =
+  the union of the op intervals inside the window and idle = window −
+  busy (averaged over devices). The window is the host span
+  ``window_span`` when one is named and most of the device's ops fall
+  inside it, else the extent of the device's own ops. Each idle gap of
+  the first device is put down to the innermost PROGRAM span (a host
+  event named ``layer/region``, as `obs.spine.span` names them) over
+  its midpoint, else to ``host:other``.
 """
 
 from __future__ import annotations
 
+import bisect
+import collections
 import dataclasses
 import glob
 import gzip
@@ -62,6 +77,13 @@ _COLLECTIVE_RE = re.compile(
     r"all-to-all|collective-broadcast|ppermute|send|recv)\b", re.I)
 _PALLAS_RE = re.compile(
     r"(custom-call|custom_call|tpu_custom_call|pallas|mosaic)", re.I)
+#: the opcode of an HLO instruction's text after " = ": the first word
+#: followed by "(" that comes after the result shape's closing bracket
+_OPCODE_RE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
+_CUSTOM_CALL_RE = re.compile(r"^%?(.+?)(?:\.\d+)? = .* custom-call\(")
+#: a host event that is a span of the program: `layer/region`
+_SPAN_RE = re.compile(r"^[A-Za-z0-9_]+/[A-Za-z0-9_./]+$")
+_MIN_GAP_NS = 2_000.0
 
 
 class TraceError(RuntimeError):
@@ -129,12 +151,14 @@ class Event:
     metadata_id: int
     duration_ps: int
     occurrences: int        # num_occurrences when aggregated, else 1
+    offset_ps: int = 0      # start, from the line's timestamp_ns
 
 
 @dataclasses.dataclass
 class Line:
     name: str
     events: list            # [Event]
+    timestamp_ns: int = 0
 
 
 @dataclasses.dataclass
@@ -145,29 +169,35 @@ class Plane:
 
 
 def _parse_event(buf: bytes) -> Event:
-    mid = dur = 0
+    mid = dur = off = 0
     occ = 1
     for fno, wt, val in _fields(buf):
         if wt != 0:
             continue
         if fno == 1:
             mid = val
+        elif fno == 2:
+            off = val
         elif fno == 3:
             dur = val
         elif fno == 5:
             occ = val
-    return Event(metadata_id=mid, duration_ps=dur, occurrences=occ)
+    return Event(metadata_id=mid, duration_ps=dur, occurrences=occ,
+                 offset_ps=off)
 
 
 def _parse_line(buf: bytes) -> Line:
     name = ""
     events = []
+    t0 = 0
     for fno, wt, val in _fields(buf):
         if fno == 2 and wt == 2:
             name = val.decode("utf-8", "replace")
+        elif fno == 3 and wt == 0:
+            t0 = val
         elif fno == 4 and wt == 2:
             events.append(_parse_event(val))
-    return Line(name=name, events=events)
+    return Line(name=name, events=events, timestamp_ns=t0)
 
 
 def _parse_emeta_entry(buf: bytes) -> tuple[int, str]:
@@ -241,7 +271,14 @@ def bucket_of(op_name: str) -> str:
     Name-based, best-effort: collectives first (a fused
     collective-permute must read as ICI time even if spelled inside a
     custom call wrapper), then the custom-call/Mosaic family, then
-    everything else."""
+    everything else. On a chip the name is the whole HLO instruction
+    (``%x = shape opcode(operands), attributes``): only its name and
+    opcode are looked at, since operands and attributes name OTHER
+    instructions (a fusion fed by ``%custom-call.3`` is not a kernel)."""
+    head, eq, rest = op_name.partition(" = ")
+    if eq:
+        m = _OPCODE_RE.search(rest)
+        op_name = f"{head} {m.group(1) if m else ''}"
     if _COLLECTIVE_RE.search(op_name):
         return "collective"
     if _PALLAS_RE.search(op_name):
@@ -249,25 +286,34 @@ def bucket_of(op_name: str) -> str:
     return "xla"
 
 
+def op_key(op_name: str) -> str:
+    """What an op's time is summed under: a ``custom-call``'s
+    instruction name (the kernel), any other op's event name."""
+    m = _CUSTOM_CALL_RE.match(op_name)
+    return m.group(1) if m else op_name
+
+
 def _is_device_plane(name: str) -> bool:
-    return "/device:" in name or "TPU" in name or "gpu" in name.lower()
+    return name.startswith("/device:")
 
 
 def _is_op_line(line_name: str, *, device: bool) -> bool:
     if device:
-        return "XLA Ops" in line_name or "XLA Op" in line_name \
-            or line_name.startswith("XLA")
+        return line_name == "XLA Ops"
     # CPU backend: the XLA executor threads carry the per-op events
     return line_name.startswith("tf_XLA")
 
 
-def op_totals(planes: list) -> tuple[dict, str]:
-    """Aggregate per-op ``{name: [total_ps, count]}`` over the op lines.
-    Returns ``(totals, plane_class)`` where plane_class is ``"device"``
-    (real accelerator planes) or ``"host-xla-proxy"`` (CPU backend —
-    shares meaningful, absolute times are host wall-clock)."""
+def _op_events(planes: list) -> tuple[list, str]:
+    """``[(plane name, [(op name, start_ns, dur_ns, occurrences,
+    dur_ps)])]`` per device and the plane class (times in whole
+    nanoseconds, as the profiler's own reader gives them; ``dur_ps`` is
+    what the totals sum): ``"device"`` (accelerator planes,
+    "XLA Ops" alone) or ``"host-xla-proxy"`` (CPU backend: the executor
+    threads together stand for one device — shares meaningful, absolute
+    times are host wall-clock)."""
     for device in (True, False):
-        totals: dict[str, list] = {}
+        per = collections.defaultdict(list)
         for plane in planes:
             if _is_device_plane(plane.name) != device:
                 continue
@@ -275,51 +321,161 @@ def op_totals(planes: list) -> tuple[dict, str]:
                 if not _is_op_line(line.name, device=device):
                     continue
                 for ev in line.events:
-                    name = plane.event_names.get(
-                        ev.metadata_id, str(ev.metadata_id))
-                    a = totals.setdefault(name, [0, 0])
-                    a[0] += ev.duration_ps
-                    a[1] += max(int(ev.occurrences), 1)
-        if totals:
-            return totals, ("device" if device else "host-xla-proxy")
-    return {}, "none"
+                    per[plane.name if device else "host"].append((
+                        plane.event_names.get(ev.metadata_id,
+                                              str(ev.metadata_id)),
+                        line.timestamp_ns + ev.offset_ps // 1000,
+                        ev.duration_ps // 1000,
+                        max(int(ev.occurrences), 1), ev.duration_ps))
+        if per:
+            return sorted(per.items()), ("device" if device
+                                         else "host-xla-proxy")
+    return [], "none"
+
+
+def op_totals(planes: list) -> tuple[dict, str]:
+    """Aggregate per-op ``{key: [total_ps, count]}`` over the op lines
+    (`op_key`). Returns ``(totals, plane_class)``."""
+    devices, plane_class = _op_events(planes)
+    return _totals(ev for _, evs in devices for ev in evs), plane_class
+
+
+def _totals(events) -> dict:
+    totals: dict[str, list] = {}
+    for name, _start, _dur, n, dur_ps in events:
+        a = totals.setdefault(name, [0, 0])
+        a[0] += dur_ps
+        a[1] += n
+    return totals
+
+
+def _host_spans(planes: list) -> dict:
+    """``{name: [(start_ns, end_ns)]}`` of the host planes' program
+    spans (and of any other host event: the window span is looked up
+    here too)."""
+    out = collections.defaultdict(list)
+    for plane in planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                start = line.timestamp_ns + ev.offset_ps // 1000
+                out[plane.event_names.get(ev.metadata_id, "")].append(
+                    (start, start + ev.duration_ps // 1000))
+    return out
+
+
+def _union(intervals, lo, hi) -> list:
+    """Merged, sorted copy of the intervals clipped to [lo, hi]."""
+    out = []
+    for s, e in sorted((max(s, lo), min(e, hi)) for s, e in intervals
+                       if e > lo and s < hi):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _innermost(spans: dict, t: float) -> str:
+    best = None
+    for name, (starts, ivs) in spans.items():
+        i = bisect.bisect_right(starts, t) - 1
+        if i >= 0 and ivs[i][1] >= t:
+            dur = ivs[i][1] - ivs[i][0]
+            if best is None or dur < best[0]:
+                best = (dur, name)
+    return best[1] if best else "host:other"
 
 
 def build_report(trace_dir: str | os.PathLike, *,
                  steps: Optional[int] = None,
-                 top: int = 200) -> dict:
-    """Per-op device-time breakdown for one banked trace directory.
+                 top: int = 200,
+                 window_span: Optional[str] = None) -> dict:
+    """Per-op device-time breakdown, busy and idle time, and the idle
+    gaps by program span, for one banked trace directory (or one
+    ``*.xplane.pb[.gz]`` file).
 
     Raises `TraceError` when the dir holds no xplane files, none
     parses, or no op events were found (an empty report would read as
     "nothing ran" when the truth is "nothing was attributable")."""
     trace_dir = os.fspath(trace_dir)
-    paths = find_xplane_files(trace_dir)
+    paths = ([trace_dir] if os.path.isfile(trace_dir)
+             else find_xplane_files(trace_dir))
     if not paths:
         raise TraceError(trace_dir, "no *.xplane.pb files under dir")
     planes = []
     for p in paths:
         planes += parse_xspace(p)
-    totals, plane_class = op_totals(planes)
-    if not totals:
+    devices, plane_class = _op_events(planes)
+    if not devices:
         lines = sorted({(pl.name, ln.name)
                         for pl in planes for ln in pl.lines})
         raise TraceError(
             trace_dir, "no per-op events on any known op line; "
             f"planes/lines seen: {lines[:12]}")
+    host = _host_spans(planes)
+
+    # per device: the window, the ops inside it, busy = their union
+    busy_ns, windows, inside = [], [], []
+    for _, evs in devices:
+        lo = min(ev[1] for ev in evs)
+        hi = max(ev[1] + ev[2] for ev in evs)
+        if window_span and host.get(window_span):
+            w_lo, w_hi = host[window_span][0]
+            n_in = sum(1 for ev in evs
+                       if ev[1] >= w_lo and ev[1] + ev[2] <= w_hi)
+            if n_in >= 0.5 * len(evs):
+                lo, hi = w_lo, w_hi
+        evs = [ev for ev in evs if ev[1] + ev[2] > lo and ev[1] < hi]
+        windows.append((lo, hi))
+        inside.append(evs)
+        busy_ns.append(sum(e - s for s, e in _union(
+            [(ev[1], ev[1] + ev[2]) for ev in evs], lo, hi)))
+    busy_s = sum(busy_ns) / len(busy_ns) * 1e-9
+    window_s = (windows[0][1] - windows[0][0]) * 1e-9
+
+    # idle gaps of the first device, by the innermost program span
+    lo, hi = windows[0]
+    spans = {name: ([s for s, _ in ivs], ivs)
+             for name, ivs in ((n, sorted(v)) for n, v in host.items()
+                               if _SPAN_RE.match(n))}
+    gaps = collections.defaultdict(float)
+    edges = [lo] + [t for iv in _union(
+        [(ev[1], ev[1] + ev[2]) for ev in inside[0]], lo, hi)
+        for t in iv] + [hi]
+    for a, b in zip(edges[0::2], edges[1::2]):
+        if b - a >= _MIN_GAP_NS:
+            gaps[_innermost(spans, 0.5 * (a + b))] += (b - a) * 1e-9
+
+    raw = _totals(ev for evs in inside for ev in evs)
+    totals: dict[str, list] = {}
+    bucket_by_key = {}
+    for name, (ps, n) in raw.items():
+        key = op_key(name)
+        a = totals.setdefault(key, [0, 0])
+        a[0] += ps
+        a[1] += n
+        bucket_by_key[key] = bucket_of(name)
     total_ps = sum(ps for ps, _n in totals.values())
     buckets = {b: 0 for b in BUCKETS}
     ops = []
-    for name, (ps, n) in sorted(totals.items(), key=lambda kv: -kv[1][0]):
-        b = bucket_of(name)
+    for key, (ps, n) in sorted(totals.items(), key=lambda kv: -kv[1][0]):
+        b = bucket_by_key[key]
         buckets[b] += ps
-        ops.append({"name": name, "bucket": b,
+        ops.append({"name": key, "bucket": b,
                     "ms": round(ps / 1e9, 6), "count": int(n),
                     "share": round(ps / total_ps, 4) if total_ps else 0.0})
     report = {
         "schema": REPORT_SCHEMA,
         "trace_dir": trace_dir,
         "plane_class": plane_class,
+        "n_devices": len(devices),
+        "window_s": window_s,
+        "busy_s": busy_s,
+        "idle_s": window_s - busy_s,
+        "idle_gaps": [[k, v] for k, v in sorted(
+            gaps.items(), key=lambda kv: -kv[1])],
         "total_op_ms": round(total_ps / 1e9, 6),
         "buckets": {b: {"ms": round(buckets[b] / 1e9, 6),
                         "share": (round(buckets[b] / total_ps, 4)
@@ -357,6 +513,12 @@ def format_report(report: dict, top: int = 25) -> str:
              f"total op time: {report['total_op_ms']:.3f} ms"
              + (f"   ({report['per_step_ms']:.3f} ms/step x "
                 f"{report['steps']})" if report.get("steps") else "")]
+    lines.append(
+        f"{report['n_devices']} device(s): busy {report['busy_s']:.6f} s, "
+        f"idle {report['idle_s']:.6f} s of {report['window_s']:.6f} s; "
+        "idle gaps: " + (", ".join(
+            f"{k} {v:.6f} s" for k, v in report["idle_gaps"][:8])
+            or "none"))
     bk = report["buckets"]
     lines.append("buckets: " + "  ".join(
         f"{b}={bk[b]['ms']:.3f}ms ({bk[b]['share'] * 100:.1f}%)"

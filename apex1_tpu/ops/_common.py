@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 _IMPL = "auto"  # "auto" | "pallas" | "xla"
 
@@ -96,6 +97,31 @@ def to_mosaic(*arrays):
     out = tuple(a if a is None or a.dtype == mosaic_dtype(a.dtype)
                 else a.astype(mosaic_dtype(a.dtype)) for a in arrays)
     return out[0] if len(out) == 1 else out
+
+
+#: what every kernel's name starts with on its compiled instruction
+KERNEL_PREFIX = "apex1_"
+
+
+def kernel_call(kernel, *, name: str, **kwargs):
+    """``pl.pallas_call`` under the name the kernel carries onto its
+    compiled instruction (``%apex1_<name>.N = ... custom-call(...)``) and
+    so into the device trace, where a bare call takes the innermost jax
+    scope's (``%layer0.7``, ``%transpose_jvp___.5``) and forward, dq and
+    dkv cannot be told apart. Every Pallas call of `ops/` goes through
+    here, each site under a name of its own (``tests/test_kernel_names``
+    pins both). Metadata only: the kernel, its blocks and its operands
+    are ``kwargs``, passed on as they are."""
+    call = pl.pallas_call(kernel, name=KERNEL_PREFIX + name, **kwargs)
+
+    def named(*args):
+        # a transform wraps the FIRST scope under it (`jvp(kernel)/
+        # apex1_x`): without this one it would wrap the kernel's own
+        # name where no module scope lies between (`jvp_apex1_x_`)
+        with jax.named_scope("kernel"):
+            return call(*args)
+
+    return named
 
 
 def out_struct(shape, dtype, *like):

@@ -41,9 +41,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (NEG_INF, interpret_mode,
-                                    out_struct, pad_to, to_mosaic,
-                                    use_pallas)
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   out_struct, pad_to, to_mosaic, use_pallas)
 from apex1_tpu.ops.stochastic import (attn_keep_mask, threshold_u32,
                                       tile_keep_mask)
 
@@ -602,11 +601,12 @@ def _flash_fwd_impl(q, k, v, qseg, kseg, q_off, k_off,
         in_specs += [_bias_spec(g, Bb, Hb)]
         args += [bp]
     Sqp = g["n_q"] * g["bq"]
-    out_p, lse_p = pl.pallas_call(
+    out_p, lse_p = kernel_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           true_sq=g["Sq"], true_sk=g["Sk"],
                           has_segs=has_segs, has_bias=has_bias,
                           n_k=g["n_k"], **_drop_kw(dropout_p, g)),
+        name="flash_fwd",
         grid=(g["B"], g["Hq"], g["n_q"], g["n_k"]),
         in_specs=in_specs,
         out_specs=(q_spec, stat_spec),
@@ -686,9 +686,10 @@ def _flash_bwd_impl(scale, causal, has_segs, block_q, block_k, res, cts,
     if has_bias:
         in_specs += [_bias_spec(g, Bb, Hb)]
         args += [bp]
-    dq = pl.pallas_call(
+    dq = kernel_call(
         functools.partial(_bwd_dq_kernel, n_k=g["n_k"],
                           has_bias=has_bias, **kern),
+        name="flash_dq",
         grid=(g["B"], g["Hq"], g["n_q"], g["n_k"]),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -714,9 +715,10 @@ def _flash_bwd_impl(scale, causal, has_segs, block_q, block_k, res, cts,
         in_specs += [_bias_spec(g, Bb, Hb, dkv=True)]
         args += [bp]
     Skp = g["n_k"] * g["bk"]
-    dk, dv = pl.pallas_call(
+    dk, dv = kernel_call(
         functools.partial(_bwd_dkv_kernel, n_q=g["n_q"], group=g["group"],
                           has_bias=has_bias, **kern),
+        name="flash_dkv",
         grid=(g["B"], g["Hkv"], g["n_k"], g["group"], g["n_q"]),
         in_specs=in_specs,
         out_specs=(dkv_spec, dkv_spec),
@@ -777,9 +779,10 @@ def _flash_bwd_impl(scale, causal, has_segs, block_q, block_k, res, cts,
             args += [qs, ks]
         in_specs += [bias_spec_b]
         args += [bp]
-        dbias_p = pl.pallas_call(
+        dbias_p = kernel_call(
             functools.partial(_dbias_kernel, n_r=n_r, **kern,
                               **({"rh": RH} if dropout_p > 0.0 else {})),
+            name="flash_dbias",
             grid=(Bb, Hb, g["n_q"], g["n_k"], n_r),
             in_specs=in_specs,
             out_specs=db_spec,

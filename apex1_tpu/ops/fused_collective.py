@@ -74,8 +74,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex1_tpu.core.mesh import AXIS_TP
-from apex1_tpu.ops._common import (NEG_INF, interpret_mode, out_struct,
-                                    pad_to, to_mosaic, use_pallas)
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   out_struct, pad_to, to_mosaic, use_pallas)
 from apex1_tpu.ops._common import vary as _vary
 
 _LANES = 128
@@ -155,8 +155,9 @@ def _chunk_matmul(rows, w, block_m=None, block_n=None):
     """
     if interpret_mode() and block_m is None and block_n is None:
         out_shape = rows.shape[:-1] + (w.shape[-1],)
-        return pl.pallas_call(
+        return kernel_call(
             _cm_whole_kernel,
+            name="chunk_matmul_whole",
             out_shape=out_struct(out_shape, jnp.float32, rows, w),
             interpret=True,
         )(rows, w)
@@ -174,8 +175,9 @@ def _chunk_matmul(rows, w, block_m=None, block_n=None):
     wp, _ = pad_to(w, 0, _LANES)
     wp, _ = pad_to(wp, 1, bn)
     n_m, n_n = xp.shape[0] // bm, wp.shape[1] // bn
-    out = pl.pallas_call(
+    out = kernel_call(
         _cm_tile_kernel,
+        name="chunk_matmul",
         grid=(n_m, n_n),
         in_specs=[pl.BlockSpec((bm, xp.shape[1]), lambda i, j: (i, 0),
                                memory_space=pltpu.VMEM),
@@ -493,10 +495,11 @@ def _agf_call(q, k, v, qseg, kseg, q_off, k_off, prev_out, prev_lse,
     in_specs += [pout_spec, stat_spec]
     args += [po, plse]
     Sqp = g["n_q"] * g["bq"]
-    out_p, lse_p = pl.pallas_call(
+    out_p, lse_p = kernel_call(
         functools.partial(_agf_kernel, scale=scale, causal=causal,
                           true_sq=g["Sq"], true_sk=g["Sk"],
                           has_segs=has_segs, n_k=g["n_k"]),
+        name="ring_flash_fold",
         grid=(g["B"], g["Hq"], g["n_q"], g["n_k"]),
         in_specs=in_specs,
         out_specs=(pout_spec, stat_spec),
@@ -880,8 +883,9 @@ def matmul_reduce_scatter_rdma(x, w, axis_name=AXIS_TP):
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR,
         ])
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_mrs_rdma_kernel, n=n, axis_name=axis_name),
+        name="matmul_reduce_scatter_rdma",
         grid_spec=grid_spec,
         out_shape=out_struct((chunk, N), jnp.float32, x, w),
         compiler_params=pltpu.CompilerParams(

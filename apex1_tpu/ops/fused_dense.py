@@ -43,8 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (
-    interpret_mode, out_struct, pad_to, to_mosaic, use_pallas)
+from apex1_tpu.ops._common import (interpret_mode, kernel_call, out_struct,
+                                   pad_to, to_mosaic, use_pallas)
 
 _LANES = 128
 
@@ -210,8 +210,9 @@ def _glu_call(x2, wg, wu, activation, bt, bf):
     wup, _ = pad_to(wum, 0, Hp)
     wup, _ = pad_to(wup, 1, bf)
     Tp, Fp = xp.shape[0], wgp.shape[1]
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_glu_kernel, activation=activation),
+        name="glu",
         grid=(Tp // bt, Fp // bf),
         in_specs=[
             pl.BlockSpec((bt, Hp), lambda i, j: (i, 0),

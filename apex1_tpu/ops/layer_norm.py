@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (as_rows, interpret_mode, mosaic_dtype,
-                                   out_struct, pad_to, to_mosaic,
+from apex1_tpu.ops._common import (as_rows, interpret_mode, kernel_call,
+                                   mosaic_dtype, out_struct, pad_to, to_mosaic,
                                    use_pallas)
 from apex1_tpu.tuning import tuned_row_block
 
@@ -120,8 +120,9 @@ def _pallas_fwd(x2, gamma2, beta2, eps, true_h, rms, br):
                 xr, gr, None, yr, mr, rr, **kw),
             eps=eps, true_h=true_h, rms=rms)
         in_specs, args = [row, vec], (x2, gamma2)
-    return pl.pallas_call(
+    return kernel_call(
         kernel,
+        name="layer_norm_fwd",
         grid=(pl.cdiv(rows, br),),
         in_specs=in_specs,
         out_specs=(row, stat, stat),
@@ -149,8 +150,9 @@ def _pallas_bwd(x2, gamma2, mean, rstd, dy2, true_h, rms, with_beta, br):
         out_specs = (row, vec)
         out_shape = (out_struct((rows, h), x2.dtype, x2, gamma2, dy2),
                      out_struct((1, h), jnp.float32, x2, gamma2, dy2))
-    return pl.pallas_call(
+    return kernel_call(
         kernel,
+        name="layer_norm_bwd",
         grid=(pl.cdiv(rows, br),),
         in_specs=[row, vec, stat, stat, row],
         out_specs=out_specs,

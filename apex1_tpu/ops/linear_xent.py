@@ -38,9 +38,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (NEG_INF, interpret_mode, mosaic_dtype,
-                                    out_struct, pad_to, to_mosaic,
-                                    use_pallas)
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   mosaic_dtype, out_struct, pad_to, to_mosaic,
+                                   use_pallas)
 
 _LANES = 128
 
@@ -283,10 +283,11 @@ def shard_stats(x2, w_shard, t2, *, col_offset=0, num_classes=None,
     k = num_classes if num_classes is not None else g["V"]
     x_spec, w_spec, stat_spec, off_spec = _specs(g)
     Tp = g["n_t"] * g["bt"]
-    outs = pl.pallas_call(
+    outs = kernel_call(
         functools.partial(_fwd_kernel, smoothing=0.0, true_k=k,
                           true_v=g["V"], padding_idx=None, bv=g["bv"],
                           n_v=g["n_v"], emit_stats=True),
+        name="linear_xent_stats",
         grid=(g["n_t"], g["n_v"]),
         in_specs=[x_spec, w_spec, stat_spec, off_spec],
         out_specs=(stat_spec,) * 4,
@@ -313,10 +314,11 @@ def shard_stats_packed(x2, w_shard, t2, *, col_offset=0, num_classes=None,
     pk_spec = pl.BlockSpec((g["bt"], 4), lambda i0, i1: (i0, 0),
                            memory_space=pltpu.VMEM)
     Tp = g["n_t"] * g["bt"]
-    packed = pl.pallas_call(
+    packed = kernel_call(
         functools.partial(_fwd_kernel, smoothing=0.0, true_k=k,
                           true_v=g["V"], padding_idx=None, bv=g["bv"],
                           n_v=g["n_v"], emit_stats="packed"),
+        name="linear_xent_pack",
         grid=(g["n_t"], g["n_v"]),
         in_specs=[x_spec, w_spec, stat_spec, off_spec],
         out_specs=pk_spec,
@@ -342,8 +344,9 @@ def shard_grads(x2, w_shard, t2, lse, dloss, *, col_offset=0,
                 padding_idx=padding_idx, bv=g["bv"])
 
     x_spec, w_spec, stat_spec, off_spec = _specs(g)
-    dx = pl.pallas_call(
+    dx = kernel_call(
         functools.partial(_bwd_dx_kernel, n_v=g["n_v"], **kern),
+        name="linear_xent_dx",
         grid=(g["n_t"], g["n_v"]),
         in_specs=[x_spec, w_spec, stat_spec, off_spec, stat_spec,
                   stat_spec],
@@ -354,8 +357,9 @@ def shard_grads(x2, w_shard, t2, lse, dloss, *, col_offset=0,
     )(xp, wp, tp, off, lse_p, dl)[:g["T"], :g["H"]]
 
     x_spec, w_spec, stat_spec, off_spec = _specs(g, for_dw=True)
-    dw = pl.pallas_call(
+    dw = kernel_call(
         functools.partial(_bwd_dw_kernel, n_t=g["n_t"], **kern),
+        name="linear_xent_dw",
         grid=(g["n_v"], g["n_t"]),
         in_specs=[x_spec, w_spec, stat_spec, off_spec, stat_spec,
                   stat_spec],
@@ -381,10 +385,11 @@ def _fused_fwd(x2, weight, t2, smoothing, padding_idx, num_classes,
     k = num_classes if num_classes is not None else g["V"]
     x_spec, w_spec, stat_spec, off_spec = _specs(g)
     Tp = g["n_t"] * g["bt"]
-    loss, lse = pl.pallas_call(
+    loss, lse = kernel_call(
         functools.partial(_fwd_kernel, smoothing=smoothing, true_k=k,
                           true_v=g["V"], padding_idx=padding_idx,
                           bv=g["bv"], n_v=g["n_v"], emit_stats=False),
+        name="linear_xent_fwd",
         grid=(g["n_t"], g["n_v"]),
         in_specs=[x_spec, w_spec, stat_spec, off_spec],
         out_specs=(stat_spec, stat_spec),

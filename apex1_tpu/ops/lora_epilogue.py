@@ -35,8 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (
-    interpret_mode, out_struct, pad_to, to_mosaic, use_pallas)
+from apex1_tpu.ops._common import (interpret_mode, kernel_call, out_struct,
+                                   pad_to, to_mosaic, use_pallas)
 
 _LANES = 128
 
@@ -162,8 +162,9 @@ def lora_delta(h, a_pages, b_pages, block_table, *, block_v=None):
         out_specs=pl.BlockSpec((1, 1, bv), lambda n, v, r, bt: (n, 0, v)),
         scratch_shapes=[pltpu.VMEM((8, bv), jnp.float32)],
     )
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_lora_kernel, n_r=R),
+        name="lora_delta",
         grid_spec=grid_spec,
         out_shape=out_struct((N, 1, Vp), jnp.float32, hm, am, bm),
         interpret=interpret_mode(),

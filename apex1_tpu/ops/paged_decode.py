@@ -51,8 +51,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (NEG_INF, interpret_mode, out_struct,
-                                   pad_to, use_pallas)
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   out_struct, pad_to, use_pallas)
 
 _LANES = 128
 _SUBLANES = 8
@@ -345,11 +345,12 @@ def fused_sample(logits, seeds, positions, *, temperature: float = 0.0,
     keys = jax.lax.bitcast_convert_type(
         _row_keys(seeds, positions), jnp.int32)
     keysp = jnp.zeros((Rp, _LANES), jnp.int32).at[:R, :2].set(keys)
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_fused_sample_kernel, n=V, v_eff=v_eff,
                           temperature=temperature,
                           scale_in_kernel=scale_in_kernel,
                           greedy=greedy, bv=bv, total=Vp2),
+        name="fused_sample",
         grid=(Rp // _SUBLANES, Vp2 // bv),
         in_specs=[pl.BlockSpec((_SUBLANES, _LANES),
                                lambda b, t: (b, 0),
@@ -578,9 +579,10 @@ def paged_attend(q, k_pages, v_pages, block_table, lengths, *,
             pltpu.VMEM((Rqp, _LANES), jnp.float32),
             pltpu.VMEM((Rqp, _LANES), jnp.float32)],
     )
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_paged_attn_kernel, scale=scale, S=S, P=P,
                           T=T, n_rows=G * S),
+        name="paged_attend",
         grid_spec=grid_spec,
         out_shape=out_struct((N, Hkv, Rqp, Dp), q.dtype, qv, kp, vp),
         interpret=interpret_mode(),

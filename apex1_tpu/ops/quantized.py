@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import interpret_mode, out_struct, use_pallas
+from apex1_tpu.ops._common import (interpret_mode, kernel_call,
+                                   out_struct, use_pallas)
 
 
 def quantize_int8(w, *, axis: int = -1):
@@ -109,8 +110,9 @@ def _pallas_int8_matmul(x, wq, scale, block_n: int, block_k: int):
     bn = _fit_block(N, block_n)
     bk = _fit_block(K, block_k)
     grid = (N // bn, K // bk)
-    return pl.pallas_call(
+    return kernel_call(
         _int8_mm_kernel,
+        name="int8_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((T, bk), lambda n, k: (0, k),

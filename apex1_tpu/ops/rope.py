@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import interpret_mode, out_struct, use_pallas
+from apex1_tpu.ops._common import (interpret_mode, kernel_call,
+                                   out_struct, use_pallas)
 from apex1_tpu.tuning import tuned_row_block
 
 
@@ -55,8 +56,9 @@ def _pallas_rope(x1, x2, cos_r, sin_r, block_rows=None):
                          requested=block_rows)
     row = pl.BlockSpec((br, half), lambda i: (i, 0),
                        memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    return kernel_call(
         _rope_kernel,
+        name="rope",
         grid=(pl.cdiv(rows, br),),
         in_specs=[row, row, row, row],
         out_specs=(row, row),

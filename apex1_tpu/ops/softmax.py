@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (NEG_INF, interpret_mode, out_struct,
-                                   pad_to, use_pallas)
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   out_struct, pad_to, use_pallas)
 from apex1_tpu.tuning import tuned_row_block
 
 
@@ -82,8 +82,9 @@ def _pallas_softmax_fwd(x4, mask4, scale, causal, true_k, bq):
             lambda xr, yr, **kw: _fwd_kernel(xr, None, yr, **kw),
             scale=scale, causal=causal, true_k=true_k)
         in_specs, args = [x_spec], (x4,)
-    return pl.pallas_call(
+    return kernel_call(
         kernel,
+        name="softmax_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=x_spec,
@@ -96,8 +97,9 @@ def _pallas_softmax_bwd(y2, dy2, scale, bq):
     rows, k = y2.shape
     row = pl.BlockSpec((bq, k), lambda i: (i, 0),
                        memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_bwd_kernel, scale=scale),
+        name="softmax_bwd",
         grid=(pl.cdiv(rows, bq),),
         in_specs=[row, row],
         out_specs=row,

@@ -51,8 +51,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (as_rows, interpret_mode, mosaic_dtype,
-                                   out_struct, pad_to, to_mosaic,
+from apex1_tpu.ops._common import (as_rows, interpret_mode, kernel_call,
+                                   mosaic_dtype, out_struct, pad_to, to_mosaic,
                                    use_pallas)
 from apex1_tpu.ops.layer_norm import layer_norm, rms_norm
 from apex1_tpu.tuning import tuned_row_block
@@ -245,8 +245,9 @@ def _bda_pallas_fwd(x2p, b2, r2p, seed, p, br):
             lambda sr, xr, rr, orf, **k: _bda_fwd_kernel(
                 sr, xr, None, rr, orf, **k), **kw)
         in_specs, args = [smem, row, row], (sarr, x2p, r2p)
-    return pl.pallas_call(
+    return kernel_call(
         kernel,
+        name="bias_dropout_add_fwd",
         grid=(pl.cdiv(rows, br),),
         in_specs=in_specs,
         out_specs=row,
@@ -272,8 +273,9 @@ def _bda_pallas_bwd(dy2p, seed, p, br, with_bias):
                 sr, dyr, dxr, None, **k), **kw)
         out_specs = row
         out_shape = out_struct((rows, hp), dy2p.dtype, dy2p)
-    return pl.pallas_call(
+    return kernel_call(
         kernel,
+        name="bias_dropout_add_bwd",
         grid=(pl.cdiv(rows, br),),
         in_specs=[smem, row],
         out_specs=out_specs,

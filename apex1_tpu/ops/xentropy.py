@@ -27,8 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex1_tpu.ops._common import (NEG_INF, interpret_mode, out_struct,
-                                   pad_to, use_pallas)
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   out_struct, pad_to, use_pallas)
 from apex1_tpu.tuning import tuned_row_block
 
 
@@ -97,9 +97,10 @@ def _fused_xent_fwd(logits, labels, smoothing, padding_idx, num_classes,
     x2p, _ = pad_to(x2p, 1, 128)
     t2p, _ = pad_to(t2, 0, br, value=-1)
     row, stat = _specs(x2p.shape[1], br)
-    loss, lse = pl.pallas_call(
+    loss, lse = kernel_call(
         functools.partial(_fwd_kernel, smoothing=smoothing, true_k=k,
                           padding_idx=padding_idx),
+        name="xentropy_fwd",
         grid=(pl.cdiv(x2p.shape[0], br),),
         in_specs=[row, stat],
         out_specs=(stat, stat),
@@ -126,9 +127,10 @@ def _fused_xent_bwd(smoothing, padding_idx, num_classes, block_rows, res,
     t2p, _ = pad_to(t2, 0, br, value=-1)
     d2p, _ = pad_to(d2, 0, br)
     row, stat = _specs(x2p.shape[1], br)
-    dx = pl.pallas_call(
+    dx = kernel_call(
         functools.partial(_bwd_kernel, smoothing=smoothing, true_k=k,
                           padding_idx=padding_idx),
+        name="xentropy_bwd",
         grid=(pl.cdiv(x2p.shape[0], br),),
         in_specs=[row, stat, stat, stat],
         out_specs=row,

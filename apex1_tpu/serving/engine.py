@@ -94,6 +94,19 @@ engine must observe each step's tokens to retire rows (one small
 blocking readback per step) — the latency cost of data-dependent
 control, paid only when asked for. Speculative mode always reads back
 (drafting needs the history; accept counts gate retirement).
+
+SPANS AND COUNTS: every phase of `step()` is an `obs.spine.span` — the
+engine's one way of naming a region (``serving/step`` > ``expire``,
+``admit`` > ``admit.alloc`` / ``prefill`` / ``admit.register`` /
+``admit.first_read`` / ``admit.patch``, ``decode_step`` or
+``verify_step``, ``read_tokens``, ``emit`` > ``retire``; the table is in
+docs/observability.md). The spans of one request share its id, the two
+in which the host waits for the device are marked ``wait``, and each
+``serving/step`` carries what the step did (``admitted``, ``retired``,
+``prefill_chunks``, ``prefill_tokens``, ``tokens_out``, ``n_active``,
+``queue_depth``) and ``control_dispatches``: the device programs it
+launched outside the two executables. They are kept in memory, always,
+and lie in a profiler trace on the device's clock when one is taken.
 """
 
 from __future__ import annotations
@@ -118,7 +131,8 @@ from apex1_tpu.serving.kv_pool import KVPool, PagedKVPool
 from apex1_tpu.serving.metrics import ServingMetrics
 from apex1_tpu.serving.scheduler import Backpressure, Request, Scheduler
 from apex1_tpu.serving.spec import ngram_propose
-from apex1_tpu.utils.observability import MetricsLogger, annotate
+from apex1_tpu.obs import spine
+from apex1_tpu.utils.observability import MetricsLogger
 
 
 def derive_request_seed(engine_seed: int, req_id: int) -> int:
@@ -340,6 +354,13 @@ class Engine:
                 (cfg.max_slots, cfg.lora_rank), jnp.int32)
             self._d_lora_on = jnp.zeros((cfg.max_slots,), bool)
         self._n_active = 0
+        # running totals a `serving/step` span reports its step's share
+        # of; "control_dispatches" counts every device program launched
+        # outside the two executables (`_patch`, lane snapshots, draft
+        # uploads)
+        self._tally = dict.fromkeys(
+            ("admitted", "retired", "prefill_chunks", "prefill_tokens",
+             "tokens_out", "control_dispatches"), 0)
         # eos_id=None: retirement is length-based, so step tokens are
         # only READ at retirement — the log keeps each step's (N,)
         # output (device array until first fetch memoizes it as numpy).
@@ -387,8 +408,17 @@ class Engine:
         """Push one slot's host block-table row to the device mirror —
         called wherever the host row changes (alloc, prefix acquire,
         free), never on the step path."""
-        self._d_bt = self._d_bt.at[slot].set(
+        self._d_bt = self._patch(
+            self._d_bt, slot,
             jnp.asarray(self.kv.block_tables[slot], jnp.int32))
+
+    def _patch(self, vec, slot: int, value):
+        """``vec`` with ``value`` at ``slot`` — the engine's ONE eager
+        write to a device control vector: a device program of its own
+        (its operand rides along), so it is counted, a step's
+        ``control_dispatches``."""
+        self._tally["control_dispatches"] += 1
+        return vec.at[slot].set(value)
 
     # ---- the two executables -------------------------------------------
 
@@ -862,9 +892,10 @@ class Engine:
         if self._lora is None:
             return
         self._lora.release(slot)
-        self._d_lora_bt = self._d_lora_bt.at[slot].set(
+        self._d_lora_bt = self._patch(
+            self._d_lora_bt, slot,
             jnp.zeros((self.cfg.lora_rank,), jnp.int32))
-        self._d_lora_on = self._d_lora_on.at[slot].set(False)
+        self._d_lora_on = self._patch(self._d_lora_on, slot, False)
 
     # ---- submission -----------------------------------------------------
 
@@ -933,27 +964,30 @@ class Engine:
         """One engine iteration: retire (deadline/cancel) → admit → one
         decode (or speculative verify) step over every occupied slot.
         Returns the number of active slots that decoded (0 = idle)."""
-        now = time.monotonic()
-        for req in self.scheduler.expire(now):
-            self._finish(req.req_id, "evicted", "deadline (queued)", [])
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            if (slot.req.deadline is not None
-                    and slot.req.deadline <= now):
-                self._retire(i, "evicted", "deadline")
-        self._admit_all()
-        n_active = self._n_active
-        if n_active == 0:
-            self.metrics.step_sample(0, self.cfg.max_slots,
-                                     self.scheduler.depth)
-            return 0
-        if self._spec:
-            self._spec_step()
-        else:
-            self._decode_step()
-        self.metrics.step_sample(n_active, self.cfg.max_slots,
-                                 self.scheduler.depth)
+        with spine.span("serving/step") as sp:
+            before = dict(self._tally)
+            with spine.span("serving/expire"):
+                now = time.monotonic()
+                for req in self.scheduler.expire(now):
+                    self._finish(req.req_id, "evicted",
+                                 "deadline (queued)", [])
+                for i, slot in enumerate(self._slots):
+                    if slot is None:
+                        continue
+                    if (slot.req.deadline is not None
+                            and slot.req.deadline <= now):
+                        self._retire(i, "evicted", "deadline")
+            self._admit_all()
+            n_active = self._n_active
+            if n_active:
+                if self._spec:
+                    self._spec_step()
+                else:
+                    self._decode_step()
+            depth = self.scheduler.depth
+            self.metrics.step_sample(n_active, self.cfg.max_slots, depth)
+            sp.counts = {k: v - before[k] for k, v in self._tally.items()}
+            sp.counts.update(n_active=n_active, queue_depth=depth)
         return n_active
 
     def _lora_args(self) -> tuple:
@@ -966,7 +1000,7 @@ class Engine:
                 self._d_lora_bt, self._d_lora_on)
 
     def _decode_step(self):
-        with annotate("serving/decode_step"):
+        with spine.span("serving/decode_step"):
             if self._paged:
                 nxt, idxs, pos, self.kv.pages = self._decode(
                     self.params, self.kv.pages, self._d_bt,
@@ -982,23 +1016,26 @@ class Engine:
             self._tok_log[self._step_no] = nxt     # fetched at retire
             toks = None
         else:
-            toks = np.asarray(nxt)                 # eos needs the values
+            with spine.span("serving/read_tokens", wait=True):
+                toks = np.asarray(nxt)             # eos needs the values
         self._step_no += 1
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            slot.n_out += 1
-            self.metrics.event(slot.req.req_id, "token")
-            if toks is not None:
-                tok = int(toks[i])
-                slot.produced.append(tok)
-                slot.history.append(tok)
-                if tok == self.cfg.eos_id:
-                    slot.eos_seen = True
-                    self._retire(i, "done", "eos")
+        with spine.span("serving/emit"):
+            for i, slot in enumerate(self._slots):
+                if slot is None:
                     continue
-            if slot.n_out >= slot.req.max_new_tokens:
-                self._retire(i, "done", "length")
+                slot.n_out += 1
+                self._tally["tokens_out"] += 1
+                self.metrics.event(slot.req.req_id, "token")
+                if toks is not None:
+                    tok = int(toks[i])
+                    slot.produced.append(tok)
+                    slot.history.append(tok)
+                    if tok == self.cfg.eos_id:
+                        slot.eos_seen = True
+                        self._retire(i, "done", "eos")
+                        continue
+                if slot.n_out >= slot.req.max_new_tokens:
+                    self._retire(i, "done", "length")
 
     def _spec_step(self):
         """One draft → verify round for every occupied slot: the host
@@ -1015,23 +1052,31 @@ class Engine:
                 drafts[i] = np.asarray(
                     self._draft_propose(st.history, K),
                     np.int32).reshape(K)
-        with annotate("serving/verify_step"):
+        d_drafts = jnp.asarray(drafts)       # an upload of its own
+        self._tally["control_dispatches"] += 1
+        with spine.span("serving/verify_step"):
             if self._paged:
                 tgt, acc, nxt, idxs, pos, self.kv.pages = self._verify(
                     self.params, self.kv.pages, self._d_bt,
                     self._d_toks, self._d_idxs, self._d_active,
-                    self._d_seeds, self._d_pos, jnp.asarray(drafts),
+                    self._d_seeds, self._d_pos, d_drafts,
                     *self._lora_args())
             else:
                 tgt, acc, nxt, idxs, pos, self.kv.cache = self._verify(
                     self.params, self.kv.cache, self._d_toks,
                     self._d_idxs, self._d_active, self._d_seeds,
-                    self._d_pos, jnp.asarray(drafts),
-                    *self._lora_args())
+                    self._d_pos, d_drafts, *self._lora_args())
         self._d_toks, self._d_idxs, self._d_pos = nxt, idxs, pos
-        tgt_np = np.asarray(tgt)
-        acc_np = np.asarray(acc)
+        with spine.span("serving/read_tokens", wait=True):
+            tgt_np = np.asarray(tgt)
+            acc_np = np.asarray(acc)
         self._step_no += 1
+        with spine.span("serving/emit"):
+            self._spec_emit(tgt_np, acc_np)
+
+    def _spec_emit(self, tgt_np, acc_np):
+        cfg = self.cfg
+        K = cfg.num_draft
         for i, st in enumerate(self._slots):
             if st is None or not st.in_batch:
                 continue
@@ -1060,6 +1105,7 @@ class Engine:
                     st.eos_seen = True
                     done_reason = "eos"
                     break
+            self._tally["tokens_out"] += n_emit
             self.metrics.event(st.req.req_id, "token", n=n_emit)
             if done_reason is None and st.n_out >= st.req.max_new_tokens:
                 done_reason = "length"
@@ -1128,7 +1174,15 @@ class Engine:
         return hit
 
     def _admit(self, req: Request):
+        rid = req.req_id
+        spine.record_span("serving/queued", int(req.submitted_at * 1e9),
+                          spine.monotonic_ns(), req=rid)
+        with spine.span("serving/admit", req=rid):
+            self._admit_one(req)
+
+    def _admit_one(self, req: Request):
         cfg = self.cfg
+        rid = req.req_id
         if (req.deadline is not None
                 and req.deadline <= time.monotonic()):
             # expired between the step's expire() sweep and this
@@ -1136,43 +1190,44 @@ class Engine:
             # — evict before paying prefill or touching the pool
             self._finish(req.req_id, "evicted", "deadline (queued)", [])
             return
-        slot = self.kv.alloc()
-        assert slot is not None
-        if self._paged:
-            # the freshly-owned page row must be on device before any
-            # prefill chunk gathers/scatters through it
-            self._sync_bt(slot)
-        if self._lora is not None:
-            # pin the tenant's adapter pages and patch the slot's row
-            # BEFORE the prefill chain — token 0 already samples
-            # through the fused epilogue. An unregistered (or None)
-            # tenant gets the zero row: same executable, exact-zero
-            # delta, flag off.
-            lrow, lora_on = self._lora.acquire(req.tenant, slot)
-            self._d_lora_bt = self._d_lora_bt.at[slot].set(
-                jnp.asarray(lrow, jnp.int32))
-            self._d_lora_on = self._d_lora_on.at[slot].set(
-                bool(lora_on))
-        prefix = tuple(req.prefix) if req.prefix else ()
-        full = self._full_prompt(req)
-        key = page = None
-        if cfg.prefix_cache:
-            # cap at len-1: a full-prompt hit must still leave >= 1
-            # real token to prefill (the logit the first token samples
-            # from)
-            key, page = self.kv.match(full, int(full.size) - 1)
-            self.metrics.incr("prefix_lookups")
-            if page is not None:
-                self.metrics.incr("prefix_hits")
-                self.metrics.incr("prefix_saved_tokens", page.length)
-        elif prefix:
-            # radix matching off: the PR-7 exact-tuple contract still
-            # holds — a second sharer of the same explicit prefix must
-            # reuse (not re-register: put_prefix would raise) the page
-            # (review finding)
-            page = self.kv.get_prefix(prefix)
-            key = prefix if page is not None else None
-        hit = page is not None
+        with spine.span("serving/admit.alloc", req=rid):
+            slot = self.kv.alloc()
+            assert slot is not None
+            if self._paged:
+                # the freshly-owned page row must be on device before
+                # any prefill chunk gathers/scatters through it
+                self._sync_bt(slot)
+            if self._lora is not None:
+                # pin the tenant's adapter pages and patch the slot's
+                # row BEFORE the prefill chain — token 0 already samples
+                # through the fused epilogue. An unregistered (or None)
+                # tenant gets the zero row: same executable, exact-zero
+                # delta, flag off.
+                lrow, lora_on = self._lora.acquire(req.tenant, slot)
+                self._d_lora_bt = self._patch(
+                    self._d_lora_bt, slot, jnp.asarray(lrow, jnp.int32))
+                self._d_lora_on = self._patch(self._d_lora_on, slot,
+                                              bool(lora_on))
+            prefix = tuple(req.prefix) if req.prefix else ()
+            full = self._full_prompt(req)
+            key = page = None
+            if cfg.prefix_cache:
+                # cap at len-1: a full-prompt hit must still leave >= 1
+                # real token to prefill (the logit the first token
+                # samples from)
+                key, page = self.kv.match(full, int(full.size) - 1)
+                self.metrics.incr("prefix_lookups")
+                if page is not None:
+                    self.metrics.incr("prefix_hits")
+                    self.metrics.incr("prefix_saved_tokens", page.length)
+            elif prefix:
+                # radix matching off: the PR-7 exact-tuple contract
+                # still holds — a second sharer of the same explicit
+                # prefix must reuse (not re-register: put_prefix would
+                # raise) the page (review finding)
+                page = self.kv.get_prefix(prefix)
+                key = prefix if page is not None else None
+            hit = page is not None
         self.metrics.event(
             req.req_id, "prefill",
             prefix_hit=(hit if cfg.prefix_cache else None),
@@ -1180,7 +1235,7 @@ class Engine:
         with self._admit_lock:
             self._mid_admit = req.req_id
         try:
-            with annotate("serving/prefill"):
+            with spine.span("serving/prefill", req=rid):
                 if hit:
                     self.kv.acquire_prefix(key, slot)
                     if self._paged:
@@ -1223,9 +1278,10 @@ class Engine:
                 C = cfg.prefill_chunk
                 lstar = ((int(full.size) - 1) // C) * C
                 if lstar >= C and lstar > (page.length if hit else 0):
-                    akey = tuple(int(t) for t in full[:lstar])
-                    if not self.kv.has_prefix(akey):
-                        self._register_page(slot, akey, lstar)
+                    with spine.span("serving/admit.register", req=rid):
+                        akey = tuple(int(t) for t in full[:lstar])
+                        if not self.kv.has_prefix(akey):
+                            self._register_page(slot, akey, lstar)
         except BaseException:
             # the first-sharer stranding window (ISSUE 15 satellite): a
             # prefill chain that dies mid-flight (chaos kill, XLA
@@ -1242,6 +1298,8 @@ class Engine:
                 self._cancel_mid.discard(req.req_id)
             raise
         self.metrics.event(req.req_id, "first_token")
+        self._tally["admitted"] += 1
+        self._tally["tokens_out"] += 1
         idx = int(full.size)
         st = _Slot(req=req, first_tok=tok0, start_step=self._step_no,
                    history=[int(t) for t in full])
@@ -1259,7 +1317,9 @@ class Engine:
             self._cancel_mid.discard(req.req_id)
         first = None
         if not self._defer:
-            first = int(np.asarray(tok0))
+            with spine.span("serving/admit.first_read", req=rid,
+                            wait=True):
+                first = int(np.asarray(tok0))
             st.produced.append(first)
             st.history.append(first)
             st.first_tok = first
@@ -1283,12 +1343,14 @@ class Engine:
         # device-side boundary patch: the slot joins the decode batch
         # (pos=1: the next sampled token is the request's output #1 —
         # prefill already drew #0 from the same per-request stream)
-        self._d_toks = self._d_toks.at[slot].set(
-            jnp.asarray(tok0, jnp.int32))
-        self._d_idxs = self._d_idxs.at[slot].set(idx)
-        self._d_active = self._d_active.at[slot].set(True)
-        self._d_seeds = self._d_seeds.at[slot].set(int(req.seed))
-        self._d_pos = self._d_pos.at[slot].set(1)
+        with spine.span("serving/admit.patch", req=rid):
+            self._d_toks = self._patch(self._d_toks, slot,
+                                       jnp.asarray(tok0, jnp.int32))
+            self._d_idxs = self._patch(self._d_idxs, slot, idx)
+            self._d_active = self._patch(self._d_active, slot, True)
+            self._d_seeds = self._patch(self._d_seeds, slot,
+                                        int(req.seed))
+            self._d_pos = self._patch(self._d_pos, slot, 1)
         st.in_batch = True
         self._n_active += 1
 
@@ -1310,6 +1372,8 @@ class Engine:
         lane = jax.tree_util.tree_map(
             lambda x: x[slot:slot + 1] if x.shape[0] > 1 else jnp.copy(x),
             self.kv.cache)
+        self._tally["control_dispatches"] += len(
+            jax.tree_util.tree_leaves(lane))
         self.kv.put_prefix(pkey, lane, length)
         self.kv.acquire_prefix(pkey, slot)
 
@@ -1325,6 +1389,8 @@ class Engine:
         C = self.cfg.prefill_chunk
         n = int(tokens.size)
         tok = None
+        self._tally["prefill_chunks"] += math.ceil(n / C)
+        self._tally["prefill_tokens"] += n
         for c in range(math.ceil(n / C)):
             seg = tokens[c * C:(c + 1) * C]
             buf = np.zeros((1, C), np.int32)
@@ -1373,28 +1439,34 @@ class Engine:
 
     def _retire(self, slot_idx: int, status: str, reason: str):
         slot = self._slots[slot_idx]
-        self._slots[slot_idx] = None
-        if self._defer:
-            produced = self._materialize(slot, slot_idx)
-            self._prune_log()
-        else:
-            produced = slot.produced
-        if slot.in_batch:
-            # boundary patch: drop the lane from the decode batch (the
-            # freed lane keeps computing masked garbage — values only)
-            self._d_active = self._d_active.at[slot_idx].set(False)
-            self._n_active -= 1
-        self.kv.free(slot_idx)
-        if self._paged:
-            # the freed row now names the trash page — REQUIRED, not
-            # hygiene: the retired lane keeps scattering its masked
-            # garbage every step, and its old pages may be reallocated
-            # (or live on as shared prefix pages) immediately
-            self._sync_bt(slot_idx)
-        self._lora_release(slot_idx)
-        spec = ({"n_drafted": slot.drafted, "n_accepted": slot.accepted}
-                if self._spec else {})
-        self._finish(slot.req.req_id, status, reason, produced, **spec)
+        with spine.span("serving/retire", req=slot.req.req_id):
+            self._slots[slot_idx] = None
+            self._tally["retired"] += 1
+            if self._defer:
+                produced = self._materialize(slot, slot_idx)
+                self._prune_log()
+            else:
+                produced = slot.produced
+            if slot.in_batch:
+                # boundary patch: drop the lane from the decode batch
+                # (the freed lane keeps computing masked garbage —
+                # values only)
+                self._d_active = self._patch(self._d_active, slot_idx,
+                                             False)
+                self._n_active -= 1
+            self.kv.free(slot_idx)
+            if self._paged:
+                # the freed row now names the trash page — REQUIRED, not
+                # hygiene: the retired lane keeps scattering its masked
+                # garbage every step, and its old pages may be
+                # reallocated (or live on as shared prefix pages)
+                # immediately
+                self._sync_bt(slot_idx)
+            self._lora_release(slot_idx)
+            spec = ({"n_drafted": slot.drafted,
+                     "n_accepted": slot.accepted} if self._spec else {})
+            self._finish(slot.req.req_id, status, reason, produced,
+                         **spec)
 
     def _finish(self, req_id: int, status: str, reason: str,
                 produced: List[int], **fields):

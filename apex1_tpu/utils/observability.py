@@ -7,8 +7,9 @@ and post-processed profiler SQLite into per-kernel FLOPs/bytes
 callable through the pipeline schedules.
 
 TPU-native equivalents:
-- `annotate` — ``jax.named_scope`` + ``jax.profiler.TraceAnnotation``
-  (≙ nvtx ranges; names land in XLA HLO metadata AND the profiler trace).
+- `annotate` — ``jax.named_scope`` + `obs.spine.span` (≙ nvtx ranges;
+  names land in XLA HLO metadata, the spine's span buffer AND the
+  profiler trace).
 - `trace` — context manager around ``jax.profiler.start_trace`` writing a
   TensorBoard-loadable trace (≙ running under nsys).
 - `cost_analysis` — compile-time FLOPs/bytes attribution from XLA
@@ -41,10 +42,11 @@ from apex1_tpu.obs import spine
 
 
 @contextlib.contextmanager
-def annotate(name: str):
-    """Name a region for both XLA metadata and profiler timelines."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+def annotate(name: str, **span_args):
+    """Name a region for XLA metadata, the spine's span buffer and the
+    profiler's timeline; yields the open `spine.Span`."""
+    with jax.named_scope(name), spine.span(name, **span_args) as sp:
+        yield sp
 
 
 @contextlib.contextmanager

@@ -91,12 +91,13 @@ def timed_steps(train_step, state, batch, iters, *, profile_dir=None):
     # clock); the full-tree float() reduction above IS the hard sync,
     # so no sync tree is passed here
     from apex1_tpu.obs import spine
+    t0_ns = spine.monotonic_ns()
     sw = spine.StopWatch().start()
     state, metrics = compiled(state)           # n loop iters + 1 leading
     float(_reduce_all((state, metrics)))       # hard sync, full tree
     dt = sw.stop()
-    spine.emit("span", "bench.timed_steps", dur_s=round(dt, 6),
-               iters=iters, step_s=round(dt / iters, 6))
+    spine.record_span("bench.timed_steps", t0_ns, spine.monotonic_ns(),
+                      iters=iters, step_s=round(dt / iters, 6))
     loss = float(metrics["loss"])
     if not math.isfinite(loss):
         raise RuntimeError(f"benchmark loss is not finite: {loss}")

@@ -39,24 +39,23 @@ def no_default_run(monkeypatch):
 class TestSpine:
     def test_run_roundtrip(self, tmp_path):
         with spine.ObsRun(dir=str(tmp_path), component="t") as run:
-            with run.span("work", iters=3):
+            with spine.span("work", iters=3):
                 pass
             run.counter("steps", 7)
-            run.gauge("loss", 1.5)
             run.event("note", detail="x")
             path = run.path
         evs = spine.read_events(path)
+        # the span was in memory until the run closed: it is written last
         assert [e["kind"] for e in evs] == [
-            "run", "span", "counter", "gauge", "event"]
+            "run", "counter", "event", "span"]
         header = evs[0]
         assert header["schema"] == spine.SCHEMA
         assert header["component"] == "t"
-        span = evs[1]
+        assert evs[1]["value"] == 7
+        assert evs[2]["detail"] == "x"
+        span = evs[3]
         assert span["name"] == "work" and span["iters"] == 3
         assert span["dur_s"] >= 0 and span["t"] >= 0
-        assert evs[2]["value"] == 7
-        assert evs[3]["value"] == 1.5
-        assert evs[4]["detail"] == "x"
 
     def test_torn_tail_skipped(self, tmp_path):
         with spine.ObsRun(dir=str(tmp_path)) as run:
@@ -161,6 +160,227 @@ class TestSpine:
 
 
 # ==========================================================================
+# spans — the one recorder (spine.span / record_span / snapshot)
+# ==========================================================================
+
+@pytest.fixture(scope="module")
+def tiny_engine_factory():
+    """A tiny fp32 GPT-2 behind `serving.Engine` (eos set, so every
+    token is read back each step, as in the benchmark's chat cell)."""
+    import jax
+    import jax.numpy as jnp
+    from apex1_tpu.core.policy import get_policy
+    from apex1_tpu.models.generate import gpt2_decoder
+    from apex1_tpu.models.gpt2 import GPT2, GPT2Config
+    from apex1_tpu.serving import Engine, EngineConfig
+
+    cfg = GPT2Config.tiny(policy=get_policy("O0"), max_seq_len=64)
+    model = GPT2(cfg)
+    params = model.init(jax.random.key(0),
+                        jnp.zeros((1, 7), jnp.int32))["params"]
+    apply_fn, make_cache = gpt2_decoder(model)
+
+    def make(**kw):
+        ekw = dict(max_slots=3, max_len=48, prefill_chunk=4,
+                   vocab_size=cfg.vocab_size, eos_id=cfg.vocab_size - 1)
+        ekw.update(kw)
+        return Engine(apply_fn, make_cache, params, EngineConfig(**ekw))
+
+    return make
+
+
+def _run_requests(engine, lens_news=((3, 4), (7, 3), (5, 5), (9, 2))):
+    import numpy as np
+    rng = np.random.default_rng(5)
+    ids = [engine.submit(rng.integers(0, 100, (n,)).tolist(),
+                         max_new_tokens=k) for n, k in lens_news]
+    engine.run(max_steps=100)
+    return ids
+
+
+def _since(mark):
+    return [sp for sp in spine.snapshot() if sp.id > mark]
+
+
+def _mark():
+    return spine.record_span("test/mark", 0, 0).id
+
+
+class TestSpans:
+    def test_nesting_gives_parents_and_counts_close_late(self):
+        mark = _mark()
+        with spine.span("outer", req=7) as outer:
+            with spine.span("inner", wait=True, k=1) as inner:
+                pass
+            with spine.span("inner2"):
+                pass
+            outer.counts["n"] = 2          # known only at the end
+        got = {sp.name: sp for sp in _since(mark)}
+        assert got["outer"].parent is None and got["outer"].req == 7
+        assert got["inner"].parent == got["outer"].id == outer.id
+        assert got["inner2"].parent == outer.id
+        assert got["inner"].wait and not got["outer"].wait
+        assert got["inner"].counts == {"k": 1}
+        assert got["outer"].counts == {"n": 2}
+        assert inner.id != outer.id
+        assert (got["outer"].start_ns <= got["inner"].start_ns
+                <= got["inner"].end_ns <= got["inner2"].start_ns
+                <= got["inner2"].end_ns <= got["outer"].end_ns)
+
+    def test_record_span_has_no_parent(self):
+        mark = _mark()
+        with spine.span("around"):
+            sp = spine.record_span("queued", 10, 30, req=4, depth=2)
+        assert sp.parent is None and (sp.start_ns, sp.end_ns) == (10, 30)
+        assert sp.req == 4 and sp.counts == {"depth": 2}
+        assert [x.name for x in _since(mark)] == ["queued", "around"]
+
+    def test_a_span_that_raises_is_recorded_and_popped(self):
+        mark = _mark()
+        with pytest.raises(KeyError):
+            with spine.span("outer"):
+                with spine.span("boom"):
+                    raise KeyError("x")
+        with spine.span("after"):
+            pass
+        got = {sp.name: sp for sp in _since(mark)}
+        assert got["boom"].parent == got["outer"].id
+        assert got["after"].parent is None      # the stack unwound
+
+    def test_two_threads_do_not_adopt_each_others_spans(self):
+        import threading
+        mark = _mark()
+        inside = threading.Event()
+        done = threading.Event()
+
+        def other():
+            with spine.span("thread/outer"):
+                inside.set()
+                done.wait(5)
+                with spine.span("thread/inner"):
+                    pass
+
+        t = threading.Thread(target=other)
+        with spine.span("main/outer"):
+            t.start()
+            assert inside.wait(5)
+            with spine.span("main/inner"):       # while the other is open
+                pass
+            done.set()
+            t.join(5)
+        got = {sp.name: sp for sp in _since(mark)}
+        assert got["main/outer"].parent is None
+        assert got["thread/outer"].parent is None
+        assert got["main/inner"].parent == got["main/outer"].id
+        assert got["thread/inner"].parent == got["thread/outer"].id
+
+    def test_buffer_drops_the_oldest_and_never_grows(self):
+        first = _mark()
+        for _ in range(spine.SPAN_CAPACITY + 10):
+            with spine.span("fill"):
+                pass
+        snap = spine.snapshot()
+        assert len(snap) == spine.SPAN_CAPACITY
+        assert snap[0].id > first               # the oldest went
+        assert snap[-1].id == snap[0].id + spine.SPAN_CAPACITY - 1
+
+    def test_annotate_is_a_named_scope_and_this_span(self):
+        from apex1_tpu.utils.observability import annotate
+        mark = _mark()
+        with annotate("train/fwd", req=3) as sp:
+            pass
+        (got,) = _since(mark)
+        assert got is sp and got.name == "train/fwd" and got.req == 3
+
+    def test_req_is_shared_along_a_requests_life(self,
+                                                 tiny_engine_factory):
+        mark = _mark()
+        engine = tiny_engine_factory()
+        ids = _run_requests(engine)
+        by_req = {}
+        for sp in _since(mark):
+            if sp.req is not None:
+                by_req.setdefault(sp.req, set()).add(sp.name)
+        assert set(by_req) == set(ids)
+        for rid in ids:
+            assert {"serving/queued", "serving/admit",
+                    "serving/admit.alloc", "serving/prefill",
+                    "serving/admit.first_read",
+                    "serving/retire"} <= by_req[rid], by_req[rid]
+
+    def test_profiler_trace_holds_the_same_spans_nested_the_same_way(
+            self, tiny_engine_factory, tmp_path):
+        """`span` enters `TraceAnnotation`, so a trace taken around a
+        run holds host events of the same names, nested as the buffer's
+        parents say."""
+        import jax
+        engine = tiny_engine_factory()
+        _run_requests(engine, ((5, 2),))                  # compile
+        mark = _mark()
+        with jax.profiler.trace(str(tmp_path)):
+            _run_requests(engine)
+        mine = [sp for sp in _since(mark) if sp.name != "serving/queued"]
+        (pb,) = xspace.find_xplane_files(tmp_path)
+        events = []                                       # (name, a, b)
+        for plane in xspace.parse_xspace(pb):
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    name = plane.event_names.get(ev.metadata_id, "")
+                    if name.startswith("serving/"):
+                        a = line.timestamp_ns * 1000 + ev.offset_ps
+                        events.append((name, a, a + ev.duration_ps))
+        import collections
+        assert (collections.Counter(n for n, _, _ in events)
+                == collections.Counter(sp.name for sp in mine))
+        # each kind in time order pairs the trace's events with the
+        # buffer's records; a record's parent must enclose it there too
+        by_name = collections.defaultdict(list)
+        for ev in sorted(events, key=lambda e: e[1]):
+            by_name[ev[0]].append(ev)
+        in_trace = {}
+        seen = collections.Counter()
+        for sp in sorted(mine, key=lambda sp: sp.start_ns):
+            in_trace[sp.id] = by_name[sp.name][seen[sp.name]]
+            seen[sp.name] += 1
+        checked = 0
+        for sp in mine:
+            if sp.parent in in_trace:
+                _, a, b = in_trace[sp.id]
+                _, pa, pb_ = in_trace[sp.parent]
+                assert pa <= a and b <= pb_, (sp.name, a, b, pa, pb_)
+                checked += 1
+        assert checked >= 20
+
+    def test_spans_written_at_close_read_back(self, no_default_run,
+                                              monkeypatch, tmp_path,
+                                              tiny_engine_factory):
+        monkeypatch.setenv("APEX1_OBS_DIR", str(tmp_path))
+        with spine.span("before/the-run"):
+            pass
+        run = spine.default_run()
+        ids = _run_requests(tiny_engine_factory())
+        assert spine.read_events(run.path, kinds=("span",)) == []
+        run.close()                       # in memory first, written now
+        rows = spine.read_events(run.path, kinds=("span",))
+        assert rows and all(r["t"] >= 0 and r["dur_s"] >= 0
+                            for r in rows)
+        assert "before/the-run" not in {r["name"] for r in rows}
+        by_id = {r["id"]: r for r in rows}
+        steps = [r for r in rows if r["name"] == "serving/step"]
+        assert steps and all(r["parent"] is None for r in steps)
+        assert "control_dispatches" in steps[0]
+        admits = [r for r in rows if r["name"] == "serving/admit"]
+        assert sorted(r["req"] for r in admits) == sorted(ids)
+        for r in admits:
+            assert by_id[r["parent"]]["name"] == "serving/step"
+        reads = [r for r in rows if r["name"] == "serving/read_tokens"]
+        assert reads and all(r["wait"] is True for r in reads)
+        assert all("wait" not in r for r in steps)
+
+
+# ==========================================================================
 # xspace — parse -> bucket -> report against the committed fixture
 # ==========================================================================
 
@@ -254,6 +474,60 @@ class TestXSpace:
         (d / "t.xplane.pb").write_bytes(b"\x07" * 32)
         with pytest.raises(xspace.TraceError):
             xspace.build_report(tmp_path)
+
+    def test_custom_call_is_keyed_by_its_kernel(self):
+        hlo = ("%apex1_flash_dq.7 = bf16[8,16,1024,128]{3,2,1,0} "
+               "custom-call(bf16[8,16,1024,128]{3,2,1,0} %p.1), "
+               'custom_call_target="tpu_custom_call"')
+        assert xspace.op_key(hlo) == "apex1_flash_dq"
+        assert xspace.bucket_of(hlo) == "pallas"
+        tup = ("%apex1_layer_norm_fwd = (bf16[64,128]{1,0}, f32[64,1]"
+               "{1,0}) custom-call(bf16[64,128]{1,0} %x), "
+               'custom_call_target="tpu_custom_call"')
+        assert xspace.op_key(tup) == "apex1_layer_norm_fwd"
+        fusion = ("%fusion.12 = bf16[8,1024]{1,0:T(8,128)(2,1)} fusion("
+                  "bf16[8,1024]{1,0} %custom-call.3, f32[] %all-reduce.1"
+                  "), kind=kLoop")
+        assert xspace.op_key(fusion) == fusion
+        # operands name other instructions: the fusion is neither
+        assert xspace.bucket_of(fusion) == "xla"
+        assert xspace.bucket_of(
+            "%ar.1 = (f32[64]{0}, f32[64]{0}) all-reduce(f32[64]{0} %a, "
+            "f32[64]{0} %b), channel_id=1") == "collective"
+        assert xspace.op_key("dot.4") == "dot.4"
+
+    def test_chip_trace_busy_and_idle_agree_with_the_benchmarks_reader(
+            self):
+        """Two readers written apart — this walker and the benchmark's
+        `harness/trace.py` on `jax.profiler.ProfileData` — agree on a
+        recorded four-chip trace: only "XLA Ops" counts, busy is a union
+        of intervals, idle is the window less busy."""
+        import sys
+        sys.path.insert(0, str(_REPO))
+        from benchmark.harness import trace as bench_trace
+        pb = _REPO / "tests" / "benchmark" / "data" / \
+            "ddp4_tiny.xplane.pb.gz"
+        mine = xspace.build_report(pb, window_span="bench/window")
+        theirs = bench_trace.reduce(str(pb))
+        assert mine["plane_class"] == "device"
+        assert mine["n_devices"] == theirs["n_devices"] == 4
+        assert mine["window_s"] == pytest.approx(theirs["window_s"],
+                                                 abs=1e-6)
+        assert mine["busy_s"] == pytest.approx(theirs["busy_s"], abs=1e-6)
+        assert mine["idle_s"] == pytest.approx(
+            theirs["window_s"] - theirs["busy_s"], abs=1e-6)
+        assert mine["idle_gaps"][0][0] == theirs["idle_gaps"][0][0] \
+            == "train/dispatch"
+        assert mine["idle_gaps"][0][1] == pytest.approx(
+            theirs["idle_gaps"][0][1], abs=1e-6)
+        # the Pallas calls of that (older) program carry their jax
+        # scope's name; each is one line, not one per instruction
+        pallas = [o for o in mine["ops"] if o["bucket"] == "pallas"]
+        assert {"layer0", "layer1"} <= {o["name"] for o in pallas}
+        # without the window span the window is the ops' own extent
+        own = xspace.build_report(pb)
+        assert own["window_s"] < mine["window_s"]
+        assert own["busy_s"] == pytest.approx(mine["busy_s"], rel=0.02)
 
     def test_bucket_rules(self):
         assert xspace.bucket_of("all-reduce-start.1") == "collective"

@@ -1032,3 +1032,177 @@ class TestRequestFeeder:
             feeder.join()                      # error still surfaced
         assert all(eng.results[r].status == "done"
                    for r in feeder.submitted)
+
+
+# --------------------------------------------------------------------------
+# spans and counts inside the engine (obs.spine is the one recorder)
+# --------------------------------------------------------------------------
+
+def _spans_since(mark):
+    from apex1_tpu.obs import spine
+    return [sp for sp in spine.snapshot() if sp.id > mark]
+
+
+def _span_mark():
+    from apex1_tpu.obs import spine
+    return spine.record_span("test/mark", 0, 0).id
+
+
+_SPAN_ENGINES = {
+    "dense": dict(prefix_cache=False),
+    "dense_eos": dict(prefix_cache=False, eos_id=5),
+    "dense_prefix_cache": dict(),
+    "paged": dict(paged=True, page_size=8),
+    "spec": dict(num_draft=2, prefix_cache=False),
+}
+
+
+class TestEngineSpans:
+    LENS = [3, 7, 5, 9, 4, 6]
+    NEWS = [6, 5, 7, 4, 6, 5]
+
+    def _run(self, tiny, rng, **kw):
+        cfg = tiny[0]
+        eng = _engine(tiny, **kw)
+        prompts = [rng.integers(6, cfg.vocab_size, (L,)).tolist()
+                   for L in self.LENS]
+        mark = _span_mark()
+        ids = [eng.submit(p, max_new_tokens=n)
+               for p, n in zip(prompts[:4], self.NEWS[:4])]
+        eng.step()
+        ids += [eng.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts[4:], self.NEWS[4:])]
+        eng.run(max_steps=200)
+        return eng, ids, _spans_since(mark)
+
+    @pytest.mark.parametrize("kind", sorted(_SPAN_ENGINES))
+    def test_children_lie_inside_their_step_and_sum_to_no_more(
+            self, tiny, rng, kind):
+        _, _, spans = self._run(tiny, rng, **_SPAN_ENGINES[kind])
+        by_id = {sp.id: sp for sp in spans}
+        steps = [sp for sp in spans if sp.name == "serving/step"]
+        assert steps and all(sp.parent is None for sp in steps)
+        kids = {}
+        for sp in spans:
+            if sp.parent is not None:
+                kids.setdefault(sp.parent, []).append(sp)
+        for sp in spans:
+            if sp.name in ("serving/queued", "test/mark"):
+                assert sp.parent is None
+                continue
+            if sp.name != "serving/step":
+                assert sp.parent in by_id, sp.name
+            below = kids.get(sp.id, [])
+            for k in below:
+                assert sp.start_ns <= k.start_ns <= k.end_ns <= sp.end_ns
+            assert (sum(k.end_ns - k.start_ns for k in below)
+                    <= sp.end_ns - sp.start_ns)
+        names = {sp.name for sp in spans}
+        want = {"serving/step", "serving/expire", "serving/queued",
+                "serving/admit", "serving/admit.alloc", "serving/prefill",
+                "serving/admit.patch", "serving/emit", "serving/retire"}
+        want.add("serving/verify_step" if kind == "spec"
+                 else "serving/decode_step")
+        if kind in ("dense_eos", "spec"):   # tokens are read every step
+            want |= {"serving/read_tokens", "serving/admit.first_read"}
+        else:                               # deferred reads: no host wait
+            assert not any(sp.wait for sp in spans)
+        if kind == "dense_prefix_cache":
+            want.add("serving/admit.register")
+        assert want <= names, want - names
+
+    @pytest.mark.parametrize("kind", sorted(_SPAN_ENGINES))
+    def test_step_counts_add_up_to_what_the_run_did(self, tiny, rng, kind):
+        eng, ids, spans = self._run(tiny, rng, **_SPAN_ENGINES[kind])
+        steps = [sp for sp in spans if sp.name == "serving/step"]
+
+        def total(key):
+            return sum(sp.counts[key] for sp in steps)
+
+        assert all(eng.results[r].status == "done" for r in ids)
+        assert total("tokens_out") == sum(
+            len(eng.results[r].tokens) for r in ids)
+        assert total("admitted") == total("retired") == len(ids)
+        if kind != "dense_prefix_cache":    # no hit: every token prefills
+            assert total("prefill_tokens") == sum(self.LENS)
+            assert total("prefill_chunks") == sum(
+                -(-n // 4) for n in self.LENS)
+        assert steps[0].counts["n_active"] == 3
+        assert steps[0].counts["queue_depth"] == 1
+        assert steps[-1].counts["queue_depth"] == 0
+
+    @pytest.mark.parametrize("kind", sorted(_SPAN_ENGINES))
+    def test_control_dispatches_counts_every_eager_call(
+            self, tiny, rng, kind, monkeypatch):
+        """The count the step span reports against one taken from
+        outside: `_patch` is the engine's only `.at[].set` (pinned on
+        its source), the draft upload and the lane snapshots of a prefix
+        registration are the other programs it launches itself."""
+        import inspect
+        from apex1_tpu.serving import engine as engine_mod
+        src = inspect.getsource(engine_mod)
+        assert src.count(".at[") == 1, "an eager patch outside _patch"
+        seen = {"n": 0}
+        real_patch = engine_mod.Engine._patch
+
+        def patch(self, vec, slot, value):
+            seen["n"] += 1
+            return real_patch(self, vec, slot, value)
+
+        monkeypatch.setattr(engine_mod.Engine, "_patch", patch)
+        real_asarray = engine_mod.jnp.asarray
+
+        class Jnp:
+            def __getattr__(self, name):
+                return getattr(jnp, name)
+
+            @staticmethod
+            def asarray(x, *a, **k):
+                if isinstance(x, np.ndarray) and x.ndim == 2 and not a:
+                    seen["n"] += 1          # the drafts: (slots, K)
+                return real_asarray(x, *a, **k)
+
+        monkeypatch.setattr(engine_mod, "jnp", Jnp())
+        real_put = engine_mod.KVPool.put_prefix
+
+        def put_prefix(self, key, lane, length):
+            seen["n"] += len(jax.tree_util.tree_leaves(lane))
+            return real_put(self, key, lane, length)
+
+        monkeypatch.setattr(engine_mod.KVPool, "put_prefix", put_prefix)
+        _, _, spans = self._run(tiny, rng, **_SPAN_ENGINES[kind])
+        steps = [sp for sp in spans if sp.name == "serving/step"]
+        assert seen["n"] > 0
+        assert sum(sp.counts["control_dispatches"]
+                   for sp in steps) == seen["n"]
+
+    def test_queued_span_runs_from_submit_to_admission(self, tiny, rng):
+        eng, ids, spans = self._run(tiny, rng, prefix_cache=False)
+        queued = {sp.req: sp for sp in spans
+                  if sp.name == "serving/queued"}
+        admits = {sp.req: sp for sp in spans if sp.name == "serving/admit"}
+        assert set(queued) == set(admits) == set(ids)
+        for rid in ids:
+            rec = eng.metrics.records[rid]
+            assert queued[rid].end_ns <= admits[rid].start_ns
+            assert queued[rid].start_ns * 1e-9 == pytest.approx(
+                rec.t_queued, abs=5e-3)
+            assert (queued[rid].end_ns - queued[rid].start_ns) >= 0
+
+    def test_buffer_does_not_grow_over_ten_thousand_steps(self, tiny, rng):
+        from apex1_tpu.obs import spine
+        cfg = tiny[0]
+        eng = _engine(tiny, prefix_cache=False)
+        for _ in range(spine.SPAN_CAPACITY):         # full before the run
+            spine.record_span("fill", 0, 0)
+        assert len(spine.snapshot()) == spine.SPAN_CAPACITY
+        for i in range(10_000):
+            if i % 500 == 0:
+                eng.submit(rng.integers(0, cfg.vocab_size, (5,)).tolist(),
+                           max_new_tokens=4)
+            eng.step()
+        snap = spine.snapshot()
+        assert len(snap) == spine.SPAN_CAPACITY
+        assert snap[-1].name == "serving/step"
+        assert sum(sp.name == "serving/step" for sp in snap) >= 10_000
+        assert len(eng.results) == 20
