@@ -53,7 +53,10 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
                      chunk_decode: bool = False):
     """Attention through the KV cache. ``q``/``k_new``/``v_new``:
     (B, H, S, D)/(B, Hkv, S, D) for the CURRENT tokens; ``cache`` holds
-    (B, Hkv, S_max, D); ``cache_index`` is the (traced) write position.
+    (B, Hkv, S_max, D); ``cache_index`` is the (traced) write position:
+    a scalar (one position for the batch — `generate`, beam search,
+    prefill) or a (B,) vector (one per row — the serving engine's
+    decode / verify step, rows at different depths); see `cache_write`.
 
     - Prefill (S > 1): must start from an empty cache at index 0 — runs
       the causal flash kernel over the current tokens (with ``bias``
@@ -103,10 +106,8 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
                                    sm_scale=sm_scale,
                                    chunk_decode=chunk_decode)
     idx = jnp.asarray(cache_index, jnp.int32)
-    k_all = jax.lax.dynamic_update_slice(
-        cache["k"], k_new.astype(cache["k"].dtype), (0, 0, idx, 0))
-    v_all = jax.lax.dynamic_update_slice(
-        cache["v"], v_new.astype(cache["v"].dtype), (0, 0, idx, 0))
+    k_all = cache_write(cache["k"], k_new, idx)
+    v_all = cache_write(cache["v"], v_new, idx)
     new_entry = {"k": k_all, "v": v_all}
     if S > 1 and not chunk_decode:
         # prefill attends only over the CURRENT tokens — valid only from
@@ -128,6 +129,33 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
     attn = cache_attend(q, k_all, v_all, idx, sm_scale=sm_scale,
                         bias=bias, valid_start=valid_start)
     return attn, new_entry
+
+
+def cache_write(cache, new, cache_index):
+    """``cache`` (B, Hkv, S_max, D) with ``new`` (B, Hkv, S, D) written
+    at ``cache_index``; the index's RANK chooses how.
+
+    A scalar (one position for the whole batch) is one
+    ``dynamic_update_slice``: S rows touched, in place in a donated
+    carry. A (B,) vector puts row b's chunk at ``idx[b] .. idx[b] + S -
+    1``; there a batched ``dynamic_update_slice`` would be a scatter,
+    which XLA expands on TPU into a loop of B one-row updates between
+    two layout copies of the whole cache. Selecting by position instead
+    is elementwise, so it fuses into the attention that reads the cache
+    next and the donated buffer is rewritten where it lies, once, in
+    the layout it is stored in (the serving engine's step;
+    `tests/test_engine_aot.py` holds that for a v5e). It rewrites every
+    position, which is why the scalar case does not take it. A position
+    past ``S_max`` is dropped there, not clamped onto earlier rows."""
+    idx = jnp.asarray(cache_index, jnp.int32)
+    new = new.astype(cache.dtype)
+    if idx.ndim == 0:
+        return jax.lax.dynamic_update_slice(cache, new, (0, 0, idx, 0))
+    rel = (jnp.arange(cache.shape[2], dtype=jnp.int32)[None, :]
+           - idx[:, None])[:, None, :, None]           # (B, 1, S_max, 1)
+    for j in range(new.shape[2]):
+        cache = jnp.where(rel == j, new[:, :, j:j + 1], cache)
+    return cache
 
 
 def last_real_logits(logits, lengths):
@@ -157,7 +185,9 @@ def generate(apply_fn: Callable, params, prompt_tokens, *,
 
     RAGGED batches: pass ``prompt_lens`` (B,) with ``prompt_tokens``
     right-padded to a common S0. TPU-first shape discipline — instead of
-    per-row dynamic cache indices (a scatter per step), rows are
+    per-row cache indices (`cached_attention` takes them, but then
+    rewrites the whole cache each step where a scalar index touches one
+    row of it), rows are
     LEFT-aligned once up front so every row's last real token sits at
     S0−1: the cache write index stays one scalar, decode steps stay one
     ``dynamic_update_slice``, and the pad prefix is masked out by the
@@ -703,8 +733,10 @@ def _decoder(model, num_kv_heads: int, head_dim: int):
                  return_hidden=False):
         B, S = tokens.shape
         if positions is None:
-            pos = jnp.asarray(cache_index, jnp.int32) + jnp.arange(S)
-            positions = jnp.broadcast_to(pos[None], (B, S))
+            # a scalar index, or one per row
+            pos = (jnp.asarray(cache_index, jnp.int32)[..., None]
+                   + jnp.arange(S))
+            positions = jnp.broadcast_to(pos, (B, S))
         # return_hidden is forwarded only when asked: models without
         # the kwarg keep working, and the serving engine's LoRA
         # epilogue path gets the pre-head hidden states it recomputes

@@ -18,15 +18,23 @@ EXACTLY TWO executables, traced once each for the life of the engine:
   its degenerate case), so admission never retraces. The slot id, the
   install-this-lane flag (zeros for a fresh request, a shared-prefix
   page for a sharer), and the real-token count are all traced operands.
-- **decode** — one step for ALL slots: a ``vmap`` of the batch-1 cached
-  forward over the pool's leading axis, each row carrying its OWN
-  traced cache index (rows are at different depths — that is the whole
-  point). Inactive lanes compute masked garbage into their free slot;
-  retirement and admission change only ARRAY VALUES, never shapes.
+- **decode** — one step for ALL slots: ONE cached forward at batch
+  ``max_slots`` over the pool, ``cache_index`` a VECTOR, each row
+  carrying its OWN traced cache index (rows are at different depths —
+  that is the whole point). `cached_attention` appends each row's K/V
+  by position (`generate.cache_write`: a select, which XLA fuses into
+  the attention's own read of the cache — no copy of the pool, no loop
+  over slots; `tests/test_engine_aot.py`). Only what is per row BY
+  CONTRACT is vmapped: the counter-keyed sampling and the LoRA
+  epilogue row. Inactive lanes compute masked garbage into their free
+  slot; retirement and admission change only ARRAY VALUES, never
+  shapes. A decoder whose rows interact (MoE capacity) sees the
+  pool's batch here, as on the paged kernel path.
 
 With ``num_draft > 0`` the decode executable is replaced by **verify**
-— same two-executable discipline, different second executable: a
-``vmap`` of a ``(1, num_draft + 1)`` chunk-decode forward that scores
+— same two-executable discipline, different second executable: the
+same batch forward over ``(max_slots, num_draft + 1)`` chunks (a plain
+decode step is its ``num_draft = 0`` case) that scores
 the previous token plus K host-proposed draft tokens in ONE dispatch
 and accepts the longest prefix matching the target's own counter-keyed
 samples (see SPECULATIVE DECODE below).
@@ -383,6 +391,8 @@ class Engine:
         # queued request per store version)
         self._probe_cache: Dict[int, bool] = {}
         self._probe_cache_ver = -1
+        self._sample_kw = dict(temperature=cfg.temperature,
+                               top_k=cfg.top_k, vocab_size=cfg.vocab_size)
         self._build_executables()
 
     def _resolve_page_size(self, make_cache, cache_dtype) -> int:
@@ -422,17 +432,24 @@ class Engine:
 
     # ---- the two executables -------------------------------------------
 
-    def _build_executables(self):
-        if self._paged:
-            return self._build_paged_executables()
+    def _model_calls(self):
+        """``(forward, lora_row, score, accept)`` — the model call,
+        the LoRA epilogue, and the step body that the dense executables
+        and the off-TPU paged ones (the parity gold) both run, so that
+        their token parity is structural.
+
+        ``score`` is the whole of a decode or verify step over a dense
+        ``(N, Hkv, L, D)`` cache tree: ONE batch-N chunk forward with a
+        per-row cache index (`cached_attention` appends each row's K/V
+        by position, see `generate.cache_write`), then every row's
+        canonical samples. Only what is per row BY CONTRACT is vmapped:
+        the counter-keyed sampling (`counter_sample`) and the LoRA
+        epilogue row. A plain decode step is the ``S = 1`` chunk."""
         cfg = self.cfg
         apply_fn = self._apply_fn
-        C = cfg.prefill_chunk
-        K = cfg.num_draft
         lora = self._lora is not None
         head = self._lora_head
-        sample_kw = dict(temperature=cfg.temperature, top_k=cfg.top_k,
-                         vocab_size=cfg.vocab_size)
+        sample_kw = self._sample_kw
 
         # LoRA epilogue leg (static — baked at build time like the
         # paged kernel_path): the forward returns HIDDEN states, the
@@ -441,24 +458,69 @@ class Engine:
         # rather than an unconditional add: the zero page makes an off
         # row's delta exactly 0.0, but `x + 0.0` can still flip -0.0
         # logits, and off rows must be BITWISE the base model's.
-        def head_logits(h):
-            return jnp.einsum("bsh,vh->bsv", h, head.astype(h.dtype),
-                              preferred_element_type=jnp.float32)
-
-        def forward(params, tokens, lane, idx, **kw):
+        def forward(params, tokens, cache, idx, **kw):
+            """``(logits, hidden, cache)``; hidden is None without LoRA."""
             if not lora:
-                return apply_fn(params, tokens, lane, idx, **kw)
-            h, lane = apply_fn(params, tokens, lane, idx,
-                               return_hidden=True, **kw)
-            return head_logits(h), h, lane
+                logits, cache = apply_fn(params, tokens, cache, idx, **kw)
+                return logits, None, cache
+            h, cache = apply_fn(params, tokens, cache, idx,
+                                return_hidden=True, **kw)
+            logits = jnp.einsum("bsh,vh->bsv", h, head.astype(h.dtype),
+                                preferred_element_type=jnp.float32)
+            return logits, h, cache
 
         def lora_row(logits, h, a_pg, b_pg, lrow, on):
+            # one slot's (S, V) logits and (S, H) hidden rows
             from apex1_tpu.ops.lora_epilogue import _lora_delta_ref
             bt = jnp.broadcast_to(lrow[None, :],
                                   (h.shape[0], lrow.shape[0]))
             delta = _lora_delta_ref(h, a_pg, b_pg, bt)
             return jnp.where(on, logits + delta.astype(logits.dtype),
                              logits)
+
+        def score(params, cache, chunks, idxs, seeds, pos, a_pg=None,
+                  b_pg=None, lbt=None, lon=None):
+            steps = jnp.arange(chunks.shape[1], dtype=jnp.int32)
+            logits, h, cache = forward(
+                params, chunks, cache, idxs,
+                positions=idxs[:, None] + steps, chunk_decode=True)
+            if lora:
+                logits = jax.vmap(
+                    lora_row, in_axes=(0, 0, None, None, 0, 0))(
+                        logits, h, a_pg, b_pg, lbt, lon)
+            # the target's CANONICAL stream at output positions
+            # p..p+S-1, token i keyed fold_in(key(seed), i) — exact-match
+            # acceptance means emitted tokens are these samples
+            # verbatim, so speculation cannot perturb the (params,
+            # prompt, seed) purity resubmission rides
+            tgt = jax.vmap(lambda lg, seed, p: counter_sample(
+                lg, seed, p + steps, **sample_kw))(logits, seeds, pos)
+            return tgt, cache
+
+        def accept(tgt, drafts, active, idxs, pos):
+            """Longest draft prefix equal to the target's own samples,
+            per slot: ``(acc, nxt, idxs, pos)`` after the round."""
+            acc = jnp.sum(jnp.cumprod(
+                (tgt[:, :-1] == drafts).astype(jnp.int32), axis=1),
+                axis=1)
+            acc = jnp.where(active, acc, 0)
+            adv = jnp.where(active, acc + 1, 0)
+            nxt = jnp.where(
+                active,
+                jnp.take_along_axis(tgt, acc[:, None], 1)[:, 0],
+                cfg.pad_id)
+            return acc, nxt, idxs + adv, pos + adv
+
+        return forward, lora_row, score, accept
+
+    def _build_executables(self):
+        if self._paged:
+            return self._build_paged_executables()
+        cfg = self.cfg
+        C = cfg.prefill_chunk
+        lora = self._lora is not None
+        sample_kw = self._sample_kw
+        forward, lora_row, score, accept = self._model_calls()
 
         def prefill(params, pool, slot, init_lane, install, tokens, idx,
                     n_real, seed, a_pg=None, b_pg=None, lbt=None,
@@ -472,14 +534,9 @@ class Engine:
                 init_lane)
             positions = (jnp.asarray(idx, jnp.int32)
                          + jnp.arange(C, dtype=jnp.int32))[None]
-            if lora:
-                logits, h, lane = forward(params, tokens, lane, idx,
-                                          positions=positions,
-                                          chunk_decode=True)
-            else:
-                logits, lane = apply_fn(params, tokens, lane, idx,
-                                        positions=positions,
-                                        chunk_decode=True)
+            logits, h, lane = forward(params, tokens, lane, idx,
+                                      positions=positions,
+                                      chunk_decode=True)
             pool = jax.tree_util.tree_map(
                 lambda p, l: jax.lax.dynamic_update_slice_in_dim(
                     p, l.astype(p.dtype), slot, 0), pool, lane)
@@ -498,82 +555,31 @@ class Engine:
             return tok, pool
 
         def decode(params, pool, toks, idxs, active, seeds, pos,
-                   a_pg=None, b_pg=None, lbt=None, lon=None):
+                   *lora_args):
             self.trace_counts["decode"] += 1    # the compile-count hook
-
-            def row(tok, lane, idx, seed, p, lrow, on):
-                lane = jax.tree_util.tree_map(lambda x: x[None], lane)
-                if lora:
-                    logits, h, lane = forward(params, tok.reshape(1, 1),
-                                              lane, idx)
-                    lg = lora_row(logits[:, -1], h[:, -1], a_pg, b_pg,
-                                  lrow, on)
-                else:
-                    logits, lane = apply_fn(params, tok.reshape(1, 1),
-                                            lane, idx)
-                    lg = logits[:, -1]
-                key = jax.random.fold_in(jax.random.key(seed), p)
-                nxt = sample_token(lg, key, **sample_kw)[0]
-                return nxt, jax.tree_util.tree_map(lambda x: x[0], lane)
-
-            if lora:
-                nxt, pool = jax.vmap(
-                    row, in_axes=(0, 0, 0, 0, 0, 0, 0))(
-                        toks, pool, idxs, seeds, pos, lbt, lon)
-            else:
-                nxt, pool = jax.vmap(
-                    row, in_axes=(0, 0, 0, 0, 0, None, None))(
-                        toks, pool, idxs, seeds, pos, None, None)
-            nxt = jnp.where(active, nxt, cfg.pad_id)
+            tgt, pool = score(params, pool, toks[:, None], idxs, seeds,
+                              pos, *lora_args)
+            nxt = jnp.where(active, tgt[:, 0], cfg.pad_id)
             adv = active.astype(jnp.int32)
             return nxt, idxs + adv, pos + adv, pool
 
         def verify(params, pool, toks, idxs, active, seeds, pos,
-                   drafts, a_pg=None, b_pg=None, lbt=None, lon=None):
+                   drafts, *lora_args):
             self.trace_counts["verify"] += 1    # the compile-count hook
+            tgt, pool = score(
+                params, pool, jnp.concatenate([toks[:, None], drafts], 1),
+                idxs, seeds, pos, *lora_args)
+            return (tgt, *accept(tgt, drafts, active, idxs, pos), pool)
 
-            def row(tok, lane, idx, seed, p, dr, lrow, on):
-                lane = jax.tree_util.tree_map(lambda x: x[None], lane)
-                chunk = jnp.concatenate([tok[None], dr])      # (K+1,)
-                if lora:
-                    logits, h, lane = forward(params, chunk[None], lane,
-                                              idx, chunk_decode=True)
-                    lg = lora_row(logits[0], h[0], a_pg, b_pg, lrow, on)
-                else:
-                    logits, lane = apply_fn(params, chunk[None], lane,
-                                            idx, chunk_decode=True)
-                    lg = logits[0]
-                # the target's CANONICAL stream at positions p..p+K —
-                # exact-match acceptance means emitted tokens are these
-                # samples verbatim, so speculation cannot perturb the
-                # (params, prompt, seed) purity resubmission rides
-                tgt = counter_sample(
-                    lg, seed, p + jnp.arange(K + 1, dtype=jnp.int32),
-                    **sample_kw)
-                a = jnp.sum(jnp.cumprod(
-                    (tgt[:K] == dr).astype(jnp.int32)))
-                return tgt, a, jax.tree_util.tree_map(
-                    lambda x: x[0], lane)
-
-            if lora:
-                tgt, acc, pool = jax.vmap(
-                    row, in_axes=(0, 0, 0, 0, 0, 0, 0, 0))(
-                        toks, pool, idxs, seeds, pos, drafts, lbt, lon)
-            else:
-                tgt, acc, pool = jax.vmap(
-                    row, in_axes=(0, 0, 0, 0, 0, 0, None, None))(
-                        toks, pool, idxs, seeds, pos, drafts, None,
-                        None)
-            acc = jnp.where(active, acc, 0)
-            adv = jnp.where(active, acc + 1, 0)
-            nxt = jnp.where(
-                active,
-                jnp.take_along_axis(tgt, acc[:, None], 1)[:, 0],
-                cfg.pad_id)
-            return tgt, acc, nxt, idxs + adv, pos + adv, pool
-
-        # donate the pool so XLA updates the cache in place (every
-        # backend: the CPU tests run the same aliasing the chip does)
+        # the pool is donated, on every backend (the CPU tests run the
+        # same aliasing the chip does). "In place" for the step means:
+        # each leaf's output IS its input buffer, and the one fusion
+        # per leaf that computes the attention scores (and the one
+        # that computes P.V) also writes the leaf back with the new
+        # rows selected in - no copy of a leaf, no loop over slots, the
+        # layout the pool is stored in. `tests/test_engine_aot.py`
+        # compiles both for a v5e at the chat cell's shapes and holds
+        # exactly that. Prefill touches one lane's chunk rows only.
         self._prefill = jax.jit(prefill, donate_argnums=1)
         if self._spec:
             self._verify = jax.jit(verify, donate_argnums=1)
@@ -584,9 +590,10 @@ class Engine:
         """The paged-mode executables. Two shapes of the same contract:
 
         - **off-TPU (the parity gold)**: gather each slot's dense lane
-          from its pages, run the UNCHANGED reference bodies (the same
-          vmap-of-batch-1 rows, the same in-row sampling ops as the
-          dense executables), scatter only the written window back.
+          from its pages, run the UNCHANGED reference body (`score` of
+          `_model_calls`: the same batch-N forward, the same per-row
+          sampling ops as the dense executables), scatter only the
+          written window back.
           Every position the reference attends or writes is
           bit-identical to the dense pool's lane — garbage beyond a
           row's horizon is masked to an exact zero either way — so
@@ -604,27 +611,14 @@ class Engine:
           ``ops.force_impl("pallas")``.
         """
         cfg = self.cfg
-        apply_fn = self._apply_fn
         C = cfg.prefill_chunk
         K = cfg.num_draft
         L = self.kv.lane_len
         lora = self._lora is not None
-        head = self._lora_head
-        sample_kw = dict(temperature=cfg.temperature, top_k=cfg.top_k,
-                         vocab_size=cfg.vocab_size)
+        sample_kw = self._sample_kw
         tree_map = jax.tree_util.tree_map
         kernel_path = use_pallas()
-
-        def head_logits(h):
-            return jnp.einsum("bsh,vh->bsv", h, head.astype(h.dtype),
-                              preferred_element_type=jnp.float32)
-
-        def forward(params, tokens, cache, idx, **kw):
-            if not lora:
-                return apply_fn(params, tokens, cache, idx, **kw)
-            h, cache = apply_fn(params, tokens, cache, idx,
-                                return_hidden=True, **kw)
-            return head_logits(h), h, cache
+        forward, lora_row, score, accept = self._model_calls()
 
         def lora_batch(logits, h, a_pg, b_pg, lbt, lon):
             # (N, V) logits + (N, H) hidden rows -> epilogue delta via
@@ -635,14 +629,6 @@ class Engine:
             delta = lora_delta(h, a_pg, b_pg, lbt)
             return jnp.where(lon[:, None],
                              logits + delta.astype(logits.dtype),
-                             logits)
-
-        def lora_rowwise(logits, h, a_pg, b_pg, lrow, on):
-            from apex1_tpu.ops.lora_epilogue import _lora_delta_ref
-            bt = jnp.broadcast_to(lrow[None, :],
-                                  (h.shape[0], lrow.shape[0]))
-            delta = _lora_delta_ref(h, a_pg, b_pg, bt)
-            return jnp.where(on, logits + delta.astype(logits.dtype),
                              logits)
 
         def window(lane, start, width):
@@ -661,35 +647,39 @@ class Engine:
             return {layer: {"k": pc.k_pages, "v": pc.v_pages}
                     for layer, pc in cache.items()}
 
+        def score_lanes(params, pages, bt, chunks, idxs, seeds, pos,
+                        *lora_args):
+            # the parity gold: dense lanes out of the pages, the dense
+            # engine's own step body, the written window back.
+            # Inactive rows (block-table = trash page) scatter their
+            # masked garbage into page 0 — harmless, never attended,
+            # never owned
+            lanes = tree_map(lambda p: gather_pages(p, bt, L), pages)
+            tgt, lanes = score(params, lanes, chunks, idxs, seeds, pos,
+                               *lora_args)
+            pages = tree_map(
+                lambda pg, ln: scatter_pages(
+                    pg, bt, window(ln, idxs, chunks.shape[1]), idxs),
+                pages, lanes)
+            return tgt, pages
+
         def prefill(params, pages, bt, slot, tokens, idx, n_real, seed,
                     a_pg=None, b_pg=None, lbt=None, lon=None):
             self.trace_counts["prefill"] += 1   # the compile-count hook
             bt_row = jax.lax.dynamic_slice_in_dim(bt, slot, 1, 0)
             positions = (jnp.asarray(idx, jnp.int32)
                          + jnp.arange(C, dtype=jnp.int32))[None]
-            h = None
             if kernel_path:
-                cache = paged_cache(pages, bt_row)
-                if lora:
-                    logits, h, cache = forward(params, tokens, cache,
-                                               idx, positions=positions,
-                                               chunk_decode=True)
-                else:
-                    logits, cache = apply_fn(params, tokens, cache, idx,
-                                             positions=positions,
-                                             chunk_decode=True)
+                logits, h, cache = forward(
+                    params, tokens, paged_cache(pages, bt_row), idx,
+                    positions=positions, chunk_decode=True)
                 pages = unpack_cache(cache)
             else:
                 lane = tree_map(lambda p: gather_pages(p, bt_row, L),
                                 pages)
-                if lora:
-                    logits, h, lane = forward(params, tokens, lane, idx,
-                                              positions=positions,
-                                              chunk_decode=True)
-                else:
-                    logits, lane = apply_fn(params, tokens, lane, idx,
-                                            positions=positions,
-                                            chunk_decode=True)
+                logits, h, lane = forward(params, tokens, lane, idx,
+                                          positions=positions,
+                                          chunk_decode=True)
                 idx_v = jnp.asarray(idx, jnp.int32)[None]
                 pages = tree_map(
                     lambda pg, ln: scatter_pages(
@@ -704,98 +694,55 @@ class Engine:
             if lora:
                 lrow = jax.lax.dynamic_slice_in_dim(lbt, slot, 1, 0)[0]
                 on = jax.lax.dynamic_slice_in_dim(lon, slot, 1, 0)[0]
-                lg = lora_rowwise(lg, last_real_logits(h, n_real[None]),
-                                  a_pg, b_pg, lrow, on)
+                lg = lora_row(lg, last_real_logits(h, n_real[None]),
+                              a_pg, b_pg, lrow, on)
             tok = fused_sample(lg, jnp.asarray(seed, jnp.int32)[None],
                                jnp.zeros((1,), jnp.int32),
                                **sample_kw)[0]
             return tok, pages
 
         def decode(params, pages, bt, toks, idxs, active, seeds, pos,
-                   a_pg=None, b_pg=None, lbt=None, lon=None):
+                   *lora_args):
             self.trace_counts["decode"] += 1    # the compile-count hook
             if kernel_path:
-                cache = paged_cache(pages, bt)
+                logits, h, cache = forward(
+                    params, toks[:, None], paged_cache(pages, bt), idxs,
+                    positions=idxs[:, None])
+                lg = logits[:, -1]
                 if lora:
-                    logits, h, cache = forward(params, toks[:, None],
-                                               cache, idxs,
-                                               positions=idxs[:, None])
-                    lg = lora_batch(logits[:, -1], h[:, -1], a_pg,
-                                    b_pg, lbt, lon)
-                else:
-                    logits, cache = apply_fn(params, toks[:, None],
-                                             cache, idxs,
-                                             positions=idxs[:, None])
-                    lg = logits[:, -1]
+                    lg = lora_batch(lg, h[:, -1], *lora_args)
                 pages = unpack_cache(cache)
                 nxt = fused_sample(lg, seeds, pos, **sample_kw)
             else:
-                lanes = tree_map(lambda p: gather_pages(p, bt, L),
-                                 pages)
-
-                def row(tok, lane, idx, seed, p, lrow, on):
-                    lane = tree_map(lambda x: x[None], lane)
-                    if lora:
-                        logits, h, lane = forward(params,
-                                                  tok.reshape(1, 1),
-                                                  lane, idx)
-                        lg = lora_rowwise(logits[:, -1], h[:, -1],
-                                          a_pg, b_pg, lrow, on)
-                    else:
-                        logits, lane = apply_fn(params,
-                                                tok.reshape(1, 1),
-                                                lane, idx)
-                        lg = logits[:, -1]
-                    key = jax.random.fold_in(jax.random.key(seed), p)
-                    nxt = sample_token(lg, key, **sample_kw)[0]
-                    return nxt, tree_map(lambda x: x[0], lane)
-
-                if lora:
-                    nxt, lanes = jax.vmap(
-                        row, in_axes=(0, 0, 0, 0, 0, 0, 0))(
-                            toks, lanes, idxs, seeds, pos, lbt, lon)
-                else:
-                    nxt, lanes = jax.vmap(
-                        row, in_axes=(0, 0, 0, 0, 0, None, None))(
-                            toks, lanes, idxs, seeds, pos, None, None)
-                # inactive rows (block-table = trash page) scatter
-                # their masked garbage into page 0 — harmless, never
-                # attended, never owned
-                pages = tree_map(
-                    lambda pg, ln: scatter_pages(
-                        pg, bt, window(ln, idxs, 1), idxs),
-                    pages, lanes)
+                tgt, pages = score_lanes(params, pages, bt,
+                                         toks[:, None], idxs, seeds,
+                                         pos, *lora_args)
+                nxt = tgt[:, 0]
             nxt = jnp.where(active, nxt, cfg.pad_id)
             adv = active.astype(jnp.int32)
             return nxt, idxs + adv, pos + adv, pages
 
         def verify(params, pages, bt, toks, idxs, active, seeds, pos,
-                   drafts, a_pg=None, b_pg=None, lbt=None, lon=None):
+                   drafts, *lora_args):
             self.trace_counts["verify"] += 1    # the compile-count hook
+            chunks = jnp.concatenate([toks[:, None], drafts], 1)
             if kernel_path:
-                cache = paged_cache(pages, bt)
-                chunks = jnp.concatenate([toks[:, None], drafts], 1)
                 positions = (idxs[:, None]
                              + jnp.arange(K + 1, dtype=jnp.int32)[None])
+                logits, h, cache = forward(
+                    params, chunks, paged_cache(pages, bt), idxs,
+                    positions=positions, chunk_decode=True)
                 if lora:
-                    logits, h, cache = forward(params, chunks, cache,
-                                               idxs,
-                                               positions=positions,
-                                               chunk_decode=True)
                     # flatten the (N, K+1) verify rows into the batch
                     # axis the paged delta kernel streams — each row
                     # repeats its slot's adapter block-table entry
-                    Hd = h.shape[-1]
-                    btr = jnp.repeat(lbt, K + 1, axis=0)
-                    onr = jnp.repeat(lon, K + 1, axis=0)
+                    a_pg, b_pg, lbt, lon = lora_args
                     logits = lora_batch(
                         logits.reshape(-1, logits.shape[-1]),
-                        h.reshape(-1, Hd), a_pg, b_pg, btr, onr
+                        h.reshape(-1, h.shape[-1]), a_pg, b_pg,
+                        jnp.repeat(lbt, K + 1, axis=0),
+                        jnp.repeat(lon, K + 1, axis=0)
                     ).reshape(logits.shape)
-                else:
-                    logits, cache = apply_fn(params, chunks, cache,
-                                             idxs, positions=positions,
-                                             chunk_decode=True)
                 pages = unpack_cache(cache)
                 posm = (pos[:, None]
                         + jnp.arange(K + 1, dtype=jnp.int32)[None])
@@ -805,56 +752,10 @@ class Engine:
                     logits.reshape(-1, V), seedm.reshape(-1),
                     posm.reshape(-1),
                     **sample_kw).reshape(-1, K + 1)
-                acc = jnp.sum(jnp.cumprod(
-                    (tgt[:, :K] == drafts).astype(jnp.int32), axis=1),
-                    axis=1)
             else:
-                lanes = tree_map(lambda p: gather_pages(p, bt, L),
-                                 pages)
-
-                def row(tok, lane, idx, seed, p, dr, lrow, on):
-                    lane = tree_map(lambda x: x[None], lane)
-                    chunk = jnp.concatenate([tok[None], dr])  # (K+1,)
-                    if lora:
-                        logits, h, lane = forward(params, chunk[None],
-                                                  lane, idx,
-                                                  chunk_decode=True)
-                        lg = lora_rowwise(logits[0], h[0], a_pg, b_pg,
-                                          lrow, on)
-                    else:
-                        logits, lane = apply_fn(params, chunk[None],
-                                                lane, idx,
-                                                chunk_decode=True)
-                        lg = logits[0]
-                    tgt = counter_sample(
-                        lg, seed,
-                        p + jnp.arange(K + 1, dtype=jnp.int32),
-                        **sample_kw)
-                    a = jnp.sum(jnp.cumprod(
-                        (tgt[:K] == dr).astype(jnp.int32)))
-                    return tgt, a, tree_map(lambda x: x[0], lane)
-
-                if lora:
-                    tgt, acc, lanes = jax.vmap(
-                        row, in_axes=(0, 0, 0, 0, 0, 0, 0, 0))(
-                            toks, lanes, idxs, seeds, pos, drafts,
-                            lbt, lon)
-                else:
-                    tgt, acc, lanes = jax.vmap(
-                        row, in_axes=(0, 0, 0, 0, 0, 0, None, None))(
-                            toks, lanes, idxs, seeds, pos, drafts,
-                            None, None)
-                pages = tree_map(
-                    lambda pg, ln: scatter_pages(
-                        pg, bt, window(ln, idxs, K + 1), idxs),
-                    pages, lanes)
-            acc = jnp.where(active, acc, 0)
-            adv = jnp.where(active, acc + 1, 0)
-            nxt = jnp.where(
-                active,
-                jnp.take_along_axis(tgt, acc[:, None], 1)[:, 0],
-                cfg.pad_id)
-            return tgt, acc, nxt, idxs + adv, pos + adv, pages
+                tgt, pages = score_lanes(params, pages, bt, chunks,
+                                         idxs, seeds, pos, *lora_args)
+            return (tgt, *accept(tgt, drafts, active, idxs, pos), pages)
 
         self._prefill = jax.jit(prefill, donate_argnums=1)
         if self._spec:

@@ -420,8 +420,9 @@ def toy_decoder(vocab_size: int = 61):
     milliseconds: multi-replica chaos drills pay supervisor cost, not
     XLA cost. The cache stores one small integer per position, so the
     int8 ``cache_dtype`` profile is EXACT here (values < 128)."""
-    import jax
     import jax.numpy as jnp
+
+    from apex1_tpu.models.generate import cache_write
 
     def make_cache(batch: int, max_len: int, dtype=None):
         dt = jnp.float32 if dtype is None else dtype
@@ -433,16 +434,17 @@ def toy_decoder(vocab_size: int = 61):
         B, S = tokens.shape
         idx = jnp.asarray(cache_index, jnp.int32)
         vals = (tokens + 1).astype(h.dtype).reshape(B, 1, S, 1)
-        zero = jnp.int32(0)
-        h = jax.lax.dynamic_update_slice(h, vals, (zero, zero, idx, zero))
+        # a scalar index or one per row, as `cached_attention` takes it
+        h = cache_write(h, vals, idx)
         # causal-prefix sum per query: pos <= idx + j (the chunk-verify
         # horizon), over the UPDATED cache so each query sees itself —
         # pad/stale residue beyond the horizon never enters
         pos = jnp.arange(h.shape[2], dtype=jnp.int32)
-        qpos = idx + jnp.arange(S, dtype=jnp.int32)
-        mask = (pos[None, :] <= qpos[:, None]).astype(jnp.float32)
+        qpos = jnp.broadcast_to(
+            idx[..., None] + jnp.arange(S, dtype=jnp.int32), (B, S))
+        mask = (pos <= qpos[..., None]).astype(jnp.float32)
         hv = h[:, 0, :, 0].astype(jnp.float32)
-        s = jnp.einsum("bp,sp->bs", hv, mask)       # (B, S)
+        s = jnp.einsum("bp,bsp->bs", hv, mask)      # (B, S)
         su = (s.astype(jnp.uint32) * params["w"].astype(jnp.uint32))
         v = jnp.arange(vocab_size, dtype=jnp.uint32)
         logits = -(((su[..., None] * jnp.uint32(2654435761)

@@ -238,6 +238,30 @@ class TestLoraEngineParity:
         assert _run(eng, set(PROMPTS)) == dense
         assert eng.trace_counts == {"prefill": 1, "verify": 1}
 
+    @pytest.mark.parametrize("kind", ["dense", "paged", "spec"])
+    def test_full_lane_in_mixed_batch_bitwise_vs_solo(self, tiny, kind):
+        """The epilogue row is vmapped over a batch forward whose lanes
+        sit at different depths: a tenant's request that fills its lane
+        to the last usable position (5 + 28 - 1 == max_len), beside
+        another tenant's and an adapterless one, equals its solo run."""
+        kw = {"dense": {}, "paged": dict(paged=True),
+              "spec": dict(num_draft=2)}[kind]
+
+        def run(active):
+            eng = _engine(tiny, **kw)
+            for rid in sorted(active):
+                toks, tenant = PROMPTS[rid]
+                eng.submit(np.asarray(toks, np.int32),
+                           28 if rid == 101 else 8, req_id=rid,
+                           tenant=tenant, seed=1000 + rid)
+            eng.run(max_steps=200)
+            return {rid: list(eng.results[rid].tokens) for rid in active}
+
+        mixed = run(set(PROMPTS))
+        assert len(mixed[101]) == 28
+        for rid in PROMPTS:
+            assert mixed[rid] == run({rid})[rid], rid
+
     def test_slots_reusable_after_retire(self, tiny):
         """Adapter pages release at retirement: more requests than
         slots forces reuse; refcounts must return to quiescent."""

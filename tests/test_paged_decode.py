@@ -422,6 +422,32 @@ class TestPagedEngineParity:
         assert paged == dense
         assert eng.trace_counts == {"prefill": 1, "verify": 1}
 
+    @pytest.mark.parametrize("num_draft", [0, 3],
+                             ids=["decode", "verify"])
+    def test_last_position_and_reused_lane_bitwise(self, tiny, num_draft):
+        """Both pools run the same step body (`Engine._model_calls`'s
+        `score`) over lanes at different depths: one request fills its
+        lane to the last usable position (12 + 5 - 1 == max_len), lanes
+        are reused after retirement, and the paged streams equal the
+        dense ones bit for bit at temperature 0.7."""
+        base = tiny[4]
+
+        def run(**kw):
+            eng = _engine(tiny, max_slots=2, max_len=16, temperature=0.7,
+                          num_draft=num_draft, **kw)
+            ids = [eng.submit(base[:12], max_new_tokens=5, seed=3),
+                   eng.submit(base[:3], max_new_tokens=2, seed=4)]
+            for _ in range(3):
+                eng.step()
+            ids += [eng.submit(base[:7], max_new_tokens=6, seed=5),
+                    eng.submit(base[:5], max_new_tokens=4, seed=6)]
+            eng.run(max_steps=100)
+            return [list(eng.results[r].tokens) for r in ids]
+
+        dense = run()
+        assert [len(t) for t in dense] == [5, 2, 6, 4]
+        assert run(paged=True) == dense
+
     def test_int8_cache_tier_bitwise(self, tiny):
         """The int8 KV tier quantizes at scatter exactly like the dense
         tier's update — the paged path must not perturb a single

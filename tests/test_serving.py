@@ -236,6 +236,100 @@ class TestContinuousBatching:
         assert "ttft_p50_ms" in s and "ttft_p99_ms" in s
 
 
+class TestPerRowCacheWrite:
+    """PR 26: the step is ONE batch forward whose `cache_index` holds
+    one position per lane; `cached_attention` then selects the new rows
+    into the cache by position (`generate.cache_write`). It must be the
+    scalar-index write, row by row, bit for bit — at the first position,
+    at the last one that fits, and past the end."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("s", [1, 3], ids=["decode", "chunk"])
+    def test_per_row_index_equals_scalar_index_loop_bitwise(self, s,
+                                                            dtype):
+        from apex1_tpu.models.generate import cached_attention
+        B, H, Hkv, S_max, D = 5, 4, 2, 12, 8
+        ks = jax.random.split(jax.random.key(s), 5)
+        q = jax.random.normal(ks[0], (B, H, s, D), dtype)
+        k_new = jax.random.normal(ks[1], (B, Hkv, s, D), dtype)
+        v_new = jax.random.normal(ks[2], (B, Hkv, s, D), dtype)
+        # a cache that is NOT zero: rows the write must leave alone
+        cache = {"k": jax.random.normal(ks[3], (B, Hkv, S_max, D), dtype),
+                 "v": jax.random.normal(ks[4], (B, Hkv, S_max, D), dtype)}
+        idx = jnp.asarray([0, 4, S_max - s, 7, 2], jnp.int32)
+        attn, new = cached_attention(q, k_new, v_new, cache, idx,
+                                     chunk_decode=True)
+        for b in range(B):
+            row = slice(b, b + 1)
+            a1, n1 = cached_attention(
+                q[row], k_new[row], v_new[row],
+                {"k": cache["k"][row], "v": cache["v"][row]}, idx[b],
+                chunk_decode=True)
+            np.testing.assert_array_equal(np.asarray(attn[row], np.float32),
+                                          np.asarray(a1, np.float32))
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(new[name][row], np.float32),
+                    np.asarray(n1[name], np.float32))
+
+    def test_row_past_the_end_is_dropped_not_clamped(self):
+        from apex1_tpu.models.generate import cache_write
+        S_max = 6
+        cache = jnp.arange(3 * S_max, dtype=jnp.float32).reshape(
+            3, 1, S_max, 1)
+        new = -jnp.ones((3, 1, 2, 1), jnp.float32) * jnp.asarray(
+            [1.0, 2.0]).reshape(1, 1, 2, 1)
+        got = np.asarray(cache_write(
+            cache, new, jnp.asarray([S_max - 1, S_max, S_max + 3])))
+        want = np.asarray(cache).copy()
+        want[0, 0, S_max - 1, 0] = -1.0       # the chunk's first row;
+        np.testing.assert_array_equal(got, want)   # nothing else moved
+
+    @pytest.mark.parametrize("case", ["last_position", "freed_lane_reused",
+                                      "freed_lane_reused_deferred_read"])
+    def test_lanes_at_different_depths_match_solo(self, tiny, rng, case):
+        """Lanes at different depths in one step, one of them writing
+        the LAST usable position (index max_len - 1); and a lane that
+        retires, computes masked garbage for some steps, then belongs
+        to a new request while its neighbour decodes on."""
+        cfg, _, _, _, solo = tiny
+        kw = dict(max_slots=3, max_len=16, prefill_chunk=4)
+        if case == "last_position":
+            eng = _engine(tiny, **kw)
+            plan = [(9, 8), (3, 5), (6, 4)]     # 9 + 8 - 1 == max_len
+            prompts = [rng.integers(0, cfg.vocab_size, (L,)).tolist()
+                       for L, _ in plan]
+            ids = [eng.submit(prompts[0], max_new_tokens=plan[0][1])]
+            eng.step()
+            eng.step()
+            ids += [eng.submit(p, max_new_tokens=n)
+                    for p, (_, n) in zip(prompts[1:], plan[1:])]
+            eng.run(max_steps=100)
+        else:
+            # eos_id set: every step's tokens are read back; unset: the
+            # deferred log, read at retirement
+            eng = _engine(tiny, max_slots=2, max_len=24, prefill_chunk=4,
+                          eos_id=(None if case.endswith("deferred_read")
+                                  else cfg.vocab_size + 1))
+            plan = [(3, 2), (5, 14), (7, 6), (4, 5)]
+            prompts = [rng.integers(0, cfg.vocab_size, (L,)).tolist()
+                       for L, _ in plan]
+            ids = [eng.submit(p, max_new_tokens=n)
+                   for p, (_, n) in zip(prompts[:2], plan[:2])]
+            for _ in range(5):                  # lane 0 retired, idle
+                eng.step()
+            assert eng._slots[0] is None and eng._slots[1] is not None
+            ids += [eng.submit(p, max_new_tokens=n)
+                    for p, (_, n) in zip(prompts[2:], plan[2:])]
+            eng.run(max_steps=100)
+        for p, (_, n), rid in zip(prompts, plan, ids):
+            assert eng.results[rid].status == "done"
+            np.testing.assert_array_equal(eng.results[rid].tokens,
+                                          solo(p, n))
+        assert eng.trace_counts == {"prefill": 1, "decode": 1}
+
+
 class TestPrefixRefcounts:
     def test_refcount_never_frees_live_page(self, tiny, rng):
         cfg = tiny[0]

@@ -112,6 +112,56 @@ class TestSpecTokenParity:
         assert s["done"] == 6
         assert "accept_rate" in s        # banked, whatever its value
 
+    @pytest.mark.parametrize("accepted", ["none", "some", "all"])
+    def test_verify_rows_at_per_lane_offsets_match_solo(self, tiny, rng,
+                                                        accepted):
+        """The verify step writes K+1 rows per lane at that lane's own
+        offset (PR 26: selected in by position, one batch forward). With
+        drafts that are all wrong, right up to the second, or all right
+        — 0, some, all of them accepted — lanes at different depths emit
+        solo greedy `generate`'s tokens, one of them up to the last
+        usable position (its last rounds write into the pool's slack,
+        K + 1 > prefill_chunk, to position max_len + K - 1)."""
+        cfg, params, apply_fn, make_cache, solo = tiny
+        K, V = 3, cfg.vocab_size
+        plan = [(9, 8), (3, 6), (6, 5), (4, 7)]   # 9 + 8 - 1 == max_len
+        prompts = [rng.integers(0, V, (L,)).tolist() for L, _ in plan]
+        want = {tuple(p): [int(t) for t in solo(p, n)]
+                for p, (_, n) in zip(prompts, plan)}
+
+        def propose(history, k):
+            p = next(q for q in want if tuple(history[:len(q)]) == q)
+            i = len(history) - len(p)
+            out = (want[p] + [0] * k)[i:i + k]
+            if accepted == "none":
+                out[0] = (out[0] + 1) % V
+            elif accepted == "some":
+                out[1] = (out[1] + 1) % V
+            return np.asarray(out, np.int32)
+
+        eng = Engine(apply_fn, make_cache, params,
+                     EngineConfig(max_slots=3, max_len=16, prefill_chunk=2,
+                                  num_draft=K, vocab_size=V),
+                     draft_propose=propose)
+        s_max = jax.tree_util.tree_leaves(eng.kv.cache)[0].shape[2]
+        assert s_max == 16 + K
+        ids = [eng.submit(prompts[0], max_new_tokens=plan[0][1])]
+        eng.step()
+        ids += [eng.submit(p, max_new_tokens=n)
+                for p, (_, n) in zip(prompts[1:], plan[1:])]
+        eng.run(max_steps=200)
+        for p, rid in zip(prompts, ids):
+            assert eng.results[rid].status == "done"
+            assert list(eng.results[rid].tokens) == want[tuple(p)]
+        recs = [eng.metrics.records[r] for r in ids]
+        if accepted == "none":
+            assert all(r.n_accepted == 0 for r in recs)
+        elif accepted == "all":
+            assert all(r.n_accepted == r.n_drafted for r in recs)
+        else:
+            assert all(0 < r.n_accepted < r.n_drafted for r in recs)
+        assert eng.trace_counts == {"prefill": 1, "verify": 1}
+
     def test_sampled_identical_to_nonspec_engine(self):
         """Temperature 0.9: exact-match verify emits the target's
         counter stream verbatim — bit-identical to the plain engine,

@@ -436,9 +436,10 @@ def _run_checks(args) -> bool:
 
         # chip_smoke.py's serve phase: the two serving.Engine
         # executables for GPT-2 125M at the smoke's own sizes, dense
-        # (vmap-of-rows over the donated pool) and paged (paged_attend +
-        # fused_sample kernels) — in no other gate, and on the chip the
-        # paged form builds a different program than any CPU test runs
+        # (one batch forward over the donated pool) and paged
+        # (paged_attend + fused_sample kernels) — in no other gate, and
+        # on the chip the paged form builds a different program than
+        # any CPU test runs
         from apex1_tpu.models.generate import gpt2_decoder
         from apex1_tpu.serving.engine import Engine
 
