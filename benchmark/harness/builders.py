@@ -1,8 +1,28 @@
-"""The glue to the system under test: one builder per model FAMILY (named
-by the configuration file's `builder` key). A builder takes the published
-sizes, hands the system's own model, loss and decoder to the runners, and
-says how a batch of that family is drawn. Imports of the program happen
-inside the methods, so a cell imports only what its kind needs.
+"""The glue to the system under test: one builder per model FAMILY, in a
+file of its own, `benchmark/builders/<builder>.py`, found by the
+configuration file's `builder` key (`get`), so a PR that brings a family
+adds a file and edits none. What every family shares stays here: the
+seed's key, the weight generator, the program's fused optimizers.
+
+The protocol. The file holds a class `Builder`, made from the
+configuration's dict (the published sizes), with imports of the program
+inside its methods, so a cell imports only what its kind needs:
+
+- `family`                  a name for the printed lines
+- `vocab_size`              token ids are drawn below it
+- `ref_cfg`                 the sizes the plain reference takes
+- `model(opt_level)`        the system's own model, under that amp policy
+- `param_shapes(model)`     the parameter tree as `ShapeDtypeStruct`s
+- `loss_fn(model)`          `(params, batch) -> loss` (training cells)
+- `make_batch(key, rows, seq_len, traffic)`   one batch of that family
+- `decoder(model)`          what `serving.Engine` takes (serving cells)
+- `train_flops_per_token(seq_len)`   logical operations, forward +
+  backward, of the published model: no recomputation, a causal product
+  counted once (`flops.mfu_pct` takes it)
+- optional `shard_step(raw_step, devices, traffic) -> (step, replicated,
+  split)`: how a step of this family is laid over several chips, and the
+  shardings of its state and of its batch; where a builder has it,
+  `train.make_step` calls it in place of its own data-parallel wrapping.
 
 Weights are the benchmark's, not the program's: `make_params` fills the
 program's parameter tree from `--seed` on the device in one jitted call,
@@ -15,6 +35,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmark.harness import manifest as mf
 
 
 def seed_key(seed: int, impl=None):
@@ -81,100 +103,12 @@ def make_params(shapes, seed: int, dtype, sharding=None):
         jax.jit(gen, out_shardings=one)(seed_key(seed)), sharding)
 
 
-class Gpt2:
-    family = "gpt2"
-
-    def __init__(self, cfg: dict):
-        self.cfg = cfg
-        self.vocab_size = cfg["vocab_size"]
-        self.ref_cfg = {k: cfg[k] for k in ("n_layer", "n_head", "n_embd",
-                                            "vocab_size")}
-
-    def model(self, opt_level: str = "O2"):
-        from apex1_tpu.core.policy import get_policy
-        from apex1_tpu.models.gpt2 import GPT2, GPT2Config
-        c = self.cfg
-        if c["n_embd"] % c["n_head"]:
-            raise ValueError("n_embd not divisible by n_head")
-        return GPT2(GPT2Config(
-            vocab_size=c["vocab_size"], max_seq_len=c["n_positions"],
-            num_layers=c["n_layer"], num_heads=c["n_head"],
-            hidden_size=c["n_embd"], dropout=c["resid_pdrop"],
-            policy=get_policy(opt_level)))
-
-    def param_shapes(self, model):
-        probe = jax.ShapeDtypeStruct((1, 8), jnp.int32)
-        return jax.eval_shape(model.init, jax.random.key(0), probe)["params"]
-
-    def loss_fn(self, model):
-        from apex1_tpu.models.gpt2 import gpt2_loss_fn
-        f = gpt2_loss_fn(model)
-        return lambda params, batch: f(params, batch["tokens"])
-
-    def make_batch(self, key, rows: int, seq_len: int, traffic: dict):
-        return {"tokens": jax.random.randint(
-            key, (rows, seq_len), 0, self.vocab_size, jnp.int32)}
-
-    def decoder(self, model):
-        from apex1_tpu.models.generate import gpt2_decoder
-        return gpt2_decoder(model)
-
-
-class BertPretrain:
-    family = "bert_pretrain"
-
-    def __init__(self, cfg: dict):
-        self.cfg = cfg
-        self.vocab_size = cfg["vocab_size"]
-        self.ref_cfg = {k: cfg[k] for k in (
-            "num_hidden_layers", "num_attention_heads", "hidden_size",
-            "vocab_size")}
-
-    def model(self, opt_level: str = "O2"):
-        from apex1_tpu.core.policy import get_policy
-        from apex1_tpu.models.bert import BertConfig, BertPretrain as M
-        c = self.cfg
-        return M(BertConfig(
-            vocab_size=c["vocab_size"],
-            max_seq_len=c["max_position_embeddings"],
-            type_vocab_size=c["type_vocab_size"],
-            num_layers=c["num_hidden_layers"],
-            num_heads=c["num_attention_heads"],
-            hidden_size=c["hidden_size"],
-            intermediate_size=c["intermediate_size"],
-            dropout=c["hidden_dropout_prob"], policy=get_policy(opt_level)))
-
-    def param_shapes(self, model):
-        probe = jax.ShapeDtypeStruct((1, 8), jnp.int32)
-        return jax.eval_shape(model.init, jax.random.key(0), probe)["params"]
-
-    def loss_fn(self, model):
-        from apex1_tpu.models.bert import bert_pretrain_loss_fn
-        return bert_pretrain_loss_fn(model)
-
-    def make_batch(self, key, rows: int, seq_len: int, traffic: dict):
-        k1, k2, k3, k4 = jax.random.split(key, 4)
-        shape = (rows, seq_len)
-        masked = jax.random.uniform(k2, shape) < traffic["mask_share"]
-        return {
-            "tokens": jax.random.randint(k1, shape, 0, self.vocab_size,
-                                         jnp.int32),
-            "mlm_labels": jnp.where(
-                masked, jax.random.randint(k3, shape, 0, self.vocab_size,
-                                           jnp.int32), -1),
-            "nsp_labels": jax.random.randint(k4, (rows,), 0, 2, jnp.int32),
-        }
-
-
-BUILDERS = {"gpt2": Gpt2, "bert_pretrain": BertPretrain}
-
-
 def get(cfg: dict):
-    try:
-        return BUILDERS[cfg["builder"]](cfg)
-    except KeyError:
-        raise KeyError(f"configuration {cfg.get('_name')} names builder "
-                       f"{cfg.get('builder')!r}; known: {sorted(BUILDERS)}")
+    """The builder of the configuration's family: the `Builder` class of
+    `benchmark/builders/<cfg["builder"]>.py`, found by name under the root
+    the configuration was loaded from."""
+    return mf.load_builder(cfg["builder"],
+                           cfg.get("_root", mf.ROOT)).Builder(cfg)
 
 
 def optimizer(spec: dict):

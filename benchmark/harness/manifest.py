@@ -7,6 +7,8 @@ per-layer metric sits in a file of its own, found here BY NAME:
 - ``benchmark/traffic/<traffic>.json``
 - ``benchmark/layer_metrics/<metric>.json`` (+ optional ``<metric>.py``)
 - ``benchmark/references/<config>.py``
+- ``benchmark/builders/<builder>.py``      (the config file's `builder`)
+- ``benchmark/limits/<workload>.json``
 
 so a later PR adds files and entries and edits nothing that is there.
 """
@@ -52,6 +54,7 @@ def load_config(manifest: dict, name: str, root: str = ROOT) -> dict:
     entry = find(manifest, "configs", name)
     cfg = _load_json(os.path.join(root, entry["file"]))
     cfg["_name"] = name
+    cfg["_root"] = root         # where its builder and reference are found
     return cfg
 
 
@@ -78,6 +81,16 @@ def load_reference(config_name: str, root: str = ROOT):
         raise ManifestError(f"no plain reference {path}")
     return load_module(
         path, "benchmark_reference_" + re.sub(r"\W", "_", config_name))
+
+
+def load_builder(name: str, root: str = ROOT):
+    """The family's glue to the system under test, as a module whose
+    `Builder` class `harness/builders.py` describes."""
+    path = os.path.join(root, "benchmark", "builders", name + ".py")
+    if not os.path.exists(path):
+        raise ManifestError(f"no builder {path}")
+    return load_module(
+        path, "benchmark_builder_" + re.sub(r"\W", "_", name))
 
 
 def cell_metrics(manifest: dict, workload: str, section: str) -> list:
@@ -180,6 +193,9 @@ def validate(manifest: dict, root: str = ROOT) -> None:
         load_traffic(w["traffic"], root)
         cfg_file = _load_json(os.path.join(root, configs[w["config"]]["file"]))
         load_reference(cfg_file.get("reference", w["config"]), root)
+        if "builder" not in cfg_file:
+            bad(f"config {w['config']} names no builder")
+        load_builder(cfg_file["builder"], root)
         if not os.path.exists(os.path.join(
                 root, "benchmark", "limits", w["name"] + ".json")):
             bad(f"no limits file for cell {w['name']}")

@@ -259,7 +259,11 @@ def run(cell: dict, cfg: dict, traffic: dict, args, phases, meter,
     k = int(traffic.get("check_requests", 8))
     sample = check.pick_sample(w_done, k, seed)
     t_check = time.perf_counter()
-    ok = bool(sample) and failed == 0 and compiled_in_window == 0
+    rows = [("requests_failed", failed, 0, failed == 0),
+            ("compilations_in_window", compiled_in_window, 0,
+             compiled_in_window == 0),
+            ("requests_to_compare_missing", int(not sample), 0,
+             bool(sample))]
     summary = {}
     # the program's state is freed before the reference's weights exist
     del engine, records, results
@@ -277,7 +281,7 @@ def run(cell: dict, cfg: dict, traffic: dict, args, phases, meter,
         print(f"check: {summary['n_requests']} requests, "
               f"{summary['n_tokens']} served tokens, reference logit std "
               f"{summary['logit_std']:.4f}", flush=True)
-        ok = check.print_rows(check.compare_serving(summary, limits)) and ok
+        rows += check.compare_serving(summary, limits)
         if "control_widest_gap" in summary:
             print(f"control: the reference in {quant} puts "
                   f"first a token {summary['control_widest_gap']:.6g} "
@@ -285,6 +289,7 @@ def run(cell: dict, cfg: dict, traffic: dict, args, phases, meter,
                   f"{limits['logit_gap']:.6g}): "
                   f"{'fails, as it must' if summary['control_widest_gap'] > limits['logit_gap'] else 'PASSES - limit too loose'}",
                   flush=True)
+    ok = check.print_rows(rows)
     print(f"check: took {time.perf_counter() - t_check:.1f} s (not in "
           f"setup_s); failed requests {failed}", flush=True)
 
@@ -303,4 +308,4 @@ def run(cell: dict, cfg: dict, traffic: dict, args, phases, meter,
               "loadgen_late_ms": w_late}
     return {"correct": bool(ok), "attempted": attempted, "failed": failed,
             "scalars": scalars, "series": series,
-            "memory_peak_bytes": peak, "check": summary}
+            "memory_peak_bytes": peak, "check": summary, "compared": rows}

@@ -4,17 +4,29 @@ shares, read with `jax.profiler.ProfileData` alone.
 A device plane (`/device:TPU:<n>`) carries the lines "XLA Ops" (one event
 per executed HLO instruction), "XLA Modules" (one per executed program)
 and, where collectives run asynchronously, "Async XLA Ops". The host plane
-carries the `TraceAnnotation` spans of the program (`serving/prefill`,
-`serving/decode_step`, `serving/verify_step`) and of the benchmark
-(`bench/window`, `engine/step`, `loadgen`, `train/dispatch`) on the same
-clock.
+carries the `TraceAnnotation` spans of the program (`serving/step`,
+`serving/admit.register`, ...) and of the benchmark (`bench/window`,
+`engine/step`, `loadgen`, `train/dispatch`) on the same clock. A span is
+known by its FORM, `<word>/<word>[.<word>]` (and `loadgen`), not by a
+list: one that a later PR adds to the program is read with no edit here.
 
 - busy   = union of the "XLA Ops" intervals inside the window, per device,
            averaged over devices; idle share = 1 - busy / window.
 - window = the host span `bench/window` where the device's ops fall inside
            it, else the extent of the device's own ops.
 - an idle gap (no op running on device 0) is attributed to the innermost
-  listed host span covering its midpoint, else to `host:other`.
+  host span covering its midpoint (the window's own span apart), else to
+  `host:other`.
+- a Pallas kernel is a `custom-call` whose instruction carries the name
+  the program gave it (`%apex1_flash_dq.7 = ... custom-call(...)`); it is
+  labelled and summed under that name (`kernels`). The program's own
+  report (`apex1_tpu/obs/xspace.py`, `op_key`) keeps the same rule; this
+  is the benchmark's copy, which no PR that claims a gain can change.
+- everything is counted INSIDE the window: an op or a program execution
+  that straddles an edge counts by the part of it that lies inside, and
+  `n_steps`, which every `*_per_step` divides by, is the sum of those
+  shares over the main program's executions (5.00 where six executions
+  touch a window that holds five steps of ops), not their number.
 """
 
 from __future__ import annotations
@@ -27,9 +39,10 @@ import os
 import re
 import statistics
 
-GAP_SPANS = ("serving/prefill", "serving/decode_step", "serving/verify_step",
-             "engine/step", "loadgen", "train/dispatch")
+SPAN_RE = re.compile(
+    r"^(?:[A-Za-z0-9_]+/[A-Za-z0-9_]+(?:\.[A-Za-z0-9_]+)*|loadgen)$")
 WINDOW_SPAN = "bench/window"
+ENGINE_STEP_SPAN = "engine/step"
 _MIN_GAP_NS = 2_000.0
 
 
@@ -82,13 +95,14 @@ def _subtract(a, b):
 
 @functools.lru_cache(maxsize=65536)
 def _parse(name: str) -> tuple:
-    """(opcode, first result shape) of an HLO instruction text such as
-    `%copy.3 = bf16[48,16]{1,0} copy(%p)` or `%x = (f32[8], s32[]) custom-
-    call(...)`; a bare `%fusion.12` gives ("fusion", None)."""
+    """(opcode, first result shape, instruction name less its `.N`) of an
+    HLO instruction text such as `%copy.3 = bf16[48,16]{1,0} copy(%p)` or
+    `%x = (f32[8], s32[]) custom-call(...)`; a bare `%fusion.12` gives
+    ("fusion", None, None)."""
     head, eq, rhs = name.partition(" = ")
     if not eq:
         base = name.strip().lstrip("%").split(" ")[0].split("(")[0]
-        return re.sub(r"[.\-_]?\d+$", "", base) or name[:40], None
+        return re.sub(r"[.\-_]?\d+$", "", base) or name[:40], None, None
     rhs = rhs.lstrip()
     if rhs.startswith("("):
         depth, end = 0, len(rhs) - 1
@@ -100,17 +114,31 @@ def _parse(name: str) -> tuple:
         shape_txt, rest = rhs[:end + 1], rhs[end + 1:]
     else:
         shape_txt, _, rest = rhs.partition(" ")
+    instr = re.sub(r"\.\d+$", "", head.strip().lstrip("%"))
     m = re.match(r"\s*([\w\-]+)\(", rest)
     first = re.search(r"[a-z]+[0-9]*\[[^\]]*\]", shape_txt)
-    return (m.group(1) if m else head.strip().lstrip("%"),
-            first.group(0) if first else None)
+    return (m.group(1) if m else instr, first.group(0) if first else None,
+            instr)
+
+
+def kernel_name(name: str) -> str | None:
+    """The name a Pallas kernel carries on its instruction
+    (`%apex1_flash_dq.7 = ... custom-call(...)` -> `apex1_flash_dq`; tuple
+    results alike), None for any other op and for a `custom-call` that XLA
+    named itself (`%custom-call.7`)."""
+    op, _, instr = _parse(name)
+    if op == "custom-call" and instr and instr != "custom-call":
+        return instr
+    return None
 
 
 def op_key(name: str) -> str:
-    """A stable label for a device op: opcode and first result shape, e.g.
-    `copy_bf16_48_16_1151_64_` — the event name is the HLO instruction,
-    which carries no kernel name."""
-    op, shape = _parse(name)
+    """A stable label for a device op: a named kernel's name, else opcode
+    and first result shape, e.g. `copy_bf16_48_16_1151_64_`."""
+    kernel = kernel_name(name)
+    if kernel is not None:
+        return kernel
+    op, shape, _ = _parse(name)
     if shape is None:
         return op
     return f"{op}_" + re.sub(r"[^A-Za-z0-9]+", "_", shape).strip("_") + "_"
@@ -126,7 +154,8 @@ def _is_allreduce(name: str) -> bool:
 
 def load(path: str) -> dict:
     """{"devices": {plane: {line: [(name, start_ns, dur_ns)]}},
-        "host": {span_name: [(start_ns, end_ns)]}}"""
+        "host": {span_name: [(start_ns, end_ns)]}} for every host event
+    whose name has a span's form (`SPAN_RE`)."""
     from jax.profiler import ProfileData
     if path.endswith(".gz"):
         import gzip
@@ -135,7 +164,8 @@ def load(path: str) -> dict:
     else:
         data = ProfileData.from_file(path)
     devices, host = {}, collections.defaultdict(list)
-    wanted = set(GAP_SPANS) | {WINDOW_SPAN}
+    is_span = functools.lru_cache(maxsize=None)(
+        lambda name: SPAN_RE.match(name) is not None)
     for plane in data.planes:
         if plane.name.startswith("/device:"):
             lines = {}
@@ -147,7 +177,7 @@ def load(path: str) -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name in wanted:
+                    if is_span(e.name):
                         host[e.name].append(
                             (float(e.start_ns),
                              float(e.start_ns) + float(e.duration_ns)))
@@ -165,11 +195,28 @@ def _covering(spans_by_name, t):
     return best[1] if best else "host:other"
 
 
+def _inside(start: float, dur: float, lo: float, hi: float) -> float:
+    """The part of [start, start + dur] that lies in [lo, hi], in ns."""
+    return max(0.0, min(start + dur, hi) - max(start, lo))
+
+
+def _shares(intervals, lo, hi) -> float:
+    """Sum over (start, end) intervals of the share of each inside the
+    window: whole ones count 1, one cut by an edge its part."""
+    return sum(_inside(s, e - s, lo, hi) / (e - s)
+               for s, e in intervals if e > s)
+
+
 def reduce(path: str, *, top: int = 10) -> dict:
-    """Every number the per-layer readers take from a trace. Times in
-    seconds unless the key says ms; `*_per_step` divide by the executions
-    of the main program (the module with most device time)."""
-    raw = load(path)
+    return reduce_events(load(path), top=top)
+
+
+def reduce_events(raw: dict, *, top: int = 10) -> dict:
+    """Every number the per-layer readers take from a trace (`raw` as
+    `load` gives it). Times in seconds unless the key says ms, counted
+    inside the window; `*_per_step` divide by `n_steps`, the steps of the
+    main program (the module with most device time) that the window
+    holds."""
     planes = sorted(p for p in raw["devices"]
                     if raw["devices"][p].get("XLA Ops"))
     if not planes:
@@ -199,26 +246,38 @@ def reduce(path: str, *, top: int = 10) -> dict:
     mods = [(n, s, d) for n, s, d in lines.get("XLA Modules", [])
             if s + d > lo and s < hi]
     by_mod = collections.defaultdict(list)
-    for n, _, d in mods:
-        by_mod[re.sub(r"\(.*$", "", n)].append(d)
+    for n, s, d in mods:
+        by_mod[re.sub(r"\(.*$", "", n)].append((s, s + d))
+    n_steps = 1.0
     if by_mod:
-        main = max(by_mod, key=lambda k: sum(by_mod[k]))
+        main = max(by_mod, key=lambda k: sum(e - s for s, e in by_mod[k]))
         out["main_module"] = main
-        out["n_steps"] = len(by_mod[main])
-        out["step_device_ms_p50"] = statistics.median(by_mod[main]) * 1e-6
-        out["modules"] = {k: [len(v), sum(v) * 1e-9]
+        out["n_executions"] = len(by_mod[main])
+        n_steps = out["n_steps"] = _shares(by_mod[main], lo, hi) or 1.0
+        out["step_device_ms_p50"] = statistics.median(
+            e - s for s, e in by_mod[main]) * 1e-6
+        out["modules"] = {k: [len(v), sum(e - s for s, e in v) * 1e-9]
                           for k, v in by_mod.items()}
-    n_steps = max(out.get("n_steps", 1), 1)
-    n_engine = len([1 for s, e in host.get("engine/step", ())
-                    if e > lo and s < hi])
+    n_engine = _shares(host.get(ENGINE_STEP_SPAN, ()), lo, hi)
     out["n_engine_steps"] = n_engine
 
     totals = collections.defaultdict(float)
-    for n, _, d in ops:
-        totals[op_key(n)] += d * 1e-9
+    kernels = {}
+    cc = 0.0
+    for n, s, d in ops:
+        secs = _inside(s, d, lo, hi) * 1e-9
+        totals[op_key(n)] += secs
+        if opcode(n) == "custom-call":
+            cc += secs
+        kernel = kernel_name(n)
+        if kernel is not None:
+            row = kernels.setdefault(kernel, [0, 0.0])
+            row[0] += 1
+            row[1] += secs
     out["device_ops"] = [[k, v] for k, v in sorted(
         totals.items(), key=lambda kv: -kv[1])[:top]]
-    cc = sum(d for n, _, d in ops if opcode(n) == "custom-call") * 1e-9
+    out["kernels"] = {k: [calls, secs, 1e3 * secs / n_steps]
+                      for k, (calls, secs) in sorted(kernels.items())}
     out["custom_call_s"] = cc
     out["custom_call_ms_per_step"] = 1e3 * cc / n_steps
     if n_engine:
@@ -238,9 +297,9 @@ def reduce(path: str, *, top: int = 10) -> dict:
     # idle gaps by what the host was doing
     busy = _union(_clip([(s, s + d) for _, s, d in ops], lo, hi))
     spans_by_name = {}
-    for name in GAP_SPANS:
-        spans = sorted(host.get(name, ()))
-        if spans:
+    for name, spans in host.items():
+        if name != WINDOW_SPAN:
+            spans = sorted(spans)
             spans_by_name[name] = ([s for s, _ in spans], spans)
     gaps = collections.defaultdict(float)
     edges = [lo] + [t for iv in busy for t in iv] + [hi]
@@ -249,4 +308,5 @@ def reduce(path: str, *, top: int = 10) -> dict:
             gaps[_covering(spans_by_name, 0.5 * (a + b))] += (b - a) * 1e-9
     out["idle_gaps"] = [[k, v] for k, v in sorted(
         gaps.items(), key=lambda kv: -kv[1])[:top]]
+    out["host_spans"] = {k: len(v) for k, v in sorted(host.items())}
     return out

@@ -57,7 +57,10 @@ def make_step(cfg: dict, traffic: dict, devices: list) -> dict:
               opt_level=opt_level,
               grad_psum_axes=("dp",) if ddp else (), **amp_kw)
     raw_step = amp.make_train_step(b.loss_fn(model))
-    if ddp:
+    if hasattr(b, "shard_step"):
+        # the family's own layout over the chips (builders' protocol)
+        raw_step, repl, split = b.shard_step(raw_step, devices, traffic)
+    elif ddp:
         from apex1_tpu.core.mesh import make_mesh
         mesh = make_mesh(dp=n, devices=list(devices))
         repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
@@ -68,7 +71,8 @@ def make_step(cfg: dict, traffic: dict, devices: list) -> dict:
                                  out_specs=(P(), P()), check_vma=False)
     else:
         if n != 1:
-            raise ValueError("a cell on several chips needs ddp: true")
+            raise ValueError("a cell on several chips needs ddp: true, or "
+                             "a builder with shard_step")
         repl = split = jax.sharding.SingleDeviceSharding(devices[0])
 
     def make_batches(key):
@@ -272,17 +276,16 @@ def run(cell: dict, cfg: dict, traffic: dict, args, phases, meter,
                                       limits)
     else:
         rows = check.compare_training(prog, res["reference"], limits)
+    all_finite = bool(np.isfinite(final_loss)) and finite == 1
+    rows += [("compilations_in_window", in_window["compiles"], 0,
+              in_window["compiles"] == 0),
+             ("final_loss_or_grads_not_finite", int(not all_finite), 0,
+              all_finite)]
     ok = check.print_rows(rows)
     print(f"check: reference followed {N_CHECKED_STEPS} steps in "
           f"{time.perf_counter() - t0:.1f} s (not in setup_s)", flush=True)
-    ok = ok and np.isfinite(final_loss) and finite == 1 \
-        and in_window["compiles"] == 0
-    if in_window["compiles"]:
-        print(f"check: {in_window['compiles']} compilations inside the "
-              f"window FAIL", flush=True)
 
-    fam = pieces["builder"].family
-    fpt = flops.TRAIN_FLOPS_PER_TOKEN[fam](cfg, pieces["seq"])
+    fpt = pieces["builder"].train_flops_per_token(pieces["seq"])
     scalars = {
         "train_tok_s_chip": tok_s_chip, "setup_s": setup_s,
         "window.interval_s": interval, "window.steps": n_steps,
@@ -295,4 +298,4 @@ def run(cell: dict, cfg: dict, traffic: dict, args, phases, meter,
             ["bf16_flops"])
     return {"correct": bool(ok), "attempted": n_steps, "failed": skipped,
             "scalars": scalars, "series": {"host_step_ms": step_ms},
-            "memory_peak_bytes": peak}
+            "memory_peak_bytes": peak, "compared": rows}

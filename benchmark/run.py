@@ -6,7 +6,9 @@ A new process per run. It finds the cell in `BENCHMARK.json`, its
 configuration, traffic mix, limits, plain reference and per-layer metric
 files BY NAME, runs the cell's kind, and prints as the last line of
 standard output one JSON object (`correct`, `attempted`, `failed`,
-`metrics`, `device`, and `breakdown` when traced). Without a TPU holding
+`metrics`, `device`, `breakdown` when traced, and last `compared`: every
+number the check compared beside its limit, which are also the last lines
+of standard error). Without a TPU holding
 the chips the cell asks for it exits non-zero and prints no result.
 
 `--control 1` (never passed by the driver) also computes the
@@ -159,7 +161,15 @@ def run_cell(argv=None, *, root=None, allow_cpu=False):
     out = runner.run(cell, cfg, traffic, args, phases, meter, devices,
                      profiler)
 
-    ctx = {"scalars": dict(out["scalars"]), "series": out["series"]}
+    # what a metric's own file may read (`layer_metrics/<metric>.py`,
+    # `read(ctx)`): the run's scalars and series, the cell with its
+    # configuration and traffic as loaded, the device with its row of
+    # `harness/peaks.json` (None off a TPU), and in a traced run the whole
+    # reduction of the trace (`trace`) and the trace file (`xplane`)
+    ctx = {"scalars": dict(out["scalars"]), "series": out["series"],
+           "cell": cell, "cfg": cfg, "traffic": traffic,
+           "device": dict(dev, peaks=None if rehearsal
+                          else device.peaks(dev["kind"]))}
     for name, secs in phases.laps.items():
         ctx["scalars"][f"setup.{name}_s"] = secs
     ctx["scalars"]["setup.compile_s"] = phases.compile_s
@@ -172,8 +182,8 @@ def run_cell(argv=None, *, root=None, allow_cpu=False):
     breakdown = None
     if args.trace:
         from benchmark.harness import trace as tr
-        red = tr.reduce(tr.find_xplane(logdir))
-        shutil.rmtree(logdir, ignore_errors=True)
+        ctx["xplane"] = tr.find_xplane(logdir)
+        red = ctx["trace"] = tr.reduce(ctx["xplane"])
         for k, v in red.items():
             if isinstance(v, (int, float)):
                 ctx["scalars"][f"trace.{k}"] = v
@@ -181,9 +191,18 @@ def run_cell(argv=None, *, root=None, allow_cpu=False):
         device_out["window_s"] = red["window_s"]
         breakdown = {"device_ops": red.get("device_ops", []),
                      "idle_gaps": red.get("idle_gaps", [])}
-        print(f"trace: main program {red.get('main_module')} x "
-              f"{red.get('n_steps')}, busy {red['busy_s']:.4f} s of "
-              f"{red['window_s']:.4f} s", flush=True)
+        print(f"trace: main program {red.get('main_module')}: n_steps "
+              f"{red.get('n_steps', 0.0):.4f} inside the window "
+              f"({red.get('n_executions', 0)} executions touch it), busy "
+              f"{red['busy_s']:.4f} s of {red['window_s']:.4f} s",
+              flush=True)
+        print("trace: kernels by name (calls, s, ms a step): " + ", ".join(
+            f"{k} {c} {s:.6f} {ms:.4f}"
+            for k, (c, s, ms) in red.get("kernels", {}).items()),
+            flush=True)
+        print("trace: host spans read by their form: " + ", ".join(
+            f"{k} x{n}" for k, n in red.get("host_spans", {}).items()),
+            flush=True)
 
     section = "per_layer" if args.trace else "end_to_end"
     metrics = {}
@@ -194,11 +213,17 @@ def run_cell(argv=None, *, root=None, allow_cpu=False):
             value = read_metric(mf.load_layer_metric(m["name"], root), ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if args.trace:      # the metrics' own files have read what they read
+        shutil.rmtree(logdir, ignore_errors=True)
     result = {"correct": out["correct"], "attempted": out["attempted"],
               "failed": out["failed"], "metrics": metrics,
               "device": device_out}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # every number compared beside its limit: the last key of the line
+    result["compared"] = {
+        name: {"value": float(value), "limit": float(limit),
+               "ok": bool(ok)} for name, value, limit, ok in out["compared"]}
     # a CPU rehearsal proves control flow and counts, never a speed: its
     # result goes back to the test that asked, never onto a result line
     return (4 if rehearsal else 0), result
@@ -209,6 +234,12 @@ def main(argv=None) -> int:
     if code == 0:
         sys.stdout.flush()
         print(json.dumps(result), flush=True)
+        # and the last lines of standard error
+        for name, row in result["compared"].items():
+            print(f"compared: {name} = {row['value']:.6g} (limit "
+                  f"{row['limit']:.6g}) {'ok' if row['ok'] else 'FAIL'}",
+                  file=sys.stderr)
+        sys.stderr.flush()
     return code
 
 

@@ -127,7 +127,9 @@ def make_root(tmp: str, cells=tuple(TINY_CELLS)) -> str:
                 NEW_END_TO_END[e2e])))
         mf.find(man, "end_to_end", e2e)["workloads"].append(name)
         for m, r in zip(man["per_layer"], real["per_layer"]):
-            if like is not None and like in r.get("workloads", ()):
+            # set-up is every cell's; the rest are its class of cell's
+            if m["moves"] == "setup_s" or (
+                    like is not None and like in r.get("workloads", ())):
                 m["workloads"].append(name)
     for rel in sorted(wanted):
         path = os.path.join(tmp, rel)
@@ -139,6 +141,187 @@ def make_root(tmp: str, cells=tuple(TINY_CELLS)) -> str:
     for p, content in before.items():       # nothing that was there moved
         assert open(os.path.join(tmp, p), "rb").read() == content, p
     return tmp
+
+
+# ---- a model FAMILY the benchmark does not have, as new files only -----
+
+TOY_CONFIG = {"builder": "toy_decoder", "layers": 2, "heads": 4,
+              "width": 128, "vocab": 256, "positions": 128}
+
+TOY_BUILDER = '''"""A decoder family under names of its own (`layers`, `width`, ...): no
+table of the harness knows it. It happens to be built from the system's
+GPT-2 blocks, which is the test's business, not the harness's."""
+
+import jax
+import jax.numpy as jnp
+
+
+class Builder:
+    family = "toy_decoder"
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.vocab_size = cfg["vocab"]
+        self.ref_cfg = {"n_layer": cfg["layers"], "n_head": cfg["heads"],
+                        "n_embd": cfg["width"], "vocab_size": cfg["vocab"]}
+
+    def model(self, opt_level="O2"):
+        from apex1_tpu.core.policy import get_policy
+        from apex1_tpu.models.gpt2 import GPT2, GPT2Config
+        c = self.cfg
+        return GPT2(GPT2Config(
+            vocab_size=c["vocab"], max_seq_len=c["positions"],
+            num_layers=c["layers"], num_heads=c["heads"],
+            hidden_size=c["width"], dropout=0.0,
+            policy=get_policy(opt_level)))
+
+    def param_shapes(self, model):
+        probe = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+        return jax.eval_shape(model.init, jax.random.key(0),
+                              probe)["params"]
+
+    def loss_fn(self, model):
+        from apex1_tpu.models.gpt2 import gpt2_loss_fn
+        f = gpt2_loss_fn(model)
+        return lambda params, batch: f(params, batch["tokens"])
+
+    def make_batch(self, key, rows, seq_len, traffic):
+        return {"tokens": jax.random.randint(
+            key, (rows, seq_len), 0, self.vocab_size, jnp.int32)}
+
+    def decoder(self, model):
+        from apex1_tpu.models.generate import gpt2_decoder
+        return gpt2_decoder(model)
+
+    def train_flops_per_token(self, seq_len):
+        c = self.cfg
+        return 6.0 * (12 * c["layers"] * c["width"] ** 2
+                      + c["vocab"] * c["width"])
+'''
+
+#: the same family laid over four chips by its OWN `shard_step`
+TOY_SHARDED_BUILDER = '''"""The toy family with a layout of its own over the chips."""
+
+import os
+
+import jax
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
+
+from benchmark.harness.manifest import load_module
+
+_toy = load_module(os.path.join(os.path.dirname(os.path.abspath(
+    __file__)), "toy_decoder.py"), "toy_sharded_base")
+
+
+class Builder(_toy.Builder):
+    family = "toy_sharded"
+
+    def shard_step(self, raw_step, devices, traffic):
+        print(f"toy_sharded.shard_step: {len(devices)} devices", flush=True)
+        mesh = Mesh(list(devices), ("dp",))
+        step = jax.shard_map(raw_step, mesh=mesh, in_specs=(P(), P("dp")),
+                             out_specs=(P(), P()), check_vma=False)
+        return step, NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+'''
+
+#: the family's plain reference: the published GPT-2 mathematics, which
+#: the file beside it already writes down
+TOY_REFERENCE = '''"""Plain reference of the toy family (the GPT-2 mathematics)."""
+
+import os
+
+from benchmark.harness.manifest import load_module
+
+_gpt2 = load_module(os.path.join(os.path.dirname(os.path.abspath(
+    __file__)), "gpt2-medium.py"), "toy_reference_gpt2")
+loss, logits = _gpt2.loss, _gpt2.logits
+BLOCKABLE, LEAF_PARTS = _gpt2.BLOCKABLE, _gpt2.LEAF_PARTS
+'''
+
+#: a per-layer metric of its own, whose reader takes what `run_cell` hands
+#: a metric's own file: the trace's reduction and file, the cell with its
+#: configuration and traffic, the device
+TOY_METRIC = '''"""toy.layers_traced.train: the configuration's depth, where a trace was
+reduced on a device the run names."""
+
+
+def read(ctx):
+    if "trace" not in ctx or not ctx["xplane"].endswith(".xplane.pb"):
+        return None
+    assert ctx["trace"]["n_devices"] >= 0 and ctx["device"]["kind"]
+    assert ctx["cell"]["name"] == "toy_train"
+    assert ctx["traffic"]["seq_len"] == 64
+    return float(ctx["cfg"]["layers"])
+'''
+
+#: cell -> (config, traffic, chips, the end-to-end metric it reports)
+TOY_CELLS = {"toy_train": ("toy-tiny", "toy_train", 1, "train_tok_s_chip"),
+             "toy_chat": ("toy-tiny", "toy_chat", 1, "tpot_p50_ms"),
+             "toy_dp4": ("toy-sharded", "toy_dp4", 4, "train_tok_s_chip")}
+
+
+def add_toy_family(root: str) -> list:
+    """Into a root made by `make_root`: the toy family with a training
+    cell, a serving cell and a four-chip cell of its own layout, as NEW
+    files and NEW entries of BENCHMARK.json only. Returns the files
+    added."""
+    before = {p: open(os.path.join(root, p), "rb").read()
+              for p in _data_files(root)}
+    tiny = tiny_files()
+    files = {
+        "benchmark/builders/toy_decoder.py": TOY_BUILDER,
+        "benchmark/builders/toy_sharded.py": TOY_SHARDED_BUILDER,
+        "benchmark/references/toy-tiny.py": TOY_REFERENCE,
+        "benchmark/references/toy-sharded.py": TOY_REFERENCE,
+        "benchmark/configs/toy-tiny.json": TOY_CONFIG,
+        "benchmark/configs/toy-sharded.json": dict(
+            TOY_CONFIG, builder="toy_sharded"),
+        "benchmark/traffic/toy_train.json":
+            tiny["benchmark/traffic/tiny_train.json"],
+        "benchmark/traffic/toy_chat.json":
+            tiny["benchmark/traffic/tiny_chat.json"],
+        "benchmark/traffic/toy_dp4.json": dict(
+            tiny["benchmark/traffic/tiny_train.json"], per_chip_batch=2,
+            ddp=True),
+        "benchmark/limits/toy_train.json": TRAIN_LIMITS,
+        "benchmark/limits/toy_chat.json": SERVE_LIMITS,
+        "benchmark/limits/toy_dp4.json": TRAIN_LIMITS,
+        "benchmark/layer_metrics/toy.layers_traced.train.json": {
+            "what": "the configuration's depth, where a trace was reduced"},
+        "benchmark/layer_metrics/toy.layers_traced.train.py": TOY_METRIC,
+    }
+    for rel, content in files.items():
+        path = os.path.join(root, rel)
+        assert not os.path.exists(path), f"{rel} would be an edit"
+        with open(path, "w") as f:
+            if isinstance(content, str):
+                f.write(content)
+            else:
+                json.dump(content, f)
+    man = mf.load_manifest(root)
+    for config in ("toy-tiny", "toy-sharded"):
+        man["configs"].append({
+            "name": config, "source": "test", "reduced": [],
+            "file": f"benchmark/configs/{config}.json",
+            "why": "a family the benchmark does not have"})
+    for name, (config, traffic, chips, e2e) in TOY_CELLS.items():
+        man["workloads"].append({"name": name, "config": config,
+                                 "traffic": traffic, "chips": chips,
+                                 "why": "a new family's cell"})
+        mf.find(man, "end_to_end", e2e)["workloads"].append(name)
+        for m in man["per_layer"]:
+            if m["moves"] == "setup_s":
+                m["workloads"].append(name)
+    man["per_layer"].append({
+        "name": "toy.layers_traced.train", "unit": "layers",
+        "better": "higher", "source": "device_trace", "layer": "model",
+        "moves": "train_tok_s_chip", "workloads": ["toy_train"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(man, f)
+    for p, content in before.items():       # nothing that was there moved
+        assert open(os.path.join(root, p), "rb").read() == content, p
+    return sorted(files)
 
 
 def _data_files(root: str) -> list:

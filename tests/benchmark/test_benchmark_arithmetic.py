@@ -1,6 +1,7 @@
 """The yardstick's arithmetic: the traffic generator, exact-interval
-counting, percentiles, the spread, and the logical FLOP counts against a
-derivation written out here."""
+counting, percentiles, the spread, the logical FLOP counts of each family
+(kept with its builder) and of each kernel (kept with its roofline metric)
+against derivations written out here, and the roofline share itself."""
 
 import json
 import os
@@ -9,7 +10,7 @@ import numpy as np
 import pytest
 
 import benchmark_testlib as lib
-from benchmark.harness import flops, loadgen, stats
+from benchmark.harness import builders, flops, loadgen, roofline, stats
 
 ROOT = lib.ROOT
 
@@ -136,7 +137,10 @@ def test_gpt2_medium_flops_per_token_against_a_written_out_derivation():
     assert attn == 150_994_944
     want = 6 * mm + attn
     assert want == 2_271_713_280                      # ~2.27 GFLOP/token
-    assert flops.gpt2_train_flops_per_token(cfg, S) == want
+    # the count lives with the family's builder, found by name
+    assert lib.mf.load_builder("gpt2", ROOT).gpt2_train_flops_per_token(
+        cfg, S) == want
+    assert builders.get(cfg).train_flops_per_token(S) == want
 
 
 def test_bert_large_flops_per_token_against_a_written_out_derivation():
@@ -153,7 +157,9 @@ def test_bert_large_flops_per_token_against_a_written_out_derivation():
     attn = L * 12 * S * E                              # bidirectional
     want = 6 * mm + attn
     assert want == 2_156_752_896                      # ~2.16 GFLOP/token
-    assert flops.bert_train_flops_per_token(cfg, S) == want
+    assert lib.mf.load_builder(
+        "bert_pretrain", ROOT).bert_train_flops_per_token(cfg, S) == want
+    assert builders.get(cfg).train_flops_per_token(S) == want
 
 
 def test_mfu_is_tokens_times_flops_over_peak():
@@ -169,3 +175,104 @@ def test_unknown_device_kind_is_an_error_not_a_default():
         device.peaks("TPU v9 imaginary")
     with pytest.raises(KeyError):
         device.peaks("_source")
+
+
+V5E = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def test_roofline_share_against_hand_numbers():
+    # bound by operations: 197e9 FLOP is 1 ms at the peak; in 2 ms, 50 %
+    assert roofline.share_pct(197e9, 1e6, 2e-3, V5E) == pytest.approx(50.0)
+    # bound by bytes: 819e6 bytes are 1 ms of the memory's peak; the few
+    # operations beside them do not matter
+    assert roofline.share_pct(1e9, 819e6, 4e-3, V5E) == pytest.approx(25.0)
+    # a time understated by 5/6 overstates the share by 6/5 (what the old
+    # divisor did): 90 % would have read 108 %
+    assert roofline.share_pct(197e9, 0, 1e-3 / 0.9 * 5 / 6, V5E) \
+        == pytest.approx(108.0)
+
+
+def _kernel_metric(kernel):
+    return lib.mf.load_layer_metric(
+        f"kernel.{kernel}.roofline_pct.train", ROOT)["_module"]
+
+
+def _gpt2m_train():
+    cfg = json.load(open(os.path.join(
+        ROOT, "benchmark/configs/gpt2-medium.json")))
+    return cfg, _traffic("pretrain_1k")
+
+
+@pytest.mark.parametrize("kernel,products", [
+    ("flash_fwd", 2), ("flash_dq", 3), ("flash_dkv", 4)])
+def test_flash_kernel_counts_against_a_written_out_derivation(kernel,
+                                                              products):
+    cfg, traffic = _gpt2m_train()
+    B, h, S, d, L = 8, 16, 1024, 64, 24
+    assert (traffic["per_chip_batch"], cfg["n_head"], traffic["seq_len"],
+            cfg["n_embd"] // cfg["n_head"], cfg["n_layer"]) == (B, h, S, d, L)
+    # one product (QK^T, PV, dO V^T, ...) of one layer: B*h heads, S x S
+    # scores, d multiply-adds each, 2 FLOPs a multiply-add; causal, so
+    # counted once: half the square. d is the published 64, not 128 lanes
+    product = 2 * B * h * S * S * d // 2
+    assert product == 8_589_934_592                       # 8.59 GFLOP
+    # fwd: scores and PV; dq: scores again, dP, dQ; dkv: scores again,
+    # dV, dP, dK -- what each kernel itself computes, all 24 layers
+    ops = products * product * L
+    assert ops == {2: 412_316_860_416, 3: 618_475_290_624,
+                   4: 824_633_720_832}[products]
+    # bytes: q, k, v (and dO in the backward) read and the results written
+    # in bfloat16, B*h*S*d elements each; per row a float32 log-sum-exp
+    # written (fwd) or it and the row sum read (backward)
+    tensor, row = B * h * S * d * 2, B * h * S * 4
+    bytes_ = L * {2: 4 * tensor + row, 3: 5 * tensor + 2 * row,
+                  4: 6 * tensor + 2 * row}[products]
+    m = _kernel_metric(kernel)
+    assert m.KERNEL == "apex1_" + kernel
+    assert m.count(cfg, traffic) == (ops, bytes_)
+    # all three are bound by operations on a v5e, the forward narrowly
+    assert ops / 197e12 > bytes_ / 819e9
+
+
+@pytest.mark.parametrize("kernel,products,out", [
+    ("linear_xent_fwd", 1, "loss"), ("linear_xent_dx", 2, "dx"),
+    ("linear_xent_dw", 2, "dw")])
+def test_linear_xent_kernel_counts_against_a_written_out_derivation(
+        kernel, products, out):
+    cfg, traffic = _gpt2m_train()
+    N, H, V = 8 * 1024, 1024, 50257         # published rows, not 50304
+    assert (traffic["per_chip_batch"] * traffic["seq_len"], cfg["n_embd"],
+            cfg["vocab_size"]) == (N, H, V)
+    # one product over tokens x hidden x vocabulary: the logits (fwd, and
+    # again in each backward kernel, which stores none), g W (dx), g^T x
+    product = 2 * N * H * V
+    assert product == 843_172_544_512                     # 843 GFLOP
+    ops = products * product
+    # x and W read in bfloat16; 4 bytes a token for each of: targets, and
+    # loss + log-sum-exp written (fwd) or log-sum-exp + upstream gradient
+    # read (backward); the result written in bfloat16
+    result = {"loss": 0, "dx": N * H * 2, "dw": V * H * 2}[out]
+    bytes_ = (N * H + V * H) * 2 + 3 * N * 4 + result
+    m = _kernel_metric(kernel)
+    assert m.KERNEL == "apex1_" + kernel
+    assert m.count(cfg, traffic) == (ops, bytes_)
+
+
+def test_kernel_share_reads_the_trace_and_leaves_out_what_is_not_there(
+        capsys):
+    cfg, traffic = _gpt2m_train()
+    m = _kernel_metric("linear_xent_fwd")
+    ctx = {"cfg": cfg, "traffic": traffic,
+           "device": {"kind": "TPU v5 lite", "peaks": V5E},
+           "trace": {"kernels": {"apex1_linear_xent_fwd":
+                                 [5, 0.0374, 7.48]}}}
+    # 843.19 GFLOP over 7.48 ms = 112.7 TFLOP/s of 197
+    assert m.read(ctx) == pytest.approx(
+        100 * 843_172_544_512 / 197e12 / 7.48e-3)
+    assert 57.0 < m.read(ctx) < 57.5
+    assert "bound by operations" in capsys.readouterr().out
+    # nothing to read: an untraced run, a trace without the kernel, a
+    # device without peaks (the CPU rehearsal) -- left out, never 0
+    assert m.read({k: v for k, v in ctx.items() if k != "trace"}) is None
+    assert m.read(dict(ctx, trace={"kernels": {}})) is None
+    assert m.read(dict(ctx, device={"kind": "cpu", "peaks": None})) is None

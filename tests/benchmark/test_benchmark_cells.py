@@ -74,6 +74,73 @@ def test_throwaway_cell_is_three_new_files_and_entries(tmp_path):
     assert "serve_tok_s" in res["metrics"]
 
 
+@pytest.fixture(scope="module")
+def toy_root(tmp_path_factory):
+    """The benchmark's data plus a model FAMILY it does not have."""
+    root = lib.make_root(str(tmp_path_factory.mktemp("toy_root")), cells=())
+    lib.add_toy_family(root)
+    return root
+
+
+def test_a_family_is_added_by_files_and_entries_alone(toy_root):
+    """What the next `model_config` PR does: a configuration whose
+    `builder` no table knows, its builder, its plain reference, traffic,
+    limits, and a per-layer metric whose own file reads the trace, the
+    configuration and the device — new files and new entries only
+    (`add_toy_family` asserts that no file that was there differs, and
+    the harness's code is the repo's). A training rehearsal (traced, so
+    the metric is read) and a serving rehearsal both return a result."""
+    added = sorted(set(lib._data_files(toy_root))
+                   - set(lib._data_files(ROOT)))
+    assert all(f.rsplit("/", 1)[1].startswith("toy") for f in added), added
+    assert not set(os.listdir(os.path.join(toy_root, "benchmark",
+                                           "harness"))) - set(os.listdir(
+        os.path.join(ROOT, "benchmark", "harness")))
+    code, res = lib.run_tiny(toy_root, "toy_train", trace=1)
+    assert code == 4 and res["correct"] is True, res
+    assert res["metrics"]["toy.layers_traced.train"] == {
+        "value": 2.0, "unit": "layers"}
+    assert "setup.compile_s" in res["metrics"]
+    # every number compared, beside its limit, is the line's last key
+    assert list(res)[-1] == "compared"
+    assert all(set(row) == {"value", "limit", "ok"} and row["ok"]
+               for row in res["compared"].values())
+    assert "first_grad_rel_diff" in res["compared"]
+    code, res = lib.run_tiny(toy_root, "toy_train")
+    assert code == 4 and res["correct"] is True
+    assert res["metrics"]["train_tok_s_chip"]["value"] > 0
+    code, res = lib.run_tiny(toy_root, "toy_chat")
+    assert code == 4 and res["correct"] is True, res
+    assert res["metrics"]["tpot_p50_ms"]["value"] > 0
+    assert "served_token_widest_logit_gap" in res["compared"]
+
+
+def test_a_builders_own_layout_over_four_chips_is_used(toy_root, capsys):
+    """A builder with `shard_step` lays its step over the chips itself:
+    `train.make_step` calls it in place of its own `ddp` wrapping, and
+    the cell runs end to end and is correct."""
+    import jax
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    code, res = lib.run_tiny(toy_root, "toy_dp4")
+    assert "toy_sharded.shard_step: 4 devices" in capsys.readouterr().out
+    assert code == 4 and res["correct"] is True, res
+    assert res["device"]["count"] == 4
+
+
+def test_a_builder_that_has_no_file_is_refused(toy_root):
+    man = lib.mf.load_manifest(toy_root)
+    cfg = lib.mf.load_config(man, "toy-tiny", toy_root)
+    from benchmark.harness import builders
+    assert builders.get(cfg).family == "toy_decoder"
+    # the same name under the repo's own root has no file: refused by
+    # name, with the path that was looked for
+    with pytest.raises(lib.mf.ManifestError, match="toy_decoder.py"):
+        builders.get(dict(lib.TOY_CONFIG))
+    with pytest.raises(lib.mf.ManifestError, match="no builder"):
+        builders.get({"builder": "nope", "_root": toy_root})
+
+
 def test_real_command_exits_nonzero_without_a_tpu():
     man = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     cell = man["workloads"][0]["name"]

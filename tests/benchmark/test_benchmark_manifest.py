@@ -57,6 +57,11 @@ def test_every_workload_resolves_to_its_files_by_name(man, name):
     traffic = mf.load_traffic(w["traffic"], ROOT)
     ref = mf.load_reference(cfg.get("reference", w["config"]), ROOT)
     assert hasattr(ref, "loss")
+    builder = mf.load_builder(cfg["builder"], ROOT).Builder(cfg)
+    for attr in ("family", "vocab_size", "ref_cfg", "model", "param_shapes",
+                 "loss_fn", "make_batch", "train_flops_per_token"):
+        assert hasattr(builder, attr), (cfg["builder"], attr)
+    assert cfg["_root"] == ROOT
     assert traffic["kind"] in ("train", "serve_open", "serve_closed")
     assert len(traffic["why"]) > 40
     limits = json.load(open(os.path.join(
@@ -119,3 +124,26 @@ def test_validation_refuses_a_broken_manifest(man, breakage):
                            if x["name"] != "setup_s"]
     with pytest.raises((mf.ManifestError, KeyError)):
         mf.validate(m, ROOT)
+
+
+@pytest.mark.parametrize("missing", ["builder", "reference"])
+def test_validation_refuses_a_configuration_whose_files_are_missing(
+        tmp_path, missing):
+    """A configuration's builder and plain reference are found by name; a
+    manifest that names one with no file is refused before any run."""
+    root = lib.make_root(str(tmp_path / "r"), cells=())
+    cfg = dict(lib.TINY_GPT2)
+    cfg[missing] = "no-such-file"
+    with open(os.path.join(root, "benchmark/configs/orphan.json"), "w") as f:
+        json.dump(cfg, f)
+    man = mf.load_manifest(root)
+    man["configs"].append({"name": "orphan", "source": "test",
+                           "reduced": [], "why": "tiny",
+                           "file": "benchmark/configs/orphan.json"})
+    man["workloads"].append(dict(man["workloads"][0], name="orphan_train",
+                                 config="orphan"))
+    with open(os.path.join(root, "benchmark/limits/orphan_train.json"),
+              "w") as f:
+        json.dump(lib.TRAIN_LIMITS, f)
+    with pytest.raises(mf.ManifestError, match="no-such-file.py"):
+        mf.validate(man, root)
