@@ -11,6 +11,7 @@ and the tests do.
 
 from __future__ import annotations
 
+import inspect
 import json
 import os
 
@@ -233,6 +234,42 @@ def pick_sample(finished: list, k: int, seed: int) -> list:
     return [finished[i] for i in chosen]
 
 
+def _products_below_float32(jaxpr) -> list:
+    """Every product (`dot_general`, a convolution) of a jaxpr, the inner
+    ones of its loops and branches too, with an operand that is not
+    float32 (or float64): `name(type, type)` of each."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("dot_general", "conv_general_dilated"):
+            types = [jnp.dtype(v.aval.dtype) for v in eqn.invars]
+            if any(t not in (jnp.float32, jnp.float64) for t in types):
+                found.append(f"{eqn.primitive.name}"
+                             f"({', '.join(map(str, types))})")
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _products_below_float32(sub)
+    return found
+
+
+def require_float32_reference(logits_fn, *args) -> None:
+    """The yardstick is float32 whatever type the weights are stored in:
+    raises where the sound reference's `logits_fn(*args)` (traced, not
+    run) multiplies anything below float32 or returns logits below it. A
+    reference that multiplied the bfloat16 leaves as stored would compare
+    the served precision with itself, and no control could fail."""
+    closed = jax.make_jaxpr(logits_fn)(*args)
+    low = _products_below_float32(closed.jaxpr)
+    out = [str(a.dtype) for a in closed.out_avals
+           if a.dtype not in (jnp.float32, jnp.float64)]
+    if low or out:
+        raise TypeError(
+            "the plain reference must compute in float32: it upcasts the "
+            "weights it is handed as stored. Products below float32: "
+            f"{sorted(set(low))}; logits of type: {out}")
+
+
 def serve_gaps(ref, ref_cfg: dict, params, sample: list, pad_len: int,
                n_out: int, quant=None, rows_per_call: int = 4) -> dict:
     """One reference forward over each prompt with its served tokens
@@ -240,25 +277,42 @@ def serve_gaps(ref, ref_cfg: dict, params, sample: list, pad_len: int,
     position). For every served token, how far its reference logit lies
     below the reference's best at that position; the widest is compared.
     With `quant`, also the same gap for the token the lower-precision
-    reference puts first at each of those positions."""
-    p32 = tmap(lambda a: a.astype(jnp.float32), params)
+    reference puts first at each of those positions.
+
+    The reference gets `params` AS STORED (the served type; it upcasts
+    what it multiplies, a large one a layer at a time), so the weights are
+    on the device once. Where its `logits` takes `positions` `(B, n)`, it
+    is asked for the `(B, n, V)` logits at the compared positions alone;
+    where not, for all of them, and they are gathered here. A reference
+    whose sound path computes below float32 is refused
+    (`require_float32_reference`)."""
+    at_positions = "positions" in inspect.signature(ref.logits).parameters
+
+    def logits_at(p, toks, pos, q):
+        if at_positions:
+            return ref.logits(p, toks, ref_cfg, q, positions=pos)
+        return jnp.take_along_axis(ref.logits(p, toks, ref_cfg, q),
+                                   pos[..., None], axis=1)
 
     def gaps(p, toks, pos, served):
-        lg = ref.logits(p, toks, ref_cfg)
-        at = jnp.take_along_axis(lg, pos[..., None], axis=1)
+        at = logits_at(p, toks, pos, None)
         best = at.max(-1)
         got = jnp.take_along_axis(at, served[..., None], -1)[..., 0]
         out = {"gap": best - got, "std": at.std(-1)}
         if quant is not None:
-            lq = ref.logits(p, toks, ref_cfg, quant)
-            atq = jnp.take_along_axis(lq, pos[..., None], axis=1)
-            first = jnp.argmax(atq, -1)
+            first = jnp.argmax(logits_at(p, toks, pos, quant), -1)
             out["control_gap"] = best - jnp.take_along_axis(
                 at, first[..., None], -1)[..., 0]
         return out
 
     fn = jax.jit(gaps)
     n_out = max(n_out, max(len(s["tokens"]) for s in sample))
+
+    def ids(n):
+        return jax.ShapeDtypeStruct((rows_per_call, n), jnp.int32)
+
+    require_float32_reference(lambda p, t, at: logits_at(p, t, at, None),
+                              params, ids(pad_len), ids(n_out))
     res = {"gap": [], "std": [], "control_gap": []}
     n_tokens = 0
     for i in range(0, len(sample), rows_per_call):
@@ -277,7 +331,7 @@ def serve_gaps(ref, ref_cfg: dict, params, sample: list, pad_len: int,
             pos[r, :lt] = np.arange(lp - 1, lp - 1 + lt)
             served[r, :lt] = s["tokens"]
             mask[r, :lt] = r < n_real
-        out = fn(p32, toks, pos, served)
+        out = fn(params, toks, pos, served)
         n_tokens += int(mask.sum())
         for k in res:
             if k in out:

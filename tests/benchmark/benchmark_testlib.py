@@ -338,3 +338,205 @@ def run_tiny(tmp: str, cell: str, *extra, seed: int = 3000000019,
     return brun.run_cell(
         ["--workload", cell, "--seed", str(seed), "--seconds", str(seconds),
          "--trace", str(trace), *extra], root=tmp, allow_cpu=True)
+
+
+# ---- a decoder as large as one chip serves, as shapes alone --------------
+
+#: "a 5-layer decoder of hidden 7680 with 8 experts of width 2048": one
+#: dense layer of width 18432 and four expert layers with a shared expert,
+#: latent attention of 128 heads (q 1536, k/v 512 + 64 shared rope
+#: values), an eighth of a 153600 vocabulary: 3.41 B parameters; 4.92 B
+#: with 16 experts (ISSUE 28's table)
+BIG_DECODER = {"hidden": 7680, "dense_layers": 1, "expert_layers": 4,
+               "experts": 8, "experts_total": 256, "expert_width": 2048,
+               "dense_width": 18432, "heads": 128, "q_rank": 1536,
+               "kv_rank": 512, "rope": 64, "nope": 128, "v_head": 128,
+               "vocab": 19200}
+#: the same layers at a size the CPU tests hold
+SMALL_DECODER = dict(BIG_DECODER, hidden=256, experts=4, experts_total=8,
+                     expert_width=128, dense_width=512, heads=4,
+                     q_rank=64, kv_rank=32, rope=16, nope=32, v_head=32,
+                     vocab=512, expert_layers=2)
+
+
+def decoder_shapes(c: dict) -> dict:
+    """The parameter tree of such a decoder as `ShapeDtypeStruct`s: norm
+    weights named `*scale`, experts stacked on a leading axis."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    h, heads = c["hidden"], c["heads"]
+
+    def mlp(width, *lead):
+        return {"gate": f(*lead, h, width), "up": f(*lead, h, width),
+                "down": f(*lead, width, h)}
+
+    def layer(expert):
+        out = {
+            "attn": {"q_a": f(h, c["q_rank"]),
+                     "q_a_norm_scale": f(c["q_rank"]),
+                     "q_b": f(c["q_rank"], heads * (c["nope"] + c["rope"])),
+                     "kv_a": f(h, c["kv_rank"] + c["rope"]),
+                     "kv_a_norm_scale": f(c["kv_rank"]),
+                     "kv_b": f(c["kv_rank"],
+                               heads * (c["nope"] + c["v_head"])),
+                     "o": f(heads * c["v_head"], h)},
+            "in_norm_scale": f(h), "post_attn_norm_scale": f(h),
+            "pre_mlp_norm_scale": f(h), "post_mlp_norm_scale": f(h)}
+        if expert:
+            out.update(router=f(h, c["experts_total"]),
+                       shared=mlp(c["expert_width"]),
+                       experts=mlp(c["expert_width"], c["experts"]))
+        else:
+            out["mlp"] = mlp(c["dense_width"])
+        return out
+
+    n = c["dense_layers"] + c["expert_layers"]
+    tree = {f"layer{i}": layer(i >= c["dense_layers"]) for i in range(n)}
+    tree.update(embed=f(c["vocab"], h), head=f(h, c["vocab"]),
+                final_norm_scale=f(h))
+    return tree
+
+
+class StandInReference:
+    """A stand-in for such a decoder's plain reference, for what a check
+    at size must show: it reads every weight AS STORED and upcasts a block
+    at a time (a head group, a column block, an expert), one row of the
+    batch at a time, and gives logits at `positions` alone where asked.
+    Its attention mixes no positions (it is no model): each token goes
+    through every product a real forward makes of it."""
+
+    def __init__(self, c: dict, block: int = 2048):
+        self.c, self.block = c, block
+        self.seen = None        # the dtypes of the leaves it was handed
+        self.asked = []         # the shape of `positions` in each call
+
+    def _row(self, p, toks, q):
+        import jax
+        import jax.numpy as jnp
+        c = self.c
+        f32 = jnp.float32
+
+        def mm(a, w):
+            if q is not None:
+                a, w = a.astype(q), w.astype(q)
+            return a.astype(f32) @ w.astype(f32)
+
+        def norm(x, w):
+            return x * jax.lax.rsqrt(jnp.mean(
+                x * x, -1, keepdims=True) + 1e-6) * w.astype(f32)
+
+        def blocks(width):
+            return [(a, min(a + self.block, width))
+                    for a in range(0, width, self.block)]
+
+        def mlp(x, w, pick=lambda a: a):
+            y = 0.0
+            for a, b in blocks(pick(w["gate"]).shape[-1]):
+                g = jax.nn.silu(mm(x, pick(w["gate"])[:, a:b]))
+                y = y + mm(g * mm(x, pick(w["up"])[:, a:b]),
+                           pick(w["down"])[a:b])
+            return y
+
+        def attn(x, w):
+            qk, v = c["nope"] + c["rope"], c["v_head"]
+            c_q = norm(mm(x, w["q_a"]), w["q_a_norm_scale"])
+            kv = mm(x, w["kv_a"])
+            c_kv = norm(kv[:, :c["kv_rank"]], w["kv_a_norm_scale"])
+            y = 0.0
+            group = max(1, self.block // (c["nope"] + v))
+            for a in range(0, c["heads"], group):
+                b = min(a + group, c["heads"])
+                qh = mm(c_q, w["q_b"][:, a * qk:b * qk]).reshape(
+                    -1, b - a, qk)
+                kvh = mm(c_kv, w["kv_b"][:, a * (c["nope"] + v):
+                                         b * (c["nope"] + v)]).reshape(
+                    -1, b - a, c["nope"] + v)
+                s = jnp.sum(qh[..., :c["nope"]] * kvh[..., :c["nope"]], -1) \
+                    + jnp.einsum("sgr,sr->sg", qh[..., c["nope"]:],
+                                 kv[:, c["kv_rank"]:])
+                out = jax.nn.sigmoid(s)[..., None] * kvh[..., c["nope"]:]
+                y = y + mm(out.reshape(-1, (b - a) * v),
+                           w["o"][a * v:b * v])
+            return y
+
+        x = p["embed"][toks].astype(f32)
+        for i in range(c["dense_layers"] + c["expert_layers"]):
+            w = p[f"layer{i}"]
+            x = x + norm(attn(norm(x, w["in_norm_scale"]), w["attn"]),
+                         w["post_attn_norm_scale"])
+            y = norm(x, w["pre_mlp_norm_scale"])
+            if "mlp" in w:
+                z = mlp(y, w["mlp"])
+            else:
+                s = jax.nn.sigmoid(mm(y, w["router"]))[:, :c["experts"]]
+                z = mlp(y, w["shared"])
+                for e in range(c["experts"]):
+                    z = z + s[:, e:e + 1] * mlp(y, w["experts"],
+                                                lambda a: a[e])
+            x = x + norm(z, w["post_mlp_norm_scale"])
+        return norm(x, p["final_norm_scale"])
+
+    def logits(self, params, tokens, cfg, quant=None, positions=None):
+        import jax
+        import jax.numpy as jnp
+        self.seen = {a.dtype for a in jax.tree_util.tree_leaves(params)}
+        self.asked.append(None if positions is None else positions.shape)
+
+        def row(toks, pos=None):
+            h = self._row(params, toks, quant)
+            if pos is not None:
+                h = h[pos]
+            return h @ params["head"].astype(jnp.float32)
+
+        if positions is None:
+            return jax.lax.map(row, tokens)
+        return jax.lax.map(lambda a: row(*a), (tokens, positions))
+
+    def without_positions(self):
+        """The same reference under the protocol of before: all logits."""
+        import types
+        return types.SimpleNamespace(
+            logits=lambda params, tokens, cfg, quant=None: self.logits(
+                params, tokens, cfg, quant))
+
+
+def weight_programs(shapes, dtype, sharding=None):
+    """[(label, lowered)]: every distinct program that
+    `builders.make_params` runs for this tree, lowered for `sharding`'s
+    device (a described chip does) and not run."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.harness import builders
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    windows, n_chunks = builders.param_windows(shapes)
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
+                                                   n_chunks))
+    i32 = spec((), jnp.int32)
+    window = spec((builders._WINDOW_CHUNKS * builders._DRAW_ROWS, 1024),
+                  jnp.float32)
+    out = [("draw", builders._draw_window.lower(
+        spec(keys.shape, keys.dtype), i32, i32))]
+    leaves = jax.tree_util.tree_leaves(shapes)
+    seen = set()
+    for _, _, pieces in windows:
+        for leaf, lead, shape, _, scale in pieces:
+            if (shape, scale) not in seen:
+                seen.add((shape, scale))
+                out.append((f"cut{shape}", builders._cut_piece.lower(
+                    window, i32, shape=shape, scale=scale,
+                    dtype=jnp.dtype(dtype))))
+            whole = tuple(leaves[leaf].shape)
+            if lead is not None and (whole, shape) not in seen:
+                seen.add((whole, shape))
+                out.append((f"place{shape}in{whole}",
+                            builders._place_piece.lower(
+                                spec(whole, dtype), spec(shape, dtype),
+                                i32)))
+    return out

@@ -163,3 +163,28 @@ def test_engine_programs_compile_for_v5e(name, topo, mosaic):
         row, total = _report(f"{name}/{tag}", lowered.compile())
         assert total < HBM, f"{tag}: {total / 2 ** 30:.2f} GiB"
     assert weights < HBM
+
+
+def test_weight_programs_of_a_3b_tree_fit_their_scratch_on_v5e(topo, mosaic):
+    """`builders.make_params` on "a 5-layer decoder of hidden 7680 with 8
+    experts of width 2048" (3.41 B parameters, 6.8 GB in bfloat16): no
+    program of it takes more than one window of the table beside its
+    arguments and outputs, and there are tens of programs, not one per
+    leaf."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    import benchmark_testlib as lib
+    from benchmark.harness import builders
+    shapes = lib.decoder_shapes(lib.BIG_DECODER)
+    programs = lib.weight_programs(shapes, jnp.bfloat16,
+                                   SingleDeviceSharding(topo.devices[0]))
+    assert len(programs) < len(jax.tree_util.tree_leaves(shapes)) / 2
+    worst = 0
+    for label, lowered in programs:
+        mem = lowered.compile().memory_analysis()
+        worst = max(worst, mem.temp_size_in_bytes)
+        assert mem.temp_size_in_bytes <= builders.SCRATCH_BYTES, label
+    print("AOT " + json.dumps({"program": "make_params/3.41B",
+                               "programs": len(programs),
+                               "worst_temp_gib": worst / 2 ** 30}))

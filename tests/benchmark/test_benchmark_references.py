@@ -111,3 +111,99 @@ def test_sample_always_holds_the_longest_finished_request():
     b = check.pick_sample(fin, 4, 1)
     assert [id(x) for x in a] == [id(x) for x in b]
     assert check.pick_sample([], 4, 0) == []
+
+
+# ---- the serving check holds the weights once ---------------------------
+
+
+def _served(vocab, lens=(20, 33, 50), n_out=8, total=96):
+    toks = np.random.default_rng(0).integers(0, vocab, total).astype(np.int32)
+    return [{"prompt": toks[:n], "tokens": toks[n:n + n_out]} for n in lens]
+
+
+class _Recording:
+    """A reference without `positions`, noting the types it is handed."""
+
+    def __init__(self, ref):
+        self.ref, self.seen = ref, None
+
+    def logits(self, params, tokens, cfg, quant=None):
+        self.seen = {a.dtype for a in jax.tree_util.tree_leaves(params)}
+        return self.ref.logits(params, tokens, cfg, quant)
+
+
+@pytest.mark.parametrize("rows_per_call", [4, 2])
+def test_serving_check_hands_the_weights_as_stored_and_reads_the_same(
+        rows_per_call):
+    """Through the GPT-2 reference, which takes no `positions`: the
+    weights arrive in the served type, and the summary is that of the
+    check that upcast them first (what `serve_gaps` did before PR 28),
+    control included."""
+    ref = _Recording(lib.mf.load_reference("gpt2-medium", ROOT))
+    b = builders.get(dict(lib.TINY_GPT2))
+    params = builders.make_params(b.param_shapes(b.model("O2")), 9,
+                                  jnp.bfloat16)
+    sample = _served(b.vocab_size)
+    quant = check.control_quant(True)
+    got = check.serve_gaps(ref, b.ref_cfg, params, sample, 96, 8, quant,
+                           rows_per_call)
+    assert ref.seen == {jnp.dtype(jnp.bfloat16)}
+    before = check.serve_gaps(
+        ref, b.ref_cfg, jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.float32), params), sample, 96, 8, quant,
+        rows_per_call)
+    assert ref.seen == {jnp.dtype(jnp.float32)}
+    assert got == before
+    assert got["n_tokens"] == 24 and got["n_requests"] == 3
+
+
+@pytest.mark.parametrize("quant", [None, "control"])
+def test_serving_check_asks_only_for_the_positions_it_compares(quant):
+    """A reference with `positions` gets them, with the weights as stored,
+    and the summary is the one that all the logits, gathered here, give."""
+    c = lib.SMALL_DECODER
+    params = builders.make_params(lib.decoder_shapes(c), 5, jnp.bfloat16)
+    sample = _served(c["vocab"])
+    quant = check.control_quant(quant)
+    ref = lib.StandInReference(c, block=64)
+    got = check.serve_gaps(ref, {}, params, sample, 96, 8, quant, 2)
+    assert ref.asked and all(shape == (2, 8) for shape in ref.asked)
+    assert ref.seen == {jnp.dtype(jnp.bfloat16)}
+    want = check.serve_gaps(lib.StandInReference(c, 64).without_positions(),
+                            {}, params, sample, 96, 8, quant, 2)
+    assert got.keys() == want.keys()
+    assert ("control_widest_gap" in got) == (quant is not None)
+    for k in got:
+        assert got[k] == pytest.approx(want[k], rel=1e-5), k
+
+
+def _stored_type_logits(params, tokens, cfg, quant=None):
+    return params["embed"][tokens] @ params["head"]        # bfloat16 x bfloat16
+
+
+@pytest.mark.parametrize("how", ["logits_as_stored", "logits_cast_up",
+                                 "product_inside_a_loop"])
+def test_serving_check_refuses_a_reference_that_multiplies_as_stored(how):
+    """The check hands over bfloat16 leaves and trusts nobody to upcast
+    them: a reference whose sound path multiplies them as they are is
+    refused before it computes a yardstick in the served precision,
+    whether its logits come back bfloat16, are cast to float32 afterwards,
+    or the product sits inside a mapped row."""
+    import types
+    c = lib.SMALL_DECODER
+    params = builders.make_params(lib.decoder_shapes(c), 5, jnp.bfloat16)
+    logits = {
+        "logits_as_stored": _stored_type_logits,
+        "logits_cast_up": lambda *a, **k: _stored_type_logits(
+            *a, **k).astype(jnp.float32),
+        "product_inside_a_loop": lambda p, toks, cfg, quant=None: jax.lax.map(
+            lambda row: _stored_type_logits(p, row, cfg).astype(jnp.float32),
+            toks)}[how]
+    with pytest.raises(TypeError, match=r"dot_general\(bfloat16, bfloat16\)"):
+        check.serve_gaps(types.SimpleNamespace(logits=logits), {}, params,
+                         _served(c["vocab"]), 96, 8, None, 2)
+    upcast = types.SimpleNamespace(
+        logits=lambda p, toks, cfg, quant=None: p["embed"][toks].astype(
+            jnp.float32) @ p["head"].astype(jnp.float32))
+    assert check.serve_gaps(upcast, {}, params, _served(c["vocab"]), 96, 8,
+                            None, 2)["n_tokens"] == 24
