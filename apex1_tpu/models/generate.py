@@ -26,7 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex1_tpu.ops import NEG_INF
+from apex1_tpu.ops._common import mosaic_dtype, use_pallas
 from apex1_tpu.ops.attention import flash_attention
+from apex1_tpu.ops.decode_attend import MAX_ROWS, decode_attend
 # the decode-attention composite and the sampling pipeline are owned by
 # ops.paged_decode so the paged serving path and this dense reference
 # path share ONE implementation (token parity is structural, not tested
@@ -37,14 +39,32 @@ from apex1_tpu.ops.paged_decode import (PagedCache,  # noqa: F401
 
 
 def init_cache(num_layers: int, batch: int, num_kv_heads: int,
-               max_len: int, head_dim: int, dtype=jnp.bfloat16):
-    """Zeroed per-layer KV cache: {"layer{i}": {"k","v": (B, Hkv, S_max,
-    D)}}."""
-    one = lambda: {
-        "k": jnp.zeros((batch, num_kv_heads, max_len, head_dim), dtype),
-        "v": jnp.zeros((batch, num_kv_heads, max_len, head_dim), dtype),
-    }
+               max_len: int, head_dim: int, dtype=jnp.bfloat16, *,
+               page_form: bool = False):
+    """Zeroed per-layer KV cache: {"layer{i}": {"k","v": (B, S_max,
+    Hkv * D)}} — one position's K (or V) for all heads is one
+    contiguous row, as it leaves the QKV projection, and the minor
+    dimension is lane-dense where Hkv * D is a multiple of 128 (a head
+    of 64 is not padded to 128 lanes). This is the ONE place that
+    chooses the dense entry's stored form; `cache_write`,
+    `ops.paged_decode.cache_attend` and `ops.decode_attend` are the
+    three that read or write positions in it, `cache_len` says how many
+    it holds, and everything else treats a leaf as opaque with the
+    batch (the serving pool's slot) on axis 0.
+
+    ``page_form=True`` is the paged pool's own form instead, (pages,
+    Hkv, page, D), which `ops.paged_decode` addresses by block table
+    (``batch`` pages of ``max_len`` positions)."""
+    shape = ((batch, num_kv_heads, max_len, head_dim) if page_form
+             else (batch, max_len, num_kv_heads * head_dim))
+    one = lambda: {"k": jnp.zeros(shape, dtype),
+                   "v": jnp.zeros(shape, dtype)}
     return {f"layer{i}": one() for i in range(num_layers)}
+
+
+def cache_len(cache) -> int:
+    """Positions a dense cache pytree (or one leaf of it) holds."""
+    return jax.tree_util.tree_leaves(cache)[0].shape[1]
 
 
 def cached_attention(q, k_new, v_new, cache, cache_index, *,
@@ -53,10 +73,17 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
                      chunk_decode: bool = False):
     """Attention through the KV cache. ``q``/``k_new``/``v_new``:
     (B, H, S, D)/(B, Hkv, S, D) for the CURRENT tokens; ``cache`` holds
-    (B, Hkv, S_max, D); ``cache_index`` is the (traced) write position:
+    (B, S_max, Hkv * D) (`init_cache`); ``cache_index`` is the (traced) write position:
     a scalar (one position for the batch — `generate`, beam search,
     prefill) or a (B,) vector (one per row — the serving engine's
-    decode / verify step, rows at different depths); see `cache_write`.
+    decode / verify step, rows at different depths; a row whose index
+    is negative has nothing to do: no K/V is appended to it, nothing of
+    it is read by the kernel, and its output is not meaningful); see
+    `cache_write`. What the call can see in its input chooses the
+    path: a rank-1 index where the kernels run (``use_pallas()``), with
+    at most ``MAX_ROWS`` query rows and neither ``bias`` nor
+    ``valid_start``, is `ops.decode_attend`'s one kernel; a scalar
+    index, a prefill chunk or a CPU takes the composite below.
 
     - Prefill (S > 1): must start from an empty cache at index 0 — runs
       the causal flash kernel over the current tokens (with ``bias``
@@ -106,6 +133,17 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
                                    sm_scale=sm_scale,
                                    chunk_decode=chunk_decode)
     idx = jnp.asarray(cache_index, jnp.int32)
+    if (idx.ndim == 1 and use_pallas() and Hq * S <= MAX_ROWS
+            and (S == 1 or chunk_decode) and bias is None
+            and valid_start is None
+            and mosaic_dtype(cache["k"].dtype) == cache["k"].dtype):
+        # the serving engine's step: rows at their own depths. One
+        # kernel appends each lane's rows where they lie and reads the
+        # lane to its horizon and no further (`ops.decode_attend`)
+        attn, k_all, v_all = decode_attend(q, k_new, v_new, cache["k"],
+                                           cache["v"], idx,
+                                           sm_scale=sm_scale)
+        return attn, {"k": k_all, "v": v_all}
     k_all = cache_write(cache["k"], k_new, idx)
     v_all = cache_write(cache["v"], v_new, idx)
     new_entry = {"k": k_all, "v": v_all}
@@ -132,29 +170,32 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
 
 
 def cache_write(cache, new, cache_index):
-    """``cache`` (B, Hkv, S_max, D) with ``new`` (B, Hkv, S, D) written
-    at ``cache_index``; the index's RANK chooses how.
+    """``cache`` (B, S_max, Hkv * D) with ``new`` (B, Hkv, S, D), as a
+    model hands its K or V, written at ``cache_index``; the index's
+    RANK chooses how.
 
     A scalar (one position for the whole batch) is one
-    ``dynamic_update_slice``: S rows touched, in place in a donated
-    carry. A (B,) vector puts row b's chunk at ``idx[b] .. idx[b] + S -
-    1``; there a batched ``dynamic_update_slice`` would be a scatter,
-    which XLA expands on TPU into a loop of B one-row updates between
-    two layout copies of the whole cache. Selecting by position instead
-    is elementwise, so it fuses into the attention that reads the cache
-    next and the donated buffer is rewritten where it lies, once, in
-    the layout it is stored in (the serving engine's step;
-    `tests/test_engine_aot.py` holds that for a v5e). It rewrites every
-    position, which is why the scalar case does not take it. A position
+    ``dynamic_update_slice``: S contiguous rows touched, in place in a
+    donated carry. A (B,) vector puts row b's chunk at ``idx[b] ..
+    idx[b] + S - 1``, and ``idx[b] < 0`` leaves row b as it is; there a
+    batched ``dynamic_update_slice`` would be a scatter, which XLA
+    expands on TPU into a loop of B one-row updates between two layout
+    copies of the whole cache. Selecting by position instead is
+    elementwise, so it fuses into the attention that reads the cache
+    next. It rewrites every position, which is why the scalar case does
+    not take it and why, where the kernels run, the engine's step does
+    not either (`ops.decode_attend` writes the rows alone). A position
     past ``S_max`` is dropped there, not clamped onto earlier rows."""
     idx = jnp.asarray(cache_index, jnp.int32)
-    new = new.astype(cache.dtype)
+    B, _, S, _ = new.shape
+    new = new.astype(cache.dtype).transpose(0, 2, 1, 3).reshape(B, S, -1)
     if idx.ndim == 0:
-        return jax.lax.dynamic_update_slice(cache, new, (0, 0, idx, 0))
-    rel = (jnp.arange(cache.shape[2], dtype=jnp.int32)[None, :]
-           - idx[:, None])[:, None, :, None]           # (B, 1, S_max, 1)
-    for j in range(new.shape[2]):
-        cache = jnp.where(rel == j, new[:, :, j:j + 1], cache)
+        return jax.lax.dynamic_update_slice(cache, new, (0, idx, 0))
+    pos = jnp.arange(cache.shape[1], dtype=jnp.int32)
+    rel = jnp.where(idx[:, None] >= 0, pos[None, :] - idx[:, None],
+                    -1)[:, :, None]                     # (B, S_max, 1)
+    for j in range(S):
+        cache = jnp.where(rel == j, new[:, j:j + 1], cache)
     return cache
 
 
@@ -230,7 +271,7 @@ def generate(apply_fn: Callable, params, prompt_tokens, *,
     B, S0 = prompt_tokens.shape
     if rng is None:
         rng = jax.random.key(0)
-    s_max = jax.tree_util.tree_leaves(cache)[0].shape[2]
+    s_max = cache_len(cache)
     if s_max < cache_start + S0 + max_new_tokens:
         # dynamic_update_slice CLAMPS out-of-range writes: an undersized
         # cache would repeatedly overwrite its last slot and silently
@@ -471,7 +512,7 @@ def speculative_generate(target_fn, target_params, draft_fn, draft_params,
         raise ValueError(f"num_draft must be >= 1, got {K}")
     for nm, c in (("target_cache", target_cache),
                   ("draft_cache", draft_cache)):
-        s_max = jax.tree_util.tree_leaves(c)[0].shape[2]
+        s_max = cache_len(c)
         if s_max < S0 + max_new_tokens + K + 1:
             # dynamic_update_slice CLAMPS out-of-range writes — an
             # undersized cache would silently overwrite earlier K/V and
@@ -749,9 +790,10 @@ def _decoder(model, num_kv_heads: int, head_dim: int):
             chunk_decode=chunk_decode, **kw)
         return out, new_cache
 
-    def make_cache(batch: int, max_len: int, dtype=None):
+    def make_cache(batch: int, max_len: int, dtype=None, **form):
         return init_cache(cfg.num_layers, batch, num_kv_heads, max_len,
-                          head_dim, dtype or cfg.policy.compute_dtype)
+                          head_dim, dtype or cfg.policy.compute_dtype,
+                          **form)
 
     return apply_fn, make_cache
 

@@ -179,9 +179,9 @@ def llama_quant_decoder(model, params):
         logits = int8_matmul(x, qp["output"]["q"], qp["output"]["s"])
         return logits, new_cache
 
-    def make_cache(batch: int, max_len: int, dtype=None):
+    def make_cache(batch: int, max_len: int, dtype=None, **form):
         return init_cache(cfg.num_layers, batch, Hkv, max_len, D,
-                          dtype or dt)
+                          dtype or dt, **form)
 
     return apply_fn, make_cache, qparams
 
@@ -281,8 +281,8 @@ def gpt2_quant_decoder(model, params):
         logits = int8_matmul(x, qp["head"]["q"], qp["head"]["s"])
         return logits, new_cache
 
-    def make_cache(batch: int, max_len: int, dtype=None):
+    def make_cache(batch: int, max_len: int, dtype=None, **form):
         return init_cache(cfg.num_layers, batch, nh, max_len, hd,
-                          dtype or dt)
+                          dtype or dt, **form)
 
     return apply_fn, make_cache, qparams

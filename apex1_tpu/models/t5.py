@@ -303,7 +303,8 @@ class T5Stack(nn.Module):
         if cache is not None and S == 1:
             # decode: one bias row at the current position vs all cache
             # slots (cached_attention masks slots > cache_index)
-            S_max = cache["layer0"]["k"].shape[2]
+            from apex1_tpu.models.generate import cache_len
+            S_max = cache_len(cache)
             bias = rel_pos(1, S_max,
                            q_positions=jnp.asarray([cache_index],
                                                    jnp.int32))

@@ -1,0 +1,291 @@
+"""The serving step's attention as ONE kernel over the dense KV pool.
+
+`serving.Engine`'s decode and verify steps run one batch forward with a
+per-row cache index: lane ``b`` appends ``S`` new K/V rows at positions
+``idx[b] .. idx[b] + S - 1`` and query ``j`` attends positions ``<=
+idx[b] + j``. As XLA ops that is a select that rewrites every pool leaf
+whole and a masked product that reads every position of every lane
+(PERF.md, PR 26). :func:`decode_attend` does both in one Pallas call a
+layer, on the pool where it lies:
+
+- the two pool leaves are aliased input/outputs that stay in HBM
+  (``memory_space=ANY``); the kernel moves blocks of ``DECODE_BLOCK``
+  positions by its own double-buffered DMA, ``cdiv(idx + S, block)`` of
+  them a lane, so a position past a lane's horizon is never read and a
+  lane with ``idx < 0`` (an idle slot) costs one empty grid step;
+- the new rows are selected into the block(s) that hold their positions
+  once those are in VMEM, and only the aligned window of rows around
+  them is written back (``W`` rows of the leaf, not the leaf);
+- a leaf is stored ``(B, L, Hkv * D)``: one position's K (or V) for all
+  heads is one contiguous row, a multiple of 128 lanes wide, so nothing
+  is padded. Per-head scores come from one MXU product with the query
+  laid out block-diagonal (``Hq * S`` rows by ``Hkv * D`` lanes, zero
+  outside a row's own head); the output is masked to each row's head
+  and summed over rows by a second, 0/1, product.
+
+Operands enter the MXU in the pool's dtype (bfloat16 as configured)
+with float32 accumulation; the softmax statistics are float32 and the
+probabilities are rounded to the value dtype before P.V, as the
+composite `ops.paged_decode.cache_attend` (the off-TPU path and the
+parity gold) does.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from apex1_tpu.ops._common import (NEG_INF, interpret_mode, kernel_call,
+                                   out_struct, pad_to, to_mosaic)
+
+#: positions one DMA moves; the engine rounds its pool's length up to a
+#: whole number of these (PERF.md §6, PR 29 has the chip readings)
+DECODE_BLOCK = 128
+#: query rows (``Hq * S``) the kernel takes; more go to the composite
+MAX_ROWS = 256
+_LANES = 128
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one VMEM tile of ``dtype`` (8 of 32 bits, packed below)."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
+                          dtype):
+    """Loud validation of a `decode_attend` geometry, at trace time and
+    again by ``tools/aot_check``: whole blocks, whole tiles, and a VMEM
+    frame under the shared `vmem_model` budget (a row narrower than a
+    multiple of 128 lanes is padded by the chip, not refused).
+    Returns ``(block, window, padded rows)``."""
+    from apex1_tpu.vmem_model import CHECKS, budget_bytes
+    sub = _sublanes(dtype)
+    blk = min(DECODE_BLOCK, length)
+    if length % blk or blk % sub:
+        raise ValueError(
+            f"decode_attend needs a pool of whole {DECODE_BLOCK}-position "
+            f"blocks (or one block of whole {sub}-row tiles), got length "
+            f"{length}: round the pool's length up")
+    win = -(-(s + sub - 1) // sub) * sub
+    if win > blk:
+        raise ValueError(
+            f"decode_attend appends at most {blk - sub + 1} rows a lane "
+            f"to a block of {blk}, got {s}")
+    rp = -(-rows // 16) * 16
+    fits, est = CHECKS["decode_attend"](
+        {"block_l": blk}, {"HD": lanes, "Rq": rp, "W": win},
+        jnp.dtype(dtype).itemsize, budget_bytes())
+    if not fits:
+        raise ValueError(
+            f"decode_attend geometry block={blk} HD={lanes} Rq={rp} needs "
+            f"~{est} B of VMEM: over budget")
+    return blk, win, rp
+
+
+def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
+                          o_ref, kp_out, vp_out, kbuf, vbuf, acc, m_scr,
+                          l_scr, rsem, wsem, *, scale, S, G, Hkv, D, blk,
+                          sub, win):
+    b = pl.program_id(0)
+    idx = idx_ref[b]
+    Rp, HD = acc.shape
+    Hq = G * Hkv
+
+    @pl.when(idx < 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def fetch(i, slot):
+        rows = pl.ds(pl.multiple_of(i * blk, blk), blk)
+        return (pltpu.make_async_copy(kp_in.at[b, rows, :], kbuf.at[slot],
+                                      rsem.at[0, slot]),
+                pltpu.make_async_copy(vp_in.at[b, rows, :], vbuf.at[slot],
+                                      rsem.at[1, slot]))
+
+    def window(i):
+        # the aligned rows of block i that hold this lane's new ones
+        r0 = jnp.maximum(idx - i * blk, 0)
+        return pl.multiple_of(jnp.minimum((r0 // sub) * sub, blk - win),
+                              sub)
+
+    def append(i, slot):
+        ws = window(i)
+        rows = pl.ds(pl.multiple_of(i * blk + ws, sub), win)
+        return (pltpu.make_async_copy(kbuf.at[slot, pl.ds(ws, win), :],
+                                      kp_out.at[b, rows, :], wsem.at[0]),
+                pltpu.make_async_copy(vbuf.at[slot, pl.ds(ws, win), :],
+                                      vp_out.at[b, rows, :], wsem.at[1]))
+
+    def patch(i, slot):
+        ws = window(i)
+        pos = i * blk + ws + jax.lax.broadcasted_iota(
+            jnp.int32, (win, HD), 0)
+        for buf, new in ((kbuf, kn_ref), (vbuf, vn_ref)):
+            tile = buf[slot, pl.ds(ws, win), :]
+            for j in range(S):
+                tile = jnp.where(pos == idx + j, new[0, j], tile)
+            buf[slot, pl.ds(ws, win), :] = tile
+
+    def attend(i, slot):
+        q = q_ref[0]                                       # (Rp, HD)
+        k = kbuf[slot].astype(q.dtype)                     # (blk, HD)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # rows are (s, g, kv): query s of the chunk sees <= idx + s
+        keep = i * blk + col <= idx + row // Hq
+        s = jnp.where(keep, s, NEG_INF)
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        e = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_new = l_prev * corr + jnp.sum(e, axis=1, keepdims=True)
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
+            e.astype(q.dtype), vbuf[slot].astype(q.dtype),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(idx >= 0)
+    def _():
+        # blocks up to the horizon; a row past the pool's end is dropped
+        n = jnp.minimum((idx + S - 1) // blk + 1, kp_in.shape[1] // blk)
+        first_new = idx // blk                 # first block with a new row
+        for c in fetch(0, 0):
+            c.start()
+        acc[...] = jnp.zeros_like(acc)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+        def body(i, _):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n)
+            def _():
+                for c in fetch(i + 1, 1 - slot):
+                    c.start()
+
+            for c in fetch(i, slot):
+                c.wait()
+
+            @pl.when(i >= first_new)
+            def _():
+                patch(i, slot)
+                for c in append(i, slot):
+                    c.start()
+
+            attend(i, slot)
+
+            @pl.when(i >= first_new)
+            def _():
+                for c in append(i, slot):
+                    c.wait()
+
+        jax.lax.fori_loop(0, n, body, None)
+        # each row keeps the lanes of its own head; a 0/1 product then
+        # sums the Hkv rows of one (s, g) into one lane-dense row
+        x = acc[...] / l_scr[:, :1]
+        row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        x = jnp.where(lane // D == row % Hkv, x, 0.0).astype(o_ref.dtype)
+        osel = jax.lax.broadcasted_iota(jnp.int32, (o_ref.shape[1], Rp), 0)
+        rsel = jax.lax.broadcasted_iota(jnp.int32, (o_ref.shape[1], Rp), 1)
+        sel = (rsel // Hkv == osel).astype(o_ref.dtype)
+        o_ref[0] = jax.lax.dot_general(
+            sel, x, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *,
+                  sm_scale: Optional[float] = None):
+    """Append and attend, one lane at its own depth: ``q`` (B, Hq, S,
+    D) and ``k_new`` / ``v_new`` (B, Hkv, S, D) for the current tokens,
+    the pool leaves (B, L, Hkv * D), ``idx`` (B,) each lane's write
+    position (``< 0``: an idle lane, neither read nor written; its
+    output rows are zero). Returns ``(attn (B, Hq, S, D), k_pool,
+    v_pool)`` with rows ``idx[b] .. idx[b] + S - 1`` of every live lane
+    replaced and nothing else touched; the pools are updated in place
+    where the caller donates them."""
+    _, Hq, S, D = q.shape
+    Hkv = k_new.shape[1]
+    _, L, HD = k_pool.shape
+    if Hq % Hkv or HD != Hkv * D:
+        raise ValueError(
+            f"decode_attend: Hq={Hq}, Hkv={Hkv}, D={D} do not match a "
+            f"pool row of {HD} lanes")
+    geometry = check_decode_geometry(L, HD, Hq * S, S, k_pool.dtype)
+    scale = (D ** -0.5) if sm_scale is None else sm_scale
+    return _decode_attend(q, k_new, v_new, k_pool, v_pool,
+                          jnp.asarray(idx, jnp.int32), scale=float(scale),
+                          geometry=geometry, interpret=interpret_mode())
+
+
+# a program's layers call this with the same shapes: jitted, they share
+# one traced kernel and one lowering of it (24 of them took 10 s of a
+# step executable's first call: PERF.md §6, PR 29)
+@functools.partial(jax.jit, static_argnames=("scale", "geometry",
+                                             "interpret"))
+def _decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *, scale,
+                   geometry, interpret):
+    B, Hq, S, D = q.shape
+    Hkv = k_new.shape[1]
+    HD = Hkv * D
+    G = Hq // Hkv
+    R = Hq * S
+    blk, win, Rp = geometry
+    SGp = -(-(S * G) // 16) * 16
+    q, k_new, v_new = to_mosaic(q, k_new, v_new)
+    # rows (s, g, kv), each with its query in the lanes of head kv
+    qr = q.reshape(B, Hkv, G, S, D).transpose(0, 3, 2, 1, 4)
+    qbd = jnp.einsum("bsgkd,kj->bsgkjd", qr, jnp.eye(Hkv, dtype=q.dtype))
+    qbd, _ = pad_to(qbd.reshape(B, R, HD), 1, Rp)
+    rows = lambda x: x.astype(k_pool.dtype).transpose(
+        0, 2, 1, 3).reshape(B, S, 1, HD)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, Rp, HD), lambda b, ix: (b, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, S, 1, HD), lambda b, ix: (b, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, S, 1, HD), lambda b, ix: (b, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            any_spec, any_spec],
+        out_specs=[
+            pl.BlockSpec((1, SGp, HD), lambda b, ix: (b, 0, 0),
+                         memory_space=pltpu.VMEM),
+            any_spec, any_spec],
+        scratch_shapes=[
+            pltpu.VMEM((2, blk, HD), k_pool.dtype),
+            pltpu.VMEM((2, blk, HD), v_pool.dtype),
+            pltpu.VMEM((Rp, HD), jnp.float32),
+            pltpu.VMEM((Rp, _LANES), jnp.float32),
+            pltpu.VMEM((Rp, _LANES), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,))],
+    )
+    out, k_pool, v_pool = kernel_call(
+        functools.partial(_decode_attend_kernel, scale=scale, S=S, G=G,
+                          Hkv=Hkv, D=D, blk=blk,
+                          sub=_sublanes(k_pool.dtype), win=win),
+        name="decode_attend",
+        grid_spec=grid_spec,
+        out_shape=[out_struct((B, SGp, HD), q.dtype, qbd, k_pool, v_pool),
+                   out_struct(k_pool.shape, k_pool.dtype, k_pool),
+                   out_struct(v_pool.shape, v_pool.dtype, v_pool)],
+        input_output_aliases={4: 1, 5: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(idx, qbd, rows(k_new), rows(v_new), k_pool, v_pool)
+    attn = out[:, :S * G].reshape(B, S, G, Hkv, D).transpose(0, 3, 2, 1, 4)
+    return attn.reshape(B, Hq, S, D), k_pool, v_pool

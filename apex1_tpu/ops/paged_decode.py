@@ -66,15 +66,17 @@ def cache_attend(q, k_all, v_all, cache_index, *,
                  sm_scale: Optional[float] = None, bias=None,
                  valid_start=None):
     """Masked composite attention of (B, Hq, S, D) queries against a
-    FULL cache (B, Hkv, S_max, D) — the decode/chunk-decode math of
-    ``models.generate.cached_attention``, factored out so the paged
-    path attends through the SAME ops (gather pages → dense → here)
-    and token parity with the dense engine is bit-exact by
-    construction. ``cache_index`` may be a scalar (the dense path) or
-    a per-row (B,) vector (the paged batch path — rows at different
-    depths). Query j sees cache slots <= index + j."""
+    FULL cache in its stored form (B, S_max, Hkv * D), one position's
+    heads side by side in a row (`models.generate.init_cache`) — the
+    decode/chunk-decode math of ``models.generate.cached_attention``,
+    factored out so the paged path attends through the SAME ops (gather
+    pages → dense → here) and token parity with the dense engine is
+    bit-exact by construction. ``cache_index`` may be a scalar (the
+    dense path) or a per-row (B,) vector (the paged batch path — rows
+    at different depths). Query j sees cache slots <= index + j."""
     B, Hq, S, D = q.shape
-    Hkv = k_all.shape[1]
+    S_max = k_all.shape[1]
+    Hkv = k_all.shape[2] // D
     idx = jnp.asarray(cache_index, jnp.int32)
     scale = (D ** -0.5) if sm_scale is None else sm_scale
     # GQA without materializing a repeated cache: group the q heads onto
@@ -83,14 +85,15 @@ def cache_attend(q, k_all, v_all, cache_index, *,
     # traffic by the group factor)
     group = Hq // Hkv
     qg = q.reshape(B, Hkv, group, S, D)
-    scores = jnp.einsum("bhgsd,bhkd->bhgsk", qg, k_all,
+    k_all = k_all.reshape(B, S_max, Hkv, D)
+    v_all = v_all.reshape(B, S_max, Hkv, D)
+    scores = jnp.einsum("bhgsd,bkhd->bhgsk", qg, k_all,
                         preferred_element_type=jnp.float32) * scale
     if bias is None:
         scores_b = scores
     else:
         scores_b = scores + bias.astype(jnp.float32).reshape(
             bias.shape[0], Hkv, group, S, -1)
-    S_max = k_all.shape[2]
     pos = jnp.arange(S_max)
     # per-query horizon: query j sees cache slots <= idx + j (S == 1
     # decode reduces to pos <= idx)
@@ -105,7 +108,7 @@ def cache_attend(q, k_all, v_all, cache_index, *,
                        >= valid_start.reshape(B, 1, 1, 1, 1))
     scores_b = jnp.where(keep, scores_b, NEG_INF)
     probs = jax.nn.softmax(scores_b, axis=-1).astype(q.dtype)
-    attn = jnp.einsum("bhgsk,bhkd->bhgsd", probs, v_all)
+    attn = jnp.einsum("bhgsk,bkhd->bhgsd", probs, v_all)
     return attn.reshape(B, Hq, S, D)
 
 
@@ -371,30 +374,36 @@ def fused_sample(logits, seeds, positions, *, temperature: float = 0.0,
 
 
 def gather_pages(pages, block_table, total_len: int):
-    """Assemble dense (N, Hkv, total_len, D) lanes from a page pool
-    (num_pages, Hkv, page, D) through an (N, T) block table — the
-    composite read path (and the CPU engine's bridge onto the UNCHANGED
-    dense reference executables: gather → reference ops → scatter)."""
+    """Assemble dense lanes (N, total_len, Hkv * D), the dense cache's
+    stored form, from a page pool (num_pages, Hkv, page, D) through an
+    (N, T) block table — the composite read path (and the CPU engine's
+    bridge onto the UNCHANGED dense reference executables: gather →
+    reference ops → scatter)."""
     g = jnp.take(pages, jnp.asarray(block_table, jnp.int32), axis=0)
-    g = jnp.swapaxes(g, 1, 2)                    # (N, Hkv, T, P, D)
-    N, Hkv, T, P, D = g.shape
-    return g.reshape(N, Hkv, T * P, D)[:, :, :total_len, :]
+    N, T, Hkv, P, D = g.shape
+    g = g.transpose(0, 1, 3, 2, 4)               # (N, T, P, Hkv, D)
+    return g.reshape(N, T * P, Hkv * D)[:, :total_len, :]
 
 
 def scatter_pages(pages, block_table, values, start):
-    """Write (N, Hkv, W, D) ``values`` into the page pool at positions
-    ``[start, start + W)`` per row (page-spanning windows handled by
-    position-wise scatter — no page-alignment requirement). Rows whose
-    block-table entries are the trash page (id 0, freed slots) write
-    harmless garbage there; page 0 is never attended."""
-    P, T = pages.shape[2], block_table.shape[1]
-    W = values.shape[2]
+    """Write ``values`` into the page pool at positions ``[start, start
+    + W)`` per row (page-spanning windows handled by position-wise
+    scatter — no page-alignment requirement): (N, Hkv, W, D) as a model
+    hands its new K/V, or (N, W, Hkv * D) rows cut from a dense lane.
+    Rows whose block-table entries are the trash page (id 0, freed
+    slots) write harmless garbage there; page 0 is never attended."""
+    Hkv, P, D = pages.shape[1:]
+    T = block_table.shape[1]
+    if values.ndim == 3:
+        vals = values.reshape(values.shape[0], -1, Hkv, D)
+    else:
+        vals = jnp.swapaxes(values, 1, 2)        # (N, W, Hkv, D)
+    W = vals.shape[1]
     start = jnp.asarray(start, jnp.int32).reshape(-1)
     pos = start[:, None] + jnp.arange(W, dtype=jnp.int32)[None]
     pid = jnp.take_along_axis(jnp.asarray(block_table, jnp.int32),
                               jnp.clip(pos // P, 0, T - 1), axis=1)
     off = pos % P
-    vals = jnp.swapaxes(values, 1, 2)            # (N, W, Hkv, D)
     return pages.at[pid, :, off, :].set(vals.astype(pages.dtype))
 
 

@@ -274,7 +274,7 @@ def kv_cache_bytes(num_layers: int, num_kv_heads: int, head_dim: int,
                    positions: int, batch: int = 1,
                    bytes_per_el: int = 2) -> int:
     """HBM bytes of a K/V cache pytree (`models.generate.init_cache`
-    layout: K + V per layer, ``(batch, Hkv, positions, D)`` each) — the
+    layout: K + V per layer, ``(batch, positions, Hkv * D)`` each) — the
     analytic mirror of `serving.KVPool.pool_bytes`, jax-free so the
     planner/bench can size pools without building one. ``bytes_per_el``
     2 = bf16 (the default compute dtype), 1 = the int8 capacity tier,

@@ -21,14 +21,17 @@ EXACTLY TWO executables, traced once each for the life of the engine:
 - **decode** — one step for ALL slots: ONE cached forward at batch
   ``max_slots`` over the pool, ``cache_index`` a VECTOR, each row
   carrying its OWN traced cache index (rows are at different depths —
-  that is the whole point). `cached_attention` appends each row's K/V
-  by position (`generate.cache_write`: a select, which XLA fuses into
-  the attention's own read of the cache — no copy of the pool, no loop
-  over slots; `tests/test_engine_aot.py`). Only what is per row BY
+  that is the whole point), an idle lane the index -1. Where the
+  kernels run, `cached_attention` hands each layer to ONE kernel over
+  the pool (`ops.decode_attend`): it appends a live lane's K/V rows
+  where they lie and reads the lane to its own horizon and no further,
+  and an idle lane is neither read nor written — no copy of the pool,
+  no leaf rewritten, no loop over slots (`tests/test_engine_aot.py`).
+  Off the TPU the same step is the composite (`generate.cache_write`'s
+  select and `cache_attend`), the parity gold. Only what is per row BY
   CONTRACT is vmapped: the counter-keyed sampling and the LoRA
-  epilogue row. Inactive lanes compute masked garbage into their free
-  slot; retirement and admission change only ARRAY VALUES, never
-  shapes. A decoder whose rows interact (MoE capacity) sees the
+  epilogue row. Retirement and admission change only ARRAY VALUES,
+  never shapes. A decoder whose rows interact (MoE capacity) sees the
   pool's batch here, as on the paged kernel path.
 
 With ``num_draft > 0`` the decode executable is replaced by **verify**
@@ -112,8 +115,12 @@ docs/observability.md). The spans of one request share its id, the two
 in which the host waits for the device are marked ``wait``, and each
 ``serving/step`` carries what the step did (``admitted``, ``retired``,
 ``prefill_chunks``, ``prefill_tokens``, ``tokens_out``, ``n_active``,
-``queue_depth``) and ``control_dispatches``: the device programs it
-launched outside the two executables. They are kept in memory, always,
+``queue_depth``), ``control_dispatches``: the device programs it
+launched outside the two executables, and ``kv_blocks_read`` of
+``kv_blocks_pool``: the blocks of K/V positions the step's attention
+has to move, each live lane's up to its horizon, of those the pool
+holds (host arithmetic on the slots' depths). They are kept in memory,
+always,
 and lie in a profiler trace on the device's clock when one is taken.
 """
 
@@ -132,6 +139,7 @@ import numpy as np
 from apex1_tpu.models.generate import (counter_sample, last_real_logits,
                                        sample_token)
 from apex1_tpu.ops._common import use_pallas
+from apex1_tpu.ops.decode_attend import DECODE_BLOCK
 from apex1_tpu.ops.paged_decode import (PagedCache, fused_sample,
                                         gather_pages, scatter_pages)
 from apex1_tpu.resilience.retry import _mix32
@@ -246,6 +254,7 @@ class _Slot:
     n_out: int = 1               # tokens emitted so far (first included)
     in_batch: bool = False       # joined the decode batch (not retired
     eos_seen: bool = False       #  at prefill)
+    depth: int = 0               # cache positions the lane holds
     produced: List[int] = dataclasses.field(default_factory=list)
     # speculative bookkeeping: the request's full known token history
     # (prefix + prompt + emitted — the self-draft corpus) and the
@@ -307,15 +316,19 @@ class Engine:
         # the current index the same way, so the slack is the max of
         # the two write widths minus one. The pad/rejected K/V in the
         # slack is masked (never attended) and overwritten by later
-        # writes; max_len itself stays the admission contract.
+        # writes; max_len itself stays the admission contract. A lane
+        # is a whole number of the step kernel's blocks
+        # (`ops.decode_attend`): 1151 positions are stored as 1152.
         slack = max(cfg.prefill_chunk, cfg.num_draft + 1) - 1
+        lane_len = -(-(cfg.max_len + slack) // DECODE_BLOCK) * DECODE_BLOCK
+        self._lane_blocks = lane_len // DECODE_BLOCK
         if cache_dtype is None:
             cache_dtype = cfg.cache_dtype    # kwarg (degraded-mode
         #                                      restarts) beats config
         self._paged = bool(cfg.paged)
         if self._paged:
             self.kv = PagedKVPool(
-                make_cache, cfg.max_slots, cfg.max_len + slack,
+                make_cache, cfg.max_slots, lane_len,
                 page_size=self._resolve_page_size(make_cache,
                                                   cache_dtype),
                 dtype=cache_dtype, max_pages=cfg.max_prefix_pages)
@@ -328,8 +341,8 @@ class Engine:
             self._d_bt = jnp.zeros(
                 (cfg.max_slots, self.kv.pages_per_lane), jnp.int32)
         else:
-            self.kv = KVPool(make_cache, cfg.max_slots,
-                             cfg.max_len + slack, dtype=cache_dtype,
+            self.kv = KVPool(make_cache, cfg.max_slots, lane_len,
+                             dtype=cache_dtype,
                              max_pages=cfg.max_prefix_pages)
         self.scheduler = Scheduler(max_queue=cfg.max_queue,
                                    policy=cfg.policy)
@@ -368,7 +381,8 @@ class Engine:
         # uploads)
         self._tally = dict.fromkeys(
             ("admitted", "retired", "prefill_chunks", "prefill_tokens",
-             "tokens_out", "control_dispatches"), 0)
+             "tokens_out", "control_dispatches", "kv_blocks_read",
+             "kv_blocks_pool"), 0)
         # eos_id=None: retirement is length-based, so step tokens are
         # only READ at retirement — the log keeps each step's (N,)
         # output (device array until first fetch memoizes it as numpy).
@@ -405,7 +419,8 @@ class Engine:
             return int(cfg.page_size)
         from apex1_tpu import tuning
         kw = {} if cache_dtype is None else {"dtype": cache_dtype}
-        probe = jax.tree_util.tree_leaves(make_cache(1, 1, **kw))[0]
+        probe = jax.tree_util.tree_leaves(
+            make_cache(1, 1, page_form=True, **kw))[0]
         tuned = tuning.lookup(
             "paged_decode",
             {"Dp": tuning.padded_lanes(probe.shape[-1]), "Rq": 8},
@@ -439,9 +454,10 @@ class Engine:
         their token parity is structural.
 
         ``score`` is the whole of a decode or verify step over a dense
-        ``(N, Hkv, L, D)`` cache tree: ONE batch-N chunk forward with a
-        per-row cache index (`cached_attention` appends each row's K/V
-        by position, see `generate.cache_write`), then every row's
+        cache tree (slot on axis 0): ONE batch-N chunk forward with a
+        per-row cache index, -1 for a lane that is not ``active``
+        (`cached_attention` appends a live row's K/V at its index and
+        leaves an idle lane alone), then every row's
         canonical samples. Only what is per row BY CONTRACT is vmapped:
         the counter-keyed sampling (`counter_sample`) and the LoRA
         epilogue row. A plain decode step is the ``S = 1`` chunk."""
@@ -478,11 +494,11 @@ class Engine:
             return jnp.where(on, logits + delta.astype(logits.dtype),
                              logits)
 
-        def score(params, cache, chunks, idxs, seeds, pos, a_pg=None,
-                  b_pg=None, lbt=None, lon=None):
+        def score(params, cache, chunks, idxs, active, seeds, pos,
+                  a_pg=None, b_pg=None, lbt=None, lon=None):
             steps = jnp.arange(chunks.shape[1], dtype=jnp.int32)
             logits, h, cache = forward(
-                params, chunks, cache, idxs,
+                params, chunks, cache, jnp.where(active, idxs, -1),
                 positions=idxs[:, None] + steps, chunk_decode=True)
             if lora:
                 logits = jax.vmap(
@@ -557,8 +573,8 @@ class Engine:
         def decode(params, pool, toks, idxs, active, seeds, pos,
                    *lora_args):
             self.trace_counts["decode"] += 1    # the compile-count hook
-            tgt, pool = score(params, pool, toks[:, None], idxs, seeds,
-                              pos, *lora_args)
+            tgt, pool = score(params, pool, toks[:, None], idxs, active,
+                              seeds, pos, *lora_args)
             nxt = jnp.where(active, tgt[:, 0], cfg.pad_id)
             adv = active.astype(jnp.int32)
             return nxt, idxs + adv, pos + adv, pool
@@ -568,18 +584,18 @@ class Engine:
             self.trace_counts["verify"] += 1    # the compile-count hook
             tgt, pool = score(
                 params, pool, jnp.concatenate([toks[:, None], drafts], 1),
-                idxs, seeds, pos, *lora_args)
+                idxs, active, seeds, pos, *lora_args)
             return (tgt, *accept(tgt, drafts, active, idxs, pos), pool)
 
         # the pool is donated, on every backend (the CPU tests run the
         # same aliasing the chip does). "In place" for the step means:
-        # each leaf's output IS its input buffer, and the one fusion
-        # per leaf that computes the attention scores (and the one
-        # that computes P.V) also writes the leaf back with the new
-        # rows selected in - no copy of a leaf, no loop over slots, the
-        # layout the pool is stored in. `tests/test_engine_aot.py`
-        # compiles both for a v5e at the chat cell's shapes and holds
-        # exactly that. Prefill touches one lane's chunk rows only.
+        # each leaf's output IS its input buffer, and on the chip the
+        # one kernel a layer that attends over the two leaves is also
+        # the only instruction that writes them, a window of rows
+        # around each live lane's new ones - no copy of a leaf, no leaf
+        # rewritten, no loop over slots. `tests/test_engine_aot.py`
+        # compiles all three for a v5e at the chat cell's shapes and
+        # holds exactly that. Prefill moves one lane, not a leaf.
         self._prefill = jax.jit(prefill, donate_argnums=1)
         if self._spec:
             self._verify = jax.jit(verify, donate_argnums=1)
@@ -632,12 +648,11 @@ class Engine:
                              logits)
 
         def window(lane, start, width):
-            # the (N, Hkv, width, D) block the model just wrote at each
+            # the (N, width, Hkv * D) rows the model just wrote at each
             # row's index — the only slice scatter-back needs
             pos = (start[:, None]
-                   + jnp.arange(width, dtype=jnp.int32))[:, None, :,
-                                                         None]
-            return jnp.take_along_axis(lane, pos, axis=2)
+                   + jnp.arange(width, dtype=jnp.int32))[:, :, None]
+            return jnp.take_along_axis(lane, pos, axis=1)
 
         def paged_cache(pages, bt):
             return {layer: PagedCache(entry["k"], entry["v"], bt, L)
@@ -647,16 +662,16 @@ class Engine:
             return {layer: {"k": pc.k_pages, "v": pc.v_pages}
                     for layer, pc in cache.items()}
 
-        def score_lanes(params, pages, bt, chunks, idxs, seeds, pos,
-                        *lora_args):
+        def score_lanes(params, pages, bt, chunks, idxs, active, seeds,
+                        pos, *lora_args):
             # the parity gold: dense lanes out of the pages, the dense
             # engine's own step body, the written window back.
             # Inactive rows (block-table = trash page) scatter their
             # masked garbage into page 0 — harmless, never attended,
             # never owned
             lanes = tree_map(lambda p: gather_pages(p, bt, L), pages)
-            tgt, lanes = score(params, lanes, chunks, idxs, seeds, pos,
-                               *lora_args)
+            tgt, lanes = score(params, lanes, chunks, idxs, active,
+                               seeds, pos, *lora_args)
             pages = tree_map(
                 lambda pg, ln: scatter_pages(
                     pg, bt, window(ln, idxs, chunks.shape[1]), idxs),
@@ -715,8 +730,8 @@ class Engine:
                 nxt = fused_sample(lg, seeds, pos, **sample_kw)
             else:
                 tgt, pages = score_lanes(params, pages, bt,
-                                         toks[:, None], idxs, seeds,
-                                         pos, *lora_args)
+                                         toks[:, None], idxs, active,
+                                         seeds, pos, *lora_args)
                 nxt = tgt[:, 0]
             nxt = jnp.where(active, nxt, cfg.pad_id)
             adv = active.astype(jnp.int32)
@@ -754,7 +769,8 @@ class Engine:
                     **sample_kw).reshape(-1, K + 1)
             else:
                 tgt, pages = score_lanes(params, pages, bt, chunks,
-                                         idxs, seeds, pos, *lora_args)
+                                         idxs, active, seeds, pos,
+                                         *lora_args)
             return (tgt, *accept(tgt, drafts, active, idxs, pos), pages)
 
         self._prefill = jax.jit(prefill, donate_argnums=1)
@@ -900,7 +916,19 @@ class Engine:
         return (self._lora.a_pages, self._lora.b_pages,
                 self._d_lora_bt, self._d_lora_on)
 
+    def _count_kv_blocks(self, width: int):
+        """Tally what the step's attention has to move, in blocks of
+        `DECODE_BLOCK` positions: each lane of the batch up to the
+        horizon of its ``width`` new tokens, of the blocks the pool
+        holds. Host arithmetic on the slots' depths, no device read."""
+        self._tally["kv_blocks_pool"] += (self.cfg.max_slots
+                                          * self._lane_blocks)
+        self._tally["kv_blocks_read"] += sum(
+            -(-(st.depth + width) // DECODE_BLOCK)
+            for st in self._slots if st is not None and st.in_batch)
+
     def _decode_step(self):
+        self._count_kv_blocks(1)
         with spine.span("serving/decode_step"):
             if self._paged:
                 nxt, idxs, pos, self.kv.pages = self._decode(
@@ -925,6 +953,7 @@ class Engine:
                 if slot is None:
                     continue
                 slot.n_out += 1
+                slot.depth += 1
                 self._tally["tokens_out"] += 1
                 self.metrics.event(slot.req.req_id, "token")
                 if toks is not None:
@@ -955,6 +984,7 @@ class Engine:
                     np.int32).reshape(K)
         d_drafts = jnp.asarray(drafts)       # an upload of its own
         self._tally["control_dispatches"] += 1
+        self._count_kv_blocks(K + 1)
         with spine.span("serving/verify_step"):
             if self._paged:
                 tgt, acc, nxt, idxs, pos, self.kv.pages = self._verify(
@@ -982,6 +1012,7 @@ class Engine:
             if st is None or not st.in_batch:
                 continue
             a = int(acc_np[i])
+            st.depth += a + 1                # as the device advanced it
             remaining = st.req.max_new_tokens - st.n_out
             emitted = [int(t) for t in tgt_np[i, :a + 1][:remaining]]
             # accept-rate accounting clamps to the EMISSION window:
@@ -1203,7 +1234,7 @@ class Engine:
         self._tally["tokens_out"] += 1
         idx = int(full.size)
         st = _Slot(req=req, first_tok=tok0, start_step=self._step_no,
-                   history=[int(t) for t in full])
+                   history=[int(t) for t in full], depth=idx)
         self._slots[slot] = st
         # close the mid-admission window only AFTER the slot is
         # published (a cancel arriving from here on routes to the
@@ -1350,8 +1381,9 @@ class Engine:
                 produced = slot.produced
             if slot.in_batch:
                 # boundary patch: drop the lane from the decode batch
-                # (the freed lane keeps computing masked garbage —
-                # values only)
+                # (values only: the step hands an idle lane's index on
+                # as -1, and the dense step's kernel then neither reads
+                # nor writes it)
                 self._d_active = self._patch(self._d_active, slot_idx,
                                              False)
                 self._n_active -= 1

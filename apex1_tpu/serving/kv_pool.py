@@ -2,7 +2,7 @@
 serving engine.
 
 The pool IS the existing cache layout (`models.generate.init_cache`:
-``{"layer{i}": {"k","v": (max_slots, Hkv, max_len, D)}}``) — slot s is
+``{"layer{i}": {"k","v": (max_slots, max_len, Hkv * D)}}``) — slot s is
 lane s of every leaf. TPU-first consequence: the pool's shapes never
 change for the life of the engine, so requests joining and leaving
 never retrace anything; all slot traffic is ``dynamic_slice`` /
@@ -425,7 +425,8 @@ class PagedKVPool:
         self.num_pages = 1 + (self.max_slots + entries_cap
                               ) * self.pages_per_lane
         kw = {} if dtype is None else {"dtype": dtype}
-        self.pages = make_cache(self.num_pages, self.page_size, **kw)
+        self.pages = make_cache(self.num_pages, self.page_size,
+                                page_form=True, **kw)
         self.block_tables = [[0] * self.pages_per_lane
                              for _ in range(self.max_slots)]
         self._alloc = PageAllocator(self.num_pages)

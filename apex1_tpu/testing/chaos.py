@@ -422,15 +422,18 @@ def toy_decoder(vocab_size: int = 61):
     int8 ``cache_dtype`` profile is EXACT here (values < 128)."""
     import jax.numpy as jnp
 
-    from apex1_tpu.models.generate import cache_write
+    from apex1_tpu.models.generate import (cache_len, cache_write,
+                                           init_cache)
 
-    def make_cache(batch: int, max_len: int, dtype=None):
+    def make_cache(batch: int, max_len: int, dtype=None, **form):
+        # one head of width 1, in `init_cache`'s stored form
         dt = jnp.float32 if dtype is None else dtype
-        return {"toy": {"h": jnp.zeros((batch, 1, max_len, 1), dt)}}
+        return {"toy": {"h": init_cache(1, batch, 1, max_len, 1, dt,
+                                        **form)["layer0"]["k"]}}
 
     def apply_fn(params, tokens, cache, cache_index, positions=None,
                  chunk_decode=False):
-        h = cache["toy"]["h"]                       # (B, 1, Smax, 1)
+        h = cache["toy"]["h"]                       # (B, Smax, 1)
         B, S = tokens.shape
         idx = jnp.asarray(cache_index, jnp.int32)
         vals = (tokens + 1).astype(h.dtype).reshape(B, 1, S, 1)
@@ -439,11 +442,11 @@ def toy_decoder(vocab_size: int = 61):
         # causal-prefix sum per query: pos <= idx + j (the chunk-verify
         # horizon), over the UPDATED cache so each query sees itself —
         # pad/stale residue beyond the horizon never enters
-        pos = jnp.arange(h.shape[2], dtype=jnp.int32)
+        pos = jnp.arange(cache_len(h), dtype=jnp.int32)
         qpos = jnp.broadcast_to(
             idx[..., None] + jnp.arange(S, dtype=jnp.int32), (B, S))
         mask = (pos <= qpos[..., None]).astype(jnp.float32)
-        hv = h[:, 0, :, 0].astype(jnp.float32)
+        hv = h[:, :, 0].astype(jnp.float32)
         s = jnp.einsum("bp,bsp->bs", hv, mask)      # (B, S)
         su = (s.astype(jnp.uint32) * params["w"].astype(jnp.uint32))
         v = jnp.arange(vocab_size, dtype=jnp.uint32)

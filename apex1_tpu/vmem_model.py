@@ -133,6 +133,26 @@ def paged_decode_check(blocks, dims, es, budget):
     return est <= budget, est
 
 
+def decode_attend_check(blocks, dims, es, budget):
+    """Dense-pool decode attention (`ops.decode_attend.decode_attend`):
+    the kernel's own two-slot K and V block buffers (``block_l``
+    positions of ``HD`` lanes, CACHE dtype ``es``; the pool itself stays
+    in HBM), the block-diagonal query block and the output block
+    (double-buffered by Pallas), the new K/V rows (each its own tile),
+    fp32 (acc, m, l) scratch over ``Rq`` query rows, and the live fp32
+    (Rq, block_l) score + exp tiles and (W, HD) append window."""
+    bl = blocks["block_l"]
+    hd, rq, w = dims["HD"], dims["Rq"], dims["W"]
+    est = (2 * DB * es * bl * hd                   # k, v block buffers
+           + DB * es * rq * hd                     # q block
+           + DB * es * rq * hd                     # o block (<= Rq rows)
+           + 2 * DB * es * w * hd                  # new k, v rows
+           + 4 * (rq * hd + 2 * rq * LANES)        # acc, m, l scratch
+           + 2 * 4 * rq * bl                       # s and e tiles
+           + 2 * es * w * hd)                      # append windows
+    return est <= budget, est
+
+
 def fused_sample_check(blocks, dims, _es, budget):
     """Fused sampling epilogue (`ops.paged_decode.fused_sample`): one
     (8, block_v) fp32 logits block (a sublane-aligned tile of rows,
@@ -252,6 +272,7 @@ CHECKS: dict[str, object] = {
     "fused_ag_flash": agf_check,
     "int8_matmul": int8_check,
     "paged_decode": paged_decode_check,
+    "decode_attend": decode_attend_check,
     "fused_sample": fused_sample_check,
     "chunked_loss": chunked_loss_check,
     "fused_swiglu": fused_swiglu_check,

@@ -110,10 +110,13 @@ SURFACE = {
         "PagedCache", "cache_attend", "check_paged_geometry",
         "fused_sample", "gather_pages", "paged_attend",
         "paged_update_attend", "sample_token", "scatter_pages"],
+    "apex1_tpu.ops.decode_attend": [
+        "decode_attend", "check_decode_geometry", "DECODE_BLOCK",
+        "MAX_ROWS"],
     "apex1_tpu.models.generate": [
         "generate", "speculative_generate", "beam_search", "t5_generate",
-        "init_cache", "cached_attention", "sample_token",
-        "counter_sample", "last_real_logits"],
+        "init_cache", "cache_len", "cache_write", "cached_attention",
+        "sample_token", "counter_sample", "last_real_logits"],
     "apex1_tpu.core.mesh": [
         "make_mesh", "make_hybrid_mesh", "MeshConfig", "MeshResource",
         "shard_batch", "replicate"],
@@ -162,7 +165,7 @@ SURFACE = {
         "CHECKS", "budget_bytes", "flash_check", "row_check",
         "linear_xent_check", "cm_check", "agf_check", "int8_check",
         "rdma_check", "rdma_slot_bytes", "static_frame_bytes",
-        "paged_decode_check", "fused_sample_check",
+        "paged_decode_check", "decode_attend_check", "fused_sample_check",
         "chunked_loss_check", "fused_swiglu_check",
         "lora_epilogue_check"],
     "apex1_tpu.perf_model": [

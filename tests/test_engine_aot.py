@@ -1,21 +1,31 @@
 """The dense engine's step appends its K/V rows WITHOUT copying the pool:
 `Engine._decode` and `Engine._verify`, built at the `gpt2m_serve_chat`
-cell's real shapes (48 slots x 1151 positions, GPT-2 medium, bfloat16) and
+cell's real shapes (48 slots x 1152 positions, GPT-2 medium, bfloat16) and
 compiled for a described v5e:2x2, hold
 
 - no `copy` in the entry computation whose result has a pool leaf's
   element count or more,
 - no `while` anywhere,
-- the whole pool aliased to its donated input, and
-- less than one leaf (113 MB) of temporaries.
+- the whole pool aliased to its donated input,
+- less than one leaf (113 MB) of temporaries,
+- no instruction that computes a result of a leaf's element count but
+  the 24 `apex1_decode_attend` kernels, one a layer, whose two leaves
+  are aliased in and out (PR 29: nothing rewrites a leaf), and
+- arguments of pool + weights to within 1 % (the stored form is not
+  padded: a head of 64 is not a row of 128 lanes).
+
+`Engine._prefill` holds no leaf-sized copy either: it moves one lane.
 
 Before PR 26 the same assertions read, for both executables: 96 such
 copies (two layout copies of each of the 48 leaves), 48 loops (XLA's
 expansion of the scatter that a batched `dynamic_update_slice` is, 48
 iterations each), and 0.235 GiB of temporaries for `_decode` (6.15 GiB
 for `_verify`); on the chip that was 50 + 8 ms of every 69 ms step
-(PERF.md, PR 26). `test_the_scatter_it_replaced_still_loops` keeps the
-detector honest: the old write, compiled the same way, is seen.
+(PERF.md, PR 26). From PR 26 to PR 29 the step's two attention fusions a
+layer each rewrote their leaf whole with the new row selected in: 48
+leaf-sized results a step, 16.8 ms of 20 (PERF.md, PR 29).
+`test_the_scatter_it_replaced_still_loops` keeps the detector honest:
+the old write, compiled the same way, is seen.
 
 The topology is described inside a fixture, so only the worker that is
 given this file loads the TPU compiler; where it cannot be described the
@@ -32,6 +42,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "gpt2m_serve_chat"
 
 RESULT_RE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[([\d,]*)\]")
+#: an instruction: its name, its result type (one array or a tuple of
+#: them) and its opcode
+INSTR_RE = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\(")
+#: opcodes that name or regroup a buffer and move nothing
+NO_DATA = {"parameter", "tuple", "get-tuple-element", "bitcast"}
 
 
 @pytest.fixture(scope="module")
@@ -84,8 +100,29 @@ def _census(compiled, leaf_elems):
     return copies, len(re.findall(r" while\(", text))
 
 
-@pytest.mark.parametrize("num_draft", [0, 4], ids=["decode", "verify"])
-def test_step_appends_without_copying_the_pool(topo, mosaic, num_draft):
+def _leaf_sized(compiled, leaf_elems):
+    """Names of the instructions, anywhere in a compiled module, that
+    compute a result of ``leaf_elems`` elements or more (an array, or
+    one of a tuple)."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = INSTR_RE.match(line)
+        if not m or m.group(3) in NO_DATA:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d or 1)
+            if n >= leaf_elems:
+                found.append(m.group(1))
+                break
+    return found
+
+
+def _cell_engine(topo, num_draft):
+    """(engine, params, `place`) at the cell's shapes: shapes with the
+    described chip's sharding, never arrays (but the pool of zeros the
+    engine makes for itself, on the CPU)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -111,55 +148,125 @@ def test_step_appends_without_copying_the_pool(topo, mosaic, num_draft):
     eng = Engine(*b.decoder(model), params, EngineConfig(
         vocab_size=b.vocab_size, num_draft=num_draft, **traffic["engine"]))
     leaves = jax.tree_util.tree_leaves(eng.kv.cache)
+    assert (len(leaves), leaves[0].shape) == (48, (48, 1152, 1024))
+    return eng, params, place
+
+
+@pytest.mark.parametrize("num_draft", [0, 4], ids=["decode", "verify"])
+def test_step_appends_without_copying_the_pool(topo, mosaic, num_draft):
+    import jax
+    import jax.numpy as jnp
+    eng, params, place = _cell_engine(topo, num_draft)
+    leaves = jax.tree_util.tree_leaves(eng.kv.cache)
     leaf_elems = leaves[0].size
     leaf_bytes = leaves[0].nbytes
     pool_bytes = sum(x.nbytes for x in leaves)
-    assert (len(leaves), leaves[0].shape) == (48, (48, 16, 1151, 64))
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree_util.tree_leaves(params))
     args = (params, place(eng.kv.cache),
             *place((eng._d_toks, eng._d_idxs, eng._d_active,
                     eng._d_seeds, eng._d_pos)))
     if num_draft:
-        drafts = jax.ShapeDtypeStruct((eng.cfg.max_slots, num_draft),
-                                      jnp.int32, sharding=s1)
+        drafts = jax.ShapeDtypeStruct(
+            (eng.cfg.max_slots, num_draft), jnp.int32,
+            sharding=jax.tree_util.tree_leaves(params)[0].sharding)
         lowered = eng._verify.lower(*args, drafts)
     else:
         lowered = eng._decode.lower(*args)
     del eng                                   # the 5 GiB pool of zeros
     compiled = lowered.compile()
     copies, loops = _census(compiled, leaf_elems)
+    big = _leaf_sized(compiled, leaf_elems)
     mem = compiled.memory_analysis()
     print(f"AOT {CELL} num_draft={num_draft}: pool-sized copies {copies}, "
-          f"while {loops}, alias {mem.alias_size_in_bytes / 2 ** 30:.3f} "
-          f"GiB, temp {mem.temp_size_in_bytes / 2 ** 30:.3f} GiB")
+          f"while {loops}, leaf-sized results {len(big)}, alias "
+          f"{mem.alias_size_in_bytes / 2 ** 30:.3f} GiB, temp "
+          f"{mem.temp_size_in_bytes / 2 ** 30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2 ** 30:.3f} GiB (pool "
+          f"{pool_bytes / 2 ** 30:.3f} + weights "
+          f"{weight_bytes / 2 ** 30:.3f})")
     assert copies == 0
     assert loops == 0
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < leaf_bytes
+    # nothing but the kernel, one a layer, computes a leaf-sized result
+    assert len(big) == 24, big
+    assert all(re.fullmatch(r"apex1_decode_attend(\.\d+)?", n)
+               for n in big), big
+    assert abs(mem.argument_size_in_bytes - pool_bytes - weight_bytes) \
+        < 0.01 * (pool_bytes + weight_bytes)
+
+
+def test_prefill_moves_one_lane_not_a_leaf(topo, mosaic):
+    """A prefill chunk's attention is the composite on one batch-1 lane
+    (scalar index, chunk 128): sliced out of the pool, written back in
+    place, with no copy of a leaf."""
+    import jax
+    import jax.numpy as jnp
+    eng, params, place = _cell_engine(topo, 0)
+    leaves = jax.tree_util.tree_leaves(eng.kv.cache)
+    leaf_elems, leaf_bytes = leaves[0].size, leaves[0].nbytes
+    pool_bytes = sum(x.nbytes for x in leaves)
+    s1 = jax.tree_util.tree_leaves(params)[0].sharding
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=s1)
+    lowered = eng._prefill.lower(
+        params, place(eng.kv.cache), i32, place(eng.kv.zeros_lane),
+        jax.ShapeDtypeStruct((), jnp.bool_, sharding=s1),
+        jax.ShapeDtypeStruct((1, eng.cfg.prefill_chunk), jnp.int32,
+                             sharding=s1), i32, i32, i32)
+    del eng
+    compiled = lowered.compile()
+    copies, loops = _census(compiled, leaf_elems)
+    big = _leaf_sized(compiled, leaf_elems)
+    mem = compiled.memory_analysis()
+    print(f"AOT {CELL} prefill: pool-sized copies {copies}, while {loops}, "
+          f"leaf-sized results {len(big)}, alias "
+          f"{mem.alias_size_in_bytes / 2 ** 30:.3f} GiB, temp "
+          f"{mem.temp_size_in_bytes / 2 ** 30:.3f} GiB")
+    assert copies == 0
+    assert loops == 0
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # a leaf-sized result is the leaf itself with the lane put back in
+    # place, never a leaf computed anew; the temporaries are lanes (48
+    # of 2.4 MB sliced out, 48 updated)
+    assert all(re.search(r"dynamic[_-]update[_-]slice", n)
+               for n in big), big
+    assert mem.temp_size_in_bytes < 2 * leaf_bytes
 
 
 def test_the_scatter_it_replaced_still_loops(topo, mosaic):
-    """The control: the write as it was (a `dynamic_update_slice` batched
-    over its index by `vmap`), compiled the same way at a small pool, is
-    still a loop between copies of the cache, and `_census` sees both;
-    `cache_write` with the same per-row index shows neither."""
+    """The control: the write as it was before PR 26 (a
+    `dynamic_update_slice` batched over its index by `vmap`), compiled
+    the same way at a small pool, is still a loop between copies of the
+    cache, and `_census` sees both; the step's kernel with the same
+    per-row index shows neither, and is the one leaf-sized result."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
     from apex1_tpu.models.generate import cache_write
+    from apex1_tpu.ops.decode_attend import decode_attend
     s1 = SingleDeviceSharding(topo.devices[0])
-    cache = jax.ShapeDtypeStruct((16, 16, 1151, 64), jnp.bfloat16,
+    cache = jax.ShapeDtypeStruct((16, 1152, 1024), jnp.bfloat16,
                                  sharding=s1)
     new = jax.ShapeDtypeStruct((16, 16, 1, 64), jnp.bfloat16, sharding=s1)
     idx = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=s1)
+    leaf_elems = 16 * 1152 * 1024
 
-    def attend(write):
-        def f(c, n, i):
-            c = write(c, n, i)
-            return jnp.einsum("bhsd,bhkd->bhsk", n, c), c
-        return jax.jit(f, donate_argnums=0).lower(cache, new, idx).compile()
+    def old(c, n, i):
+        c = jax.vmap(lambda c1, n1, i1: cache_write(
+            c1[None], n1[None], i1)[0])(c, n, i)
+        return jnp.einsum("bhsd,bkhd->bhsk", n,
+                          c.reshape(16, 1152, 16, 64)), c
 
-    old = attend(lambda c, n, i: jax.vmap(
-        lambda c1, n1, i1: cache_write(c1[None], n1[None], i1)[0])(c, n, i))
-    copies, loops = _census(old, 16 * 16 * 1151 * 64)
+    def kernel(c, n, i):
+        attn, c, _ = decode_attend(n, n, n, c, c + 1, i)
+        return attn, c
+
+    compiled = {f.__name__: jax.jit(f, donate_argnums=0).lower(
+        cache, new, idx).compile() for f in (old, kernel)}
+    copies, loops = _census(compiled["old"], leaf_elems)
     assert copies >= 1 and loops == 1
-    assert _census(attend(cache_write), 16 * 16 * 1151 * 64) == (0, 0)
+    assert _census(compiled["kernel"], leaf_elems) == (0, 0)
+    big = _leaf_sized(compiled["kernel"], leaf_elems)
+    assert [re.sub(r"\.\d+$", "", n) for n in big if "apex1" in n] == [
+        "apex1_decode_attend"]

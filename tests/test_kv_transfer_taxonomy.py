@@ -129,7 +129,8 @@ class TestHandoffErrorTaxonomy:
         eng, key = src
         page = extract_page(eng, key)
         arr = self._leaf(page)
-        page.lane = {"toy": {"h": arr.reshape(arr.shape[::-1])}}
+        page.lane = {"toy": {"h": arr.reshape(
+            (arr.shape[1], arr.shape[0]) + arr.shape[2:])}}
         with pytest.raises(HandoffError, match="shape mismatch"):
             verify_page(page)
 

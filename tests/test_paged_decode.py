@@ -170,8 +170,8 @@ class TestPagePlumbing:
         for n in range(2):
             s = int(start[n])
             np.testing.assert_array_equal(
-                np.asarray(dense[n, :, s:s + 6, :]),
-                np.asarray(vals[n]))
+                np.asarray(dense[n, s:s + 6, :]),
+                np.asarray(vals[n].transpose(1, 0, 2).reshape(6, -1)))
 
     def test_composite_matches_dense_cache_attend_bitwise(self):
         """Gather→cache_attend through a permuted block table must be
@@ -229,13 +229,12 @@ class TestPagePlumbing:
         got, new_pc = paged_update_attend(q, k_new, v_new, pc, idx)
         k_all = gather_pages(kp, bt, 12)
         v_all = gather_pages(vp, bt, 12)
-        k_up = jnp.stack([
-            jax.lax.dynamic_update_slice(k_all[n], k_new[n],
-                                         (0, int(idx[n]), 0))
+        from apex1_tpu.models.generate import cache_write
+        k_up = jnp.concatenate([
+            cache_write(k_all[n:n + 1], k_new[n:n + 1], int(idx[n]))
             for n in range(2)])
-        v_up = jnp.stack([
-            jax.lax.dynamic_update_slice(v_all[n], v_new[n],
-                                         (0, int(idx[n]), 0))
+        v_up = jnp.concatenate([
+            cache_write(v_all[n:n + 1], v_new[n:n + 1], int(idx[n]))
             for n in range(2)])
         want = cache_attend(q, k_up, v_up, idx)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -257,8 +256,8 @@ class TestPagePlumbing:
 # ---------------------------------------------------------------------------
 
 
-def _toy_cache(n, s, dtype=jnp.float32):
-    shape = (n, 2, s, 4)
+def _toy_cache(n, s, dtype=jnp.float32, page_form=False):
+    shape = (n, 2, s, 4) if page_form else (n, s, 2 * 4)
     return {"layer0": {"k": jnp.zeros(shape, dtype),
                        "v": jnp.zeros(shape, dtype)}}
 

@@ -193,10 +193,13 @@ class TestContinuousBatching:
         # only the pool's slack keeps the write from being clamped
         eng = _engine(tiny, max_slots=1, max_len=16, prefill_chunk=8)
         # the invariant that prevents the clamp: the pool allocates
-        # prefill_chunk-1 positions past the usable max_len, so every
-        # padded chunk write [start, start+chunk) fits
-        s_max = jax.tree_util.tree_leaves(eng.kv.cache)[0].shape[2]
-        assert s_max == 16 + 8 - 1
+        # prefill_chunk-1 positions past the usable max_len (and rounds
+        # up to the step kernel's block), so every padded chunk write
+        # [start, start+chunk) fits
+        from apex1_tpu.models.generate import cache_len
+        from apex1_tpu.ops.decode_attend import DECODE_BLOCK
+        s_max = cache_len(eng.kv.cache)
+        assert s_max >= 16 + 8 - 1 and s_max % DECODE_BLOCK == 0
         sysp = tuple(rng.integers(0, cfg.vocab_size, (1,)).tolist())
         p = rng.integers(0, cfg.vocab_size, (13,)).tolist()
         rid = eng.submit(p, max_new_tokens=3, prefix=sysp)
@@ -255,8 +258,8 @@ class TestPerRowCacheWrite:
         k_new = jax.random.normal(ks[1], (B, Hkv, s, D), dtype)
         v_new = jax.random.normal(ks[2], (B, Hkv, s, D), dtype)
         # a cache that is NOT zero: rows the write must leave alone
-        cache = {"k": jax.random.normal(ks[3], (B, Hkv, S_max, D), dtype),
-                 "v": jax.random.normal(ks[4], (B, Hkv, S_max, D), dtype)}
+        cache = {"k": jax.random.normal(ks[3], (B, S_max, Hkv * D), dtype),
+                 "v": jax.random.normal(ks[4], (B, S_max, Hkv * D), dtype)}
         idx = jnp.asarray([0, 4, S_max - s, 7, 2], jnp.int32)
         attn, new = cached_attention(q, k_new, v_new, cache, idx,
                                      chunk_decode=True)
@@ -277,13 +280,13 @@ class TestPerRowCacheWrite:
         from apex1_tpu.models.generate import cache_write
         S_max = 6
         cache = jnp.arange(3 * S_max, dtype=jnp.float32).reshape(
-            3, 1, S_max, 1)
+            3, S_max, 1)
         new = -jnp.ones((3, 1, 2, 1), jnp.float32) * jnp.asarray(
             [1.0, 2.0]).reshape(1, 1, 2, 1)
         got = np.asarray(cache_write(
             cache, new, jnp.asarray([S_max - 1, S_max, S_max + 3])))
         want = np.asarray(cache).copy()
-        want[0, 0, S_max - 1, 0] = -1.0       # the chunk's first row;
+        want[0, S_max - 1, 0] = -1.0          # the chunk's first row;
         np.testing.assert_array_equal(got, want)   # nothing else moved
 
     @pytest.mark.parametrize("case", ["last_position", "freed_lane_reused",
@@ -1269,6 +1272,63 @@ class TestEngineSpans:
         assert seen["n"] > 0
         assert sum(sp.counts["control_dispatches"]
                    for sp in steps) == seen["n"]
+
+    @pytest.mark.parametrize("kind", ["dense", "dense_eos", "paged",
+                                      "spec"])
+    def test_kv_block_counts_equal_a_replayed_trace(self, kind):
+        """`kv_blocks_read` / `kv_blocks_pool` on the step span against
+        the same sums replayed from the requests alone: a lane of prompt
+        P that emits one token a step holds P + t positions at its t-th
+        step and moves the blocks up to its horizon; the pool's blocks
+        count once for every step that decodes. Lengths cross the block
+        boundaries at 128 and 256; four requests queue for three slots."""
+        from apex1_tpu.ops.decode_attend import DECODE_BLOCK as blk
+        from apex1_tpu.testing.chaos import toy_decoder
+        apply_fn, make_cache, params = toy_decoder()
+        plan = [(120, 12), (250, 8), (5, 6), (127, 3)]
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(1, 60, (n,)).tolist() for n, _ in plan]
+        width = 1
+        kw = dict(_SPAN_ENGINES[kind], max_slots=3, max_len=300,
+                  prefill_chunk=64, vocab_size=61)
+        if kind == "dense_eos":
+            kw["eos_id"] = 60              # read every step, never drawn
+        extra = {}
+        if kind == "spec":
+            # a draft whose first token is always wrong: every round
+            # accepts nothing and emits one token, K + 1 rows wide
+            width = kw["num_draft"] + 1
+            want = {tuple(p): np.asarray(generate(
+                apply_fn, params, jnp.asarray([p], jnp.int32),
+                max_new_tokens=n, cache=make_cache(1, len(p) + n),
+                vocab_size=61))[0].tolist()
+                for p, (_, n) in zip(prompts, plan)}
+
+            def propose(history, k):
+                p = next(q for q in want if tuple(history[:len(q)]) == q)
+                i = len(history) - len(p)
+                out = (want[p] + [0] * k)[i:i + k]
+                out[0] = (out[0] + 1) % 61
+                return np.asarray(out, np.int32)
+            extra["draft_propose"] = propose
+        eng = Engine(apply_fn, make_cache, params, EngineConfig(**kw),
+                     **extra)
+        mark = _span_mark()
+        ids = [eng.submit(p, max_new_tokens=n)
+               for p, (_, n) in zip(prompts, plan)]
+        eng.run(max_steps=200)
+        steps = [sp for sp in _spans_since(mark)
+                 if sp.name == "serving/step"]
+        assert all(len(eng.results[r].tokens) == n
+                   for r, (_, n) in zip(ids, plan))
+        read = sum(-(-(p + t + width) // blk)
+                   for p, n in plan for t in range(n - 1))
+        lane_blocks = -(-(300 + max(64, width) - 1) // blk)
+        decoding = sum(1 for sp in steps if sp.counts["n_active"])
+        assert sum(sp.counts["kv_blocks_read"] for sp in steps) == read
+        assert sum(sp.counts["kv_blocks_pool"]
+                   for sp in steps) == decoding * 3 * lane_blocks
+        assert 0 < read < decoding * 3 * lane_blocks
 
     def test_queued_span_runs_from_submit_to_admission(self, tiny, rng):
         eng, ids, spans = self._run(tiny, rng, prefix_cache=False)

@@ -309,6 +309,42 @@ def _run_checks(args) -> bool:
                 print(f"  FAIL paged geometry gate: page={bad} must "
                       f"raise ValueError", flush=True)
 
+        # the dense pool's step attention (PR 29): append + ragged read
+        # in one kernel over leaves stored (slots, positions, Hkv * D),
+        # at the `gpt2m_serve_chat` cell's shapes (48 slots x 1152 x 16
+        # heads of 64, decode and a K=4 verify) and at llama-head rows
+        # (GQA 32/8, D=128) in both cache tiers. `check_decode_geometry`
+        # runs at trace time against the registry-shared vmem model.
+        from apex1_tpu.ops.decode_attend import (check_decode_geometry,
+                                                 decode_attend)
+        for tag, (B_d, Hq_d, Hkv_d, D_d, L_d), tiers in (
+                ("gpt2m chat cell", (48, 16, 16, 64, 1152),
+                 (("bf16", jnp.bfloat16),)),
+                ("llama heads", (8, 32, 8, 128, 2048),
+                 (("bf16", jnp.bfloat16), ("int8", jnp.int8)))):
+            for tier, cdt in tiers:
+                for S_d in (1, 5):
+                    check(f"decode_attend {tag} {tier} S={S_d} "
+                          f"({B_d},Hq{Hq_d}/Hkv{Hkv_d},D{D_d},L{L_d})",
+                          lambda q, kn, vn, kp, vp, ix: decode_attend(
+                              q, kn, vn, kp, vp, ix),
+                          [(B_d, Hq_d, S_d, D_d), (B_d, Hkv_d, S_d, D_d),
+                           (B_d, Hkv_d, S_d, D_d), (B_d, L_d, Hkv_d * D_d),
+                           (B_d, L_d, Hkv_d * D_d), (B_d,)],
+                          dtypes=[jnp.bfloat16, jnp.bfloat16, jnp.bfloat16,
+                                  cdt, cdt, jnp.int32])
+        for bad_len, bad_s in ((1151, 1), (1152, 128)):
+            try:
+                check_decode_geometry(bad_len, 1024, 16 * bad_s, bad_s,
+                                      jnp.bfloat16)
+            except ValueError as e:
+                print(f"  OK   decode geometry L={bad_len} S={bad_s} "
+                      f"raises: {str(e)[:60]}", flush=True)
+            else:
+                ok = False
+                print(f"  FAIL decode geometry gate: L={bad_len} S={bad_s} "
+                      f"must raise ValueError", flush=True)
+
         # chunked preference/distill losses, fused GLU, LoRA epilogue
         # (ISSUE 19): the chunked-loss VJP recomputes per vocab chunk
         # through the linear_xent stats kernels; fused_glu is the llama
