@@ -101,22 +101,35 @@ With ``eos_id=None`` retirement is purely length-based (known at
 admission) and the engine NEVER reads a step's tokens back before
 dispatching the next — per-step outputs accumulate in a device-side
 log and are materialized once, at retirement. With an ``eos_id`` the
-engine must observe each step's tokens to retire rows (one small
-blocking readback per step) — the latency cost of data-dependent
-control, paid only when asked for. Speculative mode always reads back
-(drafting needs the history; accept counts gate retirement).
+engine must observe every token, but not before it launches the next
+step: the loop runs ONE STEP AHEAD (`Engine._decode_step`). `step()`
+launches step n+1, fed from device arrays, and only then reads the
+tokens of step n, whose device-to-host copy was started at their own
+launch; host and device work at the same time, one thread, JAX's
+asynchronous dispatch doing the overlapping. A lane leaves the batch by
+count at the launch of its last token and its request finishes where
+those tokens are read; an ``eos`` is learnt a step late, and the
+lane-step already in flight is dropped on the host and harmless on the
+device (the ordering argument is `_decode_step`'s docstring). A token
+exists for the outside when the host holds its value. Speculative mode
+reads each round back before the next (drafting needs the history;
+accept counts gate retirement) and does not run ahead.
 
 SPANS AND COUNTS: every phase of `step()` is an `obs.spine.span` — the
 engine's one way of naming a region (``serving/step`` > ``expire``,
 ``admit`` > ``admit.alloc`` / ``prefill`` / ``admit.register`` /
 ``admit.first_read`` / ``admit.patch``, ``decode_step`` or
-``verify_step``, ``read_tokens``, ``emit`` > ``retire``; the table is in
+``verify_step``, ``read_tokens`` (with an ``eos_id``: of the launch
+BEFORE this step's), ``emit`` > ``retire``; the table is in
 docs/observability.md). The spans of one request share its id, the two
 in which the host waits for the device are marked ``wait``, and each
 ``serving/step`` carries what the step did (``admitted``, ``retired``,
 ``prefill_chunks``, ``prefill_tokens``, ``tokens_out``, ``n_active``,
-``queue_depth``), ``control_dispatches``: the device programs it
-launched outside the two executables, and ``kv_blocks_read`` of
+``queue_depth``), ``ran_ahead``: whether its launch came before the
+read of the launch before it, ``overrun_lanes``: lane-steps launched for
+a lane that had already sampled ``eos``, ``control_dispatches``: the
+device programs it launched outside the two executables, and
+``kv_blocks_read`` of
 ``kv_blocks_pool``: the blocks of K/V positions the step's attention
 has to move, each live lane's up to its horizon, of those the pool
 holds (host arithmetic on the slots' depths). They are kept in memory,
@@ -252,8 +265,10 @@ class _Slot:
     first_tok: object            # device scalar (or int once read)
     start_step: int              # engine step its first DECODE lands at
     n_out: int = 1               # tokens emitted so far (first included)
-    in_batch: bool = False       # joined the decode batch (not retired
-    eos_seen: bool = False       #  at prefill)
+    n_launched: int = 1          # tokens launched for: n_out, and one more
+    #                              while a step's tokens are still unread
+    in_batch: bool = False       # in the decode batch: joined, and has
+    eos_seen: bool = False       #  not left it by count or by retiring
     depth: int = 0               # cache positions the lane holds
     produced: List[int] = dataclasses.field(default_factory=list)
     # speculative bookkeeping: the request's full known token history
@@ -271,7 +286,10 @@ class Engine:
     Drive it with `submit` + `step`/`run`; finished requests appear in
     `results`. One `step()` = retire (deadline/cancel) → admit queued
     requests into free slots (chunked prefill) → one pooled decode (or
-    speculative verify) step. ``metrics`` collects the full lifecycle
+    speculative verify) step; with an ``eos_id`` the tokens a `step()`
+    hands out are those of the launch BEFORE its own (`_decode_step`),
+    so keep stepping while a request is unfinished (`run` does).
+    ``metrics`` collects the full lifecycle
     (`ServingMetrics`). ``draft_propose(history, k) -> k ints`` plugs a
     custom draft source into speculative mode (default: n-gram
     prompt-lookup self-drafting, zero extra params).
@@ -382,13 +400,17 @@ class Engine:
         self._tally = dict.fromkeys(
             ("admitted", "retired", "prefill_chunks", "prefill_tokens",
              "tokens_out", "control_dispatches", "kv_blocks_read",
-             "kv_blocks_pool"), 0)
+             "kv_blocks_pool", "ran_ahead", "overrun_lanes"), 0)
         # eos_id=None: retirement is length-based, so step tokens are
         # only READ at retirement — the log keeps each step's (N,)
         # output (device array until first fetch memoizes it as numpy).
         # Speculative mode always reads back (drafting needs history).
         self._defer = cfg.eos_id is None and not self._spec
         self._tok_log: Dict[int, object] = {}
+        # with an eos_id the loop runs ONE step ahead (`_decode_step`):
+        # the launch whose tokens the host has not read yet, as
+        # ``(tokens on the device, {lane: its _Slot at that launch})``
+        self._inflight: Optional[tuple] = None
         self._step_no = 0
         # the mid-admission cancel window: `cancel` from an ingest
         # thread while `_admit` runs this request's prefill chain. The
@@ -880,7 +902,9 @@ class Engine:
     def step(self) -> int:
         """One engine iteration: retire (deadline/cancel) → admit → one
         decode (or speculative verify) step over every occupied slot.
-        Returns the number of active slots that decoded (0 = idle)."""
+        Returns the number of lanes the step was launched for (0 = none:
+        the engine is idle once this call has handed out whatever the
+        launch before it still owed, see `_decode_step`)."""
         with spine.span("serving/step") as sp:
             before = dict(self._tally)
             with spine.span("serving/expire"):
@@ -896,11 +920,11 @@ class Engine:
                         self._retire(i, "evicted", "deadline")
             self._admit_all()
             n_active = self._n_active
-            if n_active:
-                if self._spec:
+            if self._spec:
+                if n_active:
                     self._spec_step()
-                else:
-                    self._decode_step()
+            elif n_active or self._inflight is not None:
+                self._decode_step()
             depth = self.scheduler.depth
             self.metrics.step_sample(n_active, self.cfg.max_slots, depth)
             sp.counts = {k: v - before[k] for k, v in self._tally.items()}
@@ -927,8 +951,14 @@ class Engine:
             -(-(st.depth + width) // DECODE_BLOCK)
             for st in self._slots if st is not None and st.in_batch)
 
-    def _decode_step(self):
+    def _launch(self) -> tuple:
+        """Dispatch the step executable for the lanes in the batch and
+        feed its outputs to the next one on the device. Returns
+        ``(tokens on the device, {lane: _Slot})``, the lanes being those
+        the step runs for."""
         self._count_kv_blocks(1)
+        lanes = {i: st for i, st in enumerate(self._slots)
+                 if st is not None and st.in_batch}
         with spine.span("serving/decode_step"):
             if self._paged:
                 nxt, idxs, pos, self.kv.pages = self._decode(
@@ -940,31 +970,96 @@ class Engine:
                     self.params, self.kv.cache, self._d_toks,
                     self._d_idxs, self._d_active, self._d_seeds,
                     self._d_pos, *self._lora_args())
-        self._d_toks, self._d_idxs, self._d_pos = nxt, idxs, pos
-        if self._defer:
-            self._tok_log[self._step_no] = nxt     # fetched at retire
-            toks = None
-        else:
-            with spine.span("serving/read_tokens", wait=True):
-                toks = np.asarray(nxt)             # eos needs the values
+            self._d_toks, self._d_idxs, self._d_pos = nxt, idxs, pos
+            if not self._defer:
+                # the tokens start for the host now, behind the step on
+                # the device, and not when the host comes to ask
+                nxt.copy_to_host_async()
+                for i, st in lanes.items():
+                    st.n_launched += 1
+                    if st.n_launched >= st.req.max_new_tokens:
+                        # leaves by COUNT, no token value needed: out of
+                        # the batch before the next launch, so the lane
+                        # never runs a step past its length. The request
+                        # finishes where these tokens are read.
+                        self._d_active = self._patch(self._d_active, i,
+                                                     False)
+                        st.in_batch = False
+                        self._n_active -= 1
+        for st in lanes.values():
+            st.depth += 1
         self._step_no += 1
+        return nxt, lanes
+
+    def _decode_step(self):
+        """One plain decode step. With an ``eos_id`` the loop runs ONE
+        STEP AHEAD: this call launches step n+1 and only then hands out
+        the tokens of step n, whose copy to the host was started at its
+        own launch, so host and device work at the same time instead of
+        in turns. Step n+1 is fed from device arrays and needs nothing
+        the host learns from step n, but for this:
+
+        A lane that sampled ``eos_id`` in step n has step n+1 in flight
+        (an OVERRUN lane-step; a deadline or a cancel with a step in
+        flight is the same case). The host drops that lane-step's token
+        (the lane's `_Slot` is no longer the one it was launched for)
+        and its K/V row lands one position past the lane's end. That row
+        harms nobody: every reader of the pool stops at its own horizon,
+        an admission into the freed lane prefills from position 0 and
+        patches the lane's index, token and output position, and the
+        device runs its programs in the order they were launched, the
+        in-flight step before any later prefill into the lane (paged:
+        before `_sync_bt` points the freed row at the trash page, and
+        the row's own pages can have no new owner before that). It
+        cannot run off the lane: a lane is launched for only while
+        ``n_launched < max_new_tokens``, and `submit` holds the request's
+        last position inside ``max_len``.
+
+        What exists for the outside (``produced``, the ``token`` event,
+        ``tokens_out``, a result) moves where the host READS a token,
+        never where it is launched. A call with nothing to launch hands
+        out what the last launch owes and leaves nothing in flight."""
+        if self._defer:
+            nxt, lanes = self._launch()
+            self._tok_log[self._step_no - 1] = nxt     # fetched at retire
+            self._emit(lanes, None)
+            return
+        prev, self._inflight = self._inflight, None
+        if prev is not None and not any(
+                self._slots[i] is st for i, st in prev[1].items()):
+            prev = None             # every lane it ran for has retired
+        if self._n_active:
+            self._inflight = self._launch()
+            self._tally["ran_ahead"] += prev is not None
+        if prev is None:
+            return
+        with spine.span("serving/read_tokens", wait=True):
+            toks = np.asarray(prev[0])
+        self._emit(prev[1], toks)
+
+    def _emit(self, lanes: dict, toks):
+        """Hand out one launch's tokens, lane by lane, and retire what
+        they finish. ``toks`` None: the values stay on the device (the
+        deferred log) and only the counts move."""
         with spine.span("serving/emit"):
-            for i, slot in enumerate(self._slots):
-                if slot is None:
-                    continue
-                slot.n_out += 1
-                slot.depth += 1
+            for i, st in lanes.items():
+                if self._slots[i] is not st:
+                    continue        # retired with this step in flight
+                st.n_out += 1
                 self._tally["tokens_out"] += 1
-                self.metrics.event(slot.req.req_id, "token")
+                self.metrics.event(st.req.req_id, "token")
                 if toks is not None:
                     tok = int(toks[i])
-                    slot.produced.append(tok)
-                    slot.history.append(tok)
+                    st.produced.append(tok)
+                    st.history.append(tok)
                     if tok == self.cfg.eos_id:
-                        slot.eos_seen = True
+                        st.eos_seen = True
+                        self._tally["overrun_lanes"] += (
+                            self._inflight is not None
+                            and self._inflight[1].get(i) is st)
                         self._retire(i, "done", "eos")
                         continue
-                if slot.n_out >= slot.req.max_new_tokens:
+                if st.n_out >= st.req.max_new_tokens:
                     self._retire(i, "done", "length")
 
     def _spec_step(self):
@@ -1422,7 +1517,9 @@ class Engine:
 
     @property
     def n_active(self) -> int:
-        return self._n_active
+        """Requests that hold a lane: in the decode batch, or out of it
+        by count with their last tokens still to be read."""
+        return sum(s is not None for s in self._slots)
 
     def slot_view(self) -> List[Optional[int]]:
         """req_id per slot (None = free) — the occupancy diagram."""

@@ -1360,3 +1360,257 @@ class TestEngineSpans:
         assert snap[-1].name == "serving/step"
         assert sum(sp.name == "serving/step" for sp in snap) >= 10_000
         assert len(eng.results) == 20
+
+
+# --------------------------------------------------------------------------
+# the loop runs one step ahead (eos_id set): step n+1 is launched before
+# step n's tokens are read
+# --------------------------------------------------------------------------
+
+_POOLS = {"dense": dict(prefix_cache=False),
+          "paged": dict(prefix_cache=False, paged=True, page_size=8)}
+
+
+class _Watched:
+    """An engine with an ``eos_id``, watched from outside: every launch
+    of the step executable (its token array and the lanes it ran for),
+    every one of those arrays the host has turned into numpy, and after
+    each `step()` what every request had by then."""
+
+    def __init__(self, tiny, monkeypatch, pool, **kw):
+        from apex1_tpu.serving import engine as engine_mod
+        self.eng = eng = _engine(tiny, **_POOLS[pool], **kw)
+        self.launches = []          # (tokens on device, {lane: _Slot})
+        self.read = set()           # indices into `launches`
+        self.after = []             # per step(): what the outside saw
+        real = eng._decode
+
+        def decode(*a):
+            out = real(*a)
+            self.launches.append((out[0], {
+                i: st for i, st in enumerate(eng._slots)
+                if st is not None and st.in_batch}))
+            return out
+
+        eng._decode = decode
+        watched = self
+
+        class Np:
+            def __getattr__(self, name):
+                return getattr(np, name)
+
+            @staticmethod
+            def asarray(x, *a, **k):
+                for n, (toks, _) in enumerate(watched.launches):
+                    if x is toks:
+                        watched.read.add(n)
+                return np.asarray(x, *a, **k)
+
+        monkeypatch.setattr(engine_mod, "np", Np())
+        self.mark = _span_mark()
+
+    def step(self):
+        eng = self.eng
+        before = {i: (st, len(st.produced))
+                  for i, st in enumerate(eng._slots) if st is not None}
+        n_launches = len(self.launches)
+        eng.step()
+        held = {}
+        for st in [s for s in eng._slots if s is not None]:
+            rid = st.req.req_id
+            lanes_of = [n for n, (_, lanes) in enumerate(self.launches)
+                        if any(s is st for s in lanes.values())]
+            rec = eng.metrics.records[rid]
+            held[rid] = dict(n_generated=rec.n_generated,
+                             produced=len(st.produced),
+                             launched=len(lanes_of),
+                             read=len(self.read.intersection(lanes_of)))
+        self.after.append(dict(
+            before=before, held=held, n_launches=len(self.launches),
+            launched=len(self.launches) > n_launches,
+            read=set(self.read)))
+
+    def run(self):
+        eng = self.eng
+        while eng.scheduler.depth > 0 or eng.n_active:
+            self.step()
+            assert len(self.after) < 500
+
+    def steps(self):
+        return [sp for sp in _spans_since(self.mark)
+                if sp.name == "serving/step"]
+
+    def children(self, step):
+        return [sp for sp in _spans_since(self.mark)
+                if sp.parent == step.id]
+
+
+@pytest.mark.parametrize("pool", sorted(_POOLS))
+class TestRunsOneStepAhead:
+    LENS = [3, 7, 5, 9, 4, 6, 8]
+    NEW = 10
+
+    @pytest.fixture
+    def served(self, tiny, monkeypatch, pool):
+        """Seven requests over two lanes, joining while others decode,
+        with an ``eos_id`` that greedy decoding draws in mid-stream:
+        ``(watched engine, ids, prompts, what solo generate gives up to
+        its first eos)``."""
+        cfg, _, _, _, solo = tiny
+        rng = np.random.default_rng(1234)
+        prompts = [rng.integers(0, cfg.vocab_size, (L,)).tolist()
+                   for L in self.LENS]
+        full = [solo(p, self.NEW).tolist() for p in prompts]
+        eos = full[0][2]
+        want = [f[:f.index(eos) + 1] if eos in f else f for f in full]
+        # an eos in mid-stream leaves a step in flight behind it; one on
+        # the first token retires at admission, one on the last by count
+        assert sum(2 <= len(w) < self.NEW for w in want) >= 2
+        w = _Watched(tiny, monkeypatch, pool, max_slots=2, eos_id=eos)
+        ids = [w.eng.submit(p, max_new_tokens=self.NEW)
+               for p in prompts[:3]]
+        w.step()
+        w.step()
+        ids.append(w.eng.submit(prompts[3], max_new_tokens=self.NEW))
+        w.step()
+        ids += [w.eng.submit(p, max_new_tokens=self.NEW)
+                for p in prompts[4:]]
+        w.run()
+        return w, ids, prompts, want
+
+    def test_streams_equal_solo_generate_through_eos_and_lane_reuse(
+            self, served, pool):
+        """(a) every stream is solo `generate`'s up to its first eos:
+        the overrun lane-step's token is nowhere, and the request that
+        took the lane an overrun lane-step wrote past the end of is
+        token-identical too; (d) two executables, traced once."""
+        w, ids, _, want = served
+        eng = w.eng
+        for rid, tokens in zip(ids, want):
+            res = eng.results[rid]
+            assert res.status == "done"
+            assert res.reason == ("eos" if tokens[-1] == eng.cfg.eos_id
+                                  else "length")
+            np.testing.assert_array_equal(res.tokens, tokens)
+        # a lane that an eos left with a step in flight was taken again
+        overran = {rid for rid, tokens in zip(ids, want)
+                   if 2 <= len(tokens) < self.NEW}
+        owners = {}
+        for _, lanes in w.launches:
+            for i, st in lanes.items():
+                seq = owners.setdefault(i, [])
+                if not seq or seq[-1] != st.req.req_id:
+                    seq.append(st.req.req_id)
+        followed = [seq[n + 1] for seq in owners.values()
+                    for n, rid in enumerate(seq[:-1]) if rid in overran]
+        assert followed and all(
+            eng.results[rid].status == "done" for rid in followed)
+        assert eng.trace_counts == {"prefill": 1, "decode": 1}
+
+    def test_launch_precedes_the_read_of_the_launch_before(self, served,
+                                                           pool):
+        """(b) in every step with ``ran_ahead`` the launch's span ends
+        before the read's begins, and what that read hands out are the
+        tokens of the launch BEFORE this step's."""
+        w, _, _, _ = served
+        steps = w.steps()
+        assert len(steps) == len(w.after)
+        assert sum(sp.counts["ran_ahead"] for sp in steps) >= 10
+        for sp, seen in zip(steps, w.after):
+            kids = {k.name: k for k in w.children(sp)}
+            assert sp.counts["ran_ahead"] in (0, 1)
+            if not sp.counts["ran_ahead"]:
+                # nothing was in flight, or nothing was left to launch
+                assert not ("serving/decode_step" in kids
+                            and "serving/read_tokens" in kids)
+                continue
+            assert seen["launched"]
+            launch, read = (kids["serving/decode_step"],
+                            kids["serving/read_tokens"])
+            assert launch.end_ns <= read.start_ns and read.wait
+            assert read.end_ns <= kids["serving/emit"].start_ns
+            n = seen["n_launches"]           # launches so far: n - 1 is
+            toks, lanes = w.launches[n - 2]  # this step's own
+            assert n - 2 in seen["read"] and n - 1 not in seen["read"]
+            toks = np.asarray(toks)
+            for i, st in lanes.items():
+                if i not in seen["before"] or seen["before"][i][0] is not st:
+                    continue                 # retired since: dropped
+                had = seen["before"][i][1]
+                assert st.produced[had:had + 1] == [int(toks[i])]
+
+    def test_counts_follow_the_read_not_the_launch(self, served, pool):
+        """(c) over the steps: ``tokens_out`` is what the results hold,
+        ``overrun_lanes`` the requests an eos ended with a step in
+        flight, and after no `step()` does a request's ``n_generated``
+        count a token the host has not read."""
+        w, ids, _, want = served
+        eng = w.eng
+        steps = w.steps()
+
+        def total(key):
+            return sum(sp.counts[key] for sp in steps)
+
+        assert total("tokens_out") == sum(
+            len(eng.results[r].tokens) for r in ids) == sum(map(len, want))
+        assert total("overrun_lanes") == sum(
+            2 <= len(tokens) < self.NEW for tokens in want) >= 2
+        assert total("admitted") == total("retired") == len(ids)
+        ahead = 0
+        for seen in w.after:
+            for got in seen["held"].values():
+                assert got["n_generated"] == got["produced"]
+                assert got["n_generated"] == 1 + got["read"]
+                assert got["launched"] - got["read"] in (0, 1)
+                ahead += got["launched"] - got["read"]
+        assert ahead >= 10          # launched, and not yet counted
+        # a request's token events are as many as its tokens
+        for rid, tokens in zip(ids, want):
+            assert eng.metrics.records[rid].n_generated == len(tokens)
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline"])
+    def test_retired_with_a_step_in_flight_drops_that_token(
+            self, tiny, monkeypatch, pool, how):
+        """(e) a cancel or a deadline that falls on a lane with a step
+        in flight: the request ends with the tokens the host had read,
+        the in-flight token is dropped, the neighbour decodes on and the
+        request that takes the lane is token-identical."""
+        cfg, _, _, _, solo = tiny
+        rng = np.random.default_rng(77)
+        p1, p2, p3 = (rng.integers(0, cfg.vocab_size, (L,)).tolist()
+                      for L in (5, 6, 4))
+        w = _Watched(tiny, monkeypatch, pool, max_slots=2,
+                     eos_id=cfg.vocab_size + 1)     # never drawn
+        eng = w.eng
+        kw = ({"deadline": time.monotonic() + 3600.0}
+              if how == "deadline" else {})
+        r1 = eng.submit(p1, max_new_tokens=30, **kw)
+        r2 = eng.submit(p2, max_new_tokens=12)
+        for _ in range(3):
+            w.step()                # three launches, two of them read
+        (lane,) = [i for i, st in enumerate(eng._slots)
+                   if st.req.req_id == r1]
+        assert w.after[-1]["held"][r1] == dict(
+            n_generated=3, produced=3, launched=3, read=2)
+        r3 = eng.submit(p3, max_new_tokens=6)
+        if how == "cancel":
+            assert eng.cancel(r1)
+        else:
+            eng._slots[lane].req.deadline = time.monotonic() - 1.0
+        w.step()                    # r3 takes the lane in this step
+        res = eng.results[r1]
+        assert res.status == ("cancelled" if how == "cancel"
+                              else "evicted")
+        np.testing.assert_array_equal(res.tokens, solo(p1, 30)[:3])
+        assert eng.slot_view()[lane] == r3
+        w.run()
+        np.testing.assert_array_equal(eng.results[r1].tokens,
+                                      solo(p1, 30)[:3])
+        np.testing.assert_array_equal(eng.results[r2].tokens,
+                                      solo(p2, 12))
+        np.testing.assert_array_equal(eng.results[r3].tokens,
+                                      solo(p3, 6))
+        steps = w.steps()
+        assert sum(sp.counts["tokens_out"] for sp in steps) == 3 + 12 + 6
+        assert sum(sp.counts["overrun_lanes"] for sp in steps) == 0
+        assert eng.trace_counts == {"prefill": 1, "decode": 1}
