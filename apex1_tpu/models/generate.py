@@ -63,8 +63,13 @@ def init_cache(num_layers: int, batch: int, num_kv_heads: int,
 
 
 def cache_len(cache) -> int:
-    """Positions a dense cache pytree (or one leaf of it) holds."""
-    return jax.tree_util.tree_leaves(cache)[0].shape[1]
+    """Positions a dense cache pytree (or one leaf of it) holds: read off
+    a K/V leaf, since a tree may hold entries of another kind beside them
+    (a recurrent state has no positions: `granite_hybrid_decoder`)."""
+    leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
+    kv = [x for path, x in leaves
+          if path and getattr(path[-1], "key", None) in ("k", "v")]
+    return (kv or [x for _, x in leaves])[0].shape[1]
 
 
 def cached_attention(q, k_new, v_new, cache, cache_index, *,
@@ -802,6 +807,29 @@ def gpt2_decoder(model):
     """(apply_fn, make_cache) for `models.gpt2.GPT2`."""
     cfg = model.cfg
     return _decoder(model, cfg.num_heads, cfg.hidden_size // cfg.num_heads)
+
+
+def granite_hybrid_decoder(model):
+    """(apply_fn, make_cache) for `models.granite_hybrid.GraniteHybrid`:
+    the model has no positions (``positions`` is taken and unused), and
+    its cache tree holds two kinds of entry, one a layer by the model's
+    own ``layer_types``: K/V for an attention layer, a recurrent state
+    and a convolution's last inputs for a Mamba layer. ``n_real``
+    (scalar, with a scalar ``cache_index``): the tokens of a right-padded
+    run that are real; a state, unlike K/V, must not see the others."""
+    from apex1_tpu.models.granite_hybrid import init_hybrid_cache
+
+    def apply_fn(params, tokens, cache, cache_index, *, positions=None,
+                 chunk_decode=False, n_real=None):
+        del positions
+        return model.apply({"params": params}, tokens, cache=cache,
+                           cache_index=cache_index,
+                           chunk_decode=chunk_decode, n_real=n_real)
+
+    def make_cache(batch: int, max_len: int, dtype=None):
+        return init_hybrid_cache(model.cfg, batch, max_len, dtype)
+
+    return apply_fn, make_cache
 
 
 def t5_generate(model, params, enc_tokens, *, max_new_tokens: int,

@@ -135,6 +135,22 @@ has to move, each live lane's up to its horizon, of those the pool
 holds (host arithmetic on the slots' depths). They are kept in memory,
 always,
 and lie in a profiler trace on the device's clock when one is taken.
+
+RECURRENT STATE: a decoder's cache tree may hold, beside K/V, leaves
+that hold no positions (`models.generate.granite_hybrid_decoder`: a
+state-space layer's state and its convolution's last inputs). The pool
+treats them like any leaf, slot on axis 0, and a fresh request's lane is
+zeroed at admission, which is what a fresh state is. What differs: a
+prefill chunk is right-padded, and a state advanced by pad tokens is
+wrong for every later token, so ``prefill`` hands such a decoder
+``n_real``; and a state is no list of positions, so a longer lane does
+not hold a shorter prefix and a rejected draft cannot be rolled back:
+``prefix_cache``, ``num_draft > 0`` and ``paged`` are refused at
+construction. The step span of such an engine carries two more counts,
+``state_lanes`` (the lanes whose state the launch updates) and
+``state_bytes`` (what that moves: each of those lanes' recurrent leaves
+read once and written once). The one-step-ahead loop is as it is: an
+overrun lane-step advances a state that the next admission zeroes.
 """
 
 from __future__ import annotations
@@ -279,6 +295,17 @@ class _Slot:
     accepted: int = 0
 
 
+def recurrent_lane_bytes(make_cache) -> int:
+    """Bytes of the leaves of ONE lane of a decoder's cache tree that hold
+    no positions (their shape does not follow ``max_len``): a recurrent
+    state, as against K/V. 0 for a decoder that keeps K/V alone. Shapes
+    only, nothing is allocated."""
+    short, long = (jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda n=n: make_cache(1, n))) for n in (8, 16))
+    return sum(a.size * a.dtype.itemsize for a, b in zip(short, long)
+               if a.shape == b.shape)
+
+
 class Engine:
     """Continuous-batching engine over a ``(apply_fn, make_cache)``
     decoder pair (`models.generate.gpt2_decoder` / `llama_decoder`).
@@ -305,6 +332,24 @@ class Engine:
         self.params = params
         self._apply_fn = apply_fn
         self._spec = cfg.num_draft > 0
+        self._state_lane_bytes = recurrent_lane_bytes(make_cache)
+        if self._state_lane_bytes:
+            missing = [why for asked, why in (
+                (cfg.prefix_cache,
+                 "prefix_cache=True (a prefix page needs a snapshot of the "
+                 "state AT the share point; the lane is snapshotted after "
+                 "the last chunk, and a later state does not hold an "
+                 "earlier one)"),
+                (cfg.num_draft > 0,
+                 "num_draft > 0 (a rejected draft has advanced the state, "
+                 "and a state is no list of positions to roll back)"),
+                (cfg.paged,
+                 "paged=True (the paged pool holds pages of positions and "
+                 "has no place for a leaf without them)")) if asked]
+            if missing:
+                raise ValueError(
+                    "this decoder's cache carries recurrent state, which "
+                    "the engine cannot serve with " + "; ".join(missing))
         # multi-tenant LoRA (cfg.lora_rank > 0): the adapter-page store
         # rides beside the KV pool, and the executables recompute the
         # head matmul from the decoder's HIDDEN states (apply_fn must
@@ -401,6 +446,8 @@ class Engine:
             ("admitted", "retired", "prefill_chunks", "prefill_tokens",
              "tokens_out", "control_dispatches", "kv_blocks_read",
              "kv_blocks_pool", "ran_ahead", "overrun_lanes"), 0)
+        if self._state_lane_bytes:
+            self._tally.update(state_lanes=0, state_bytes=0)
         # eos_id=None: retirement is length-based, so step tokens are
         # only READ at retirement — the log keeps each step's (N,)
         # output (device array until first fetch memoizes it as numpy).
@@ -559,11 +606,16 @@ class Engine:
         lora = self._lora is not None
         sample_kw = self._sample_kw
         forward, lora_row, score, accept = self._model_calls()
+        recurrent = bool(self._state_lane_bytes)
+        # the traced bodies hold the counter, not the engine: an engine
+        # is in no reference cycle, so its pool and weights are freed
+        # with its last reference, without the cycle collector
+        trace_counts = self.trace_counts
 
         def prefill(params, pool, slot, init_lane, install, tokens, idx,
                     n_real, seed, a_pg=None, b_pg=None, lbt=None,
                     lon=None):
-            self.trace_counts["prefill"] += 1   # the compile-count hook
+            trace_counts["prefill"] += 1   # the compile-count hook
             lane = jax.tree_util.tree_map(
                 lambda x: jax.lax.dynamic_slice_in_dim(x, slot, 1, 0),
                 pool)
@@ -572,9 +624,12 @@ class Engine:
                 init_lane)
             positions = (jnp.asarray(idx, jnp.int32)
                          + jnp.arange(C, dtype=jnp.int32))[None]
-            logits, h, lane = forward(params, tokens, lane, idx,
-                                      positions=positions,
-                                      chunk_decode=True)
+            # K/V written for the chunk's pad tokens lies past the
+            # horizon; a recurrent state is told which tokens are real
+            logits, h, lane = forward(
+                params, tokens, lane, idx, positions=positions,
+                chunk_decode=True,
+                **({"n_real": n_real} if recurrent else {}))
             pool = jax.tree_util.tree_map(
                 lambda p, l: jax.lax.dynamic_update_slice_in_dim(
                     p, l.astype(p.dtype), slot, 0), pool, lane)
@@ -594,7 +649,7 @@ class Engine:
 
         def decode(params, pool, toks, idxs, active, seeds, pos,
                    *lora_args):
-            self.trace_counts["decode"] += 1    # the compile-count hook
+            trace_counts["decode"] += 1    # the compile-count hook
             tgt, pool = score(params, pool, toks[:, None], idxs, active,
                               seeds, pos, *lora_args)
             nxt = jnp.where(active, tgt[:, 0], cfg.pad_id)
@@ -603,7 +658,7 @@ class Engine:
 
         def verify(params, pool, toks, idxs, active, seeds, pos,
                    drafts, *lora_args):
-            self.trace_counts["verify"] += 1    # the compile-count hook
+            trace_counts["verify"] += 1    # the compile-count hook
             tgt, pool = score(
                 params, pool, jnp.concatenate([toks[:, None], drafts], 1),
                 idxs, active, seeds, pos, *lora_args)
@@ -657,6 +712,7 @@ class Engine:
         tree_map = jax.tree_util.tree_map
         kernel_path = use_pallas()
         forward, lora_row, score, accept = self._model_calls()
+        trace_counts = self.trace_counts   # not the engine: no cycle
 
         def lora_batch(logits, h, a_pg, b_pg, lbt, lon):
             # (N, V) logits + (N, H) hidden rows -> epilogue delta via
@@ -702,7 +758,7 @@ class Engine:
 
         def prefill(params, pages, bt, slot, tokens, idx, n_real, seed,
                     a_pg=None, b_pg=None, lbt=None, lon=None):
-            self.trace_counts["prefill"] += 1   # the compile-count hook
+            trace_counts["prefill"] += 1   # the compile-count hook
             bt_row = jax.lax.dynamic_slice_in_dim(bt, slot, 1, 0)
             positions = (jnp.asarray(idx, jnp.int32)
                          + jnp.arange(C, dtype=jnp.int32))[None]
@@ -740,7 +796,7 @@ class Engine:
 
         def decode(params, pages, bt, toks, idxs, active, seeds, pos,
                    *lora_args):
-            self.trace_counts["decode"] += 1    # the compile-count hook
+            trace_counts["decode"] += 1    # the compile-count hook
             if kernel_path:
                 logits, h, cache = forward(
                     params, toks[:, None], paged_cache(pages, bt), idxs,
@@ -761,7 +817,7 @@ class Engine:
 
         def verify(params, pages, bt, toks, idxs, active, seeds, pos,
                    drafts, *lora_args):
-            self.trace_counts["verify"] += 1    # the compile-count hook
+            trace_counts["verify"] += 1    # the compile-count hook
             chunks = jnp.concatenate([toks[:, None], drafts], 1)
             if kernel_path:
                 positions = (idxs[:, None]
@@ -959,6 +1015,10 @@ class Engine:
         self._count_kv_blocks(1)
         lanes = {i: st for i, st in enumerate(self._slots)
                  if st is not None and st.in_batch}
+        if self._state_lane_bytes:
+            self._tally["state_lanes"] += len(lanes)
+            self._tally["state_bytes"] += (2 * len(lanes)
+                                           * self._state_lane_bytes)
         with spine.span("serving/decode_step"):
             if self._paged:
                 nxt, idxs, pos, self.kv.pages = self._decode(

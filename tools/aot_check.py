@@ -345,6 +345,17 @@ def _run_checks(args) -> bool:
                 print(f"  FAIL decode geometry gate: L={bad_len} S={bad_s} "
                       f"must raise ValueError", flush=True)
 
+        # the decode step's state update of a state-space layer (PR 34),
+        # at the `granite4hm_serve_chat` cell's shapes: 48 slots of 64
+        # heads of 64 by a state of 128, stored two heads a row
+        from apex1_tpu.ops.ssm import ssm_step
+        check("ssm_step granite chat cell (48,H64,P64,N128)", ssm_step,
+              [(48, 64, 64), (48, 64), (64,), (48, 128), (48, 128), (64,),
+               (48, 32, 128, 128), (48,)],
+              dtypes=[jnp.float32] * 7 + [jnp.int32],
+              in_specs=(P("dp"), P("dp"), P(), P("dp"), P("dp"), P(),
+                        P("dp"), P("dp")))
+
         # chunked preference/distill losses, fused GLU, LoRA epilogue
         # (ISSUE 19): the chunked-loss VJP recomputes per vocab chunk
         # through the linear_xent stats kernels; fused_glu is the llama
