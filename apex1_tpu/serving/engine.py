@@ -136,6 +136,19 @@ holds (host arithmetic on the slots' depths). They are kept in memory,
 always,
 and lie in a profiler trace on the device's clock when one is taken.
 
+OPERANDS: a launch costs the host by the array it is handed, whatever
+the array's size, and most leaves of a decoder's tree are tiny. At
+construction the small leaves are stacked by shape, dtype and sharding
+(`serving.packing`), once; every executable is launched with the stacks
+and the remaining leaves (the caller's own buffers: no matrix is
+copied) and rebuilds the caller's tree at the head of its traced body.
+``params`` stays the caller's tree, and an executable is still called
+with it. The launch's span carries ``operands``, the arrays it hands
+over. Cutting the stacks apart costs the device a little every step, so
+a tree whose bytes alone take the device longer to stream than the
+launch takes the host (`packing.launch_is_hidden`) is handed over as it
+is: that launch lies under the step in flight.
+
 RECURRENT STATE: a decoder's cache tree may hold, beside K/V, leaves
 that hold no positions (`models.generate.granite_hybrid_decoder`: a
 state-space layer's state and its convolution's last inputs). The pool
@@ -174,6 +187,7 @@ from apex1_tpu.ops.paged_decode import (PagedCache, fused_sample,
 from apex1_tpu.resilience.retry import _mix32
 from apex1_tpu.serving.kv_pool import KVPool, PagedKVPool
 from apex1_tpu.serving.metrics import ServingMetrics
+from apex1_tpu.serving.packing import Executable, PackedParams
 from apex1_tpu.serving.scheduler import Backpressure, Request, Scheduler
 from apex1_tpu.serving.spec import ngram_propose
 from apex1_tpu.obs import spine
@@ -673,11 +687,37 @@ class Engine:
         # rewritten, no loop over slots. `tests/test_engine_aot.py`
         # compiles all three for a v5e at the chat cell's shapes and
         # holds exactly that. Prefill moves one lane, not a leaf.
-        self._prefill = jax.jit(prefill, donate_argnums=1)
+        self._jit_step(prefill, verify if self._spec else decode)
+
+    def _jit_step(self, prefill, step):
+        """The two executables from their traced bodies. Each is called
+        with the caller's tree, as its body is, and LAUNCHED with the
+        tree's packed operands (`serving.packing`: the small leaves
+        stacked by shape, once, here; every other leaf the caller's own
+        buffer), because a launch costs the host by the operand; where
+        the device's step hides the launch anyway the tree is handed
+        over as it is. The pool is donated. ``_n_operands`` is the
+        arrays a call of each hands over, reckoned here: the count its
+        span carries."""
+        pool = len(jax.tree_util.tree_leaves(
+            self.kv.pages if self._paged else self.kv.cache))
+        # beside the parameters a step hands over the pool, the adapters,
+        # the block table, the five control vectors and a verify's drafts
+        step_others = (pool + len(self._lora_args()) + self._paged + 5
+                       + self._spec)
+        self._packed = packed = PackedParams(self.params, step_others)
+        self._prefill = Executable(prefill, packed)
         if self._spec:
-            self._verify = jax.jit(verify, donate_argnums=1)
+            self._verify = Executable(step, packed)
         else:
-            self._decode = jax.jit(decode, donate_argnums=1)
+            self._decode = Executable(step, packed)
+        n = packed.layout.n_operands
+        # a prefill: the block table or a lane to install; slot, install
+        # flag, tokens, index, real tokens, seed
+        self._n_operands = {
+            "prefill": n + pool + len(self._lora_args())
+            + (1 + 5 if self._paged else pool + 6),
+            "step": n + step_others}
 
     def _build_paged_executables(self):
         """The paged-mode executables. Two shapes of the same contract:
@@ -851,11 +891,7 @@ class Engine:
                                          *lora_args)
             return (tgt, *accept(tgt, drafts, active, idxs, pos), pages)
 
-        self._prefill = jax.jit(prefill, donate_argnums=1)
-        if self._spec:
-            self._verify = jax.jit(verify, donate_argnums=1)
-        else:
-            self._decode = jax.jit(decode, donate_argnums=1)
+        self._jit_step(prefill, verify if self._spec else decode)
 
     # ---- multi-tenant LoRA adapters -------------------------------------
 
@@ -1019,7 +1055,8 @@ class Engine:
             self._tally["state_lanes"] += len(lanes)
             self._tally["state_bytes"] += (2 * len(lanes)
                                            * self._state_lane_bytes)
-        with spine.span("serving/decode_step"):
+        with spine.span("serving/decode_step",
+                        operands=self._n_operands["step"]):
             if self._paged:
                 nxt, idxs, pos, self.kv.pages = self._decode(
                     self.params, self.kv.pages, self._d_bt,
@@ -1140,7 +1177,8 @@ class Engine:
         d_drafts = jnp.asarray(drafts)       # an upload of its own
         self._tally["control_dispatches"] += 1
         self._count_kv_blocks(K + 1)
-        with spine.span("serving/verify_step"):
+        with spine.span("serving/verify_step",
+                        operands=self._n_operands["step"]):
             if self._paged:
                 tgt, acc, nxt, idxs, pos, self.kv.pages = self._verify(
                     self.params, self.kv.pages, self._d_bt,
@@ -1322,7 +1360,8 @@ class Engine:
         with self._admit_lock:
             self._mid_admit = req.req_id
         try:
-            with spine.span("serving/prefill", req=rid):
+            with spine.span("serving/prefill", req=rid,
+                            operands=self._n_operands["prefill"]):
                 if hit:
                     self.kv.acquire_prefix(key, slot)
                     if self._paged:

@@ -12,7 +12,10 @@ compiled for a described v5e:2x2, hold
   the 24 `apex1_decode_attend` kernels, one a layer, whose two leaves
   are aliased in and out (PR 29: nothing rewrites a leaf), and
 - arguments of pool + weights to within 1 % (the stored form is not
-  padded: a head of 64 is not a row of 128 lanes).
+  padded: a head of 64 is not a row of 128 lanes), and
+- no more parameters than the operands the engine reckons it hands over
+  (PR 35: the 194 vectors of the tree in 3 stacks), with no copy of a
+  matrix or of a pool leaf on the way to the model.
 
 `Engine._prefill` holds no leaf-sized copy either: it moves one lane.
 
@@ -45,7 +48,8 @@ RESULT_RE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[([\d,]*)\]")
 #: an instruction: its name, its result type (one array or a tuple of
 #: them) and its opcode
 INSTR_RE = re.compile(
-    r"^\s*(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\(")
+    r"^\s*(?:ROOT )?%([\w.\-]+) = "
+    r"(\((?:[^=]|/\*index=\d+\*/)*?\)|\S+) ([\w\-]+)\(")
 #: opcodes that name or regroup a buffer and move nothing
 NO_DATA = {"parameter", "tuple", "get-tuple-element", "bitcast"}
 
@@ -195,6 +199,97 @@ def test_step_appends_without_copying_the_pool(topo, mosaic, num_draft):
                for n in big), big
     assert abs(mem.argument_size_in_bytes - pool_bytes - weight_bytes) \
         < 0.01 * (pool_bytes + weight_bytes)
+
+
+#: an entry parameter: its name and its type
+PARAM_RE = re.compile(r"^\s*%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* parameter\(")
+#: a copy, plain or asynchronous: its result type and its operand
+COPY_RE = re.compile(
+    r"^\s*%[\w.\-]+ = (\([^=]*?\)|\S+) (copy|copy-start)\(%([\w.\-]+)\)")
+
+
+def _entry_of_decode(topo):
+    """(the engine's reckoned operands, the tree's leaves, the compiled
+    decode step's entry computation) at the cell's shapes."""
+    import jax
+    eng, params, place = _cell_engine(topo, 0)
+    lowered = eng._decode.lower(
+        params, place(eng.kv.cache),
+        *place((eng._d_toks, eng._d_idxs, eng._d_active, eng._d_seeds,
+                eng._d_pos)))
+    reckoned = eng._n_operands["step"]
+    del eng
+    text = lowered.compile().as_text()
+    return (reckoned, len(jax.tree_util.tree_leaves(params)),
+            text[text.index("\nENTRY "):])
+
+
+def _opcodes(entry):
+    import collections
+    return collections.Counter(
+        m.group(3) for m in map(INSTR_RE.match, entry.splitlines()) if m)
+
+
+def test_decode_is_launched_with_the_reckoned_operands_and_copies_none(
+        topo, mosaic, monkeypatch):
+    """`Engine._decode` at the cell's shapes is launched with the packed
+    operands (`serving.packing`: 98 matrices as they are, the 194 vectors
+    in 3 stacks, 48 pool leaves, 5 control vectors), the compiled program
+    has no more parameters than that, and cutting the stacks apart costs
+    no copy of a matrix or of a pool leaf: no `copy` reads a large
+    parameter, and a `copy-start` of one is the compiler's prefetch into
+    fast memory (`S(1)`), as before the stacks. Behind its head the step
+    is the step of the unpacked tree: the same kernels, the same
+    prefetches of weight matrices (`slice-start`), the same copies, and
+    the fusions that cut the stacks apart besides, each with up to 19
+    results: a dozen for 194 leaves, ~2 us each on the chip (without
+    `Layout.unpack`'s barrier the compiler prefetched twice the matrices
+    under the attention kernels, and the chip ran the step 22 % longer:
+    PERF.md, PR 35)."""
+    from apex1_tpu.serving import packing
+    SMALL_BYTES = packing.SMALL_BYTES
+    reckoned, n_leaves, entry = _entry_of_decode(topo)
+    assert reckoned == 98 + 3 + 48 + 5
+    assert n_leaves == 292
+    monkeypatch.setattr(packing, "SMALL_BYTES", -1)      # nothing is small
+    unpacked, _, plain = _entry_of_decode(topo)
+    assert unpacked == 292 + 48 + 5
+    ops, was = _opcodes(entry), _opcodes(plain)
+    print(f"AOT {CELL} decode, packed: {dict(ops)}; unpacked: {dict(was)}")
+    for opcode in ("custom-call", "slice-start", "copy", "while"):
+        assert ops[opcode] == was[opcode], opcode
+    assert ops["copy-start"] <= was["copy-start"]
+    cutters = sum(
+        bool(re.search(r" fusion\(%operands_[012]_\.\d+\)", line))
+        for line in entry.splitlines())
+    assert ops["fusion"] == was["fusion"] + cutters
+    assert 3 <= cutters <= 194 // 16
+    large = set()
+    n_params = 0
+    for line in entry.splitlines():
+        m = PARAM_RE.match(line)
+        if not m:
+            continue
+        n_params += 1
+        n = 1
+        for d in m.group(3).split(","):
+            n *= int(d or 1)
+        # every large parameter is bfloat16: a matrix, a pool leaf or
+        # a stack
+        if n * 2 > SMALL_BYTES and m.group(2) == "bf16":
+            large.add(m.group(1))
+    # a parameter the step never reads (the seeds of a greedy step) is
+    # dropped by `jit`
+    assert reckoned - 2 <= n_params <= reckoned
+    assert len(large) == 98 + 48 + 3
+    bad = []
+    for line in entry.splitlines():
+        m = COPY_RE.match(line)
+        if m and m.group(3) in large and not (
+                m.group(2) == "copy-start"
+                and re.match(r"\(\w+\[[\d,]*\]\{[^}]*S\(1\)\}", m.group(1))):
+            bad.append(line.strip()[:200])
+    assert not bad, bad
 
 
 def test_prefill_moves_one_lane_not_a_leaf(topo, mosaic):
