@@ -832,6 +832,35 @@ def granite_hybrid_decoder(model):
     return apply_fn, make_cache
 
 
+def lfm2_moe_decoder(model):
+    """(apply_fn, make_cache) for `models.lfm2.Lfm2Moe`: RoPE takes
+    ``positions`` (None: from ``cache_index`` on); the cache tree holds,
+    by the model's own ``layer_types``, K/V for an attention layer and the
+    last inputs of a short convolution (a leaf without positions) for a
+    conv layer; ``n_real`` (scalar, with a scalar ``cache_index``): the
+    tokens of a right-padded run that are real: the others enter no
+    convolution's state and are not routed. ``moe_counts=True`` adds a
+    third result, (2,) int32: the (row, expert) pairs the sparse layers
+    computed and the held experts they touched, summed over the layers;
+    ``apply_fn.moe_expert_slots`` is the most the second can be, and tells
+    `serving.Engine` that the counts are there to ask for."""
+    from apex1_tpu.models.lfm2 import init_lfm2_cache
+
+    def apply_fn(params, tokens, cache, cache_index, *, positions=None,
+                 chunk_decode=False, n_real=None, moe_counts=False):
+        return model.apply({"params": params}, tokens, positions=positions,
+                           cache=cache, cache_index=cache_index,
+                           chunk_decode=chunk_decode, n_real=n_real,
+                           moe_counts=moe_counts)
+
+    apply_fn.moe_expert_slots = model.cfg.moe_expert_slots
+
+    def make_cache(batch: int, max_len: int, dtype=None):
+        return init_lfm2_cache(model.cfg, batch, max_len, dtype)
+
+    return apply_fn, make_cache
+
+
 def t5_generate(model, params, enc_tokens, *, max_new_tokens: int,
                 dec_start_id: int = 0, enc_pad_mask=None,
                 temperature: float = 0.0, top_k: Optional[int] = None,

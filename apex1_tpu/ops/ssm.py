@@ -59,20 +59,24 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def causal_conv(x, w, b, conv_state, n_real=None):
     """Depthwise causal convolution over ``x`` (B, S, C) continuing from
-    ``conv_state`` (B, K - 1, C), the last ``K - 1`` inputs: ``y_t = b +
-    sum_k w[k] xx[t + k]`` over ``xx = [conv_state, x]`` (``w`` (K, C);
-    ``w[K - 1]`` meets the current input, as `torch.nn.Conv1d` with
-    padding ``K - 1`` cut to S). Returns ``(y (B, S, C), new state)``:
-    the inputs ``n_real - K + 1 .. n_real - 1`` (``n_real`` a scalar,
-    None for S: every input real), so a right-padded chunk keeps the last
-    REAL inputs."""
-    S, K = x.shape[1], w.shape[0]
+    ``conv_state`` (B, R, C), the last ``R >= K - 1`` inputs: ``y_t = b +
+    sum_k w[k] z[t - (K - 1) + k]`` over the inputs ``z`` so far (``w``
+    (K, C); ``w[K - 1]`` meets the current input, as `torch.nn.Conv1d`
+    with padding ``K - 1`` cut to S). Returns ``(y (B, S, C), new
+    state)``: the last ``R`` inputs up to ``n_real - 1`` (``n_real`` a
+    scalar, None for S: every input real), so a right-padded chunk keeps
+    the last REAL inputs. A state of ``K - 1`` rows is all the next
+    output needs; a family that keeps ``K`` (the kernel's whole width,
+    as its published cache does) hands that in and gets it back."""
+    S, K, R = x.shape[1], w.shape[0], conv_state.shape[1]
     xx = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)
-    y = sum(w[k].astype(x.dtype) * xx[:, k:k + S] for k in range(K))
+    lead = R - (K - 1)
+    y = sum(w[k].astype(x.dtype) * xx[:, lead + k:lead + k + S]
+            for k in range(K))
     if b is not None:
         y = y + b.astype(x.dtype)
     start = S if n_real is None else n_real
-    new = jax.lax.dynamic_slice_in_dim(xx, start, K - 1, axis=1)
+    new = jax.lax.dynamic_slice_in_dim(xx, start, R, axis=1)
     return y, new.astype(conv_state.dtype)
 
 

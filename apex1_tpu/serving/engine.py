@@ -31,8 +31,16 @@ EXACTLY TWO executables, traced once each for the life of the engine:
   select and `cache_attend`), the parity gold. Only what is per row BY
   CONTRACT is vmapped: the counter-keyed sampling and the LoRA
   epilogue row. Retirement and admission change only ARRAY VALUES,
-  never shapes. A decoder whose rows interact (MoE capacity) sees the
-  pool's batch here, as on the paged kernel path.
+  never shapes. The step is one forward at the pool's batch, so a
+  decoder whose rows INTERACT sees its neighbours here, as on the paged
+  kernel path: a capacity-routed expert layer (`transformer.moe.router`,
+  `MoEMLP`: a row's pair is dropped where the rows before it filled its
+  expert) makes a lane's tokens depend on who shares the pool. The
+  dropless layer (`transformer.moe.held_experts_mlp`, what
+  `models.lfm2` is served with) keeps rows independent: every pair is
+  computed whatever the batch, an idle lane and a padded prefill row
+  are not routed at all, and a lane's tokens are those it gets alone
+  (`tests/test_lfm2.py`).
 
 With ``num_draft > 0`` the decode executable is replaced by **verify**
 — same two-executable discipline, different second executable: the
@@ -164,6 +172,17 @@ construction. The step span of such an engine carries two more counts,
 ``state_bytes`` (what that moves: each of those lanes' recurrent leaves
 read once and written once). The one-step-ahead loop is as it is: an
 overrun lane-step advances a state that the next admission zeroes.
+
+EXPERTS: a decoder with sparse layers says so (``apply_fn.
+moe_expert_slots``: the experts it holds, summed over its sparse layers)
+and can be asked for a launch's routing counts. The plain step with an
+``eos_id`` asks: the two counts ride behind the tokens in the ONE array
+the host reads (no copy more, no synchronisation more; they arrive a step
+late, as the tokens do), and the step span whose `read_tokens` brought
+them carries ``moe_rows`` (the (row, expert) pairs the launch computed
+here), ``moe_experts_touched`` (the held experts with at least one, which
+is what the launch had to stream) and ``moe_expert_slots``. A decoder
+without experts is launched and read exactly as before.
 """
 
 from __future__ import annotations
@@ -347,6 +366,9 @@ class Engine:
         self._apply_fn = apply_fn
         self._spec = cfg.num_draft > 0
         self._state_lane_bytes = recurrent_lane_bytes(make_cache)
+        # a decoder with experts says how many it holds, summed over its
+        # sparse layers, and can be asked for a launch's routing counts
+        self._moe_slots = int(getattr(apply_fn, "moe_expert_slots", 0))
         if self._state_lane_bytes:
             missing = [why for asked, why in (
                 (cfg.prefix_cache,
@@ -467,6 +489,14 @@ class Engine:
         # output (device array until first fetch memoizes it as numpy).
         # Speculative mode always reads back (drafting needs history).
         self._defer = cfg.eos_id is None and not self._spec
+        # the routing counts come with the tokens, where those are read
+        # step by step: the dense pool's plain step with an eos_id
+        self._moe_read = (bool(self._moe_slots) and not self._defer
+                          and not self._spec and not self._paged
+                          and self._lora is None)
+        if self._moe_read:
+            self._tally.update(moe_rows=0, moe_experts_touched=0,
+                               moe_expert_slots=0)
         self._tok_log: Dict[int, object] = {}
         # with an eos_id the loop runs ONE step ahead (`_decode_step`):
         # the launch whose tokens the host has not read yet, as
@@ -557,8 +587,14 @@ class Engine:
         # rather than an unconditional add: the zero page makes an off
         # row's delta exactly 0.0, but `x + 0.0` can still flip -0.0
         # logits, and off rows must be BITWISE the base model's.
-        def forward(params, tokens, cache, idx, **kw):
-            """``(logits, hidden, cache)``; hidden is None without LoRA."""
+        def forward(params, tokens, cache, idx, moe_counts=False, **kw):
+            """``(logits, hidden, cache)``; hidden is None without LoRA.
+            With ``moe_counts`` (a decoder with experts, asked by the
+            dense step alone) a fourth: the launch's routing counts."""
+            if moe_counts:
+                logits, cache, counts = apply_fn(params, tokens, cache, idx,
+                                                 moe_counts=True, **kw)
+                return logits, None, cache, counts
             if not lora:
                 logits, cache = apply_fn(params, tokens, cache, idx, **kw)
                 return logits, None, cache
@@ -578,11 +614,13 @@ class Engine:
                              logits)
 
         def score(params, cache, chunks, idxs, active, seeds, pos,
-                  a_pg=None, b_pg=None, lbt=None, lon=None):
+                  a_pg=None, b_pg=None, lbt=None, lon=None, *,
+                  moe_counts=False):
             steps = jnp.arange(chunks.shape[1], dtype=jnp.int32)
-            logits, h, cache = forward(
+            logits, h, cache, *counts = forward(
                 params, chunks, cache, jnp.where(active, idxs, -1),
-                positions=idxs[:, None] + steps, chunk_decode=True)
+                positions=idxs[:, None] + steps, chunk_decode=True,
+                moe_counts=moe_counts)
             if lora:
                 logits = jax.vmap(
                     lora_row, in_axes=(0, 0, None, None, 0, 0))(
@@ -594,7 +632,7 @@ class Engine:
             # prompt, seed) purity resubmission rides
             tgt = jax.vmap(lambda lg, seed, p: counter_sample(
                 lg, seed, p + steps, **sample_kw))(logits, seeds, pos)
-            return tgt, cache
+            return (tgt, cache, *counts)
 
         def accept(tgt, drafts, active, idxs, pos):
             """Longest draft prefix equal to the target's own samples,
@@ -621,6 +659,7 @@ class Engine:
         sample_kw = self._sample_kw
         forward, lora_row, score, accept = self._model_calls()
         recurrent = bool(self._state_lane_bytes)
+        moe = self._moe_read
         # the traced bodies hold the counter, not the engine: an engine
         # is in no reference cycle, so its pool and weights are freed
         # with its last reference, without the cycle collector
@@ -664,11 +703,15 @@ class Engine:
         def decode(params, pool, toks, idxs, active, seeds, pos,
                    *lora_args):
             trace_counts["decode"] += 1    # the compile-count hook
-            tgt, pool = score(params, pool, toks[:, None], idxs, active,
-                              seeds, pos, *lora_args)
+            tgt, pool, *counts = score(params, pool, toks[:, None], idxs,
+                                       active, seeds, pos, *lora_args,
+                                       moe_counts=moe)
             nxt = jnp.where(active, tgt[:, 0], cfg.pad_id)
             adv = active.astype(jnp.int32)
-            return nxt, idxs + adv, pos + adv, pool
+            # a decoder with experts: the launch's routing counts ride
+            # behind the tokens, in the one array the host reads
+            read = [jnp.concatenate([nxt, *counts])] if moe else []
+            return (nxt, idxs + adv, pos + adv, *read, pool)
 
         def verify(params, pool, toks, idxs, active, seeds, pos,
                    drafts, *lora_args):
@@ -1062,16 +1105,20 @@ class Engine:
                     self.params, self.kv.pages, self._d_bt,
                     self._d_toks, self._d_idxs, self._d_active,
                     self._d_seeds, self._d_pos, *self._lora_args())
+                read = nxt
             else:
-                nxt, idxs, pos, self.kv.cache = self._decode(
+                nxt, idxs, pos, *read, self.kv.cache = self._decode(
                     self.params, self.kv.cache, self._d_toks,
                     self._d_idxs, self._d_active, self._d_seeds,
                     self._d_pos, *self._lora_args())
+                # what the host reads: the tokens, and behind them the
+                # routing counts of a decoder with experts
+                read = read[0] if read else nxt
             self._d_toks, self._d_idxs, self._d_pos = nxt, idxs, pos
             if not self._defer:
                 # the tokens start for the host now, behind the step on
                 # the device, and not when the host comes to ask
-                nxt.copy_to_host_async()
+                read.copy_to_host_async()
                 for i, st in lanes.items():
                     st.n_launched += 1
                     if st.n_launched >= st.req.max_new_tokens:
@@ -1086,7 +1133,7 @@ class Engine:
         for st in lanes.values():
             st.depth += 1
         self._step_no += 1
-        return nxt, lanes
+        return read, lanes
 
     def _decode_step(self):
         """One plain decode step. With an ``eos_id`` the loop runs ONE
@@ -1132,6 +1179,12 @@ class Engine:
             return
         with spine.span("serving/read_tokens", wait=True):
             toks = np.asarray(prev[0])
+        if self._moe_read:
+            # of the launch whose tokens these are: a step late, as they
+            rows, touched = toks[self.cfg.max_slots:]
+            self._tally["moe_rows"] += int(rows)
+            self._tally["moe_experts_touched"] += int(touched)
+            self._tally["moe_expert_slots"] += self._moe_slots
         self._emit(prev[1], toks)
 
     def _emit(self, lanes: dict, toks):

@@ -20,12 +20,19 @@ compiled steps agree instruction for instruction (PERF.md, PR 35).
 
 Cutting a stack apart costs the DEVICE a little every step (a fusion of
 ~2 us for every 19 leaves), so packing pays only where the launch is
-what the step waits for. A decode step streams every parameter once and
-lasts at least their bytes over the memory's bandwidth; where that alone
-outlasts the launch and the host's loop (`launch_is_hidden`:
+what the step waits for. A decode step streams every parameter it uses
+once and lasts at least their bytes over the memory's bandwidth; where
+that alone outlasts the launch and the host's loop (`launch_is_hidden`:
 granite-4.0-h-micro's 6.4 GB are 7.8 ms on a v5e against a launch of
 1.9), the launch lies under the step in flight, fewer operands buy
-nothing, and the tree is handed over as it is.
+nothing, and the tree is handed over as it is. A decoder with experts
+streams the TOUCHED experts' matrices, not all it holds: the rule counts
+the whole tree, which is the step's bytes where every held expert is
+touched every step (a serving batch of a dozen rows an expert:
+lfm2-8b-a1b's share of 5.05 GB, 6.2 ms, handed over unpacked) and an
+overestimate for a batch so small that most experts idle; there the
+launch may not be hidden after all, and the rule wants the step's counts
+(`moe_experts_touched`) before it is trusted.
 
 Nothing here is an option: what is packed follows from what the tree
 holds and the device it is served from. A tree with no small leaves, or
@@ -84,9 +91,11 @@ def launch_is_hidden(leaves: list, n_other: int) -> bool:
     """Whether a launch of ``leaves`` and ``n_other`` operands more ends
     before the device can have streamed ``leaves`` once: the step in
     flight then outlasts the launch of the next, and the host waits for
-    the device whatever the launch costs. Off an accelerator, or on one
-    with no row in `core.capability`, nothing is known of the memory,
-    and nothing is hidden."""
+    the device whatever the launch costs. (Every leaf counts, a sparse
+    layer's experts too, though a step streams only those its rows
+    touch: the module's docstring says what that assumes.) Off an
+    accelerator, or on one with no row in `core.capability`, nothing is
+    known of the memory, and nothing is hidden."""
     try:
         generation = capability.detect_generation()
     except capability.CapabilityError:

@@ -21,6 +21,23 @@ strategy on TPU pods. The design is the canonical TPU MoE dataflow
      ``ep``; ``jax.lax.all_to_all`` routes (expert, capacity) slots to
      the expert's device and back — the NCCL-alltoall dataflow the
      reference never had, on ICI.
+
+**The dropless layer** (`dropless_route`, `expert_rows`, `held_experts_mlp`)
+is the other dataflow, the one today's large sparse models are served with:
+no capacity, no token dropped, rows of one batch independent of each
+other. The router's form is DATA (`RouteConfig`: softmax or sigmoid
+scores, an optional bias that chooses but never weighs, optional
+normalisation of the kept weights, a scale), not a model's name. The
+expert layer is TOLD which experts it holds (``held``, a range of expert
+ids: this chip's share of an expert-parallel deployment): it routes over
+all of them, keeps the (row, expert) pairs whose expert it holds, sorts
+them by expert into one frame (`expert_rows`: a counting sort, groups
+padded to `ops.moe_experts.ROW_TILE`), runs the grouped product
+(`ops.moe_experts`) and adds each row's weighted results back. What the
+experts held elsewhere would add is LEFT OUT: on one chip the layer runs
+without its exchange, and nothing stands in for the absent chips. The sum
+of the parts that every share gives is the whole layer
+(`tests/test_moe_dropless.py`).
 """
 
 from __future__ import annotations
@@ -35,6 +52,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from apex1_tpu.core.mesh import AXIS_EP
+from apex1_tpu.ops.moe_experts import ROW_TILE, moe_experts, padded_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,3 +208,111 @@ def moe_shard_map_apply(x_local, wg, w1_local, w2_local, cfg: MoEConfig,
     y = jnp.einsum("tec,ech->th", combine.astype(dtype), ye)
     # aux is a per-shard mean over local tokens; callers pmean it
     return y, aux
+
+
+# ---- the dropless layer ---------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class RouteConfig:
+    """A dropless router, as data: ``top_k`` of ``num_experts``; scores
+    ``softmax`` or ``sigmoid`` of the gate's outputs; the experts CHOSEN
+    by score + bias where a bias is given (``select_bias``), the weights
+    always the unbiased scores at the chosen; divided by their sum
+    (``normalize``); times ``scale``."""
+
+    num_experts: int
+    top_k: int
+    score: str = "softmax"
+    select_bias: bool = False
+    normalize: bool = True
+    scale: float = 1.0
+
+    def __post_init__(self):
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {self.score!r}: softmax or sigmoid")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.num_experts}")
+
+
+def dropless_route(x2, wg, bias, cfg: RouteConfig):
+    """``x2`` (T, H) rows, ``wg`` (H, E) the gate, ``bias`` (E,) or None:
+    ``(experts (T, k) int32, weights (T, k) float32)``, the experts in
+    the order `lax.top_k` gives them. All of it in float32, the gate's
+    product at full precision: a TPU's default would round both operands
+    to bfloat16, and a choice between two experts turns on the last
+    bits."""
+    if (bias is not None) != cfg.select_bias:
+        raise ValueError("a selection bias is given exactly where the "
+                         "router's form has one")
+    logits = jnp.dot(x2.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    chosen_by = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(chosen_by, cfg.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.normalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights * cfg.scale
+
+
+def expert_rows(experts, held: range, live=None):
+    """Where each (row, expert) pair of a routing lies in the grouped
+    frame of the experts ``held``: a counting sort by expert, stable in
+    the row.
+
+    ``experts`` (T, k) from `dropless_route`; ``live`` (T,) bool or
+    None: a row that is padding, or an idle lane, is not routed
+    and claims no row. Returns ``(dest (T, k) int32, starts (E_held,),
+    counts (E_held,))``: the frame's row of each pair, -1 for a pair that
+    is not computed here; each held expert's first row, a multiple of
+    `ROW_TILE`, and how many rows it has."""
+    n = len(held)
+    local = experts - held.start
+    here = (local >= 0) & (local < n)
+    if live is not None:
+        here = here & live[:, None]
+    group = jnp.where(here, local, n).reshape(-1)               # (T k,)
+    hot = (group[:, None] == jnp.arange(n, dtype=jnp.int32)).astype(
+        jnp.int32)                                              # (T k, n)
+    rank = jnp.cumsum(hot, axis=0) - hot       # pairs of the group before
+    counts = jnp.sum(hot, axis=0)
+    padded = -(-counts // ROW_TILE) * ROW_TILE
+    starts = jnp.cumsum(padded) - padded
+    dest = jnp.sum(hot * (starts[None, :] + rank), axis=1)
+    dest = jnp.where(here.reshape(-1), dest, -1).reshape(experts.shape)
+    return dest.astype(jnp.int32), starts.astype(jnp.int32), \
+        counts.astype(jnp.int32)
+
+
+def held_experts_mlp(x2, experts, weights, w1, w3, w2, held: range,
+                     live=None):
+    """The part of a dropless mixture that the experts ``held`` give:
+    ``y_t = sum over the chosen experts e of t that are held of weights[t,
+    e] * W2[e] (silu(W1[e]^T x_t) * (W3[e]^T x_t))``. ``w1`` / ``w3``
+    (E_held, H, F), ``w2`` (E_held, F, H): the held experts' matrices
+    alone. Returns ``(y (T, H) of x2's dtype, counts (2,) int32)``: the
+    pairs computed here, and the held experts with at least one.
+
+    The sort is applied as a product with a 0/1 matrix (exact: one term a
+    row), and so is the way back, which sums a token's up to k weighted
+    rows in float32. That is R x T x H operations each way: microseconds
+    at a serving batch's few hundred rows. A caller with many thousands
+    of rows (a training step) wants a gather and a segment sum here."""
+    dest, starts, counts = expert_rows(experts, held, live)
+    # a token's chosen experts are distinct: at most min(k, held) here
+    R = padded_rows(x2.shape[0] * min(experts.shape[1], len(held)),
+                    len(held))
+    place = dest[None, :, :] == jnp.arange(R, dtype=jnp.int32)[:, None, None]
+    row_of = jnp.any(place, axis=-1)                            # (R, T)
+    gains = jnp.sum(jnp.where(place, weights[None], 0.0), axis=(1, 2))
+    # exact in the rows' own type: float32 rows want the full product
+    exact = (jax.lax.Precision.HIGHEST if x2.dtype == jnp.float32 else None)
+    x_rows = jnp.dot(row_of.astype(x2.dtype), x2, precision=exact,
+                     preferred_element_type=jnp.float32).astype(x2.dtype)
+    y_rows = moe_experts(x_rows, gains, w1, w3, w2, starts, counts)
+    y = jnp.dot(row_of.T.astype(y_rows.dtype), y_rows, precision=exact,
+                preferred_element_type=jnp.float32)
+    return y.astype(x2.dtype), jnp.stack(
+        [jnp.sum(counts), jnp.sum(counts > 0)]).astype(jnp.int32)

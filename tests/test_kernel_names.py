@@ -63,7 +63,7 @@ def test_one_pallas_call_and_it_is_the_helpers():
 
 def test_every_site_names_itself_and_no_two_alike():
     sites = _sites()
-    assert len(sites) == 29, sites
+    assert len(sites) == 30, sites
     unnamed = [s for s in sites if not s[2]]
     assert not unnamed, unnamed
     twice = [n for n, c in collections.Counter(
@@ -75,7 +75,7 @@ def test_every_site_names_itself_and_no_two_alike():
             "linear_xent_stats", "linear_xent_pack", "linear_xent_dx",
             "linear_xent_dw", "linear_xent_fwd", "layer_norm_fwd",
             "layer_norm_bwd", "paged_attend", "fused_sample",
-            "decode_attend", "ssm_step"} <= names
+            "decode_attend", "ssm_step", "moe_experts"} <= names
 
 
 def test_name_reaches_the_jaxpr_under_interpret_mode():

@@ -356,6 +356,19 @@ def _run_checks(args) -> bool:
               in_specs=(P("dp"), P("dp"), P(), P("dp"), P("dp"), P(),
                         P("dp"), P("dp")))
 
+        # the grouped expert product of a sparse layer (PR 38), at the
+        # `lfm2moe_serve_rollout` cell's shapes: 8 experts of 2048 x 1792
+        # held, the decode step's 96 rows x top-4 in their padded frame
+        from apex1_tpu.ops.moe_experts import moe_experts, padded_rows
+        rows = padded_rows(96 * 4, 8)
+        check("moe_experts lfm2 rollout cell (96x4 rows, E8, 2048x1792)",
+              moe_experts,
+              [(rows, 2048), (rows,), (8, 2048, 1792), (8, 2048, 1792),
+               (8, 1792, 2048), (8,), (8,)],
+              dtypes=[jnp.bfloat16, jnp.float32] + [jnp.bfloat16] * 3
+              + [jnp.int32] * 2,
+              in_specs=(P(),) * 7)
+
         # chunked preference/distill losses, fused GLU, LoRA epilogue
         # (ISSUE 19): the chunked-loss VJP recomputes per vocab chunk
         # through the linear_xent stats kernels; fused_glu is the llama
