@@ -386,20 +386,22 @@ def fused_all_gather_matmul_serial(x, w, axis_name=AXIS_TP, seq_dim=0,
 
 def _agf_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
                 scale, causal, true_sq, true_sk, has_segs, n_k):
-    """`ops.attention._fwd_kernel`'s exact compute (no bias/dropout
-    operands) extended with carried (prev_out fp32, prev_lse) inputs:
-    the final key block's epilogue performs `parallel.ring_attention.
-    _merge` in VMEM instead of a per-ring-step XLA read-modify-write of
-    the full (B, H, S, D) output in HBM. The attend math and the merge
-    formula replicate their decomposed counterparts op for op — the
-    bitwise-parity contract of the fused form."""
+    """The flash forward's tile update (`ops.attention._attend_tile`, the
+    SAME function `_fwd_kernel` runs; no bias/dropout operands) on the
+    grid (b, h, qi, ki), extended with carried (prev_outᵀ fp32, prev_lse)
+    inputs: the final key block's epilogue performs
+    `parallel.ring_attention._merge` in VMEM instead of a per-ring-step
+    XLA read-modify-write of the full (B, H, S, D) output in HBM. One
+    tile function and the merge formula op for op — the parity contract
+    of the fused form (a tile the plain kernel runs without a mask, or
+    with the scale folded into q, holds the same values: the mask is all
+    true there and the scale a power of two). Like the plain kernel it
+    works on the TRANSPOSED tile: the accumulator, the carried output
+    and the merged one are outᵀ (Dp, bq), the statistics (1, bq) rows."""
+    from apex1_tpu.ops.attention import _attend_tile, _mask_for
     rest = list(rest)
-    if has_segs:
-        qseg_ref, kseg_ref = rest[0], rest[1]
-        rest = rest[2:]
-        qseg, kseg = qseg_ref[0], kseg_ref[0]
-    else:
-        qseg = kseg = None
+    qseg_ref, kseg_ref = (rest.pop(0), rest.pop(0)) if has_segs \
+        else (None, None)
     po_ref, pl_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
     qi, ki = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
@@ -411,26 +413,14 @@ def _agf_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
 
     def compute():
-        from apex1_tpu.ops.attention import _mask_for
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
         mask = _mask_for(qi, ki, bq, bk, causal=causal, true_sq=true_sq,
                          true_sk=true_sk, q_off=qo_ref[0, 0],
-                         k_off=ko_ref[0, 0], qseg=qseg, kseg=kseg)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        e = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_new = l_prev * corr + jnp.sum(e, axis=1, keepdims=True)
-        v = v_ref[0, 0]
-        acc[...] = acc[...] * corr + jax.lax.dot_general(
-            e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+                         k_off=ko_ref[0, 0],
+                         qseg=qseg_ref[0, 0] if has_segs else None,
+                         kseg=kseg_ref[0] if has_segs else None,
+                         transposed=True)
+        _attend_tile(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], acc, m_scr,
+                     l_scr, scale=scale, mask=mask)
 
     if causal:
         pl.when((ki * bk + ko_ref[0, 0])
@@ -443,16 +433,16 @@ def _agf_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
         # this shard's (out_t, lse_t) exactly as the plain flash kernel
         # emits them (incl. the q.dtype round-trip the decomposed ring's
         # flash output makes), then `_merge` op for op
-        l = l_scr[:, :1]
+        l = l_scr[...]
         safe = jnp.where(l > 0.0, l, 1.0)
         o_t = (acc[...] / safe).astype(q_ref.dtype)
-        lse_t = jnp.where(l > 0.0, m_scr[:, :1] + jnp.log(safe), NEG_INF)
-        prev_lse = pl_ref[0, 0]
+        lse_t = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe), NEG_INF)
+        prev_lse = pl_ref[0, 0, 0]
         lse_new = jnp.logaddexp(prev_lse, lse_t)
         w_a = jnp.exp(prev_lse - lse_new)
         w_b = jnp.exp(lse_t - lse_new)
         o_ref[0, 0] = po_ref[0, 0] * w_a + o_t.astype(jnp.float32) * w_b
-        lse_ref[0, 0] = lse_new
+        lse_ref[0, 0, 0] = lse_new
 
 
 def _agf_blocks(D, block_q, block_k, dtype, seq):
@@ -473,25 +463,27 @@ def _agf_blocks(D, block_q, block_k, dtype, seq):
 def _agf_call(q, k, v, qseg, kseg, q_off, k_off, prev_out, prev_lse,
               scale, causal, has_segs, block_q, block_k):
     """One ring step: attend the visiting K/V shard AND fold the result
-    into the carried (out, lse) — one pallas_call."""
+    into the carried (outᵀ, lse) — one pallas_call. ``prev_out`` and the
+    returned output are TRANSPOSED, (B, Hq, D, Sq), as the kernel's
+    accumulator lies."""
     from apex1_tpu.ops.attention import (_common_specs, _off_arrays,
-                                         _prep)
+                                         _prep, _stat_rows)
     q, k, v = to_mosaic(q, k, v)
     qp, kp, vp, qs, ks, g = _prep(q, k, v, qseg, kseg, has_segs,
                                   block_q, block_k)
     q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec = \
-        _common_specs(g)
-    po, _ = pad_to(prev_out, 2, g["bq"])
-    po, _ = pad_to(po, 3, _LANES)
-    plse, _ = pad_to(prev_lse[..., None], 2, g["bq"], value=NEG_INF)
-    pout_spec = pl.BlockSpec((1, 1, g["bq"], g["Dp"]),
-                             lambda b, h, qi, ki: (b, h, qi, 0),
+        _common_specs(g, transposed=True)
+    po, _ = pad_to(prev_out, 2, _LANES)
+    po, _ = pad_to(po, 3, g["bq"])
+    plse = _stat_rows(prev_lse, g, NEG_INF)
+    pout_spec = pl.BlockSpec((1, 1, g["Dp"], g["bq"]),
+                             lambda b, h, qi, ki: (b, h, 0, qi),
                              memory_space=pltpu.VMEM)
     in_specs = [q_spec, kv_spec, kv_spec, off_spec, off_spec]
     args = [qp, kp, vp, *_off_arrays(q_off, k_off)]
     if has_segs:
         in_specs += [qseg_spec, kseg_spec]
-        args += [qs, ks]
+        args += [qs[1], ks[0]]
     in_specs += [pout_spec, stat_spec]
     args += [po, plse]
     Sqp = g["n_q"] * g["bq"]
@@ -504,17 +496,18 @@ def _agf_call(q, k, v, qseg, kseg, q_off, k_off, prev_out, prev_lse,
         in_specs=in_specs,
         out_specs=(pout_spec, stat_spec),
         out_shape=(
-            out_struct((g["B"], g["Hq"], Sqp, g["Dp"]), jnp.float32,
+            out_struct((g["B"], g["Hq"], g["Dp"], Sqp), jnp.float32,
                        qp, kp, vp, po, plse),
-            out_struct((g["B"], g["Hq"], Sqp, 1), jnp.float32,
-                       qp, kp, vp, po, plse)),
+            out_struct((g["B"], g["Hq"], g["n_q"], 1, g["bq"]),
+                       jnp.float32, qp, kp, vp, po, plse)),
         scratch_shapes=[
-            pltpu.VMEM((g["bq"], g["Dp"]), jnp.float32),
-            pltpu.VMEM((g["bq"], _LANES), jnp.float32),
-            pltpu.VMEM((g["bq"], _LANES), jnp.float32)],
+            pltpu.VMEM((g["Dp"], g["bq"]), jnp.float32),
+            pltpu.VMEM((1, g["bq"]), jnp.float32),
+            pltpu.VMEM((1, g["bq"]), jnp.float32)],
         interpret=interpret_mode(),
     )(*args)
-    return (out_p[:, :, :g["Sq"], :g["D"]], lse_p[:, :, :g["Sq"], 0])
+    return (out_p[:, :, :g["D"], :g["Sq"]],
+            lse_p.reshape(g["B"], g["Hq"], Sqp)[:, :, :g["Sq"]])
 
 
 def _agf_fwd_loop(q, k, v, qseg, axis_name, causal, sm_scale, has_segs,
@@ -539,8 +532,10 @@ def _agf_fwd_loop(q, k, v, qseg, axis_name, causal, sm_scale, has_segs,
         idx = _axis_index(axis_name)
         q_off = idx * Sq
     perm = [(i, (i + 1) % n) for i in range(n)]
-    out = _vary(jnp.zeros(q.shape, jnp.promote_types(q.dtype,
-                                                     jnp.float32)),
+    # the carry is outᵀ (B, Hq, D, Sq), as the kernel's accumulator lies;
+    # turned once, after the last shard
+    out = _vary(jnp.zeros((B, Hq, D, Sq),
+                          jnp.promote_types(q.dtype, jnp.float32)),
                 axis_name)
     lse = _vary(jnp.full((B, Hq, Sq), NEG_INF, jnp.float32), axis_name)
 
@@ -561,19 +556,24 @@ def _agf_fwd_loop(q, k, v, qseg, axis_name, causal, sm_scale, has_segs,
             # the decomposed ring merges a (zeros, NEG_INF) partial for
             # fully-masked shards; replicate that exact merge (identity
             # up to fp edge cases like -0 + 0) instead of passing the
-            # carry through, so the pin stays bitwise
-            return _merge(out, lse,
+            # carry through, so the pin stays bitwise (`_merge` weights a
+            # trailing head axis; outᵀ's queries are the last one)
+            o, l = _merge(jnp.swapaxes(out, 2, 3), lse,
                           _vary(jnp.zeros(q.shape, q.dtype), axis_name),
                           _vary(jnp.full((B, Hq, Sq), NEG_INF,
                                          jnp.float32), axis_name))
+            return jnp.swapaxes(o, 2, 3), l
 
         if causal:
             return jax.lax.cond(ko > qo + Sq - 1, skip, run, None)
         return run(None)
 
+    def turned(out_lse):
+        return jnp.swapaxes(out_lse[0], 2, 3), out_lse[1]
+
     kseg0 = qseg if has_segs else jnp.zeros((), jnp.int32)
     if n == 1:
-        return attend(k, v, kseg0, 0, out, lse)
+        return turned(attend(k, v, kseg0, 0, out, lse))
 
     k_cur = jax.lax.ppermute(k, axis_name, perm)
     v_cur = jax.lax.ppermute(v, axis_name, perm)
@@ -593,7 +593,7 @@ def _agf_fwd_loop(q, k, v, qseg, axis_name, causal, sm_scale, has_segs,
     if n > 2:
         (k_cur, v_cur, kseg_cur, out, lse), _ = jax.lax.scan(
             step, (k_cur, v_cur, kseg_cur, out, lse), jnp.arange(1, n - 1))
-    return attend(k_cur, v_cur, kseg_cur, n - 1, out, lse)
+    return turned(attend(k_cur, v_cur, kseg_cur, n - 1, out, lse))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))

@@ -193,22 +193,17 @@ def _step_grads_pallas(q, k_cur, v_cur, qseg, kseg_cur, q_off, k_off, out,
     the per-shard cotangents are exact (the same lse-residual backward
     the single-shard flash custom VJP runs, with dlse = 0 since the
     ring consumes lse internally)."""
-    from apex1_tpu.ops.attention import (_auto_blocks, _block,
-                                         _flash_bwd_impl)
-    from apex1_tpu.ops._common import pad_to
+    from apex1_tpu.ops.attention import _auto_blocks, _flash_bwd_impl
 
     block_q, block_k = _auto_blocks(q.shape[3], block_q, block_k, q.dtype,
                                     k_cur.shape[2])
-    Sq = q.shape[2]
-    bq = _block(Sq, block_q)
-    lse_p, _ = pad_to(lse[..., None], 2, bq, value=NEG_INF)
     dummy = jnp.zeros((1, 1), jnp.int32)
     sd = (jnp.asarray(seed, jnp.int32) if dropout_p > 0.0
           else jnp.zeros((), jnp.int32))
     res = (q, k_cur, v_cur,
            qseg if has_segs else dummy,
            kseg_cur if has_segs else dummy,
-           q_off, k_off, sd, out, lse_p)
+           q_off, k_off, sd, out, lse)
     cts = (do, jnp.zeros(lse.shape, jnp.float32))
     # cast=False: dk/dv stay in the kernels' native fp32 so the ring
     # accumulation is exact (dq is q.dtype — the dq kernel's output

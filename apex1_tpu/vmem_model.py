@@ -51,16 +51,67 @@ def budget_bytes(generation: str | None = None) -> int:
     return vmem_budget(generation)
 
 
-def flash_check(blocks, dims, es, budget):
-    """Flash attention frame: q/k/v/o blocks (double-buffered, input
-    dtype), fp32 (acc, m, l) scratch, and the live fp32 score + exp
-    tiles (bq, bk) the MXU step materializes in vregs/VMEM."""
+def _flash_grid_frame(blocks, dims, es):
+    """The GRID form's frame (key axis a grid axis): q/k/v/o blocks
+    (double-buffered, input dtype), fp32 (acc, m, l) scratch, and the
+    live fp32 score + exp tiles (bq, bk) the MXU step materializes in
+    vregs/VMEM."""
     bq, bk = blocks["block_q"], blocks["block_k"]
     dp = dims["Dp"]
-    est = (DB * es * (bq * dp + 2 * bk * dp)       # q, k, v in
-           + DB * es * bq * dp                     # o out
-           + 4 * (bq * dp + 2 * bq * LANES)        # acc, m, l scratch
-           + 2 * 4 * bq * bk)                      # s and e tiles
+    return (DB * es * (bq * dp + 2 * bk * dp)      # q, k, v in
+            + DB * es * bq * dp                    # o out
+            + 4 * (bq * dp + 2 * bq * LANES)       # acc, m, l scratch
+            + 2 * 4 * bq * bk)                     # s and e tiles
+
+
+def flash_kv_row_check(blocks, dims, es, budget):
+    """The RESIDENT form of the flash forward and dq kernels
+    (`ops.attention._kv_resident`): the (batch, kv head) row's whole K
+    and V (``Skp`` padded keys) one double-buffered block beside the
+    tiles, priced at the dq kernel's frame, the larger of the two: q, dO
+    in and dq out, the two fp32 statistics ((1, bq) rows, a sublane tile
+    of 8 each, and the (bq, 128) columns they are turned into), the fp32
+    accumulator, and the live fp32 s, p, dp and ds tiles. Where it does
+    not fit the key axis stays a grid axis."""
+    bq, bk = blocks["block_q"], blocks["block_k"]
+    dp = dims["Dp"]
+    est = (DB * es * 2 * dims["Skp"] * dp          # the row's K and V
+           + 3 * DB * es * bq * dp                 # q, dO in, dq out
+           + 2 * (DB * 4 * 8 * bq + 4 * bq * LANES)  # lse, delta - dlse
+           + 4 * bq * dp                           # dq accumulator
+           + 4 * 4 * bq * bk)                      # s, p, dp, ds tiles
+    return est <= budget, est
+
+
+def flash_q_row_check(blocks, dims, es, budget):
+    """The RESIDENT form of the flash dk/dv kernel
+    (`ops.attention._q_resident`): the GQA group's whole Q and dO rows
+    (``Sqp`` padded queries a head) and their two fp32 statistics
+    ((1, bq) rows along the lanes, a sublane tile of 8 each)
+    double-buffered beside the k, v blocks in, the dk, dv blocks out
+    (priced fp32), the two fp32 accumulators and the live s, p, dp and
+    ds tiles."""
+    bq, bk = blocks["block_q"], blocks["block_k"]
+    dp = dims["Dp"]
+    est = (DB * dims["group"] * dims["Sqp"]
+           * (2 * es * dp + 2 * 4 * 8)             # Q, dO, lse, delta - dlse
+           + 2 * DB * es * bk * dp                 # k, v in
+           + 2 * DB * 4 * bk * dp                  # dk, dv out
+           + 2 * 4 * bk * dp                       # dk, dv accumulators
+           + 4 * 4 * bq * bk)                      # s, p, dp, ds tiles
+    return est <= budget, est
+
+
+def flash_check(blocks, dims, es, budget):
+    """Flash attention frame at key length ``Sb``: the resident form's
+    (`flash_kv_row_check`, which `ops.attention` takes wherever it
+    fits: the same function decides there), else the grid form's."""
+    if "Sb" in dims:     # an entry keyed on the head width alone: any length
+        ok, est = flash_kv_row_check(blocks, {**dims, "Skp": dims["Sb"]},
+                                     es, budget)
+        if ok:
+            return ok, est
+    est = _flash_grid_frame(blocks, dims, es)
     return est <= budget, est
 
 
@@ -104,10 +155,12 @@ def cm_check(blocks, dims, es, budget):
 
 def agf_check(blocks, dims, es, budget):
     """All-gather-fused flash attention (`ops.fused_collective.
-    _agf_kernel`): the flash frame plus the carried fp32 (prev_out,
-    prev_lse) merge operands and the fp32 merged output block the
-    epilogue writes (the plain kernel's output is input-dtype)."""
-    ok, est = flash_check(blocks, dims, es, budget)
+    _agf_kernel`): the flash GRID frame (the visiting shard's K/V are
+    never held whole: the kernel keeps the key axis on the grid at every
+    length) plus the carried fp32 (prev_out, prev_lse) merge operands
+    and the fp32 merged output block the epilogue writes (the plain
+    kernel's output is input-dtype)."""
+    est = _flash_grid_frame(blocks, dims, es)
     bq, dp = blocks["block_q"], dims["Dp"]
     extra = (DB * 4 * (bq * dp + bq * LANES)     # prev_out, prev_lse in
              + DB * 4 * bq * dp                  # merged fp32 out

@@ -256,3 +256,161 @@ class TestAdditiveBias:
                                 bias=jnp.zeros((3, 2, 48, 48)))
             with pytest.raises(ValueError, match="bias"):
                 flash_attention(q, k, v, bias=jnp.zeros((48, 48)))
+
+
+# ---------------------------------------------------------------------------
+# the resident form: K/V (fwd, dq) or the group's Q rows (dkv) whole in VMEM,
+# the pass over the other axis a loop in the kernel that stops at the diagonal
+# ---------------------------------------------------------------------------
+
+_OFFSETS = [(0, 0), (32, 0), (0, 32), (48, 16)]
+
+# 80 keys in tiles of 32 (the last one padded), 80 queries in tiles of 16:
+# with the offsets above every class of tile occurs: interior, crossed by
+# the diagonal, last tile of a padded length, never visited
+_TILE_CASES = {
+    "base": dict(),
+    "padded_rows": dict(Sq=72, Sk=96),
+    "wide_q_tile": dict(block_q=32, block_k=16),
+    "cross_lengths": dict(Sq=48, Sk=112),
+    "gqa": dict(Hq=4, Hkv=2),
+    "segments": dict(segs=True),
+    "bias": dict(bias=True),
+    "dropout": dict(dropout_p=0.25),
+    "scale_pow2": dict(sm_scale=0.25),
+    "scale_other": dict(sm_scale=0.3),
+    "not_causal": dict(causal=False),
+    "row_too_long": dict(budget=200_000),
+    # tiles a multiple of the 128 lanes: dq turns the statistics' rows to
+    # columns by a transpose, not by picking a diagonal
+    "lane_tiles": dict(Sq=256, Sk=256, block_q=128, block_k=128),
+}
+
+
+def _grid_ranks(fn, *args):
+    """Grid rank of every pallas_call under ``fn`` (custom VJPs opened)."""
+    ranks = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                ranks.append(len(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return ranks
+
+
+@pytest.mark.parametrize("q_off,k_off", _OFFSETS)
+@pytest.mark.parametrize("case", sorted(_TILE_CASES))
+def test_resident_tile_classes(rng, monkeypatch, case, q_off, k_off):
+    """Forward and every gradient against the XLA composite over every
+    class of tile the resident loop makes, times the ring's offsets."""
+    c = dict(_TILE_CASES[case])
+    Sq, Sk = c.pop("Sq", 80), c.pop("Sk", 80)
+    q, k, v = _qkv(rng, Hq=c.pop("Hq", 2), Hkv=c.pop("Hkv", None), Sq=Sq,
+                   Sk=Sk)
+    kw = dict(causal=c.pop("causal", True), q_offset=q_off, k_offset=k_off,
+              block_q=c.pop("block_q", 16), block_k=c.pop("block_k", 32))
+    if c.pop("segs", False):
+        kw["segment_ids"] = (jnp.asarray(np.arange(Sq) // 24)[None].repeat(2, 0),
+                             jnp.asarray(np.arange(Sk) // 24)[None].repeat(2, 0))
+    if "dropout_p" in c:
+        kw.update(dropout_p=c.pop("dropout_p"), dropout_seed=1234)
+    if "sm_scale" in c:
+        kw["sm_scale"] = c.pop("sm_scale")
+    bias = (jnp.asarray(rng.normal(size=(1, 2, Sq, Sk)), jnp.float32)
+            if c.pop("bias", False) else None)
+    if "budget" in c:
+        # K/V rows (and the Q rows of dkv) no longer fit: the grid form
+        import apex1_tpu.vmem_model as vm
+        monkeypatch.setattr(vm, "budget_bytes", lambda *a: c["budget"])
+    w = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+
+    def loss(impl):
+        def f(q, k, v, bias):
+            with force_impl(impl):
+                out, lse = flash_attention(q, k, v, bias=bias,
+                                           return_lse=True, **kw)
+            live = lse > -1e29     # empty rows carry the finite sentinel
+            return (jnp.sum(out.astype(jnp.float32) * w)
+                    + jnp.sum(jnp.where(live, lse, 0.0)))
+        argnums = (0, 1, 2) + ((3,) if bias is not None else ())
+        return jax.value_and_grad(f, argnums=argnums)(q, k, v, bias)
+
+    (lp, gp), (lx, gx) = loss("pallas"), loss("xla")
+    np.testing.assert_allclose(lp, lx, rtol=1e-5)
+    for a, b in zip(gp, gx):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5)
+
+    # which form ran: fwd, dq, dkv (and dbias) by the rank of their grids
+    with force_impl("pallas"):
+        ranks = _grid_ranks(
+            lambda q, k, v: jax.grad(
+                lambda q, k, v: jnp.sum(flash_attention(
+                    q, k, v, bias=bias, **kw)), argnums=(0, 1, 2))(q, k, v),
+            q, k, v)
+    grid_form = bias is not None or "budget" in c
+    assert sorted(ranks) == ([4, 4, 5, 5] if bias is not None else
+                             [4, 4, 5] if grid_form else [3, 3, 3])
+
+
+def _brute_tiles(Sq, Sk, bq, bk, q_off, k_off, causal):
+    """{(qi, ki): 'interior' | 'masked' | 'never'} from the mask itself."""
+    row = np.arange(-(-Sq // bq) * bq)[:, None]
+    col = np.arange(-(-Sk // bk) * bk)[None, :]
+    live = (row < Sq) & (col < Sk)
+    if causal:
+        live &= (col + k_off) <= (row + q_off)
+    out = {}
+    for qi in range(live.shape[0] // bq):
+        for ki in range(live.shape[1] // bk):
+            t = live[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            out[qi, ki] = ("interior" if t.all() else
+                           "masked" if t.any() else "never")
+    return out
+
+
+@pytest.mark.parametrize("q_off,k_off", _OFFSETS + [(0, 200), (200, 0)])
+@pytest.mark.parametrize("Sq,Sk,bq,bk,causal", [
+    (80, 80, 16, 32, True), (72, 96, 16, 32, True), (80, 80, 32, 16, True),
+    (48, 112, 16, 32, True), (80, 80, 16, 32, False),
+    (1024, 1024, 256, 256, True), (1024, 1024, 512, 512, True),
+    (100, 60, 48, 16, True)])
+def test_tile_plan_is_the_mask(Sq, Sk, bq, bk, causal, q_off, k_off):
+    """The loop bounds the resident kernels run are exactly the tiles
+    whose mask has a live element, and the unmasked ones exactly those
+    whose mask is all live: by brute force from the mask."""
+    from apex1_tpu.ops.attention import (_key_tiles, _query_tiles,
+                                         tile_plan)
+    want = _brute_tiles(Sq, Sk, bq, bk, q_off, k_off, causal)
+    n_q, n_k = -(-Sq // bq), -(-Sk // bk)
+    args = (bq, bk, Sq, Sk, q_off, k_off, causal)
+    by_q, by_k = {}, {}
+    for qi in range(n_q):
+        n_int, n_vis = _key_tiles(qi, *args)
+        assert 0 <= n_int <= n_vis <= n_k
+        for ki in range(n_k):
+            by_q[qi, ki] = ("interior" if ki < n_int else
+                            "masked" if ki < n_vis else "never")
+    for ki in range(n_k):
+        lo, a, b, hi = _query_tiles(ki, *args)
+        assert 0 <= lo <= a <= b <= hi == n_q
+        for qi in range(n_q):
+            by_k[qi, ki] = ("never" if qi < lo else
+                            "interior" if a <= qi < b else "masked")
+    assert by_q == want
+    assert by_k == want
+    count = lambda cls: sum(1 for c in want.values() if c == cls)
+    assert tile_plan(Sq, Sk, bq, bk, q_off, k_off, causal) == (
+        count("interior"), count("masked"), count("never"))
+
+
+def test_tile_plan_of_the_training_cell():
+    """GPT-2 medium's call (S = 1024): the share of the square visited is
+    (1 + 1/n_q) / 2, and most visited tiles need no mask."""
+    from apex1_tpu.ops.attention import tile_plan
+    assert tile_plan(1024, 1024, 512, 512) == (1, 2, 1)
+    assert tile_plan(1024, 1024, 256, 256) == (6, 4, 6)
+    assert tile_plan(1024, 1024, 128, 128) == (28, 8, 28)

@@ -728,11 +728,26 @@ class TestBudgetAndBinding:
 _L, _D = 128, 2
 
 
+def _orig_flash_grid(blocks, dims, es):
+    bq, bk = blocks["block_q"], blocks["block_k"]
+    dp = dims["Dp"]
+    return (_D * es * (bq * dp + 2 * bk * dp) + _D * es * bq * dp
+            + 4 * (bq * dp + 2 * bq * _L) + 2 * 4 * bq * bk)
+
+
+# re-gated by PR 39 (the resident flash kernels): where the row's K and
+# V (Sb keys) fit beside the dq kernel's tiles the frame is THAT one, the
+# form `ops.attention` then takes; else the pre-refactor grid frame
 def _orig_flash(blocks, dims, es, budget):
     bq, bk = blocks["block_q"], blocks["block_k"]
     dp = dims["Dp"]
-    est = (_D * es * (bq * dp + 2 * bk * dp) + _D * es * bq * dp
-           + 4 * (bq * dp + 2 * bq * _L) + 2 * 4 * bq * bk)
+    if "Sb" in dims:
+        est = (_D * es * 2 * dims["Sb"] * dp + 3 * _D * es * bq * dp
+               + 2 * (_D * 4 * 8 * bq + 4 * bq * _L) + 4 * bq * dp
+               + 4 * 4 * bq * bk)
+        if est <= budget:
+            return True, est
+    est = _orig_flash_grid(blocks, dims, es)
     return est <= budget, est
 
 
@@ -760,7 +775,7 @@ def _orig_cm(blocks, dims, es, budget):
 
 
 def _orig_agf(blocks, dims, es, budget):
-    ok, est = _orig_flash(blocks, dims, es, budget)
+    est = _orig_flash_grid(blocks, dims, es)
     bq, dp = blocks["block_q"], dims["Dp"]
     est += (_D * 4 * (bq * dp + bq * _L) + _D * 4 * bq * dp
             - _D * es * bq * dp)

@@ -184,6 +184,12 @@ def _sweep(backend):
     check("flash_fwd_bwd_causal",
           lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
           (q, k, v), grad_argnums=(0, 1, 2))
+    # the gpt2m_train cell's own call (8 x 16 x 1024 x 64): the resident
+    # loop with interior AND masked tiles at the heuristic's tile size
+    qc, kc, vc = (bf(8, 16, 1024, D) for _ in range(3))
+    check("flash_fwd_bwd_causal_cell",
+          lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
+          (qc, kc, vc), grad_argnums=(0, 1, 2))
     kg, vg = bf(B, 2, S, D), bf(B, 2, S, D)
     check("flash_fwd_bwd_gqa",
           lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
