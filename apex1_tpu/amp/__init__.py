@@ -44,6 +44,7 @@ from apex1_tpu.core.loss_scale import (LossScaleState, all_finite,
                                        make_loss_scale, select_tree)
 from apex1_tpu.core.policy import PrecisionPolicy, get_policy
 from apex1_tpu.core.pytree import global_norm
+from apex1_tpu.obs.regions import region
 
 
 @chex.dataclass
@@ -158,11 +159,13 @@ class Amp:
             ls = self._get_ls(state, loss_id)
 
             def scaled_loss_fn(master_params, *mb):
-                compute_params = policy.cast_to_compute(master_params)
+                with region("amp"):
+                    compute_params = policy.cast_to_compute(master_params)
                 out = loss_fn(compute_params, *mb)
                 loss, aux = out if has_aux else (out, None)
-                return scaler.scale(loss.astype(jnp.float32),
-                                    ls), (loss, aux)
+                with region("amp"):
+                    return scaler.scale(loss.astype(jnp.float32),
+                                        ls), (loss, aux)
 
             if accum_steps == 1:
                 grads, (loss, aux) = jax.grad(
@@ -202,23 +205,30 @@ class Amp:
             for ax in self.grad_psum_axes:
                 grads = jax.lax.pmean(grads, ax)
                 loss = jax.lax.pmean(loss, ax)  # report the GLOBAL mean
-            grads = scaler.unscale(grads, ls)
-            finite = all_finite(grads, axis_names=self.grad_psum_axes)
-            gnorm = global_norm(grads)
-            if self.max_grad_norm is not None:
-                from apex1_tpu.optim.clip_grad import clip_grad_norm
-                grads, _ = clip_grad_norm(grads, self.max_grad_norm)
+            with region("amp"):
+                grads = scaler.unscale(grads, ls)
+                finite = all_finite(grads, axis_names=self.grad_psum_axes)
+                gnorm = global_norm(grads)
+                if self.max_grad_norm is not None:
+                    from apex1_tpu.optim.clip_grad import clip_grad_norm
+                    grads, _ = clip_grad_norm(grads, self.max_grad_norm)
 
-            updates, new_opt_state = self.tx.update(grads, state.opt_state,
-                                                    state.params)
-            new_params = optax.apply_updates(state.params, updates)
-            # skip-on-overflow: keep old params/opt state (≙ noop_flag)
-            new_params = select_tree(finite, new_params, state.params)
-            new_opt_state = select_tree(finite, new_opt_state,
-                                        state.opt_state)
-            new_ls = scaler.adjust(ls, finite)
+            with region("optim"):
+                updates, new_opt_state = self.tx.update(
+                    grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
+                # skip-on-overflow: keep old params/opt state (≙ noop_flag,
+                # which the reference's optimizer kernels take themselves;
+                # XLA fuses the update into these selects, so the fusion's
+                # time, the state's traffic, is the optimizer's)
+                new_params = select_tree(finite, new_params, state.params)
+                new_opt_state = select_tree(finite, new_opt_state,
+                                            state.opt_state)
+            with region("amp"):
+                new_ls = scaler.adjust(ls, finite)
+                new_step = state.step + 1
             new_state = AmpState(
-                step=state.step + 1,
+                step=new_step,
                 params=new_params,
                 opt_state=new_opt_state,
                 loss_scale=self._set_ls(state.loss_scale, loss_id, new_ls),

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex1_tpu.obs.regions import region
 from apex1_tpu.ops import NEG_INF
 from apex1_tpu.ops._common import mosaic_dtype, use_pallas
 from apex1_tpu.ops.attention import flash_attention
@@ -123,55 +124,56 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
 
     Returns (attn (B, H, S, D), new_cache_entry).
     """
-    B, Hq, S, D = q.shape
-    Hkv = k_new.shape[1]
-    if isinstance(cache, PagedCache):
-        # paged serving tier: K/V live in a shared page pool addressed
-        # through the entry's block table; bias/segment_ids/valid_start
-        # have no paged consumers (serving prompts are right-padded)
-        if (bias is not None or segment_ids is not None
-                or valid_start is not None):
-            raise ValueError(
-                "PagedCache attention does not support bias/"
-                "segment_ids/valid_start")
-        return paged_update_attend(q, k_new, v_new, cache, cache_index,
-                                   sm_scale=sm_scale,
-                                   chunk_decode=chunk_decode)
-    idx = jnp.asarray(cache_index, jnp.int32)
-    if (idx.ndim == 1 and use_pallas() and Hq * S <= MAX_ROWS
-            and (S == 1 or chunk_decode) and bias is None
-            and valid_start is None
-            and mosaic_dtype(cache["k"].dtype) == cache["k"].dtype):
-        # the serving engine's step: rows at their own depths. One
-        # kernel appends each lane's rows where they lie and reads the
-        # lane to its horizon and no further (`ops.decode_attend`)
-        attn, k_all, v_all = decode_attend(q, k_new, v_new, cache["k"],
-                                           cache["v"], idx,
-                                           sm_scale=sm_scale)
-        return attn, {"k": k_all, "v": v_all}
-    k_all = cache_write(cache["k"], k_new, idx)
-    v_all = cache_write(cache["v"], v_new, idx)
-    new_entry = {"k": k_all, "v": v_all}
-    if S > 1 and not chunk_decode:
-        # prefill attends only over the CURRENT tokens — valid only from
-        # an empty cache. Fail fast on a concrete nonzero index (the
-        # common prefill call passes a Python 0); a traced nonzero index
-        # remains the documented precondition (ADVICE r3).
-        if isinstance(cache_index, int) and cache_index != 0:
-            raise ValueError(
-                f"cached_attention prefill (S={S} > 1) requires an empty "
-                f"cache at cache_index 0, got {cache_index} — it attends "
-                f"only over the new tokens, so a non-empty cache would "
-                f"be silently ignored")
-        # prefill is always autoregressive; with bias the flash kernel's
-        # additive-bias operand keeps this O(S·D) too
-        attn = flash_attention(q, k_new, v_new, causal=True,
-                               sm_scale=sm_scale, bias=bias,
-                               segment_ids=segment_ids)
+    with region("attn"):
+        B, Hq, S, D = q.shape
+        Hkv = k_new.shape[1]
+        if isinstance(cache, PagedCache):
+            # paged serving tier: K/V live in a shared page pool addressed
+            # through the entry's block table; bias/segment_ids/valid_start
+            # have no paged consumers (serving prompts are right-padded)
+            if (bias is not None or segment_ids is not None
+                    or valid_start is not None):
+                raise ValueError(
+                    "PagedCache attention does not support bias/"
+                    "segment_ids/valid_start")
+            return paged_update_attend(q, k_new, v_new, cache, cache_index,
+                                       sm_scale=sm_scale,
+                                       chunk_decode=chunk_decode)
+        idx = jnp.asarray(cache_index, jnp.int32)
+        if (idx.ndim == 1 and use_pallas() and Hq * S <= MAX_ROWS
+                and (S == 1 or chunk_decode) and bias is None
+                and valid_start is None
+                and mosaic_dtype(cache["k"].dtype) == cache["k"].dtype):
+            # the serving engine's step: rows at their own depths. One
+            # kernel appends each lane's rows where they lie and reads the
+            # lane to its horizon and no further (`ops.decode_attend`)
+            attn, k_all, v_all = decode_attend(q, k_new, v_new, cache["k"],
+                                               cache["v"], idx,
+                                               sm_scale=sm_scale)
+            return attn, {"k": k_all, "v": v_all}
+        k_all = cache_write(cache["k"], k_new, idx)
+        v_all = cache_write(cache["v"], v_new, idx)
+        new_entry = {"k": k_all, "v": v_all}
+        if S > 1 and not chunk_decode:
+            # prefill attends only over the CURRENT tokens — valid only from
+            # an empty cache. Fail fast on a concrete nonzero index (the
+            # common prefill call passes a Python 0); a traced nonzero index
+            # remains the documented precondition (ADVICE r3).
+            if isinstance(cache_index, int) and cache_index != 0:
+                raise ValueError(
+                    f"cached_attention prefill (S={S} > 1) requires an "
+                    f"empty cache at cache_index 0, got {cache_index} — it "
+                    f"attends only over the new tokens, so a non-empty "
+                    f"cache would be silently ignored")
+            # prefill is always autoregressive; with bias the flash kernel's
+            # additive-bias operand keeps this O(S·D) too
+            attn = flash_attention(q, k_new, v_new, causal=True,
+                                   sm_scale=sm_scale, bias=bias,
+                                   segment_ids=segment_ids)
+            return attn, new_entry
+        attn = cache_attend(q, k_all, v_all, idx, sm_scale=sm_scale,
+                            bias=bias, valid_start=valid_start)
         return attn, new_entry
-    attn = cache_attend(q, k_all, v_all, idx, sm_scale=sm_scale,
-                        bias=bias, valid_start=valid_start)
-    return attn, new_entry
 
 
 def cache_write(cache, new, cache_index):

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from apex1_tpu.core.policy import PrecisionPolicy, get_policy
+from apex1_tpu.obs.regions import region
 from apex1_tpu.ops import (layer_norm, linear_cross_entropy,
                            scaled_upper_triang_masked_softmax,
                            softmax_cross_entropy_loss)
@@ -98,55 +99,58 @@ class Block(nn.Module):
         # scores + fused-softmax path is kept via use_flash=False for
         # the kernel-parity cross-check)
         y = norm("ln1", x)
-        qkv = nn.Dense(3 * h, dtype=dtype, name="qkv")(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        B, S = x.shape[0], x.shape[1]
-        q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-        new_cache = None
-        if cache is not None:
-            from apex1_tpu.models.generate import cached_attention
-            attn, new_cache = cached_attention(
-                q, k, v, cache, cache_index,
-                sm_scale=1.0 / math.sqrt(hd),
-                segment_ids=segment_ids, valid_start=valid_start,
-                chunk_decode=chunk_decode)
-        elif cfg.use_flash:
-            attn = flash_attention(q, k, v, causal=True,
-                                   segment_ids=segment_ids,
-                                   sm_scale=1.0 / math.sqrt(hd),
-                                   dropout_p=cfg.dropout if active else 0.0,
-                                   dropout_seed=(fold_seed(seed, 0)
-                                                 if active else None))
-        else:
-            if segment_ids is not None:
-                raise ValueError("packed batches need use_flash=True")
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                                preferred_element_type=jnp.float32)
-            probs = scaled_upper_triang_masked_softmax(
-                scores, scale=1.0 / math.sqrt(hd))
-            attn = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(dtype), v)
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, S, h)
-        proj = nn.Dense(h, dtype=dtype, name="proj")(attn)
-        if active:
-            # Megatron bias_dropout_add epilogue (pre-LN stack: no norm
-            # after the add) — mask recomputed from the seed in backward
-            x = fused_bias_dropout_add(proj, x, p=cfg.dropout,
-                                       seed=fold_seed(seed, 1))
-        else:
-            x = x + proj
+        with region("attn"):
+            qkv = nn.Dense(3 * h, dtype=dtype, name="qkv")(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            B, S = x.shape[0], x.shape[1]
+            q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            new_cache = None
+            if cache is not None:
+                from apex1_tpu.models.generate import cached_attention
+                attn, new_cache = cached_attention(
+                    q, k, v, cache, cache_index,
+                    sm_scale=1.0 / math.sqrt(hd),
+                    segment_ids=segment_ids, valid_start=valid_start,
+                    chunk_decode=chunk_decode)
+            elif cfg.use_flash:
+                attn = flash_attention(
+                    q, k, v, causal=True, segment_ids=segment_ids,
+                    sm_scale=1.0 / math.sqrt(hd),
+                    dropout_p=cfg.dropout if active else 0.0,
+                    dropout_seed=fold_seed(seed, 0) if active else None)
+            else:
+                if segment_ids is not None:
+                    raise ValueError("packed batches need use_flash=True")
+                scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                                    preferred_element_type=jnp.float32)
+                probs = scaled_upper_triang_masked_softmax(
+                    scores, scale=1.0 / math.sqrt(hd))
+                attn = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(dtype),
+                                  v)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, S, h)
+            proj = nn.Dense(h, dtype=dtype, name="proj")(attn)
+            if active:
+                # Megatron bias_dropout_add epilogue (pre-LN stack: no
+                # norm after the add) — mask recomputed from the seed in
+                # backward
+                x = fused_bias_dropout_add(proj, x, p=cfg.dropout,
+                                           seed=fold_seed(seed, 1))
+            else:
+                x = x + proj
 
         # MLP
         y = norm("ln2", x)
-        y = nn.Dense(cfg.mlp_ratio * h, dtype=dtype, name="fc_in")(y)
-        y = nn.gelu(y)
-        y = nn.Dense(h, dtype=dtype, name="fc_out")(y)
-        if active:
-            out = fused_bias_dropout_add(y, x, p=cfg.dropout,
-                                         seed=fold_seed(seed, 2))
-        else:
-            out = x + y
+        with region("ffn"):
+            y = nn.Dense(cfg.mlp_ratio * h, dtype=dtype, name="fc_in")(y)
+            y = nn.gelu(y)
+            y = nn.Dense(h, dtype=dtype, name="fc_out")(y)
+            if active:
+                out = fused_bias_dropout_add(y, x, p=cfg.dropout,
+                                             seed=fold_seed(seed, 2))
+            else:
+                out = x + y
         return out if new_cache is None else (out, new_cache)
 
 
@@ -176,16 +180,18 @@ class GPT2(nn.Module):
                          (cfg.padded_vocab, cfg.hidden_size), jnp.float32)
         wpe = self.param("wpe", nn.initializers.normal(0.01),
                          (cfg.max_seq_len, cfg.hidden_size), jnp.float32)
-        if positions is None:
-            pos_emb = wpe[:S].astype(dtype)[None]
-        else:
-            # out-of-range positions (e.g. runtime.pack_documents chunking
-            # a long document without restart_chunk_positions=True) must
-            # not silently clamp under jit — fill with NaN so the loss
-            # goes non-finite and the mistake is visible immediately
-            pos_emb = jnp.take(wpe, positions, axis=0, mode="fill",
-                               fill_value=jnp.nan).astype(dtype)
-        x = wte[tokens].astype(dtype) + pos_emb
+        with region("embed"):
+            if positions is None:
+                pos_emb = wpe[:S].astype(dtype)[None]
+            else:
+                # out-of-range positions (e.g. runtime.pack_documents
+                # chunking a long document without
+                # restart_chunk_positions=True) must not silently clamp
+                # under jit — fill with NaN so the loss goes non-finite
+                # and the mistake is visible immediately
+                pos_emb = jnp.take(wpe, positions, axis=0, mode="fill",
+                                   fill_value=jnp.nan).astype(dtype)
+            x = wte[tokens].astype(dtype) + pos_emb
         new_cache = {}
         for i in range(cfg.num_layers):
             out = Block(cfg, name=f"h{i}")(
@@ -210,9 +216,10 @@ class GPT2(nn.Module):
             # adapter deltas can fuse in (llama does the same)
             h = x.astype(dtype)
             return h if cache is None else (h, new_cache)
-        logits = jnp.einsum("bsh,vh->bsv", x.astype(dtype),
-                            wte.astype(dtype),
-                            preferred_element_type=jnp.float32)
+        with region("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x.astype(dtype),
+                                wte.astype(dtype),
+                                preferred_element_type=jnp.float32)
         # returned over padded_vocab — slice-free; consumers mask with
         # num_classes=cfg.vocab_size (the CE kernel does it in-lane)
         return logits if cache is None else (logits, new_cache)
@@ -268,18 +275,21 @@ def gpt2_loss_fn(model: GPT2, *, fuse_head: bool = True):
         if fuse_head:
             h = model.apply({"params": params}, tokens, return_hidden=True,
                             **kw)
-            w = params["wte"].astype(h.dtype)
-            losses = linear_cross_entropy(
-                h[:, :-1], w, tokens[:, 1:],
-                num_classes=model.cfg.vocab_size)
         else:
             logits = model.apply({"params": params}, tokens, **kw)
-            losses = softmax_cross_entropy_loss(
-                logits[:, :-1].astype(jnp.float32), tokens[:, 1:],
-                num_classes=model.cfg.vocab_size)
-        if segment_ids is not None:
-            from apex1_tpu.ops import masked_next_token_mean
-            return masked_next_token_mean(losses, segment_ids)
-        return jnp.mean(losses)
+        with region("head"):
+            if fuse_head:
+                w = params["wte"].astype(h.dtype)
+                losses = linear_cross_entropy(
+                    h[:, :-1], w, tokens[:, 1:],
+                    num_classes=model.cfg.vocab_size)
+            else:
+                losses = softmax_cross_entropy_loss(
+                    logits[:, :-1].astype(jnp.float32), tokens[:, 1:],
+                    num_classes=model.cfg.vocab_size)
+            if segment_ids is not None:
+                from apex1_tpu.ops import masked_next_token_mean
+                return masked_next_token_mean(losses, segment_ids)
+            return jnp.mean(losses)
 
     return loss_fn

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from apex1_tpu.core.policy import PrecisionPolicy, get_policy
+from apex1_tpu.obs.regions import region
 from apex1_tpu.ops import rms_norm
 from apex1_tpu.ops.attention import flash_attention
 from apex1_tpu.ops.ssm import (causal_conv, heads_a_row, pack_state,
@@ -231,21 +232,24 @@ class GraniteHybridBlock(nn.Module):
             return rms_norm(z, g, eps=cfg.rms_norm_eps).astype(dtype)
 
         h = norm("in_norm_scale", x)
-        if self.kind == ATTENTION:
-            y, new_cache = self._attention(h, cache, cache_index,
-                                           chunk_decode)
-        else:
-            y, new_cache = self._mamba(h, cache, cache_index, n_real)
-        x = x + (cfg.residual_multiplier * y).astype(x.dtype)
+        with region("attn" if self.kind == ATTENTION else "mixer"):
+            if self.kind == ATTENTION:
+                y, new_cache = self._attention(h, cache, cache_index,
+                                               chunk_decode)
+            else:
+                y, new_cache = self._mamba(h, cache, cache_index, n_real)
+            x = x + (cfg.residual_multiplier * y).astype(x.dtype)
 
         h = norm("post_norm_scale", x)
-        w_in = self.param("mlp_in", init, (E, 2 * F),
-                          jnp.float32).astype(dtype)
-        w_out = self.param("mlp_out", init, (F, E),
-                           jnp.float32).astype(dtype)
-        gate, up = jnp.split(h @ w_in, 2, axis=-1)
-        y = (jax.nn.silu(gate) * up) @ w_out
-        return x + (cfg.residual_multiplier * y).astype(x.dtype), new_cache
+        with region("ffn"):
+            w_in = self.param("mlp_in", init, (E, 2 * F),
+                              jnp.float32).astype(dtype)
+            w_out = self.param("mlp_out", init, (F, E),
+                               jnp.float32).astype(dtype)
+            gate, up = jnp.split(h @ w_in, 2, axis=-1)
+            y = (jax.nn.silu(gate) * up) @ w_out
+            return (x + (cfg.residual_multiplier * y).astype(x.dtype),
+                    new_cache)
 
 
 class GraniteHybrid(nn.Module):
@@ -264,7 +268,8 @@ class GraniteHybrid(nn.Module):
         dtype = cfg.policy.compute_dtype
         emb = self.param("embed", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        x = (emb[tokens] * cfg.embedding_multiplier).astype(dtype)
+        with region("embed"):
+            x = (emb[tokens] * cfg.embedding_multiplier).astype(dtype)
         new_cache = {}
         for i, kind in enumerate(cfg.layer_types):
             x, entry = GraniteHybridBlock(cfg, kind, name=f"layer{i}")(
@@ -276,9 +281,10 @@ class GraniteHybrid(nn.Module):
         if not cfg.policy.keep_norms_fp32:
             g = g.astype(dtype)
         x = rms_norm(x, g, eps=cfg.rms_norm_eps).astype(dtype)
-        logits = jnp.einsum("bsh,vh->bsv", x, emb.astype(dtype),
-                            preferred_element_type=jnp.float32) \
-            / cfg.logits_scaling
+        with region("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x, emb.astype(dtype),
+                                preferred_element_type=jnp.float32) \
+                / cfg.logits_scaling
         return logits if cache is None else (logits, new_cache)
 
 

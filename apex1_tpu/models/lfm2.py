@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from apex1_tpu.core.policy import PrecisionPolicy, get_policy
+from apex1_tpu.obs.regions import region
 from apex1_tpu.ops import apply_rotary_pos_emb, rms_norm, rope_tables
 from apex1_tpu.ops.attention import flash_attention
 from apex1_tpu.ops.ssm import causal_conv
@@ -281,25 +282,28 @@ class Lfm2MoeBlock(nn.Module):
 
         idx = None if cache is None else jnp.asarray(cache_index, jnp.int32)
         h = norm("operator_norm_scale", x)
-        if self.kind == ATTENTION:
-            y, entry = self._attention(h, cache, cache_index, chunk_decode,
-                                       cos, sin)
-        else:
-            y, entry = self._conv(h, cache, idx, n_real)
-        x = x + y.astype(x.dtype)
+        with region("attn" if self.kind == ATTENTION else "mixer"):
+            if self.kind == ATTENTION:
+                y, entry = self._attention(h, cache, cache_index,
+                                           chunk_decode, cos, sin)
+            else:
+                y, entry = self._conv(h, cache, idx, n_real)
+            x = x + y.astype(x.dtype)
 
         h = norm("ffn_norm_scale", x)
-        if self.ffn == DENSE:
-            return x + self._dense(h).astype(x.dtype), entry, None
-        # who is routed: a live lane's one token, a run's real tokens
-        B, S = x.shape[:2]
-        live = None
-        if idx is not None and idx.ndim == 1:
-            live = jnp.broadcast_to((idx >= 0)[:, None], (B, S))
-        elif n_real is not None:
-            live = jnp.broadcast_to(jnp.arange(S)[None, :] < n_real, (B, S))
-        y, counts = self._sparse(h, live)
-        return x + y.astype(x.dtype), entry, counts
+        with region("ffn"):
+            if self.ffn == DENSE:
+                return x + self._dense(h).astype(x.dtype), entry, None
+            # who is routed: a live lane's one token, a run's real tokens
+            B, S = x.shape[:2]
+            live = None
+            if idx is not None and idx.ndim == 1:
+                live = jnp.broadcast_to((idx >= 0)[:, None], (B, S))
+            elif n_real is not None:
+                live = jnp.broadcast_to(jnp.arange(S)[None, :] < n_real,
+                                        (B, S))
+            y, counts = self._sparse(h, live)
+            return x + y.astype(x.dtype), entry, counts
 
 
 class Lfm2Moe(nn.Module):
@@ -324,17 +328,19 @@ class Lfm2Moe(nn.Module):
         B, S = tokens.shape
         emb = self.param("embed", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        x = emb[tokens].astype(dtype)
-        if positions is None:
-            positions = jnp.arange(S, dtype=jnp.int32)
-            if cache_index is not None:
-                start = jnp.asarray(cache_index, jnp.int32)
-                positions = (start[:, None] + positions if start.ndim
-                             else start + positions)
-        cos, sin = rope_tables(jnp.reshape(positions, (-1,)), cfg.head_dim,
-                               base=cfg.rope_theta)
-        if jnp.ndim(positions) == 2:
-            cos, sin = (t.reshape(B, S, -1) for t in (cos, sin))
+        with region("embed"):
+            x = emb[tokens].astype(dtype)
+        with region("attn"):        # every attention layer's RoPE tables
+            if positions is None:
+                positions = jnp.arange(S, dtype=jnp.int32)
+                if cache_index is not None:
+                    start = jnp.asarray(cache_index, jnp.int32)
+                    positions = (start[:, None] + positions if start.ndim
+                                 else start + positions)
+            cos, sin = rope_tables(jnp.reshape(positions, (-1,)),
+                                   cfg.head_dim, base=cfg.rope_theta)
+            if jnp.ndim(positions) == 2:
+                cos, sin = (t.reshape(B, S, -1) for t in (cos, sin))
         new_cache = {}
         counts = jnp.zeros((2,), jnp.int32)
         for i, (kind, ffn) in enumerate(zip(cfg.layer_types,
@@ -350,8 +356,9 @@ class Lfm2Moe(nn.Module):
         if not cfg.policy.keep_norms_fp32:
             g = g.astype(dtype)
         x = rms_norm(x, g, eps=cfg.norm_eps).astype(dtype)
-        logits = jnp.einsum("bsh,vh->bsv", x, emb.astype(dtype),
-                            preferred_element_type=jnp.float32)
+        with region("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x, emb.astype(dtype),
+                                preferred_element_type=jnp.float32)
         out = (logits,) if cache is None else (logits, new_cache)
         if moe_counts:
             out = out + (counts,)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex1_tpu.obs.regions import region
 from apex1_tpu.ops._common import (as_rows, interpret_mode, kernel_call,
                                    mosaic_dtype, out_struct, pad_to, to_mosaic,
                                    use_pallas)
@@ -230,33 +231,32 @@ def _xla_norm(x, gamma, beta, eps, rms):
 # public API
 # --------------------------------------------------------------------------
 
+def _norm(x, gamma, beta, eps, rms, block_rows):
+    if not use_pallas():
+        return _xla_norm(x, gamma, beta, eps, rms)
+    kdt = mosaic_dtype(x.dtype)  # fp16 -> bf16 on compiled TPU
+    gamma, beta = to_mosaic(gamma, beta)  # O3_fp16 params
+    if kdt != x.dtype:
+        return _fused_norm(x.astype(kdt), gamma, beta, eps, rms,
+                           block_rows).astype(x.dtype)
+    return _fused_norm(x, gamma, beta, eps, rms, block_rows)
+
+
 def layer_norm(x, gamma, beta, *, eps: float = 1e-5,
                block_rows: int | None = None):
     """Fused LayerNorm over the last axis. bf16/fp16 ``x`` with fp32 ``γ/β``
     is the reference "MixedFused" path; output keeps ``x.dtype``.
     ``block_rows``: static rows-per-grid-step; ``None`` resolves tuning
     table > heuristic (`apex1_tpu.tuning.tuned_row_block`)."""
-    if use_pallas():
-        kdt = mosaic_dtype(x.dtype)  # fp16 -> bf16 on compiled TPU
-        gamma, beta = to_mosaic(gamma, beta)  # O3_fp16 params
-        if kdt != x.dtype:
-            return _fused_norm(x.astype(kdt), gamma, beta, eps, False,
-                               block_rows).astype(x.dtype)
-        return _fused_norm(x, gamma, beta, eps, False, block_rows)
-    return _xla_norm(x, gamma, beta, eps, False)
+    with region("norm"):
+        return _norm(x, gamma, beta, eps, False, block_rows)
 
 
 def rms_norm(x, gamma, *, eps: float = 1e-6,
              block_rows: int | None = None):
     """Fused RMSNorm (``FusedRMSNorm`` — stock torch lacked it)."""
-    if use_pallas():
-        kdt = mosaic_dtype(x.dtype)  # fp16 -> bf16 on compiled TPU
-        gamma = to_mosaic(gamma)  # O3_fp16 params
-        if kdt != x.dtype:
-            return _fused_norm(x.astype(kdt), gamma, None, eps, True,
-                               block_rows).astype(x.dtype)
-        return _fused_norm(x, gamma, None, eps, True, block_rows)
-    return _xla_norm(x, gamma, None, eps, True)
+    with region("norm"):
+        return _norm(x, gamma, None, eps, True, block_rows)
 
 
 # --------------------------------------------------------------------------

@@ -210,6 +210,7 @@ from apex1_tpu.serving.packing import Executable, PackedParams
 from apex1_tpu.serving.scheduler import Backpressure, Request, Scheduler
 from apex1_tpu.serving.spec import ngram_propose
 from apex1_tpu.obs import spine
+from apex1_tpu.obs.regions import region
 from apex1_tpu.utils.observability import MetricsLogger
 
 
@@ -630,8 +631,9 @@ class Engine:
             # acceptance means emitted tokens are these samples
             # verbatim, so speculation cannot perturb the (params,
             # prompt, seed) purity resubmission rides
-            tgt = jax.vmap(lambda lg, seed, p: counter_sample(
-                lg, seed, p + steps, **sample_kw))(logits, seeds, pos)
+            with region("head"):
+                tgt = jax.vmap(lambda lg, seed, p: counter_sample(
+                    lg, seed, p + steps, **sample_kw))(logits, seeds, pos)
             return (tgt, cache, *counts)
 
         def accept(tgt, drafts, active, idxs, pos):
@@ -696,8 +698,9 @@ class Engine:
                               a_pg, b_pg, lrow, on)
             # output token 0's counter-based key (re-seeding per draw
             # is the counter-PRNG contract — see ops.stochastic)
-            key = jax.random.fold_in(jax.random.key(seed), 0)
-            tok = sample_token(lg, key, **sample_kw)[0]
+            with region("head"):
+                key = jax.random.fold_in(jax.random.key(seed), 0)
+                tok = sample_token(lg, key, **sample_kw)[0]
             return tok, pool
 
         def decode(params, pool, toks, idxs, active, seeds, pos,
@@ -872,9 +875,10 @@ class Engine:
                 on = jax.lax.dynamic_slice_in_dim(lon, slot, 1, 0)[0]
                 lg = lora_row(lg, last_real_logits(h, n_real[None]),
                               a_pg, b_pg, lrow, on)
-            tok = fused_sample(lg, jnp.asarray(seed, jnp.int32)[None],
-                               jnp.zeros((1,), jnp.int32),
-                               **sample_kw)[0]
+            with region("head"):
+                tok = fused_sample(lg, jnp.asarray(seed, jnp.int32)[None],
+                                   jnp.zeros((1,), jnp.int32),
+                                   **sample_kw)[0]
             return tok, pages
 
         def decode(params, pages, bt, toks, idxs, active, seeds, pos,
@@ -888,7 +892,8 @@ class Engine:
                 if lora:
                     lg = lora_batch(lg, h[:, -1], *lora_args)
                 pages = unpack_cache(cache)
-                nxt = fused_sample(lg, seeds, pos, **sample_kw)
+                with region("head"):
+                    nxt = fused_sample(lg, seeds, pos, **sample_kw)
             else:
                 tgt, pages = score_lanes(params, pages, bt,
                                          toks[:, None], idxs, active,
@@ -924,10 +929,11 @@ class Engine:
                         + jnp.arange(K + 1, dtype=jnp.int32)[None])
                 seedm = jnp.broadcast_to(seeds[:, None], posm.shape)
                 V = logits.shape[-1]
-                tgt = fused_sample(
-                    logits.reshape(-1, V), seedm.reshape(-1),
-                    posm.reshape(-1),
-                    **sample_kw).reshape(-1, K + 1)
+                with region("head"):
+                    tgt = fused_sample(
+                        logits.reshape(-1, V), seedm.reshape(-1),
+                        posm.reshape(-1),
+                        **sample_kw).reshape(-1, K + 1)
             else:
                 tgt, pages = score_lanes(params, pages, bt, chunks,
                                          idxs, active, seeds, pos,

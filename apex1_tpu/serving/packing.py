@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex1_tpu.core import capability
+from apex1_tpu.obs.regions import region
 
 #: a leaf of at most this many bytes is small: a launch pays for it what
 #: it pays for a matrix, and a stack of hundreds is still a few MB
@@ -208,7 +209,11 @@ class Executable:
         layout = packed.layout      # the body holds no array
 
         def body(operands, *args):
-            return fn(layout.unpack(operands), *args)
+            # what the engine adds around the decoder (the cuts, the
+            # control vectors, the lane moved in and out) is `engine`;
+            # the model's own regions, opened inside, win
+            with region("engine"):
+                return fn(layout.unpack(operands), *args)
 
         body.__name__ = fn.__name__     # the module's name in a trace
         self._operands_of = packed.operands_of

@@ -7,9 +7,10 @@ and post-processed profiler SQLite into per-kernel FLOPs/bytes
 callable through the pipeline schedules.
 
 TPU-native equivalents:
-- `annotate` — ``jax.named_scope`` + `obs.spine.span` (≙ nvtx ranges;
-  names land in XLA HLO metadata, the spine's span buffer AND the
-  profiler trace).
+- a region of DEVICE work is named by `obs.regions.region` (≙ nvtx
+  ranges: the name lands in XLA's HLO metadata and, with the op, in the
+  profiler's trace); a region of HOST time by `obs.spine.span`. Never
+  both at once: a host span around traced code times the tracing, once.
 - `trace` — context manager around ``jax.profiler.start_trace`` writing a
   TensorBoard-loadable trace (≙ running under nsys).
 - `cost_analysis` — compile-time FLOPs/bytes attribution from XLA
@@ -39,14 +40,6 @@ import jax
 import numpy as np
 
 from apex1_tpu.obs import spine
-
-
-@contextlib.contextmanager
-def annotate(name: str, **span_args):
-    """Name a region for XLA metadata, the spine's span buffer and the
-    profiler's timeline; yields the open `spine.Span`."""
-    with jax.named_scope(name), spine.span(name, **span_args) as sp:
-        yield sp
 
 
 @contextlib.contextmanager

@@ -1,0 +1,7 @@
+"""model.attn_ms.train: device ms a step of the main program inside the program's region `attn` (`apex1_tpu/obs/regions.py`), forward and backward, read by `harness/regions.py`."""
+
+from benchmark.harness import regions
+
+
+def read(ctx):
+    return regions.region_ms(ctx, "attn")

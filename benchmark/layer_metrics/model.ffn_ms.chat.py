@@ -1,0 +1,7 @@
+"""model.ffn_ms.chat: device ms a step of the main program inside the program's region `ffn` (`apex1_tpu/obs/regions.py`), forward and backward, read by `harness/regions.py`."""
+
+from benchmark.harness import regions
+
+
+def read(ctx):
+    return regions.region_ms(ctx, "ffn")

@@ -18,7 +18,7 @@ from apex1_tpu.checkpoint import (CheckpointManager, restore_checkpoint,
                                   save_checkpoint)
 from apex1_tpu.core.mesh import make_mesh
 from apex1_tpu.optim.fused_adam import fused_adam
-from apex1_tpu.utils.observability import (MetricsLogger, Timers, annotate,
+from apex1_tpu.utils.observability import (MetricsLogger, Timers,
                                            cost_analysis)
 
 
@@ -136,14 +136,25 @@ def test_cost_analysis_flops():
     assert ca.get("flops", 0) >= 2 * 128 ** 3 * 0.9
 
 
-def test_timers_and_annotate():
+def test_timers_and_region():
+    """A timer around work that a region names: the region is a scope of
+    the traced program (it lands in the jaxpr's name stack and changes
+    nothing that runs), the timer host time."""
+    from apex1_tpu.obs.regions import region
+
+    def f(a):
+        with region("ffn"):
+            return a @ a
+
     t = Timers()
-    with annotate("fwd"):
-        t("fwd").start()
-        x = jnp.ones((64, 64)) @ jnp.ones((64, 64))
-        t("fwd").stop(sync=x)
+    t("fwd").start()
+    x = jax.jit(f)(jnp.ones((64, 64)))
+    t("fwd").stop(sync=x)
     out = t.log()
     assert out["fwd"] > 0
+    (eqn,) = [e for e in jax.make_jaxpr(f)(jnp.ones((64, 64))).eqns
+              if e.primitive.name == "dot_general"]
+    assert str(eqn.source_info.name_stack) == "~ffn"
 
 
 def test_metrics_logger():

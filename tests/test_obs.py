@@ -284,13 +284,27 @@ class TestSpans:
         assert snap[0].id > first               # the oldest went
         assert snap[-1].id == snap[0].id + spine.SPAN_CAPACITY - 1
 
-    def test_annotate_is_a_named_scope_and_this_span(self):
-        from apex1_tpu.utils.observability import annotate
+    def test_a_region_is_a_named_scope_and_no_span(self):
+        """`obs.regions.region` names DEVICE work: a scope on the traced
+        ops' name stack, nothing in the span buffer (a host span around
+        traced code would time the tracing, once); a name outside the
+        closed list raises where it is opened."""
+        import jax
+        import jax.numpy as jnp
+        from apex1_tpu.obs.regions import REGIONS, region
+
+        def f(x):
+            with region("attn"):
+                return jnp.tanh(x)
+
         mark = _mark()
-        with annotate("train/fwd", req=3) as sp:
-            pass
-        (got,) = _since(mark)
-        assert got is sp and got.name == "train/fwd" and got.req == 3
+        jaxpr = jax.make_jaxpr(f)(jnp.ones((4,)))
+        assert not _since(mark)
+        assert [str(e.source_info.name_stack) for e in jaxpr.eqns] == [
+            "~attn"]
+        assert "attn" in REGIONS and "train/fwd" not in REGIONS
+        with pytest.raises(ValueError, match="no region"):
+            region("train/fwd")
 
     def test_req_is_shared_along_a_requests_life(self,
                                                  tiny_engine_factory):

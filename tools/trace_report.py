@@ -6,9 +6,12 @@ Every on-silicon ``bench.py`` record stamps a ``profile_artifact``
 tool parses them with the dependency-free `apex1_tpu.obs.xspace`
 walker (no TensorFlow import roulette) and persists a
 ``trace_report.json`` NEXT TO the trace it describes — Pallas-kernel /
-collective / XLA-op buckets, so exposed-ICI time is directly readable
-— plus a human table on stdout. A corrupt or truncated trace is a
-typed, named error (`obs.xspace.TraceError`), never a traceback.
+collective / XLA-op buckets, so exposed-ICI time is directly readable,
+and the device's time by the program's own regions (`obs.regions`:
+``by region, ms an execution of <module>``, forward and backward, with
+each region's largest ops and what no region reaches) — plus a human
+table on stdout. A corrupt or truncated trace is a typed, named error
+(`obs.xspace.TraceError`), never a traceback.
 
 CPU-rehearsable end-to-end: ``jax.profiler.trace`` works on the CPU
 backend (the report is then labelled ``host-xla-proxy`` — shares
