@@ -26,7 +26,7 @@ from apex1_tpu.obs.regions import region
 from apex1_tpu.ops import (layer_norm, linear_cross_entropy,
                            scaled_upper_triang_masked_softmax,
                            softmax_cross_entropy_loss)
-from apex1_tpu.ops.attention import flash_attention
+from apex1_tpu.ops.attention import fmha
 from apex1_tpu.ops.stochastic import (fold_seed, fused_bias_dropout_add,
                                       seed_from_key)
 
@@ -97,39 +97,49 @@ class Block(nn.Module):
 
         # attention — flash kernel (O(S·D) memory; the materialized
         # scores + fused-softmax path is kept via use_flash=False for
-        # the kernel-parity cross-check)
+        # the kernel-parity cross-check). Training (no cache, use_flash)
+        # hands the qkv product's output to `fmha` AS IT LIES, (B, S, 3·h)
+        # seen as (B, S, 3, heads, head width), and the result to `proj`:
+        # where `ops.attention.flash_form` allows (two heads of 64 to a
+        # 128-lane block; GPT-2's every size) no split, pad or transpose
+        # runs in XLA, forward or backward, and the qkv product's
+        # backward gets dq, dk, dv as one array. The cached and the
+        # composite paths want (B, heads, S, head width) and turn q, k, v
         y = norm("ln1", x)
         with region("attn"):
             qkv = nn.Dense(3 * h, dtype=dtype, name="qkv")(y)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
             B, S = x.shape[0], x.shape[1]
-            q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-            k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-            v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
             new_cache = None
-            if cache is not None:
-                from apex1_tpu.models.generate import cached_attention
-                attn, new_cache = cached_attention(
-                    q, k, v, cache, cache_index,
-                    sm_scale=1.0 / math.sqrt(hd),
-                    segment_ids=segment_ids, valid_start=valid_start,
-                    chunk_decode=chunk_decode)
-            elif cfg.use_flash:
-                attn = flash_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids,
-                    sm_scale=1.0 / math.sqrt(hd),
+            if cache is None and cfg.use_flash:
+                attn = fmha(
+                    qkv.reshape(B, S, 3, nh, hd), causal=True,
+                    segment_ids=segment_ids, sm_scale=1.0 / math.sqrt(hd),
                     dropout_p=cfg.dropout if active else 0.0,
                     dropout_seed=fold_seed(seed, 0) if active else None)
             else:
-                if segment_ids is not None:
-                    raise ValueError("packed batches need use_flash=True")
-                scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                                    preferred_element_type=jnp.float32)
-                probs = scaled_upper_triang_masked_softmax(
-                    scores, scale=1.0 / math.sqrt(hd))
-                attn = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(dtype),
-                                  v)
-            attn = attn.transpose(0, 2, 1, 3).reshape(B, S, h)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+                k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+                v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+                if cache is not None:
+                    from apex1_tpu.models.generate import cached_attention
+                    attn, new_cache = cached_attention(
+                        q, k, v, cache, cache_index,
+                        sm_scale=1.0 / math.sqrt(hd),
+                        segment_ids=segment_ids, valid_start=valid_start,
+                        chunk_decode=chunk_decode)
+                else:
+                    if segment_ids is not None:
+                        raise ValueError(
+                            "packed batches need use_flash=True")
+                    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                                        preferred_element_type=jnp.float32)
+                    probs = scaled_upper_triang_masked_softmax(
+                        scores, scale=1.0 / math.sqrt(hd))
+                    attn = jnp.einsum("bhqk,bhkd->bhqd",
+                                      probs.astype(dtype), v)
+                attn = attn.transpose(0, 2, 1, 3)
+            attn = attn.reshape(B, S, h)
             proj = nn.Dense(h, dtype=dtype, name="proj")(attn)
             if active:
                 # Megatron bias_dropout_add epilogue (pre-LN stack: no

@@ -44,6 +44,21 @@ the TPU-native design is a flash/online-softmax kernel with NO seqlen cap:
   partial results around an ICI ring — differentiably (the custom VJP
   handles the lse cotangent: ∂lse/∂s = softmax(s) ⇒ ds += p·dlse).
 
+- **two layouts, chosen by what the call hands over** (`flash_form`; no
+  option): HEADS — `flash_attention`'s ``(B, H, S, D)`` operands, a block
+  one head padded to 128 lanes; ROWS — `fmha`'s packed ``(B, S, 3, H, D)``
+  array read WHERE THE QKV PRODUCT LEFT IT, as ``(B, S, 3·H·D)``: a block
+  is ``(1, rows, 128)``, its lanes ``128 // D`` whole heads side by side,
+  q, k and v three lane-block offsets into the one array, ``out`` written
+  as the output projection reads it, dq, dk, dv into the lane ranges of
+  ONE ``(B, S, 3·H·D)`` array, and δ = Σ dO·out made in the dq kernel.
+  No pad, split, reshape, transpose or reduce in XLA. Inside a program the heads of a block take turns through the SAME
+  tile bodies: the operand read once a program has the other heads'
+  lanes zeroed (`_head_lanes`), so a contraction over the 128 lanes is
+  one head's alone (exact zeros added: bitwise a head padded with
+  zeros), and of a product's 128 output lanes (rows of outᵀ) the head's
+  own are kept.
+
 Shapes: ``q`` (B, Hq, Sq, D); ``k``/``v`` (B, Hkv, Sk, D), Hq % Hkv == 0.
 Accumulation is fp32 regardless of input dtype (bf16 inputs feed the MXU
 directly; only the running statistics are fp32) — matching the reference's
@@ -260,6 +275,40 @@ def tile_plan(Sq, Sk, bq, bk, q_off=0, k_off=0, causal=True):
         -(-Sq // bq) * -(-Sk // bk) - interior - masked
 
 
+def flash_form(Hq, Hkv, Sq, Sk, D, *, packed=False, has_bias=False,
+               block_q=None, block_k=None, dtype=jnp.bfloat16):
+    """The form a call takes, from what it can see and nothing else (no
+    argument, option or environment variable picks it): ``layout``
+    (``"rows"`` | ``"heads"``), ``heads_per_block``, ``resident`` (the
+    forward's and dq's, dk/dv's) and ``blocks``. `fmha` and the custom
+    VJPs decide with THIS function; a traced call says the same on the
+    `obs` spine (counter ``flash/form``).
+
+    ROWS, the packed array read where the qkv product left it (module
+    docstring), wants: the packed array (``packed``: `fmha`; what hands
+    over (B, H, S, D) has turned its arrays already), whole heads to a
+    128-lane block (``128 % D == 0`` and the heads a multiple of
+    ``128 // D``), as many K/V heads as query heads, no bias (its tile is
+    per (qi, ki): the grid form, which the rows layout has not) and rows
+    that fit VMEM for all three kernels at ``128 // D`` heads to a block
+    (`vmem_model.flash_kv_row_check` / `flash_q_row_check`). Anything
+    else runs the HEADS layout, `fmha` after turning its array."""
+    block_q, block_k = _auto_blocks(D, block_q, block_k, dtype, Sk)
+    es = jnp.dtype(dtype).itemsize
+    n = _LANES // D if D <= _LANES and _LANES % D == 0 else 0
+    if (packed and n and not has_bias and Hq == Hkv and Sq == Sk
+            and Hq % n == 0):
+        g = _sizes(1, Hq, Hkv, Sq, Sk, D, es, block_q, block_k, n)
+        if _kv_resident(g) and _q_resident(g):
+            return dict(layout="rows", heads_per_block=n,
+                        resident=(True, True), blocks=(g["bq"], g["bk"]))
+    g = _sizes(1, Hq, Hkv, Sq, Sk, D, es, block_q, block_k)
+    return dict(layout="heads", heads_per_block=1,
+                resident=(_kv_resident(g, has_bias),
+                          _q_resident(g, has_bias)),
+                blocks=(g["bq"], g["bk"]))
+
+
 def _exact_scale(scale):
     """A power-of-two ``scale`` commutes with every rounding on the way
     (bf16 operand, fp32 product and sum), so it may leave the (bq, bk)
@@ -307,6 +356,43 @@ def _rows(i, n):
     return pl.ds(pl.multiple_of(i * n, n), n)
 
 
+def _tile_of(ref, i, n):
+    """Rows [i·n, (i+1)·n) of a resident row's block, (1, 1, rows, Dp) or
+    (1, rows, 128)."""
+    return ref[(0,) * (len(ref.shape) - 2) + (_rows(i, n), slice(None))]
+
+
+# The ROWS layout (module docstring): a (rows, 128) block holds ``heads``
+# whole heads side by side in its lanes.
+
+def _lane_head(shape, heads):
+    """Which of a block's ``heads`` heads each lane of ``shape`` is."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) \
+        // (_LANES // heads)
+
+
+def _head_lanes(x, heads):
+    """``x`` once a head, the OTHER heads' lanes zeroed: a contraction
+    over the 128 lanes against it is that head's alone, and what the
+    zeros add is exact. Made once a program, of the operand read once a
+    program. One head to a block: ``x`` itself."""
+    if heads == 1:
+        return (x,)
+    lane = _lane_head(x.shape, heads)
+    return tuple(jnp.where(lane == t, x, jnp.zeros_like(x))
+                 for t in range(heads))
+
+
+def _own_lanes(xs):
+    """Of each head's (rows, 128) product its own lanes: one block."""
+    out = xs[-1]
+    if len(xs) > 1:
+        lane = _lane_head(out.shape, len(xs))
+        for t in range(len(xs) - 2, -1, -1):
+            out = jnp.where(lane == t, xs[t], out)
+    return out
+
+
 def _over_key_tiles(tile, row, init, finish, *, block_k, qi, bq, bk, n_k,
                     true_sq, true_sk, q_off, k_off, causal, mask_all):
     """Drive ``tile(ki, masked, *row())`` over query tile ``qi``'s key
@@ -339,7 +425,7 @@ def _over_key_tiles(tile, row, init, finish, *, block_k, qi, bq, bk, n_k,
 
 
 def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
-                   mask=None, keep=None, dropout_p=0.0):
+                   mask=None, keep=None, dropout_p=0.0, own=None):
     """Fold one score tile into the running (outᵀ, max, sum) of the
     online softmax. The tile is TRANSPOSED, (bk, bq) with the keys down
     the sublanes: a query's max and sum then run over sublanes and vregs
@@ -348,9 +434,12 @@ def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
     training cell's shapes. ``acc`` is outᵀ (Dp, bq), ``m_scr`` and
     ``l_scr`` (1, bq) rows. ``mask=None`` is the INTERIOR body: no iota,
     compare or select (on an all-live tile they change nothing);
-    ``scale=None`` means the caller folded it into ``q``. The forward
-    kernel and `ops.fused_collective._agf_kernel` both run THIS function,
-    which is what keeps the fused ring equal to the decomposed one."""
+    ``scale=None`` means the caller folded it into ``q``. ``own``: the
+    rows of ``vᵀ·eᵀ`` that are this head's (the ROWS layout, where ``v``
+    holds several heads' lanes and ``acc`` is this head's rows of outᵀ).
+    The forward kernel and `ops.fused_collective._agf_kernel` both run
+    THIS function, which is what keeps the fused ring equal to the
+    decomposed one."""
     # native-dtype operands: bf16 inputs ride the MXU's bf16 path with
     # fp32 accumulation (an fp32 upcast before the dot would run the MXU
     # ~8x slower); running statistics stay fp32
@@ -377,35 +466,50 @@ def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
         # e, only the AV contribution is masked+rescaled, so (out, lse)
         # merge exactly across ring shards
         e = jnp.where(keep, e * (1.0 / (1.0 - dropout_p)), 0.0)
-    acc[...] = acc[...] * corr + jax.lax.dot_general(         # vᵀ · eᵀ
+    old = acc[...] * corr
+    pv = jax.lax.dot_general(                                 # vᵀ · eᵀ
         v, e.astype(v.dtype), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    acc[...] = old + (pv if own is None else pv[own])
     m_scr[...] = m_new
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
                 scale, causal, true_sq, true_sk, has_segs, has_bias, n_k,
-                block_k=None, dropout_p=0.0, n_h=0, interp=False):
+                block_k=None, dropout_p=0.0, n_h=0, interp=False, heads=0):
     """``block_k`` given: the RESIDENT form, grid (b, h, qi), the row's
     whole K and V in ``k_ref``/``v_ref`` and the pass over key tiles a
     loop in here (`_key_tiles`); else the GRID form, (b, h, qi, ki).
-    Writes outᵀ, a (Dp, bq) block, and lse as a (1, bq) row."""
+    Writes outᵀ, a (Dp, bq) block, and lse as a (1, bq) row.
+    ``heads`` > 0: the ROWS layout (resident only), grid (b, j, qi) over
+    lane blocks of ``heads`` whole heads; every tile is loaded and its
+    mask built once and run for each head in turn, head ``t`` into its
+    own rows of the one outᵀ accumulator, which is turned once, at the
+    end, and written as (bq, 128) rows of ``out``."""
     sd_ref, qseg_ref, kseg_ref, bias_ref, rest = _split_refs(
         rest, has_segs, has_bias, dropout_p)
     o_ref, lse_ref, acc, m_scr, l_scr = rest
     resident = block_k is not None
+    rows, n = heads > 0, max(heads, 1)
+    lead = (0,) * (len(q_ref.shape) - 2)
     # program ids read out HERE: inside a `pl.when` or loop body the
     # primitive has no interpret-mode lowering
     b, h, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[2]
-    bk = block_k if resident else k_ref.shape[2]
+    bq = q_ref.shape[-2]
+    bk = block_k if resident else k_ref.shape[-2]
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
     fold = _exact_scale(scale)
+    d = _LANES // n
+    own = [slice(t * d, (t + 1) * d) if rows else None for t in range(n)]
+    # head t's running (outᵀ rows, max, sum)
+    state = [(acc.at[own[t], :], m_scr.at[t], l_scr.at[t]) if rows
+             else (acc, m_scr, l_scr) for t in range(n)]
 
     def row():
         # what a tile takes from this program's query rows; read ONCE a
         # program in the resident form, per computed tile in the grid's
-        return (q_ref[0, 0] * scale if fold else q_ref[0, 0],
+        return (_head_lanes(q_ref[lead] * scale if fold else q_ref[lead],
+                            n),
                 qseg_ref[0, 0] if has_segs else None)         # (1, bq)
 
     def init():
@@ -413,9 +517,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    def tile(ki, masked, q, qseg):
+    def tile(ki, masked, qs, qseg):
         if resident:
-            k, v = k_ref[0, 0, _rows(ki, bk), :], v_ref[0, 0, _rows(ki, bk), :]
+            k, v = _tile_of(k_ref, ki, bk), _tile_of(v_ref, ki, bk)
             kseg = kseg_ref[0, _rows(ki, bk), :] if has_segs else None
         else:
             k, v = k_ref[0, 0], v_ref[0, 0]
@@ -424,21 +528,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
                          true_sk=true_sk, q_off=q_off, k_off=k_off,
                          qseg=qseg, kseg=kseg, transposed=True) \
             if masked else None
-        keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b, h,
-                          dropout_p=dropout_p, n_h=n_h, interp=interp,
-                          transposed=True) if dropout_p > 0.0 else None
-        _attend_tile(q, k, v, acc, m_scr, l_scr,
-                     scale=None if fold else scale,
-                     bias=bias_ref[0, 0] if has_bias else None,
-                     mask=mask, keep=keep, dropout_p=dropout_p)
+        for t in range(n):
+            keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b,
+                              h * n + t if rows else h,
+                              dropout_p=dropout_p, n_h=n_h, interp=interp,
+                              transposed=True) if dropout_p > 0.0 else None
+            _attend_tile(qs[t], k, v, *state[t],
+                         scale=None if fold else scale,
+                         bias=bias_ref[0, 0] if has_bias else None,
+                         mask=mask, keep=keep, dropout_p=dropout_p,
+                         own=own[t])
 
     def finish():
-        l = l_scr[...]
-        safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc[...] / safe).astype(o_ref.dtype)
-        # finite NEG_INF sentinel for empty rows keeps ring merges exact
-        lse_ref[0, 0, 0] = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe),
-                                     NEG_INF)
+        for t in range(n):
+            a, m, l_ = state[t]
+            l = l_[...]
+            safe = jnp.where(l > 0.0, l, 1.0)
+            if rows:
+                a[...] = a[...] / safe
+            else:
+                o_ref[0, 0] = (a[...] / safe).astype(o_ref.dtype)
+            # finite NEG_INF sentinel for empty rows keeps ring merges exact
+            lse_ref[0, t, 0] = jnp.where(l > 0.0, m[...] + jnp.log(safe),
+                                         NEG_INF)
+        if rows:
+            o_ref[0] = acc[...].T.astype(o_ref.dtype)
 
     _over_key_tiles(tile, row, init, finish, block_k=block_k, qi=qi, bq=bq,
                     bk=bk, n_k=n_k, true_sq=true_sq, true_sk=true_sk,
@@ -449,67 +563,107 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                    qo_ref, ko_ref, *rest,
                    scale, causal, true_sq, true_sk, has_segs, has_bias,
-                   n_k, block_k=None, dropout_p=0.0, n_h=0, interp=False):
-    """Resident (``block_k`` given) or grid form, as `_fwd_kernel`."""
+                   n_k, block_k=None, dropout_p=0.0, n_h=0, interp=False,
+                   heads=0):
+    """Resident (``block_k`` given) or grid form, and HEADS or ROWS
+    layout (``heads`` > 0), as `_fwd_kernel`. ROWS: q AND dO are zeroed
+    a head (both are contracted over the lanes, both read once a
+    program), each head's ``ds·k`` keeps its own lanes and the block's
+    heads share the one (bq, 128) accumulator; the dq block goes to the
+    q lanes of a (B, S, 3·H·D) array that the dk/dv call completes. And
+    δ = Σ dO·out is MADE here, once a program: ``dd_ref`` is the
+    forward's ``out`` block, beside dO as it lies (in XLA a head's 64
+    lanes summed out of a (B, S, H·D) array cost a float32 product
+    written, a transposing copy and a reduce; here one turn of a
+    (bq, 128) tile), written as the dense rows the dk/dv call reads."""
     sd_ref, qseg_ref, kseg_ref, bias_ref, rest = _split_refs(
         rest, has_segs, has_bias, dropout_p)
-    dq_ref, dq_acc = rest
+    rows, n = heads > 0, max(heads, 1)
+    if rows:
+        dq_ref, delta_ref, dq_acc = rest
+    else:
+        dq_ref, dq_acc = rest
     resident = block_k is not None
+    lead = (0,) * (len(q_ref.shape) - 2)
     b, h, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[2]
-    bk = block_k if resident else k_ref.shape[2]
+    bq = q_ref.shape[-2]
+    bk = block_k if resident else k_ref.shape[-2]
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
     fold = _exact_scale(scale)
 
+    def delta():
+        # (dO·out)ᵀ: a head's δ is the sum down its own rows, one dense
+        # (1, bq) row: what the dk/dv call reads, and `_as_col`'s input
+        d = _LANES // n
+        prod = (do_ref[0].astype(jnp.float32)
+                * dd_ref[0].astype(jnp.float32)).T
+        dds = tuple(jnp.sum(prod[t * d:(t + 1) * d], axis=0, keepdims=True)
+                    for t in range(n))
+        for t in range(n):
+            delta_ref[0, t, 0] = dds[t]
+        return dds
+
     def row():
         # folded: s = (q·scale)kᵀ here and dq = (Σ ds·k)·scale at the end
-        return (q_ref[0, 0] * scale if fold else q_ref[0, 0], do_ref[0, 0],
-                _as_col(lse_ref[0, 0, 0]), _as_col(dd_ref[0, 0, 0]),
+        return (_head_lanes(q_ref[lead] * scale if fold else q_ref[lead],
+                            n),
+                _head_lanes(do_ref[lead], n),
+                tuple(_as_col(lse_ref[0, t, 0]) for t in range(n)),
+                tuple(_as_col(dd) for dd in (
+                    delta() if rows else [dd_ref[0, 0, 0]])),
                 qseg_ref[0] if has_segs else None)
 
     def init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def tile(ki, masked, q, do, lse, dd, qseg):
+    def tile(ki, masked, qs, dos, lses, dds, qseg):
         if resident:
-            k, v = k_ref[0, 0, _rows(ki, bk), :], v_ref[0, 0, _rows(ki, bk), :]
+            k, v = _tile_of(k_ref, ki, bk), _tile_of(v_ref, ki, bk)
             kseg = kseg_ref[0, ki] if has_segs else None
         else:
             k, v = k_ref[0, 0], v_ref[0, 0]
             kseg = kseg_ref[0, 0] if has_segs else None
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if not fold:
-            s = s * scale
-        if has_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        p = jnp.exp(s - lse)
-        if masked:
-            mask = _mask_for(qi, ki, bq, bk, causal=causal,
-                             true_sq=true_sq, true_sk=true_sk, q_off=q_off,
-                             k_off=k_off, qseg=qseg, kseg=kseg)
-            p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_p > 0.0:
-            # out = Σ drop∘softmax(s)·v with drop a CONSTANT mask ⇒
-            # ds = p·(drop·dp − δ + dlse): the recomputed mask scales
-            # only the dp term (δ already carries the dropped weights
-            # through do·out); dd is δ − dlse
-            keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk,
-                              b, h, dropout_p=dropout_p, n_h=n_h,
-                              interp=interp)
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
-        ds = p * (dp - dd)
-        if not fold:
-            ds = ds * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        mask, dqs = None, []
+        for t in range(n):
+            s = jax.lax.dot_general(qs[t], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            if has_bias:
+                s = s + bias_ref[0, 0].astype(jnp.float32)
+            p = jnp.exp(s - lses[t])
+            if masked:
+                if mask is None:   # one mask a tile, whatever the head
+                    mask = _mask_for(qi, ki, bq, bk, causal=causal,
+                                     true_sq=true_sq, true_sk=true_sk,
+                                     q_off=q_off, k_off=k_off, qseg=qseg,
+                                     kseg=kseg)
+                p = jnp.where(mask, p, 0.0)
+            dp = jax.lax.dot_general(dos[t], v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if dropout_p > 0.0:
+                # out = Σ drop∘softmax(s)·v with drop a CONSTANT mask ⇒
+                # ds = p·(drop·dp − δ + dlse): the recomputed mask scales
+                # only the dp term (δ already carries the dropped weights
+                # through do·out); dd is δ − dlse
+                keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk,
+                                  b, h * n + t if rows else h,
+                                  dropout_p=dropout_p, n_h=n_h,
+                                  interp=interp)
+                dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
+            ds = p * (dp - dds[t])
+            if not fold:
+                ds = ds * scale
+            if t == n - 1:
+                acc = dq_acc[...]
+            dqs.append(jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        dq_acc[...] = acc + _own_lanes(dqs)
 
     def finish():
         dq = dq_acc[...] * scale if fold else dq_acc[...]
-        dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+        dq_ref[lead] = dq.astype(dq_ref.dtype)
 
     _over_key_tiles(tile, row, init, finish, block_k=block_k, qi=qi, bq=bq,
                     bk=bk, n_k=n_k, true_sq=true_sq, true_sk=true_sk,
@@ -521,7 +675,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                     qo_ref, ko_ref, *rest,
                     scale, causal, true_sq, true_sk, has_segs, has_bias,
                     n_q, group, block_q=None, dropout_p=0.0, n_h=0,
-                    interp=False):
+                    interp=False, heads=0):
     """dk/dv of one key tile, accumulated over the GQA group's query
     heads and their query tiles in VMEM scratch and written ONCE at Hkv
     granularity — no (B, Hq, Sk, D) fp32 partials in HBM, each k/v tile
@@ -534,14 +688,31 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     the sublanes: dv += pᵀ·dO and dk += dsᵀ·q then take their left
     operand as it lies (in (bq, bk) both would be turned in the
     transpose unit, every tile), and a query's lse and δ − dlse are
-    the (1, bq) rows they are stored as."""
+    the (1, bq) rows they are stored as.
+
+    ``heads`` > 0: the ROWS layout (resident only), grid (b, j, ki, c)
+    over lane blocks of ``heads`` whole heads. k AND v are zeroed a
+    head, each head's ``pᵀ·dO`` and ``dsᵀ·q`` keep their own lanes.
+    dk and dv are two lane ranges of ONE (B, S, 3·H·D) output and a
+    call's output has one block a step, so a key tile takes two steps:
+    ``c`` = 0 computes both and writes dk, ``c`` = 1 only hands the dv
+    accumulator to the block the output's index map has moved to the v
+    lanes; the operands' blocks at ``c`` = 1 are already the NEXT key
+    tile's (`_dkv_specs`), fetched under this tile's work. The output
+    IS the dq call's array (``rest[0]``, aliased, never read here): its
+    q lanes are kept."""
     sd_ref, qseg_ref, kseg_ref, bias_ref, rest = _split_refs(
         rest, has_segs, has_bias, dropout_p)
-    dk_ref, dv_ref, dk_acc, dv_acc = rest
+    rows, n = heads > 0, max(heads, 1)
+    if rows:
+        _, dkv_ref, dk_acc, dv_acc = rest
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
     resident = block_q is not None
+    lead = (0,) * (len(k_ref.shape) - 2)
     b, hkv, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     bq = block_q if resident else q_ref.shape[2]
-    bk = k_ref.shape[2]
+    bk = k_ref.shape[-2]
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
     fold = _exact_scale(scale)
     nt = (((1,), (1,)), ((), ()))     # x · yᵀ
@@ -549,77 +720,110 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
     def col():
         # folded: s = (k·scale)qᵀ here and dk = (Σ dsᵀ·q)·scale at the end
-        return (k_ref[0, 0] * scale if fold else k_ref[0, 0], v_ref[0, 0],
+        return (_head_lanes(k_ref[lead] * scale if fold else k_ref[lead],
+                            n),
+                _head_lanes(v_ref[lead], n),
                 kseg_ref[0] if has_segs else None)            # (bk, 1)
 
     def init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def tile(gi, qi, masked, ks, v, kseg):
+    def tile(gi, qi, masked, ks, vs, kseg):
+        if rows:
+            at = (0, _rows(qi, bq), slice(None))
+        elif resident:
+            at = (0, gi, _rows(qi, bq), slice(None))
+        else:
+            at = (0, 0)
         if resident:
-            rows, stat = (0, gi, _rows(qi, bq), slice(None)), (0, gi, qi)
             qseg = qseg_ref[0, qi] if has_segs else None      # (1, bq)
         else:
-            rows, stat = (0, 0), (0, 0, 0)
             qseg = qseg_ref[0, 0] if has_segs else None
-        q, do = q_ref[rows], do_ref[rows]
-        s = jax.lax.dot_general(ks, q, nt,
-                                preferred_element_type=jnp.float32)
-        if not fold:
-            s = s * scale
-        if has_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32).T
-        p = jnp.exp(s - lse_ref[stat])                        # (1, bq) rows
-        if masked:
-            mask = _mask_for(qi, ki, bq, bk, causal=causal,
-                             true_sq=true_sq, true_sk=true_sk, q_off=q_off,
-                             k_off=k_off, qseg=qseg, kseg=kseg,
-                             transposed=True)
-            p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(v, do, nt,
-                                 preferred_element_type=jnp.float32)
-        p_av = p
-        if dropout_p > 0.0:
-            # q head hkv·group + gi — the SAME salt, and the same (bq, bk)
-            # draw turned over, the forward used for this (b, h, qi, ki)
-            keep = _keep_tile(
-                sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b,
-                hkv * group + gi, dropout_p=dropout_p, n_h=n_h,
-                interp=interp, transposed=True)
-            inv = 1.0 / (1.0 - dropout_p)
-            p_av = jnp.where(keep, p * inv, 0.0)  # dv sees DROPPED probs
-            dp = jnp.where(keep, dp * inv, 0.0)
-        dv_acc[...] += jax.lax.dot_general(                  # p_avᵀ · do
-            p_av.astype(do.dtype), do, nn,
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dd_ref[stat])                          # δ − dlse
-        if not fold:
-            ds = ds * scale
-        dk_acc[...] += jax.lax.dot_general(                  # dsᵀ · q
-            ds.astype(q.dtype), q, nn,
-            preferred_element_type=jnp.float32)
+        q, do = q_ref[at], do_ref[at]
+        mask, dvs, dks = None, [], []
+        for t in range(n):
+            # head t's statistics: its (1, bq) rows
+            stat = (0, t if rows else gi, qi) if resident else (0, 0, 0)
+            s = jax.lax.dot_general(ks[t], q, nt,
+                                    preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            if has_bias:
+                s = s + bias_ref[0, 0].astype(jnp.float32).T
+            p = jnp.exp(s - lse_ref[stat])
+            if masked:
+                if mask is None:   # one mask a tile, whatever the head
+                    mask = _mask_for(qi, ki, bq, bk, causal=causal,
+                                     true_sq=true_sq, true_sk=true_sk,
+                                     q_off=q_off, k_off=k_off, qseg=qseg,
+                                     kseg=kseg, transposed=True)
+                p = jnp.where(mask, p, 0.0)
+            dp = jax.lax.dot_general(vs[t], do, nt,
+                                     preferred_element_type=jnp.float32)
+            p_av = p
+            if dropout_p > 0.0:
+                # q head hkv·group + gi (ROWS: the block's head t) — the
+                # SAME salt, and the same (bq, bk) draw turned over, the
+                # forward used for this (b, h, qi, ki)
+                keep = _keep_tile(
+                    sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b,
+                    hkv * n + t if rows else hkv * group + gi,
+                    dropout_p=dropout_p, n_h=n_h, interp=interp,
+                    transposed=True)
+                inv = 1.0 / (1.0 - dropout_p)
+                p_av = jnp.where(keep, p * inv, 0.0)  # dv sees DROPPED probs
+                dp = jnp.where(keep, dp * inv, 0.0)
+            if t == n - 1:
+                dv = dv_acc[...]
+            dvs.append(jax.lax.dot_general(                  # p_avᵀ · do
+                p_av.astype(do.dtype), do, nn,
+                preferred_element_type=jnp.float32))
+            if t == n - 1:
+                dv_acc[...] = dv + _own_lanes(dvs)
+            ds = p * (dp - dd_ref[stat])                      # δ − dlse
+            if not fold:
+                ds = ds * scale
+            if t == n - 1:
+                dk = dk_acc[...]
+            dks.append(jax.lax.dot_general(                  # dsᵀ · q
+                ds.astype(q.dtype), q, nn,
+                preferred_element_type=jnp.float32))
+        dk_acc[...] = dk + _own_lanes(dks)
 
     def finish():
         dk = dk_acc[...] * scale if fold else dk_acc[...]
+        if rows:    # dv waits in its accumulator for the step c = 1
+            dkv_ref[0] = dk.astype(dkv_ref.dtype)
+            return
         dk_ref[0, 0] = dk.astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
     if resident:
-        lo, a, b_, hi = _query_tiles(ki, bq, bk, true_sq, true_sk, q_off,
-                                     k_off, causal)
-        if has_segs or dropout_p > 0.0:
-            a = b_ = hi   # those operands are per tile by nature
-        init()
-        ops = col()
+        def run():
+            lo, a, b_, hi = _query_tiles(ki, bq, bk, true_sq, true_sk,
+                                         q_off, k_off, causal)
+            if has_segs or dropout_p > 0.0:
+                a = b_ = hi   # those operands are per tile by nature
+            init()
+            ops = col()
 
-        def head(gi):
-            _loop(lo, a, lambda qi: tile(gi, qi, True, *ops))
-            _loop(a, b_, lambda qi: tile(gi, qi, False, *ops))
-            _loop(b_, hi, lambda qi: tile(gi, qi, True, *ops))
+            def head(gi):
+                _loop(lo, a, lambda qi: tile(gi, qi, True, *ops))
+                _loop(a, b_, lambda qi: tile(gi, qi, False, *ops))
+                _loop(b_, hi, lambda qi: tile(gi, qi, True, *ops))
 
-        _loop(0, group, head)
-        finish()
+            _loop(0, group, head)
+            finish()
+
+        if not rows:
+            return run()
+        c = pl.program_id(3)
+        pl.when(c == 0)(run)
+
+        @pl.when(c == 1)
+        def _():
+            dkv_ref[0] = dv_acc[...].astype(dkv_ref.dtype)
         return
     gi, qi = pl.program_id(3), pl.program_id(4)
     pl.when((gi == 0) & (qi == 0))(init)
@@ -700,28 +904,47 @@ def _dbias_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dbias_ref[0, 0] = db_acc[...].astype(dbias_ref.dtype)
 
 
-def _geometry(q, k, block_q, block_k):
-    """The call's sizes, from shapes alone: true and tile sizes, tile
-    counts, padded head width, element size."""
-    B, Hq, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+def _sizes(B, Hq, Hkv, Sq, Sk, D, es, block_q, block_k, heads=0):
+    """A call's sizes: true and tile sizes, tile counts, padded head
+    width, element size; ``heads`` > 0: the ROWS layout with so many
+    heads to a 128-lane block, 0 the HEADS layout (``per_block``: 1)."""
     bq, bk = _block(Sq, block_q), _block(Sk, block_k)
     return dict(B=B, Hq=Hq, Hkv=Hkv, group=Hq // Hkv, Sq=Sq, Sk=Sk, D=D,
                 bq=bq, bk=bk, n_q=-(-Sq // bq), n_k=-(-Sk // bk),
-                Dp=-(-D // _LANES) * _LANES,
-                es=jnp.dtype(q.dtype).itemsize)
+                Dp=-(-D // _LANES) * _LANES, es=es, heads=heads,
+                per_block=max(heads, 1))
 
 
-def _prep(q, k, v, qseg, kseg, has_segs, block_q, block_k):
-    """Pad operands to block multiples; returns padded arrays + geometry."""
-    g = _geometry(q, k, block_q, block_k)
+def _geometry(q, k, block_q, block_k, heads=0):
+    """`_sizes` from the operands' shapes alone: ``q``, ``k`` (B, H, S, D)
+    or, ``heads`` > 0, ``q`` the packed (B, S, 3·H·D) array."""
+    es = jnp.dtype(q.dtype).itemsize
+    if heads:
+        B, S, W3 = q.shape
+        D = _LANES // heads
+        return _sizes(B, W3 // (3 * D), W3 // (3 * D), S, S, D, es,
+                      block_q, block_k, heads)
+    B, Hq, Sq, D = q.shape
+    return _sizes(B, Hq, k.shape[1], Sq, k.shape[2], D, es, block_q,
+                  block_k)
+
+
+def _prep(q, k, v, qseg, kseg, has_segs, block_q, block_k, heads=0):
+    """Pad operands to block multiples; returns padded arrays + geometry.
+    ROWS (``heads`` > 0): ``q`` is the packed array, padded along S only
+    where a tile is ragged, and stands for k and v too."""
+    g = _geometry(q, k, block_q, block_k, heads)
     B, bq, bk, n_q, n_k = g["B"], g["bq"], g["bk"], g["n_q"], g["n_k"]
-    qp, _ = pad_to(q, 2, bq)
-    qp, _ = pad_to(qp, 3, _LANES)
-    kp, _ = pad_to(k, 2, bk)
-    kp, _ = pad_to(kp, 3, _LANES)
-    vp, _ = pad_to(v, 2, bk)
-    vp, _ = pad_to(vp, 3, _LANES)
+    if heads:
+        qp, _ = pad_to(q, 1, max(n_q * bq, n_k * bk))
+        kp = vp = qp
+    else:
+        qp, _ = pad_to(q, 2, bq)
+        qp, _ = pad_to(qp, 3, _LANES)
+        kp, _ = pad_to(k, 2, bk)
+        kp, _ = pad_to(kp, 3, _LANES)
+        vp, _ = pad_to(v, 2, bk)
+        vp, _ = pad_to(vp, 3, _LANES)
     if has_segs:
         # each side's ids as a COLUMN (B, S, 1) and as tile ROWS
         # (B, n, 1, block), a tile's ids one index of a LEADING axis (a
@@ -750,8 +973,8 @@ def _kv_resident(g, has_bias=False):
     from apex1_tpu.vmem_model import budget_bytes, flash_kv_row_check
     return not has_bias and flash_kv_row_check(
         {"block_q": g["bq"], "block_k": g["bk"]},
-        {"Dp": g["Dp"], "Skp": g["n_k"] * g["bk"]}, g["es"],
-        budget_bytes())[0]
+        {"Dp": g["Dp"], "Skp": g["n_k"] * g["bk"],
+         "heads": g["per_block"]}, g["es"], budget_bytes())[0]
 
 
 def _q_resident(g, has_bias=False):
@@ -761,8 +984,8 @@ def _q_resident(g, has_bias=False):
     from apex1_tpu.vmem_model import budget_bytes, flash_q_row_check
     return not has_bias and flash_q_row_check(
         {"block_q": g["bq"], "block_k": g["bk"]},
-        {"Dp": g["Dp"], "Sqp": g["n_q"] * g["bq"], "group": g["group"]},
-        g["es"], budget_bytes())[0]
+        {"Dp": g["Dp"], "Sqp": g["n_q"] * g["bq"], "group": g["group"],
+         "heads": g["per_block"]}, g["es"], budget_bytes())[0]
 
 
 def _vmem(blk, imap):
@@ -774,7 +997,9 @@ def _common_specs(g, resident=False, transposed=False):
     or (b, h, qi) with the row's K/V (and key segment ids) whole in the
     ``resident`` form. The segment ids as dq's (bq, bk) tile takes them,
     queries a column and keys a row, or, ``transposed`` (the forward's
-    (bk, bq) tile), queries a row and keys a column (`_prep` makes both)."""
+    (bk, bq) tile), queries a row and keys a column (`_prep` makes both).
+    ROWS layout (``g["heads"]`` > 0, resident): grid (b, j, qi) over the
+    lane blocks of ONE packed array, ``kv_spec`` the pair (k's, v's)."""
     group, bq, bk, Dp = g["group"], g["bq"], g["bk"], g["Dp"]
     Skp, n_k = g["n_k"] * bk, g["n_k"]
     q_spec = _vmem((1, 1, bq, Dp), lambda b, h, qi, *_: (b, h, qi, 0))
@@ -796,6 +1021,14 @@ def _common_specs(g, resident=False, transposed=False):
         kseg_spec = (_vmem((1, bk, 1), lambda b, h, qi, ki: (b, ki, 0))
                      if transposed else
                      _vmem((1, 1, 1, bk), lambda b, h, qi, ki: (b, ki, 0, 0)))
+    if g["heads"]:
+        n, W = g["heads"], g["Hq"] // g["heads"]
+        q_spec = _vmem((1, bq, _LANES), lambda b, j, qi: (b, qi, j))
+        kv_spec = tuple(_vmem((1, Skp, _LANES),
+                              lambda b, j, qi, at=at: (b, 0, at + j))
+                        for at in (W, 2 * W))
+        stat_spec = _vmem((1, n, 1, 1, bq),
+                          lambda b, j, qi: (b, j, qi, 0, 0))
     off_spec = pl.BlockSpec((1, 1), lambda *_: (0, 0),
                             memory_space=pltpu.SMEM)
     return q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec
@@ -807,7 +1040,11 @@ def _dkv_specs(g, resident=False):
     the ``resident`` form, grid (b, hkv, ki), the q-side blocks are the
     group's whole rows. The statistics are (B, Hq, n_q, 1, bq) arrays
     and the query segment ids (B, n_q, 1, bq): a query tile's values one
-    ROW along the lanes (`_tile_rows`), as the transposed tile takes."""
+    ROW along the lanes (`_stat_rows`), as the transposed tile takes.
+    ROWS layout (``g["heads"]`` > 0, resident): grid (b, j, ki, c) over
+    the lane blocks of ONE packed array, ``kv_spec`` the pair (k's, v's);
+    every operand's block at step (.., ki, c = 1) is the NEXT key tile's
+    (``ahead``), the output's block (the caller's) is dk's then dv's."""
     group, bq, bk, Dp, n_q = g["group"], g["bq"], g["bk"], g["Dp"], g["n_q"]
     kv_spec = _vmem((1, 1, bk, Dp), lambda b, hkv, ki, *_: (b, hkv, ki, 0))
     kseg_spec = _vmem((1, bk, 1), lambda b, hkv, ki, *_: (b, ki, 0))
@@ -816,7 +1053,8 @@ def _dkv_specs(g, resident=False):
                        lambda b, hkv, ki: (b, hkv, 0, 0))
         stat_spec = _vmem((1, group, n_q, 1, bq),
                           lambda b, hkv, ki: (b, hkv, 0, 0, 0))
-        qseg_spec = _vmem((1, n_q, 1, bq), lambda b, hkv, ki: (b, 0, 0, 0))
+        qseg_spec = _vmem((1, n_q, 1, bq),
+                          lambda b, hkv, ki, *_: (b, 0, 0, 0))
     else:
         q_spec = _vmem(
             (1, 1, bq, Dp),
@@ -826,6 +1064,30 @@ def _dkv_specs(g, resident=False):
             lambda b, hkv, ki, gi, qi: (b, hkv * group + gi, qi, 0, 0))
         qseg_spec = _vmem((1, 1, 1, bq),
                           lambda b, hkv, ki, gi, qi: (b, qi, 0, 0))
+    if g["heads"]:
+        n, W, n_k = g["heads"], g["Hq"] // g["heads"], g["n_k"]
+        last = g["B"] * W * n_k - 1
+
+        def ahead(b, j, ki, c):
+            # the step c = 1 reads no operand and is over at once: a
+            # fetch it started for the next key tile's step would stand
+            # exposed (2.1 ms a step of GPT-2 medium: PERF.md §6, PR 41).
+            # Its blocks ARE the next tile's, so the long step c = 0
+            # before it fetches them and nothing moves after it
+            at = jnp.minimum((b * W + j) * n_k + ki + c, last)
+            return at // (W * n_k), at // n_k % W, at % n_k
+
+        def spec(blk, imap):
+            return _vmem(blk, lambda *ids: imap(*ahead(*ids)))
+
+        q_spec = spec((1, n_q * bq, _LANES), lambda b, j, ki: (b, 0, j))
+        kv_spec = tuple(spec((1, bk, _LANES),
+                             lambda b, j, ki, at=at: (b, ki, at + j))
+                        for at in (W, 2 * W))
+        stat_spec = spec((1, n, n_q, 1, bq),
+                         lambda b, j, ki: (b, j, 0, 0, 0))
+        qseg_spec = spec((1, n_q, 1, bq), lambda b, j, ki: (b, 0, 0, 0))
+        kseg_spec = spec((1, bk, 1), lambda b, j, ki: (b, ki, 0))
     off_spec = pl.BlockSpec((1, 1), lambda *_: (0, 0),
                             memory_space=pltpu.SMEM)
     return q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec
@@ -914,19 +1176,35 @@ def _flash_fwd_impl(q, k, v, qseg, kseg, q_off, k_off,
 # set-up otherwise). What a trace depends on besides shapes — the form
 # and the interpreter — is a static argument, decided outside.
 _STATIC = ("scale", "causal", "has_segs", "block_q", "block_k",
-           "dropout_p", "resident", "interpret")
+           "dropout_p", "resident", "interpret", "heads")
+
+
+def _emit_form(g, resident):
+    """The form a call took, said once where it is traced (these calls
+    are jitted: once a shape and form) on the `obs` spine."""
+    from apex1_tpu.obs import spine
+    spine.emit("counter", "flash/form", value=1,
+               layout="rows" if g["heads"] else "heads",
+               heads_per_block=g["per_block"],
+               resident_kv=bool(resident[0]), resident_q=bool(resident[1]),
+               block_q=g["bq"], block_k=g["bk"])
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, qseg, kseg, q_off, k_off, seed, bias, *, scale,
               causal, has_segs, block_q, block_k, dropout_p, resident,
-              interpret):
+              interpret, heads=0):
+    """``heads`` > 0, the ROWS layout: ``q`` is the packed (B, S, 3·H·D)
+    array, ``k`` and ``v`` None; returns ``out`` (B, S, H·D) and lse AS
+    THE KERNELS KEEP IT, (B, H, n_q, 1, bq)."""
     qp, kp, vp, qs, ks, g = _prep(q, k, v, qseg, kseg, has_segs,
-                                  block_q, block_k)
+                                  block_q, block_k, heads)
     has_bias = bias is not None
+    _emit_form(g, (resident, _q_resident(g, has_bias)))
     q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec = \
         _common_specs(g, resident, transposed=True)
-    in_specs = [q_spec, kv_spec, kv_spec, off_spec, off_spec]
+    k_spec, v_spec = kv_spec if heads else (kv_spec, kv_spec)
+    in_specs = [q_spec, k_spec, v_spec, off_spec, off_spec]
     args = [qp, kp, vp, *_off_arrays(q_off, k_off)]
     if dropout_p > 0.0:
         in_specs += [off_spec]
@@ -940,31 +1218,35 @@ def _fwd_call(q, k, v, qseg, kseg, q_off, k_off, seed, bias, *, scale,
         args += [bp]
     Sqp = g["n_q"] * g["bq"]
     # the kernel's accumulator is outᵀ: written as it lies, (B, Hq, Dp, Sqp),
-    # and turned by XLA with the slice it makes anyway
+    # and turned by XLA with the slice it makes anyway; ROWS: turned once
+    # a program in the kernel and written where the caller reads it
     out_p, lse_p = kernel_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           true_sq=g["Sq"], true_sk=g["Sk"],
                           has_segs=has_segs, has_bias=has_bias,
                           n_k=g["n_k"],
                           block_k=g["bk"] if resident else None,
-                          **_drop_kw(dropout_p, g, interpret)),
+                          heads=heads, **_drop_kw(dropout_p, g, interpret)),
         name="flash_fwd",
-        grid=(g["B"], g["Hq"], g["n_q"]) + (() if resident
-                                            else (g["n_k"],)),
+        grid=(g["B"], g["Hq"] // g["per_block"], g["n_q"])
+        + (() if resident else (g["n_k"],)),
         in_specs=in_specs,
-        out_specs=(_vmem((1, 1, g["Dp"], g["bq"]),
+        out_specs=(q_spec if heads else
+                   _vmem((1, 1, g["Dp"], g["bq"]),
                          lambda b, h, qi, *_: (b, h, 0, qi)), stat_spec),
         out_shape=(
-            out_struct((g["B"], g["Hq"], g["Dp"], Sqp), q.dtype,
+            out_struct((g["B"], qp.shape[1], g["Hq"] * g["D"]) if heads
+                       else (g["B"], g["Hq"], g["Dp"], Sqp), q.dtype,
                        qp, kp, vp),
             out_struct((g["B"], g["Hq"], g["n_q"], 1, g["bq"]),
                        jnp.float32, qp, kp, vp)),
-        scratch_shapes=[
-            pltpu.VMEM((g["Dp"], g["bq"]), jnp.float32),
-            pltpu.VMEM((1, g["bq"]), jnp.float32),
-            pltpu.VMEM((1, g["bq"]), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((g["Dp"], g["bq"]), jnp.float32)]
+        + [pltpu.VMEM((heads, 1, g["bq"]) if heads else (1, g["bq"]),
+                      jnp.float32)] * 2,
         interpret=interpret,
     )(*args)
+    if heads:
+        return out_p[:, :g["Sq"]], lse_p
     out = jnp.swapaxes(out_p[:, :, :g["D"], :g["Sq"]], 2, 3)
     lse = lse_p.reshape(g["B"], g["Hq"], Sqp)[:, :, :g["Sq"]]
     return out, lse
@@ -1006,25 +1288,35 @@ def _flash_bwd_impl(scale, causal, has_segs, block_q, block_k, res, cts,
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("cast",))
 def _bwd_call(res, cts, bias, *, scale, causal, has_segs, block_q, block_k,
-              dropout_p, cast, resident, interpret):
+              dropout_p, cast, resident, interpret, heads=0):
     """(dq, dk, dv, dbias) by the dq, dk/dv and (with a bias) dbias
-    kernels; ``resident`` is the pair (dq's form, dk/dv's)."""
+    kernels; ``resident`` is the pair (dq's form, dk/dv's). ``heads`` >
+    0, the ROWS layout: ``res`` holds the packed array for q (None for k
+    and v), ``out`` (B, S, H·D) and lse as the kernels keep it; dq, dk
+    and dv come back as ONE (B, S, 3·H·D) array, in dq's place."""
     q, k, v, qseg, kseg, q_off, k_off, seed, out, lse = res
     dout, dlse = cts
     qp, kp, vp, qs, ks, g = _prep(q, k, v, qseg, kseg, has_segs,
-                                  block_q, block_k)
+                                  block_q, block_k, heads)
     Sqp = g["n_q"] * g["bq"]
-    dop, _ = pad_to(dout.astype(q.dtype), 2, g["bq"])
-    dop, _ = pad_to(dop, 3, g["Dp"])
-    # δ_i = Σ_d dout·out — padded regions are zero so no masking needed
-    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
-    # ds = p·(dp − δ + dlse): the two row terms as one, δ − dlse (δ itself
-    # where lse feeds nothing but the ring's merge, dlse = 0); rows whose
-    # lse is the NEG_INF sentinel (no live key; the padding) give p = 0
-    stat_args = [_stat_rows(lse, g, NEG_INF),
-                 _stat_rows(delta - dlse.astype(jnp.float32), g),
-                 *_off_arrays(q_off, k_off)]
+    if heads:
+        # δ is the dq kernel's to make, from dO and out as they lie
+        dop, _ = pad_to(dout.astype(q.dtype), 1, qp.shape[1])
+        outp, _ = pad_to(out, 1, qp.shape[1])
+        stat_args = [lse, outp, *_off_arrays(q_off, k_off)]
+    else:
+        dop, _ = pad_to(dout.astype(q.dtype), 2, g["bq"])
+        dop, _ = pad_to(dop, 3, g["Dp"])
+        # δ_i = Σ_d dout·out — padded regions are zero so no masking needed
+        delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        # ds = p·(dp − δ + dlse): the two row terms as one, δ − dlse (δ
+        # itself where lse feeds nothing but the ring's merge, dlse = 0);
+        # rows whose lse is the NEG_INF sentinel (no live key; the
+        # padding) give p = 0
+        stat_args = [_stat_rows(lse, g, NEG_INF),
+                     _stat_rows(delta - dlse.astype(jnp.float32), g),
+                     *_off_arrays(q_off, k_off)]
     n_seed = 0
     if dropout_p > 0.0:
         stat_args += [jnp.asarray(seed, jnp.int32).reshape(1, 1)]
@@ -1036,12 +1328,15 @@ def _bwd_call(res, cts, bias, *, scale, causal, has_segs, block_q, block_k,
                 true_sk=g["Sk"], has_segs=has_segs,
                 **_drop_kw(dropout_p, g, interpret))
 
-    # dq: grid (b, h, qi, ki), key axis innermost; resident: (b, h, qi)
+    # dq: grid (b, h, qi, ki), key axis innermost; resident: (b, h, qi);
+    # ROWS: (b, j, qi), the block written to the q lanes of a fresh
+    # (B, S, 3·H·D) array
     resident, q_resident = resident
     q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec = \
         _common_specs(g, resident)
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec,
-                off_spec, off_spec]
+    k_spec, v_spec = kv_spec if heads else (kv_spec, kv_spec)
+    in_specs = [q_spec, k_spec, v_spec, q_spec, stat_spec,
+                q_spec if heads else stat_spec, off_spec, off_spec]
     in_specs += [off_spec] * n_seed
     args = [qp, kp, vp, dop] + stat_args
     if has_segs:
@@ -1050,32 +1345,41 @@ def _bwd_call(res, cts, bias, *, scale, causal, has_segs, block_q, block_k,
     if has_bias:
         in_specs += [_bias_spec(g, Bb, Hb)]
         args += [bp]
+    stats = out_struct((g["B"], g["Hq"], g["n_q"], 1, g["bq"]), jnp.float32,
+                       qp, dop)
     dq = kernel_call(
         functools.partial(_bwd_dq_kernel, n_k=g["n_k"],
                           block_k=g["bk"] if resident else None,
-                          has_bias=has_bias, **kern),
+                          has_bias=has_bias, heads=heads, **kern),
         name="flash_dq",
-        grid=(g["B"], g["Hq"], g["n_q"]) + (() if resident
-                                            else (g["n_k"],)),
+        grid=(g["B"], g["Hq"] // g["per_block"], g["n_q"])
+        + (() if resident else (g["n_k"],)),
         in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=out_struct((g["B"], g["Hq"], Sqp, g["Dp"]), q.dtype,
-                             qp, kp, vp, dop),
+        out_specs=(q_spec, stat_spec) if heads else q_spec,
+        out_shape=(out_struct(qp.shape, q.dtype, qp, dop), stats) if heads
+        else out_struct((g["B"], g["Hq"], Sqp, g["Dp"]), q.dtype,
+                        qp, kp, vp, dop),
         scratch_shapes=[pltpu.VMEM((g["bq"], g["Dp"]), jnp.float32)],
         interpret=interpret,
-    )(*args)[:, :, :g["Sq"], :g["D"]]
+    )(*args)
+    if heads:
+        dq, stat_args[1] = dq
+    else:
+        dq = dq[:, :, :g["Sq"], :g["D"]]
 
     # dk/dv: grid (b, hkv, ki, gi, qi) — query axis innermost, GQA group
     # axis above it, so group accumulation happens in VMEM scratch and the
     # outputs are written at Hkv granularity (no Hq-sized fp32 partials);
     # resident: (b, hkv, ki), the same order as a loop in the kernel. The
     # kernel writes what the caller keeps: k.dtype where the caller would
-    # cast (the accumulator's one rounding, made in VMEM), fp32 otherwise
+    # cast (the accumulator's one rounding, made in VMEM), fp32 otherwise.
+    # ROWS: (b, j, ki, c), dk (c = 0) and dv (c = 1) into the k and v
+    # lanes of dq's array, handed over as an operand that stays in HBM
+    # and is the output
     q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec = \
         _dkv_specs(g, q_resident)
-    dk_dtype, dv_dtype = ((k.dtype, v.dtype) if cast
-                          else (jnp.float32, jnp.float32))
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec,
+    k_spec, v_spec = kv_spec if heads else (kv_spec, kv_spec)
+    in_specs = [q_spec, k_spec, v_spec, q_spec, stat_spec, stat_spec,
                 off_spec, off_spec]
     in_specs += [off_spec] * n_seed
     args = [qp, kp, vp, dop] + stat_args
@@ -1086,24 +1390,44 @@ def _bwd_call(res, cts, bias, *, scale, causal, has_segs, block_q, block_k,
         in_specs += [_bias_spec(g, Bb, Hb, dkv=True)]
         args += [bp]
     Skp = g["n_k"] * g["bk"]
-    dk, dv = kernel_call(
-        functools.partial(_bwd_dkv_kernel, n_q=g["n_q"], group=g["group"],
-                          block_q=g["bq"] if q_resident else None,
-                          has_bias=has_bias, **kern),
-        name="flash_dkv",
-        grid=(g["B"], g["Hkv"], g["n_k"]) + (() if q_resident
-                                             else (g["group"], g["n_q"])),
-        in_specs=in_specs,
-        out_specs=(kv_spec, kv_spec),
-        out_shape=(
+    if heads:
+        W = g["Hq"] // heads
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)]
+        args += [dq]
+        out_specs = _vmem((1, g["bk"], _LANES),
+                          lambda b, j, ki, c: (b, ki, (1 + c) * W + j))
+        out_shape = out_struct(qp.shape, q.dtype, qp, dop)
+        grid = (g["B"], W, g["n_k"], 2)
+        alias = {"input_output_aliases": {len(args) - 1: 0}}
+    else:
+        dk_dtype, dv_dtype = ((k.dtype, v.dtype) if cast
+                              else (jnp.float32, jnp.float32))
+        out_specs = (kv_spec, kv_spec)
+        out_shape = (
             out_struct((g["B"], g["Hkv"], Skp, g["Dp"]), dk_dtype,
                        qp, kp, vp, dop),
             out_struct((g["B"], g["Hkv"], Skp, g["Dp"]), dv_dtype,
-                       qp, kp, vp, dop)),
+                       qp, kp, vp, dop))
+        grid = (g["B"], g["Hkv"], g["n_k"]) + (
+            () if q_resident else (g["group"], g["n_q"]))
+        alias = {}
+    dkv = kernel_call(
+        functools.partial(_bwd_dkv_kernel, n_q=g["n_q"], group=g["group"],
+                          block_q=g["bq"] if q_resident else None,
+                          has_bias=has_bias, heads=heads, **kern),
+        name="flash_dkv",
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((g["bk"], g["Dp"]), jnp.float32),
                         pltpu.VMEM((g["bk"], g["Dp"]), jnp.float32)],
         interpret=interpret,
+        **alias,
     )(*args)
+    if heads:
+        return dkv[:, :g["Sq"]], None, None, None
+    dk, dv = dkv
     dk = dk[:, :, :g["Sk"], :g["D"]]
     dv = dv[:, :, :g["Sk"], :g["D"]]
 
@@ -1181,6 +1505,45 @@ def _flash_bwd(scale, causal, has_segs, block_q, block_k, dropout_p,
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+# The ROWS layout's entry (`fmha`; `flash_form` says when): the packed
+# (B, S, 3·H·D) array in, ``out`` (B, S, H·D) back, and the gradient ONE
+# array of the packed shape. Residuals are the array, ``out`` and lse as
+# they lie: nothing padded or turned is kept.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 13)))
+def _flash_rows(qkv, qseg, kseg, q_off, k_off, seed,
+                scale, causal, has_segs, block_q, block_k, dropout_p,
+                heads):
+    return _flash_rows_fwd(qkv, qseg, kseg, q_off, k_off, seed, scale,
+                           causal, has_segs, block_q, block_k, dropout_p,
+                           heads)[0]
+
+
+def _flash_rows_fwd(qkv, qseg, kseg, q_off, k_off, seed,
+                    scale, causal, has_segs, block_q, block_k, dropout_p,
+                    heads):
+    out, lse = _fwd_call(qkv, None, None, qseg, kseg, q_off, k_off, seed,
+                         None, scale=scale, causal=causal,
+                         has_segs=has_segs, block_q=block_q,
+                         block_k=block_k, dropout_p=dropout_p,
+                         resident=True, interpret=interpret_mode(),
+                         heads=heads)
+    return out, (qkv, None, None, qseg, kseg, q_off, k_off, seed, out, lse)
+
+
+def _flash_rows_bwd(scale, causal, has_segs, block_q, block_k, dropout_p,
+                    heads, res, dout):
+    dqkv = _bwd_call(res, (dout, None), None, scale=scale, causal=causal,
+                     has_segs=has_segs, block_q=block_q, block_k=block_k,
+                     dropout_p=dropout_p, cast=True, resident=(True, True),
+                     interpret=interpret_mode(), heads=heads)[0]
+    f0 = lambda x: np.zeros(jnp.shape(x), dtype=jax.dtypes.float0)
+    return (dqkv,) + tuple(f0(x) for x in res[3:8])
+
+
+_flash_rows.defvjp(_flash_rows_fwd, _flash_rows_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12, 13, 14))
@@ -1274,6 +1637,19 @@ def _norm_segments(segment_ids, Sq, Sk):
     return True, qseg, kseg
 
 
+def _dropout_args(dropout_p, dropout_seed):
+    """``(p, seed)`` as the kernels take them, or why not."""
+    dropout_p = float(dropout_p)
+    if not 0.0 <= dropout_p < 1.0:
+        raise ValueError(f"dropout_p must be in [0, 1), got {dropout_p}")
+    if dropout_p > 0.0 and dropout_seed is None:
+        raise ValueError("dropout_p > 0 needs an explicit int32 "
+                         "dropout_seed (ops.stochastic.seed_from_key / "
+                         "fold_seed at the call site)")
+    return dropout_p, (jnp.asarray(dropout_seed, jnp.int32)
+                       if dropout_p > 0.0 else jnp.zeros((), jnp.int32))
+
+
 def flash_attention(q, k, v, *, causal: bool = False, segment_ids=None,
                     sm_scale: float | None = None, q_offset=0, k_offset=0,
                     block_q: int | None = None, block_k: int | None = None,
@@ -1344,15 +1720,7 @@ def flash_attention(q, k, v, *, causal: bool = False, segment_ids=None,
                 or bias.shape[2:] != (Sq, Sk)):
             raise ValueError(f"bias shape {bias.shape} must be "
                              f"(1|{B}, 1|{Hq}, {Sq}, {Sk})")
-    dropout_p = float(dropout_p)
-    if not 0.0 <= dropout_p < 1.0:
-        raise ValueError(f"dropout_p must be in [0, 1), got {dropout_p}")
-    if dropout_p > 0.0 and dropout_seed is None:
-        raise ValueError("dropout_p > 0 needs an explicit int32 "
-                         "dropout_seed (ops.stochastic.seed_from_key / "
-                         "fold_seed at the call site)")
-    seed = (jnp.asarray(dropout_seed, jnp.int32) if dropout_p > 0.0
-            else jnp.zeros((), jnp.int32))
+    dropout_p, seed = _dropout_args(dropout_p, dropout_seed)
     if use_pallas():
         dummy = jnp.zeros((1, 1), jnp.int32)
         if bias is not None:
@@ -1380,13 +1748,46 @@ def flash_attention(q, k, v, *, causal: bool = False, segment_ids=None,
 
 def fmha(qkv, *, segment_ids=None, causal: bool = True,
          sm_scale: float | None = None, dropout_p: float = 0.0,
-         dropout_seed=None):
+         dropout_seed=None, q_offset=0, k_offset=0,
+         block_q: int | None = None, block_k: int | None = None):
     """``apex.contrib.fmha.FMHAFun`` equivalent: packed (B, S, 3, H, D)
-    QKV, varlen via ``segment_ids`` instead of cu_seqlens. No seqlen-512 or
-    head-dim-64 cap — the flash kernel serves all sizes. ``dropout_p``
-    is the reference's in-kernel probability dropout (seeded, fused)."""
+    QKV in, (B, S, H, D) out, varlen via ``segment_ids`` instead of
+    cu_seqlens. No seqlen-512 or head-dim-64 cap — the flash kernel
+    serves all sizes. ``dropout_p`` is the reference's in-kernel
+    probability dropout (seeded, fused); ``q_offset``/``k_offset`` and
+    ``block_q``/``block_k`` as `flash_attention`'s.
+
+    The packed array is what a fused qkv projection leaves, (B, S, 3·H·D)
+    seen as five axes, and where `flash_form` allows (whole heads to a
+    128-lane block, rows that fit VMEM) the kernels read it AS IT LIES
+    and write ``out`` as the output projection reads it — the ROWS
+    layout: no split, pad or transpose in XLA, forward or backward, and
+    the gradient one packed array. Otherwise the array is turned to
+    (B, H, S, D) and `flash_attention` runs. Both reshapes around a call
+    (to five axes and back) cancel in XLA."""
+    B, S, _, H, D = qkv.shape
+    io_dtype = qkv.dtype
+    if use_pallas():
+        packed = to_mosaic(qkv)
+        bq, bk = _auto_blocks(D, block_q, block_k, packed.dtype, S)
+        form = flash_form(H, H, S, S, D, packed=True, block_q=bq,
+                          block_k=bk, dtype=packed.dtype)
+        if form["layout"] == "rows":
+            dropout_p, seed = _dropout_args(dropout_p, dropout_seed)
+            has_segs, qseg, kseg = _norm_segments(segment_ids, S, S)
+            dummy = jnp.zeros((1, 1), jnp.int32)
+            out = _flash_rows(
+                packed.reshape(B, S, 3 * H * D),
+                qseg if has_segs else dummy, kseg if has_segs else dummy,
+                q_offset, k_offset, seed,
+                1.0 / float(np.sqrt(D)) if sm_scale is None
+                else float(sm_scale), causal, has_segs, bq, bk, dropout_p,
+                form["heads_per_block"])
+            return out.reshape(B, S, H, D).astype(io_dtype)
     q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))
     out = flash_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                           sm_scale=sm_scale, dropout_p=dropout_p,
-                          dropout_seed=dropout_seed)
+                          dropout_seed=dropout_seed, q_offset=q_offset,
+                          k_offset=k_offset, block_q=block_q,
+                          block_k=block_k)
     return out.transpose(0, 2, 1, 3)

@@ -72,14 +72,21 @@ def flash_kv_row_check(blocks, dims, es, budget):
     in and dq out, the two fp32 statistics ((1, bq) rows, a sublane tile
     of 8 each, and the (bq, 128) columns they are turned into), the fp32
     accumulator, and the live fp32 s, p, dp and ds tiles. Where it does
-    not fit the key axis stays a grid axis."""
+    not fit the key axis stays a grid axis. ``dims["heads"]`` (default
+    1): the ROWS layout's heads to a 128-lane block (`ops.attention.
+    flash_form` decides with THIS function). The row, the blocks and the
+    accumulator are the same bytes at any count (two heads of 64 lie
+    where one padded head lay); what grows a head is its own zeroed
+    copies of q and dO, its statistics and its live tiles, the heads of
+    a block running through one loop body."""
     bq, bk = blocks["block_q"], blocks["block_k"]
-    dp = dims["Dp"]
+    dp, n = dims["Dp"], dims.get("heads", 1)
     est = (DB * es * 2 * dims["Skp"] * dp          # the row's K and V
            + 3 * DB * es * bq * dp                 # q, dO in, dq out
-           + 2 * (DB * 4 * 8 * bq + 4 * bq * LANES)  # lse, delta - dlse
+           + (n - 1) * 2 * es * bq * dp            # q, dO zeroed a head
+           + n * 2 * (DB * 4 * 8 * bq + 4 * bq * LANES)  # lse, delta - dlse
            + 4 * bq * dp                           # dq accumulator
-           + 4 * 4 * bq * bk)                      # s, p, dp, ds tiles
+           + n * 4 * 4 * bq * bk)                  # s, p, dp, ds tiles
     return est <= budget, est
 
 
@@ -90,22 +97,26 @@ def flash_q_row_check(blocks, dims, es, budget):
     ((1, bq) rows along the lanes, a sublane tile of 8 each)
     double-buffered beside the k, v blocks in, the dk, dv blocks out
     (priced fp32), the two fp32 accumulators and the live s, p, dp and
-    ds tiles."""
+    ds tiles. ``dims["heads"]`` as in `flash_kv_row_check`: a head more
+    is its statistics' rows, its zeroed copies of k and v and its live
+    tiles."""
     bq, bk = blocks["block_q"], blocks["block_k"]
-    dp = dims["Dp"]
+    dp, n = dims["Dp"], dims.get("heads", 1)
     est = (DB * dims["group"] * dims["Sqp"]
-           * (2 * es * dp + 2 * 4 * 8)             # Q, dO, lse, delta - dlse
+           * (2 * es * dp + n * 2 * 4 * 8)         # Q, dO, lse, delta - dlse
            + 2 * DB * es * bk * dp                 # k, v in
+           + (n - 1) * 2 * es * bk * dp            # k, v zeroed a head
            + 2 * DB * 4 * bk * dp                  # dk, dv out
            + 2 * 4 * bk * dp                       # dk, dv accumulators
-           + 4 * 4 * bq * bk)                      # s, p, dp, ds tiles
+           + n * 4 * 4 * bq * bk)                  # s, p, dp, ds tiles
     return est <= budget, est
 
 
 def flash_check(blocks, dims, es, budget):
     """Flash attention frame at key length ``Sb``: the resident form's
     (`flash_kv_row_check`, which `ops.attention` takes wherever it
-    fits: the same function decides there), else the grid form's."""
+    fits: the same function decides there, ``dims["heads"]`` and all),
+    else the grid form's."""
     if "Sb" in dims:     # an entry keyed on the head width alone: any length
         ok, est = flash_kv_row_check(blocks, {**dims, "Skp": dims["Sb"]},
                                      es, budget)

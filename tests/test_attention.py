@@ -414,3 +414,207 @@ def test_tile_plan_of_the_training_cell():
     assert tile_plan(1024, 1024, 512, 512) == (1, 2, 1)
     assert tile_plan(1024, 1024, 256, 256) == (6, 4, 6)
     assert tile_plan(1024, 1024, 128, 128) == (28, 8, 28)
+
+
+# ---------------------------------------------------------------------------
+# the ROWS layout: `fmha` reads the packed array as it lies (`flash_form`)
+# ---------------------------------------------------------------------------
+
+def _packed(rng, B, S, H, D, dtype=jnp.float32):
+    return jnp.asarray(rng.normal(size=(B, S, 3, H, D)), dtype)
+
+
+def _heads_path(qkv, **kw):
+    """What `fmha` did before the rows layout, and still does where the
+    layout does not apply: turn the array, run `flash_attention` (B, H,
+    S, D), turn the result back."""
+    q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))
+    return flash_attention(q, k, v, **kw).transpose(0, 2, 1, 3)
+
+
+# head width -> heads to a 128-lane block; S = 80 in tiles of 16 x 32 is
+# ragged on both sides (5 and 2.5 tiles), S = 96 in 32 x 32 is not
+_ROW_CASES = {
+    "two_to_a_block": dict(H=4, D=64),
+    "four_to_a_block": dict(H=8, D=32),
+    "one_to_a_block": dict(H=2, D=128),
+    "whole_tiles": dict(H=2, D=64, S=96, block_q=32, block_k=32),
+    "not_causal": dict(H=2, D=64, causal=False),
+    "wide_q_tile": dict(H=2, D=64, block_q=32, block_k=16),
+    "ring_offsets": dict(H=2, D=64, q_offset=48, k_offset=16),
+    "keys_ahead": dict(H=2, D=64, q_offset=0, k_offset=32),
+    "segments": dict(H=2, D=64, segs=True),
+    "dropout": dict(H=4, D=64, dropout_p=0.25),
+    "scale_other": dict(H=2, D=64, sm_scale=0.3),
+    "lane_tiles": dict(H=2, D=64, S=256, block_q=128, block_k=128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROW_CASES))
+def test_rows_layout_parity(rng, case):
+    """`fmha` in the ROWS layout against the XLA composite AND against
+    the (B, H, S, D) kernels, forward and the gradient of every third of
+    the packed array (dq, dk, dv); the form read off `flash_form` and off
+    the grids' ranks (forward and dq (b, j, qi), dk/dv (b, j, ki, c))."""
+    from apex1_tpu.ops.attention import flash_form
+    c = dict(_ROW_CASES[case])
+    H, D, S = c.pop("H"), c.pop("D"), c.pop("S", 80)
+    qkv = _packed(rng, 2, S, H, D)
+    kw = dict(causal=c.pop("causal", True), block_q=c.pop("block_q", 16),
+              block_k=c.pop("block_k", 32),
+              q_offset=c.pop("q_offset", 0), k_offset=c.pop("k_offset", 0))
+    if c.pop("segs", False):
+        kw["segment_ids"] = jnp.asarray(np.arange(S) // 24)[None].repeat(2, 0)
+    if "dropout_p" in c:
+        # the mask is keyed on the TRUE head index: the same seed draws
+        # the head layout's mask, or the gradients could not agree
+        kw.update(dropout_p=c.pop("dropout_p"), dropout_seed=1234)
+    if "sm_scale" in c:
+        kw["sm_scale"] = c.pop("sm_scale")
+    assert not c
+    w = jnp.asarray(rng.normal(size=(2, S, H, D)), jnp.float32)
+
+    def run(fn, impl):
+        def f(qkv):
+            with force_impl(impl):
+                return jnp.sum(fn(qkv, **kw).astype(jnp.float32) * w)
+        return jax.value_and_grad(f)(qkv)
+
+    form = flash_form(H, H, S, S, D, packed=True, block_q=kw["block_q"],
+                      block_k=kw["block_k"], dtype=jnp.float32)
+    assert form == dict(layout="rows", heads_per_block=128 // D,
+                        resident=(True, True),
+                        blocks=(kw["block_q"], kw["block_k"]))
+    (lr, gr), (lx, gx), (lh, gh) = (run(fmha, "pallas"), run(fmha, "xla"),
+                                    run(_heads_path, "pallas"))
+    np.testing.assert_allclose(lr, lx, rtol=1e-5)
+    np.testing.assert_allclose(gr, gx, rtol=1e-4, atol=2e-5)
+    np.testing.assert_allclose(lr, lh, rtol=1e-5)
+    np.testing.assert_allclose(gr, gh, rtol=1e-5, atol=2e-6)
+    with force_impl("pallas"):
+        ranks = _grid_ranks(jax.grad(lambda x: jnp.sum(fmha(x, **kw))), qkv)
+    assert sorted(ranks) == [3, 3, 4]
+
+
+def test_rows_layout_bf16_and_the_shapes_it_returns(rng):
+    qkv = _packed(rng, 2, 48, 2, 64, jnp.bfloat16)
+    with force_impl("pallas"):
+        got, grad = jax.value_and_grad(
+            lambda x: jnp.sum(fmha(x).astype(jnp.float32)))(qkv)
+        out = fmha(qkv)
+    with force_impl("xla"):
+        want = fmha(qkv)
+    assert out.shape == (2, 48, 2, 64) and out.dtype == jnp.bfloat16
+    assert grad.shape == qkv.shape and grad.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               want.astype(jnp.float32), rtol=2e-2,
+                               atol=2e-2)
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("odd_heads", dict(H=3, D=64)),          # 3 heads do not fill 2-blocks
+    ("wide_head", dict(H=2, D=80)),          # 128 % 80
+    ("over_a_block", dict(H=2, D=256)),
+    ("rows_too_long", dict(H=2, D=64, budget=200_000)),
+])
+def test_rows_layout_falls_back_to_the_heads_form(rng, monkeypatch, why, kw):
+    """Where whole heads do not fill 128-lane blocks, or the rows do not
+    fit VMEM, `fmha` turns its array and runs the (B, H, S, D) kernels:
+    the parent's form, by `flash_form` and by the grids' ranks, and the
+    composite's numbers."""
+    from apex1_tpu.ops.attention import flash_form
+    H, D = kw["H"], kw["D"]
+    if "budget" in kw:
+        import apex1_tpu.vmem_model as vm
+        monkeypatch.setattr(vm, "budget_bytes", lambda *a: kw["budget"])
+    form = flash_form(H, H, 80, 80, D, packed=True, block_q=16, block_k=32,
+                      dtype=jnp.float32)
+    assert form["layout"] == "heads" and form["heads_per_block"] == 1
+    qkv = _packed(rng, 2, 80, H, D)
+    call = lambda x: jnp.sum(fmha(x, block_q=16, block_k=32))
+    with force_impl("pallas"):
+        ranks = _grid_ranks(jax.grad(call), qkv)
+        got = jax.grad(call)(qkv)
+    assert sorted(ranks) == ([4, 4, 5] if "budget" in kw else [3, 3, 3])
+    with force_impl("xla"):
+        want = jax.grad(call)(qkv)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-5)
+
+
+def test_flash_form_of_what_is_not_the_packed_array():
+    """(B, H, S, D) operands have been turned already, a bias keeps the
+    grid, and GQA in the rows layout is not taken: each is the heads
+    form, whatever the head width."""
+    from apex1_tpu.ops.attention import flash_form
+    assert flash_form(16, 16, 1024, 1024, 64)["layout"] == "heads"
+    assert flash_form(16, 16, 1024, 1024, 64, packed=True,
+                      has_bias=True) == dict(
+        layout="heads", heads_per_block=1, resident=(False, False),
+        blocks=(512, 512))
+    assert flash_form(32, 8, 1024, 1024, 64,
+                      packed=True)["layout"] == "heads"
+
+
+def test_flash_form_of_the_training_cell():
+    """GPT-2 medium's call: 16 heads of 64 at S = 1024, bfloat16: two
+    heads to a block, all three kernels resident, 512 x 512 tiles. GPT-2
+    small's 12 heads fill six blocks the same way; four heads of 32 to a
+    block at these tiles would not fit beside the row and fall back."""
+    from apex1_tpu.ops.attention import flash_form
+    want = dict(layout="rows", heads_per_block=2, resident=(True, True),
+                blocks=(512, 512))
+    assert flash_form(16, 16, 1024, 1024, 64, packed=True) == want
+    assert flash_form(12, 12, 1024, 1024, 64, packed=True) == want
+    assert flash_form(16, 16, 1024, 1024, 32,
+                      packed=True)["layout"] == "heads"
+    assert flash_form(16, 16, 1024, 1024, 32, packed=True, block_q=256,
+                      block_k=256)["heads_per_block"] == 4
+
+
+def test_flash_form_is_said_on_the_spine(rng, tmp_path):
+    """A traced call says the form it took, once, as the counter
+    `flash/form`: the rows layout for the packed array, the heads layout
+    for (B, H, S, D)."""
+    from apex1_tpu.obs import spine
+    run = spine.ObsRun(str(tmp_path), component="test")
+    old = spine.default_run()
+    spine.set_default_run(run)
+    try:
+        with force_impl("pallas"):
+            # shapes no other test uses: the calls are jitted, and a trace
+            # another test made would say nothing here
+            jax.grad(lambda x: jnp.sum(fmha(x, block_q=16, block_k=16)))(
+                _packed(rng, 1, 40, 2, 64))
+            q, k, v = _qkv(rng, B=1, Hq=3, Sq=40, D=16)
+            flash_attention(q, k, v, block_q=16, block_k=16)
+    finally:
+        spine.set_default_run(old)
+        run.close()
+    said = [e for e in spine.read_events(run.path, kinds=("counter",))
+            if e["name"] == "flash/form"]
+    assert [(e["layout"], e["heads_per_block"], e["resident_kv"],
+             e["resident_q"], e["block_q"], e["block_k"]) for e in said] == [
+        ("rows", 2, True, True, 16, 16), ("heads", 1, True, True, 16, 16)]
+
+
+def test_gpt2_training_path_takes_the_rows_layout(rng):
+    """`models.gpt2.Block` hands the qkv product's output to `fmha`: with
+    the kernels on, no transpose of a per-head array is left between the
+    qkv product and `proj`, forward or backward."""
+    from apex1_tpu.models.gpt2 import GPT2, GPT2Config
+    model = GPT2(GPT2Config.tiny(num_heads=2, hidden_size=128))
+    toks = jnp.asarray(rng.integers(0, 256, (2, 32)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), toks)["params"]
+
+    def loss(p):
+        return jnp.sum(model.apply({"params": p}, toks).astype(jnp.float32))
+
+    with force_impl("pallas"):
+        text = str(jax.make_jaxpr(jax.grad(loss))(params))
+        ranks = _grid_ranks(jax.grad(loss), params)
+    assert "transpose[permutation=(0, 2, 1, 3)]" not in text
+    # two layers: forward and dq (b, j, qi), dk/dv (b, j, ki, c)
+    assert sorted(r for r in ranks if r >= 3) == [3, 3, 3, 3, 4, 4]
+    with force_impl("xla"):
+        assert "transpose[permutation=(0, 2, 1, 3)]" in str(
+            jax.make_jaxpr(jax.grad(loss))(params))

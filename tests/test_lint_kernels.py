@@ -927,6 +927,46 @@ class TestVmemModelShared:
         for name, spec in SPECS.items():
             assert spec.check is CHECKS[name], name
 
+    @pytest.mark.parametrize("heads", [1, 2, 4])
+    def test_flash_rows_layout_is_priced_by_the_deciding_functions(
+            self, heads):
+        """The ROWS layout's heads to a 128-lane block (PR 41) are a key of
+        the SAME resident checks `ops.attention.flash_form` decides with:
+        one head is the frozen frame bit for bit (the registry's
+        `flash_check` included, which hands the key through), each head
+        more costs its statistics, its zeroed operands and its live
+        tiles, and the training cell's two heads of 64 fit a v5e where
+        four at the same tiles do not."""
+        from apex1_tpu.vmem_model import (budget_bytes, flash_check,
+                                          flash_kv_row_check,
+                                          flash_q_row_check)
+        blocks = {"block_q": 512, "block_k": 512}
+        v5e = budget_bytes("v5e")
+        kv = {"Dp": 128, "Skp": 1024}
+        qr = {"Dp": 128, "Sqp": 1024, "group": 1}
+        got_kv = flash_kv_row_check(blocks, {**kv, "heads": heads}, 2, v5e)
+        got_q = flash_q_row_check(blocks, {**qr, "heads": heads}, 2, v5e)
+        assert flash_check(blocks, {"Dp": 128, "Sb": 1024, "heads": heads},
+                           2, v5e) == (got_kv if got_kv[0] else
+                                       flash_check(blocks, {"Dp": 128}, 2,
+                                                   v5e))
+        if heads == 1:
+            assert got_kv == flash_kv_row_check(blocks, kv, 2, v5e) \
+                == _orig_flash(blocks, {"Dp": 128, "Sb": 1024}, 2, v5e)
+            assert got_q == flash_q_row_check(blocks, qr, 2, v5e)
+            return
+        less_kv = flash_kv_row_check(blocks, {**kv, "heads": heads - 1}, 2,
+                                     v5e)
+        less_q = flash_q_row_check(blocks, {**qr, "heads": heads - 1}, 2,
+                                   v5e)
+        assert got_kv[1] > less_kv[1] and got_q[1] > less_q[1]
+        assert (got_kv[0], got_q[0]) == ((True, True) if heads == 2
+                                         else (False, False))
+        from apex1_tpu.ops.attention import flash_form
+        form = flash_form(8 * heads, 8 * heads, 1024, 1024, 128 // heads,
+                          packed=True)
+        assert (form["layout"] == "rows") == (heads == 2)
+
     def test_rdma_rule_reproduces_gate_data_points(self):
         """The previously comment-only 16*chunk*N rule, now falsifiable:
         the aot gate's passing shape fits v5e with margin, the measured

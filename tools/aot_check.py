@@ -170,7 +170,7 @@ def _run_checks(args) -> bool:
         from apex1_tpu.ops import (layer_norm, rms_norm,
                                    scaled_upper_triang_masked_softmax,
                                    softmax_cross_entropy_loss)
-        from apex1_tpu.ops.attention import flash_attention
+        from apex1_tpu.ops.attention import flash_attention, fmha
         from apex1_tpu.ops.linear_xent import linear_cross_entropy
         from apex1_tpu.ops.rope import apply_rotary_pos_emb, rope_tables
 
@@ -181,6 +181,15 @@ def _run_checks(args) -> bool:
                          (1, 32, 16384, 64))):
             check(f"{nm} fwd", fa, [shp] * 3)
             check(f"{nm} fwd+bwd", fa, [shp] * 3, grad=True)
+        # the ROWS layout (PR 41): the packed array as the qkv product
+        # leaves it, two heads of 64 to a 128-lane block (GPT-2 medium's
+        # call), with and without the in-kernel dropout
+        for nm, fn in (("", lambda x: fmha(x, causal=True)),
+                       (" dropout p=0.1", lambda x: fmha(
+                           x, causal=True, dropout_p=0.1,
+                           dropout_seed=1234))):
+            check(f"fmha rows gpt2m (8,1024,3,16,64){nm} fwd+bwd", fn,
+                  [(8, 1024, 3, 16, 64)], grad=True)
         # GQA (Hq/Hkv = 8): the dkv kernel accumulates the group in VMEM
         # and writes Hkv-sized fp32 outputs — temp must stay near the
         # group=1 case, not 8x it
