@@ -110,17 +110,27 @@ admission) and the engine NEVER reads a step's tokens back before
 dispatching the next — per-step outputs accumulate in a device-side
 log and are materialized once, at retirement. With an ``eos_id`` the
 engine must observe every token, but not before it launches the next
-step: the loop runs ONE STEP AHEAD (`Engine._decode_step`). `step()`
-launches step n+1, fed from device arrays, and only then reads the
-tokens of step n, whose device-to-host copy was started at their own
-launch; host and device work at the same time, one thread, JAX's
-asynchronous dispatch doing the overlapping. A lane leaves the batch by
+steps: the loop runs AHEAD OF THE READ (`Engine._decode_step`). `step()`
+launches a step, fed from device arrays, and only then reads the tokens
+of the oldest launch in flight, whose device-to-host copy was started at
+their own launch; host and device work at the same time, one thread,
+JAX's asynchronous dispatch doing the overlapping. How many launches
+stand behind the one that is read is the engine's DEPTH, and it is no
+option: where the step in flight outlasts the launch of the next
+(`packing.launch_is_hidden`, asked once at construction) the host waits
+for the device whatever it does, and the depth is 1: launch n+1, read n.
+Where the launch is not hidden, one launch in flight leaves the device
+idle for the rest of every launch (two steps share one lap of launch +
+loop + device step + a token's way to the host), and the depth is 2:
+launch n+2, read n, three steps to the lap, and the gap between two
+tokens falls to the longer of the device's step and the host's loop. A
+call reads ONE launch's tokens, never two. A lane leaves the batch by
 count at the launch of its last token and its request finishes where
-those tokens are read; an ``eos`` is learnt a step late, and the
-lane-step already in flight is dropped on the host and harmless on the
-device (the ordering argument is `_decode_step`'s docstring). A token
-exists for the outside when the host holds its value. Speculative mode
-reads each round back before the next (drafting needs the history;
+those tokens are read; an ``eos`` is learnt ``depth`` steps late, and the
+lane-steps already in flight for it are dropped on the host and harmless
+on the device (the ordering argument is `_decode_step`'s docstring). A
+token exists for the outside when the host holds its value. Speculative
+mode reads each round back before the next (drafting needs the history;
 accept counts gate retirement) and does not run ahead.
 
 SPANS AND COUNTS: every phase of `step()` is an `obs.spine.span` — the
@@ -128,14 +138,15 @@ engine's one way of naming a region (``serving/step`` > ``expire``,
 ``admit`` > ``admit.alloc`` / ``prefill`` / ``admit.register`` /
 ``admit.first_read`` / ``admit.patch``, ``decode_step`` or
 ``verify_step``, ``read_tokens`` (with an ``eos_id``: of the launch
-BEFORE this step's), ``emit`` > ``retire``; the table is in
+``depth`` before this step's), ``emit`` > ``retire``; the table is in
 docs/observability.md). The spans of one request share its id, the two
 in which the host waits for the device are marked ``wait``, and each
 ``serving/step`` carries what the step did (``admitted``, ``retired``,
 ``prefill_chunks``, ``prefill_tokens``, ``tokens_out``, ``n_active``,
-``queue_depth``), ``ran_ahead``: whether its launch came before the
-read of the launch before it, ``overrun_lanes``: lane-steps launched for
-a lane that had already sampled ``eos``, ``control_dispatches``: the
+``queue_depth``), ``ran_ahead``: the launches in flight, unread, when
+its own was made (0 to ``depth``), ``overrun_lanes``: lane-steps launched
+for a lane that had already sampled ``eos`` (up to ``depth`` an ``eos``),
+``control_dispatches``: the
 device programs it launched outside the two executables, and
 ``kv_blocks_read`` of
 ``kv_blocks_pool``: the blocks of K/V positions the step's attention
@@ -155,7 +166,8 @@ with it. The launch's span carries ``operands``, the arrays it hands
 over. Cutting the stacks apart costs the device a little every step, so
 a tree whose bytes alone take the device longer to stream than the
 launch takes the host (`packing.launch_is_hidden`) is handed over as it
-is: that launch lies under the step in flight.
+is: that launch lies under the step in flight. The same answer sets how
+far the plain loop runs ahead (ASYNC DISPATCH).
 
 RECURRENT STATE: a decoder's cache tree may hold, beside K/V, leaves
 that hold no positions (`models.generate.granite_hybrid_decoder`: a
@@ -170,15 +182,17 @@ not hold a shorter prefix and a rejected draft cannot be rolled back:
 construction. The step span of such an engine carries two more counts,
 ``state_lanes`` (the lanes whose state the launch updates) and
 ``state_bytes`` (what that moves: each of those lanes' recurrent leaves
-read once and written once). The one-step-ahead loop is as it is: an
-overrun lane-step advances a state that the next admission zeroes.
+read once and written once). The loop runs ahead as it does for any
+decoder: an overrun lane-step, or two, advances a state that the next
+admission zeroes, behind them in the device's order.
 
 EXPERTS: a decoder with sparse layers says so (``apply_fn.
 moe_expert_slots``: the experts it holds, summed over its sparse layers)
 and can be asked for a launch's routing counts. The plain step with an
 ``eos_id`` asks: the two counts ride behind the tokens in the ONE array
-the host reads (no copy more, no synchronisation more; they arrive a step
-late, as the tokens do), and the step span whose `read_tokens` brought
+the host reads (no copy more, no synchronisation more; they arrive as
+late as the tokens of their launch do), and the step span whose
+`read_tokens` brought
 them carries ``moe_rows`` (the (row, expert) pairs the launch computed
 here), ``moe_experts_touched`` (the held experts with at least one, which
 is what the launch had to stream) and ``moe_expert_slots``. A decoder
@@ -316,7 +330,7 @@ class _Slot:
     start_step: int              # engine step its first DECODE lands at
     n_out: int = 1               # tokens emitted so far (first included)
     n_launched: int = 1          # tokens launched for: n_out, and one more
-    #                              while a step's tokens are still unread
+    #                              for each launch still unread
     in_batch: bool = False       # in the decode batch: joined, and has
     eos_seen: bool = False       #  not left it by count or by retiring
     depth: int = 0               # cache positions the lane holds
@@ -499,10 +513,11 @@ class Engine:
             self._tally.update(moe_rows=0, moe_experts_touched=0,
                                moe_expert_slots=0)
         self._tok_log: Dict[int, object] = {}
-        # with an eos_id the loop runs ONE step ahead (`_decode_step`):
-        # the launch whose tokens the host has not read yet, as
-        # ``(tokens on the device, {lane: its _Slot at that launch})``
-        self._inflight: Optional[tuple] = None
+        # with an eos_id the loop runs AHEAD of the read (`_decode_step`):
+        # the launches whose tokens the host has not read yet, oldest
+        # first, each ``(tokens on the device, {lane: its _Slot at that
+        # launch})``
+        self._inflight: List[tuple] = []
         self._step_no = 0
         # the mid-admission cancel window: `cancel` from an ingest
         # thread while `_admit` runs this request's prefill chain. The
@@ -752,6 +767,12 @@ class Engine:
         step_others = (pool + len(self._lora_args()) + self._paged + 5
                        + self._spec)
         self._packed = packed = PackedParams(self.params, step_others)
+        # the launches the plain loop keeps in flight behind the read
+        # (`_decode_step`): where the step in flight outlasts the next
+        # launch the host waits for the device anyway and a second buys
+        # nothing; where it does not, a second takes the launch and a
+        # token's way to the host out of the gap between two tokens
+        self._depth = 1 if packed.layout.hidden else 2
         self._prefill = Executable(prefill, packed)
         if self._spec:
             self._verify = Executable(step, packed)
@@ -1044,8 +1065,8 @@ class Engine:
         """One engine iteration: retire (deadline/cancel) → admit → one
         decode (or speculative verify) step over every occupied slot.
         Returns the number of lanes the step was launched for (0 = none:
-        the engine is idle once this call has handed out whatever the
-        launch before it still owed, see `_decode_step`)."""
+        such a call hands out what the oldest launch in flight owes, and
+        the engine is idle once it leaves none, see `_decode_step`)."""
         with spine.span("serving/step") as sp:
             before = dict(self._tally)
             with spine.span("serving/expire"):
@@ -1064,7 +1085,7 @@ class Engine:
             if self._spec:
                 if n_active:
                     self._spec_step()
-            elif n_active or self._inflight is not None:
+            elif n_active or self._inflight:
                 self._decode_step()
             depth = self.scheduler.depth
             self.metrics.step_sample(n_active, self.cfg.max_slots, depth)
@@ -1142,56 +1163,67 @@ class Engine:
         return read, lanes
 
     def _decode_step(self):
-        """One plain decode step. With an ``eos_id`` the loop runs ONE
-        STEP AHEAD: this call launches step n+1 and only then hands out
-        the tokens of step n, whose copy to the host was started at its
-        own launch, so host and device work at the same time instead of
-        in turns. Step n+1 is fed from device arrays and needs nothing
-        the host learns from step n, but for this:
+        """One plain decode step. With an ``eos_id`` the loop runs AHEAD
+        OF THE READ: this call launches a step and only then hands out
+        the tokens of the OLDEST launch in flight, and only once
+        ``_depth`` later launches stand behind it (1: launch n+1, read
+        n; 2: launch n+2, read n). Their copy to the host was started
+        at their own launch, so host and device work at the same time
+        instead of in turns. A launch is fed from device arrays and
+        needs nothing the host learns from the launches before it, but
+        for this:
 
-        A lane that sampled ``eos_id`` in step n has step n+1 in flight
-        (an OVERRUN lane-step; a deadline or a cancel with a step in
-        flight is the same case). The host drops that lane-step's token
-        (the lane's `_Slot` is no longer the one it was launched for)
-        and its K/V row lands one position past the lane's end. That row
-        harms nobody: every reader of the pool stops at its own horizon,
-        an admission into the freed lane prefills from position 0 and
-        patches the lane's index, token and output position, and the
-        device runs its programs in the order they were launched, the
-        in-flight step before any later prefill into the lane (paged:
-        before `_sync_bt` points the freed row at the trash page, and
-        the row's own pages can have no new owner before that). It
-        cannot run off the lane: a lane is launched for only while
-        ``n_launched < max_new_tokens``, and `submit` holds the request's
-        last position inside ``max_len``.
+        A lane that sampled ``eos_id`` in step n has up to ``_depth``
+        later steps in flight (OVERRUN lane-steps; a deadline or a
+        cancel with launches in flight is the same case). The host drops
+        those lane-steps' tokens (the lane's `_Slot` is no longer the
+        one they were launched for, which holds for every launch in the
+        queue) and their K/V rows land on the positions past the lane's
+        end. Those rows harm nobody: every reader of the pool stops at
+        its own horizon, an admission into the freed lane prefills from
+        position 0 and patches the lane's index, token and output
+        position, and the device runs its programs in the order they
+        were launched, every step in flight before any later prefill
+        into the lane (paged: before `_sync_bt` points the freed row at
+        the trash page, and the row's own pages can have no new owner
+        before that). The argument is the device's order, not the number
+        of steps. They cannot run off the lane: a lane is launched for
+        only while ``n_launched < max_new_tokens``, and `submit` holds
+        the request's last position inside ``max_len``.
 
         What exists for the outside (``produced``, the ``token`` event,
         ``tokens_out``, a result) moves where the host READS a token,
-        never where it is launched. A call with nothing to launch hands
-        out what the last launch owes and leaves nothing in flight."""
+        never where it is launched, and a call reads one launch's
+        tokens, never two: a token's stamp is the return of the
+        `step()` that read it, so a gap is one call long. A call with
+        nothing to launch hands out what the oldest launch owes, and the
+        engine is idle once such a call leaves nothing in flight."""
         if self._defer:
             nxt, lanes = self._launch()
             self._tok_log[self._step_no - 1] = nxt     # fetched at retire
             self._emit(lanes, None)
             return
-        prev, self._inflight = self._inflight, None
-        if prev is not None and not any(
-                self._slots[i] is st for i, st in prev[1].items()):
-            prev = None             # every lane it ran for has retired
+        # a launch every lane of which has retired is dropped unread
+        flight = self._inflight = [
+            launch for launch in self._inflight
+            if any(self._slots[i] is st for i, st in launch[1].items())]
         if self._n_active:
-            self._inflight = self._launch()
-            self._tally["ran_ahead"] += prev is not None
-        if prev is None:
+            self._tally["ran_ahead"] += len(flight)
+            flight.append(self._launch())
+            if len(flight) <= self._depth:
+                return
+        elif not flight:
             return
+        toks, lanes = flight.pop(0)
         with spine.span("serving/read_tokens", wait=True):
-            toks = np.asarray(prev[0])
+            toks = np.asarray(toks)
         if self._moe_read:
-            # of the launch whose tokens these are: a step late, as they
+            # of the launch whose tokens these are: as late as they
             rows, touched = toks[self.cfg.max_slots:]
             self._tally["moe_rows"] += int(rows)
             self._tally["moe_experts_touched"] += int(touched)
             self._tally["moe_expert_slots"] += self._moe_slots
-        self._emit(prev[1], toks)
+        self._emit(lanes, toks)
 
     def _emit(self, lanes: dict, toks):
         """Hand out one launch's tokens, lane by lane, and retire what
@@ -1210,9 +1242,9 @@ class Engine:
                     st.history.append(tok)
                     if tok == self.cfg.eos_id:
                         st.eos_seen = True
-                        self._tally["overrun_lanes"] += (
-                            self._inflight is not None
-                            and self._inflight[1].get(i) is st)
+                        self._tally["overrun_lanes"] += sum(
+                            launch[1].get(i) is st
+                            for launch in self._inflight)
                         self._retire(i, "done", "eos")
                         continue
                 if st.n_out >= st.req.max_new_tokens:

@@ -34,9 +34,19 @@ overestimate for a batch so small that most experts idle; there the
 launch may not be hidden after all, and the rule wants the step's counts
 (`moe_experts_touched`) before it is trusted.
 
-Nothing here is an option: what is packed follows from what the tree
-holds and the device it is served from. A tree with no small leaves, or
-with one leaf of a kind, passes through unchanged.
+The same answer decides a second thing (`Layout.hidden`, read by
+`serving.Engine` at construction): how many launches the plain decode
+loop keeps in flight behind the read. Hidden, the host waits for the
+device whatever the launch costs, and one launch in flight is all that
+can help; a second would cost a step of every token's way out and a
+second overrun lane-step at every ``eos``. Not hidden, the device stands
+idle for part of every launch while one step is in flight, and the loop
+keeps two (`Engine._decode_step`).
+
+Nothing here is an option: what is packed, and how far the loop runs
+ahead, follow from what the tree holds and the device it is served from.
+A tree with no small leaves, or with one leaf of a kind, passes through
+unchanged.
 """
 
 from __future__ import annotations
@@ -122,12 +132,14 @@ def _stack(leaves: list):
 class Layout:
     """Where each leaf of a tree lies among the operands: static, holds
     no array. ``slots[i]`` is ``(operand, row)`` for the tree's i-th
-    leaf, ``row`` None for a leaf that is an operand of its own."""
+    leaf, ``row`` None for a leaf that is an operand of its own.
+    ``hidden`` is what `launch_is_hidden` said of the tree."""
 
     def __init__(self, tree, n_other: int = 0):
         leaves, self.treedef = jax.tree_util.tree_flatten(tree)
         groups = collections.defaultdict(list)
-        if not launch_is_hidden(leaves, n_other):
+        self.hidden = launch_is_hidden(leaves, n_other)
+        if not self.hidden:
             for i, leaf in enumerate(leaves):
                 key = _group_key(leaf)
                 if key is not None:

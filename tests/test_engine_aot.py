@@ -153,6 +153,10 @@ def _cell_engine(topo, num_draft):
         vocab_size=b.vocab_size, num_draft=num_draft, **traffic["engine"]))
     leaves = jax.tree_util.tree_leaves(eng.kv.cache)
     assert (len(leaves), leaves[0].shape) == (48, (48, 1152, 1024))
+    # 0.71 GB stream in 0.87 ms, less than their launch takes the host:
+    # not hidden, so the tree is packed and the plain loop keeps two
+    # launches in flight behind the read
+    assert not eng._packed.layout.hidden and eng._depth == 2
     return eng, params, place
 
 

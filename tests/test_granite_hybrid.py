@@ -395,6 +395,8 @@ def test_decode_step_updates_a_state_leaf_by_the_kernel_alone(topo, mosaic):
     assert eng.kv.cache["layer5"]["k"].shape == (8, 1280, 512)
     assert eng._state_lane_bytes == 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
     assert eng._packed.layout.groups == []
+    # hidden: the loop keeps the one launch in flight it kept before
+    assert eng._packed.layout.hidden and eng._depth == 1
     assert eng._n_operands["step"] == 466 + 80 + 5
     pool_bytes = eng.kv.pool_bytes()
     compiled = eng._decode.lower(

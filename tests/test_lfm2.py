@@ -493,6 +493,8 @@ def test_decode_step_of_the_cell_compiles_for_a_v5e(topo, mosaic):
     assert eng._state_lane_bytes == 18 * 3 * 2048 * 2
     assert eng._moe_slots == 8 * 22 and eng._moe_read
     assert eng._packed.layout.groups == []
+    # hidden: the loop keeps the one launch in flight it kept before
+    assert eng._packed.layout.hidden and eng._depth == 1
     pool_bytes = eng.kv.pool_bytes()
     compiled = eng._decode.lower(
         weights, place(eng.kv.cache),

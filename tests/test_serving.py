@@ -24,8 +24,7 @@ from apex1_tpu.serving import (Backpressure, Engine, EngineConfig,
                                ServingMetrics)
 
 
-@pytest.fixture(scope="module")
-def tiny():
+def _tiny():
     """Tiny fp32 GPT-2 + its decoder pair + a solo-generate oracle."""
     cfg = GPT2Config.tiny(policy=get_policy("O0"), max_seq_len=64)
     model = GPT2(cfg)
@@ -42,6 +41,11 @@ def tiny():
             vocab_size=cfg.vocab_size))[0]
 
     return cfg, params, apply_fn, make_cache, solo
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return _tiny()
 
 
 def _engine(tiny, **kw):
@@ -1363,23 +1367,146 @@ class TestEngineSpans:
 
 
 # --------------------------------------------------------------------------
-# the loop runs one step ahead (eos_id set): step n+1 is launched before
-# step n's tokens are read
+# the loop runs ahead of the read (eos_id set): a launch's tokens are read
+# once `depth` later launches stand behind it. The depth follows from
+# `packing.launch_is_hidden`: 1 where the launch is hidden, 2 where not
 # --------------------------------------------------------------------------
 
-_POOLS = {"dense": dict(prefix_cache=False),
-          "paged": dict(prefix_cache=False, paged=True, page_size=8)}
+_KINDS = ("dense", "paged", "granite", "lfm2")
+_DEPTHS = (1, 2)
+
+
+class _Family:
+    """A decoder to serve, its engine's shape, and what it gives a
+    request alone: ``solo(prompt, n)``, memoised."""
+
+    LENS = [3, 7, 5, 9, 4, 6, 8]
+    NEW = 10
+
+    def __init__(self, kind, tiny):
+        self.kind = kind
+        if kind in ("dense", "paged"):
+            cfg, self.params, self.apply_fn, self.make_cache, _ = tiny
+            self.vocab = cfg.vocab_size
+            self.ekw = dict(max_slots=2, max_len=48, prefill_chunk=4,
+                            prefix_cache=False)
+            if kind == "paged":
+                self.ekw.update(paged=True, page_size=8)
+        else:
+            from apex1_tpu.models.generate import (granite_hybrid_decoder,
+                                                   lfm2_moe_decoder)
+            from apex1_tpu.models.granite_hybrid import (
+                GraniteHybrid, GraniteHybridConfig)
+            from apex1_tpu.models.lfm2 import Lfm2Moe, Lfm2MoeConfig
+            # seeded so that every layer weighs in the logits at a tiny
+            # width: the two families' own files draw theirs the same way
+            from test_lfm2 import make_params
+            if kind == "granite":
+                cfg = GraniteHybridConfig.tiny(embedding_multiplier=1.0)
+                model, decoder = GraniteHybrid(cfg), granite_hybrid_decoder
+            else:
+                cfg = Lfm2MoeConfig.tiny()
+                model, decoder = Lfm2Moe(cfg), lfm2_moe_decoder
+                #: the (row, expert) pairs one lane-step computes: every
+                #: expert is held here
+                self.pairs_a_row = cfg.num_experts_per_tok * sum(
+                    k == "sparse" for k in cfg.ffn_kinds)
+            self.params = make_params(model)
+            self.apply_fn, self.make_cache = decoder(model)
+            self.vocab = cfg.vocab_size
+            self.ekw = dict(max_slots=2, max_len=96, prefill_chunk=16,
+                            prefix_cache=False)
+        self._solo, self._alone = {}, None
+        rng = np.random.default_rng(1234)
+        self.prompts = [rng.integers(0, self.vocab - 12, (L,)).tolist()
+                        for L in self.LENS]
+        self._served = None
+
+    def solo(self, tokens, n_new):
+        """What a request gives alone. The tiny GPT-2: `generate`. The
+        two decoders with recurrent state: the deferred engine
+        (``eos_id=None``: no read before the request retires) with
+        nothing else in it, one engine for them all; their engine is
+        held against `generate` by their own files."""
+        key = (tuple(tokens), n_new)
+        if key in self._solo:
+            return self._solo[key]
+        if self.kind in ("dense", "paged"):
+            cache = self.make_cache(1, self.ekw["max_len"])
+            out = np.asarray(generate(
+                self.apply_fn, self.params,
+                jnp.asarray([tokens], jnp.int32), max_new_tokens=n_new,
+                cache=cache, vocab_size=self.vocab))[0]
+        else:
+            if self._alone is None:
+                self._alone = self.engine()
+            eng = self._alone
+            assert eng._defer and eng.n_active == 0
+            rid = eng.submit(tokens, max_new_tokens=n_new)
+            eng.run(max_steps=200)
+            out = eng.pop_result(rid).tokens
+        self._solo[key] = out
+        return out
+
+    def engine(self, **kw):
+        return Engine(self.apply_fn, self.make_cache, self.params,
+                      EngineConfig(vocab_size=self.vocab,
+                                   **dict(self.ekw, **kw)))
+
+    def served_alone(self):
+        """``(eos, want)``: the seven prompts' streams up to their first
+        ``eos``, which is the token that ends most of them in
+        mid-stream, far enough from the end for two launches to be in
+        flight behind it. The streams without an ``eos`` are what each
+        request gives alone and what the deferred engine (``eos_id=None``,
+        no read before a request retires) gives them together, request
+        by request."""
+        if self._served is None:
+            full = [self.solo(p, self.NEW).tolist() for p in self.prompts]
+            eng = self.engine()
+            assert eng._defer
+            ids = [eng.submit(p, max_new_tokens=self.NEW)
+                   for p in self.prompts]
+            eng.run(max_steps=200)
+            for rid, f in zip(ids, full):
+                np.testing.assert_array_equal(eng.results[rid].tokens, f)
+
+            def cut(f, eos):
+                return f[:f.index(eos) + 1] if eos in f else f
+
+            eos = max(sorted({t for f in full for t in f}), key=lambda t: sum(
+                2 <= len(cut(f, t)) <= self.NEW - 2 for f in full))
+            self._served = eos, [cut(f, eos) for f in full]
+        return self._served
+
+
+@pytest.fixture(scope="module")
+def family(tiny):
+    built = {}
+
+    def get(kind):
+        if kind not in built:
+            built[kind] = _Family(kind, tiny)
+        return built[kind]
+
+    return get
 
 
 class _Watched:
-    """An engine with an ``eos_id``, watched from outside: every launch
-    of the step executable (its token array and the lanes it ran for),
-    every one of those arrays the host has turned into numpy, and after
-    each `step()` what every request had by then."""
+    """An engine with an ``eos_id``, of the depth asked for through the
+    predicate, watched from outside: every launch of the step executable
+    (the array the host will read and the lanes it ran for), every one
+    of those arrays the host has turned into numpy, and after each
+    `step()` what every request had by then."""
 
-    def __init__(self, tiny, monkeypatch, pool, **kw):
+    def __init__(self, fam, monkeypatch, depth, **kw):
         from apex1_tpu.serving import engine as engine_mod
-        self.eng = eng = _engine(tiny, **_POOLS[pool], **kw)
+        from apex1_tpu.serving import packing
+        monkeypatch.setattr(packing, "launch_is_hidden",
+                            lambda leaves, n_other: depth == 1)
+        self.eng = eng = fam.engine(**kw)
+        assert eng._depth == depth
+        self.depth = depth
         self.launches = []          # (tokens on device, {lane: _Slot})
         self.read = set()           # indices into `launches`
         self.after = []             # per step(): what the outside saw
@@ -1387,7 +1514,8 @@ class _Watched:
 
         def decode(*a):
             out = real(*a)
-            self.launches.append((out[0], {
+            # a decoder with experts: the counts ride behind the tokens
+            self.launches.append((out[3] if len(out) == 5 else out[0], {
                 i: st for i, st in enumerate(eng._slots)
                 if st is not None and st.in_batch}))
             return out
@@ -1408,12 +1536,14 @@ class _Watched:
 
         monkeypatch.setattr(engine_mod, "np", Np())
         self.mark = _span_mark()
+        self.spans = None
 
     def step(self):
         eng = self.eng
         before = {i: (st, len(st.produced))
                   for i, st in enumerate(eng._slots) if st is not None}
         n_launches = len(self.launches)
+        n_read = len(self.read)
         eng.step()
         held = {}
         for st in [s for s in eng._slots if s is not None]:
@@ -1428,73 +1558,97 @@ class _Watched:
         self.after.append(dict(
             before=before, held=held, n_launches=len(self.launches),
             launched=len(self.launches) > n_launches,
-            read=set(self.read)))
+            n_read=len(self.read) - n_read, read=set(self.read),
+            # the engine's queue, as indices into `launches`
+            flight=[n for toks, _ in eng._inflight
+                    for n, (t, _) in enumerate(self.launches)
+                    if t is toks]))
 
     def run(self):
         eng = self.eng
         while eng.scheduler.depth > 0 or eng.n_active:
             self.step()
             assert len(self.after) < 500
+        # the spans' buffer is bounded: keep this run's now
+        self.spans = _spans_since(self.mark)
 
     def steps(self):
-        return [sp for sp in _spans_since(self.mark)
-                if sp.name == "serving/step"]
+        return [sp for sp in self.spans if sp.name == "serving/step"]
 
     def children(self, step):
-        return [sp for sp in _spans_since(self.mark)
-                if sp.parent == step.id]
+        return [sp for sp in self.spans if sp.parent == step.id]
 
 
-@pytest.mark.parametrize("pool", sorted(_POOLS))
-class TestRunsOneStepAhead:
-    LENS = [3, 7, 5, 9, 4, 6, 8]
-    NEW = 10
+@pytest.fixture(scope="module")
+def served_runs(family):
+    """``(kind, depth)`` -> one run, made once and only read by the
+    tests: seven requests over two lanes, joining while others decode,
+    with an ``eos_id`` that greedy decoding draws in mid-stream:
+    ``(watched engine, ids, what each request gives alone up to its
+    first eos)``."""
+    runs = {}
 
-    @pytest.fixture
-    def served(self, tiny, monkeypatch, pool):
-        """Seven requests over two lanes, joining while others decode,
-        with an ``eos_id`` that greedy decoding draws in mid-stream:
-        ``(watched engine, ids, prompts, what solo generate gives up to
-        its first eos)``."""
-        cfg, _, _, _, solo = tiny
-        rng = np.random.default_rng(1234)
-        prompts = [rng.integers(0, cfg.vocab_size, (L,)).tolist()
-                   for L in self.LENS]
-        full = [solo(p, self.NEW).tolist() for p in prompts]
-        eos = full[0][2]
-        want = [f[:f.index(eos) + 1] if eos in f else f for f in full]
-        # an eos in mid-stream leaves a step in flight behind it; one on
-        # the first token retires at admission, one on the last by count
-        assert sum(2 <= len(w) < self.NEW for w in want) >= 2
-        w = _Watched(tiny, monkeypatch, pool, max_slots=2, eos_id=eos)
-        ids = [w.eng.submit(p, max_new_tokens=self.NEW)
-               for p in prompts[:3]]
-        w.step()
-        w.step()
-        ids.append(w.eng.submit(prompts[3], max_new_tokens=self.NEW))
-        w.step()
-        ids += [w.eng.submit(p, max_new_tokens=self.NEW)
-                for p in prompts[4:]]
-        w.run()
-        return w, ids, prompts, want
+    def get(kind, depth):
+        if (kind, depth) in runs:
+            return runs[kind, depth]
+        fam = family(kind)
+        eos, want = fam.served_alone()
+        # an eos in mid-stream leaves `depth` launches in flight behind
+        # it; one on the first token retires at admission, one on the
+        # last by count
+        assert any(2 <= len(w) <= fam.NEW - 2 for w in want)
+        with pytest.MonkeyPatch.context() as mp:
+            w = _Watched(fam, mp, depth, eos_id=eos)
+            ids = [w.eng.submit(p, max_new_tokens=fam.NEW)
+                   for p in fam.prompts[:3]]
+            w.step()
+            w.step()
+            ids.append(w.eng.submit(fam.prompts[3],
+                                    max_new_tokens=fam.NEW))
+            w.step()
+            ids += [w.eng.submit(p, max_new_tokens=fam.NEW)
+                    for p in fam.prompts[4:]]
+            w.run()
+        runs[kind, depth] = w, ids, want
+        return runs[kind, depth]
+
+    return get
+
+
+def _overrun(want, new, depth):
+    """The lane-steps in flight for a request at the read of its last
+    token: ``depth`` behind an ``eos`` in mid-stream, fewer where the
+    lane had left by count, none for one that ran to its length or
+    ended at admission."""
+    if len(want) < 2:
+        return 0
+    return min(depth, new - len(want))
+
+
+@pytest.mark.parametrize("depth", _DEPTHS)
+class TestRunsAheadOfTheRead:
+    @pytest.fixture(params=_KINDS)
+    def served(self, request, served_runs, depth):
+        return served_runs(request.param, depth) + (request.param,)
 
     def test_streams_equal_solo_generate_through_eos_and_lane_reuse(
-            self, served, pool):
-        """(a) every stream is solo `generate`'s up to its first eos:
-        the overrun lane-step's token is nowhere, and the request that
-        took the lane an overrun lane-step wrote past the end of is
+            self, served, family, depth):
+        """(a) every stream is solo `generate`'s (and the deferred
+        engine's) up to its first eos: the overrun lane-steps' tokens
+        are nowhere, and the request that took the lane they wrote past
+        the end of (whose recurrent state they advanced) is
         token-identical too; (d) two executables, traced once."""
-        w, ids, _, want = served
-        eng = w.eng
+        w, ids, want, kind = served
+        eng, new = w.eng, family(kind).NEW
         for rid, tokens in zip(ids, want):
             res = eng.results[rid]
             assert res.status == "done"
             assert res.reason == ("eos" if tokens[-1] == eng.cfg.eos_id
                                   else "length")
             np.testing.assert_array_equal(res.tokens, tokens)
-        # a lane that an eos left with a step in flight was taken again
+        # a lane that an eos left with launches in flight was taken again
         overran = {rid for rid, tokens in zip(ids, want)
-                   if 2 <= len(tokens) < self.NEW}
+                   if _overrun(tokens, new, depth) == depth}
         owners = {}
         for _, lanes in w.launches:
             for i, st in lanes.items():
@@ -1507,31 +1661,44 @@ class TestRunsOneStepAhead:
             eng.results[rid].status == "done" for rid in followed)
         assert eng.trace_counts == {"prefill": 1, "decode": 1}
 
-    def test_launch_precedes_the_read_of_the_launch_before(self, served,
-                                                           pool):
-        """(b) in every step with ``ran_ahead`` the launch's span ends
-        before the read's begins, and what that read hands out are the
-        tokens of the launch BEFORE this step's."""
+    def test_launch_precedes_the_read_of_the_launch_depth_before(
+            self, served, depth):
+        """(b) ``ran_ahead`` is the launches in flight, unread, when the
+        step's launch was made; a step that launches reads at most one
+        launch's tokens, after its own launch's span has ended, and they
+        are the tokens of the launch ``depth`` before this step's."""
         w, _, _, _ = served
         steps = w.steps()
         assert len(steps) == len(w.after)
-        assert sum(sp.counts["ran_ahead"] for sp in steps) >= 10
+        assert sum(sp.counts["ran_ahead"] == depth for sp in steps) >= 10
+        was = set()
         for sp, seen in zip(steps, w.after):
             kids = {k.name: k for k in w.children(sp)}
-            assert sp.counts["ran_ahead"] in (0, 1)
-            if not sp.counts["ran_ahead"]:
-                # nothing was in flight, or nothing was left to launch
-                assert not ("serving/decode_step" in kids
-                            and "serving/read_tokens" in kids)
+            assert sp.counts["ran_ahead"] in range(depth + 1)
+            assert seen["n_read"] in (0, 1)      # never two a call
+            assert seen["launched"] == ("serving/decode_step" in kids)
+            assert seen["n_read"] == ("serving/read_tokens" in kids)
+            flight = seen["flight"]
+            assert flight == sorted(flight) and len(flight) <= depth
+            now_read, was = seen["read"] - was, seen["read"]
+            if not seen["launched"]:
+                # nothing to launch: what is owed, oldest first, and
+                # the engine is idle once nothing is
+                assert sp.counts["ran_ahead"] == 0
+                assert seen["n_read"] or not flight
                 continue
-            assert seen["launched"]
+            if not seen["n_read"]:
+                continue            # the queue is filling
+            assert sp.counts["ran_ahead"] == depth == len(flight)
             launch, read = (kids["serving/decode_step"],
                             kids["serving/read_tokens"])
             assert launch.end_ns <= read.start_ns and read.wait
             assert read.end_ns <= kids["serving/emit"].start_ns
-            n = seen["n_launches"]           # launches so far: n - 1 is
-            toks, lanes = w.launches[n - 2]  # this step's own
-            assert n - 2 in seen["read"] and n - 1 not in seen["read"]
+            # behind the launch read stand `depth` more, this step's
+            # own the last of them
+            (n,) = now_read
+            assert n < flight[0] and flight[-1] == seen["n_launches"] - 1
+            toks, lanes = w.launches[n]
             toks = np.asarray(toks)
             for i, st in lanes.items():
                 if i not in seen["before"] or seen["before"][i][0] is not st:
@@ -1539,13 +1706,17 @@ class TestRunsOneStepAhead:
                 had = seen["before"][i][1]
                 assert st.produced[had:had + 1] == [int(toks[i])]
 
-    def test_counts_follow_the_read_not_the_launch(self, served, pool):
+    def test_counts_follow_the_read_not_the_launch(self, served, family,
+                                                   depth):
         """(c) over the steps: ``tokens_out`` is what the results hold,
-        ``overrun_lanes`` the requests an eos ended with a step in
-        flight, and after no `step()` does a request's ``n_generated``
-        count a token the host has not read."""
-        w, ids, _, want = served
-        eng = w.eng
+        ``overrun_lanes`` every lane-step in flight for a request at the
+        read of its eos (``depth`` of them in mid-stream), and after no
+        `step()` does a request's ``n_generated`` count a token the host
+        has not read. A recurrent state was advanced by every launch, an
+        overrun too; a decoder with experts tallies the routing of the
+        launches whose tokens were read, and of no other."""
+        w, ids, want, kind = served
+        eng, fam = w.eng, family(kind)
         steps = w.steps()
 
         def total(key):
@@ -1553,45 +1724,60 @@ class TestRunsOneStepAhead:
 
         assert total("tokens_out") == sum(
             len(eng.results[r].tokens) for r in ids) == sum(map(len, want))
-        assert total("overrun_lanes") == sum(
-            2 <= len(tokens) < self.NEW for tokens in want) >= 2
+        overruns = [_overrun(tokens, fam.NEW, depth) for tokens in want]
+        assert total("overrun_lanes") == sum(overruns) > 0
+        assert max(overruns) == depth
         assert total("admitted") == total("retired") == len(ids)
         ahead = 0
         for seen in w.after:
             for got in seen["held"].values():
                 assert got["n_generated"] == got["produced"]
                 assert got["n_generated"] == 1 + got["read"]
-                assert got["launched"] - got["read"] in (0, 1)
+                assert got["launched"] - got["read"] in range(depth + 1)
                 ahead += got["launched"] - got["read"]
-        assert ahead >= 10          # launched, and not yet counted
+        assert ahead >= 10 * depth  # launched, and not yet counted
         # a request's token events are as many as its tokens
         for rid, tokens in zip(ids, want):
             assert eng.metrics.records[rid].n_generated == len(tokens)
+        lane_steps = sum(len(lanes) for _, lanes in w.launches)
+        assert lane_steps == (total("tokens_out") - total("admitted")
+                              + total("overrun_lanes"))
+        if kind in ("granite", "lfm2"):
+            assert total("state_lanes") == lane_steps
+        if kind == "lfm2":
+            read = [w.launches[n] for n in sorted(w.read)]
+            assert 0 < len(read) < len(w.launches)   # one was dropped
+            assert total("moe_rows") == fam.pairs_a_row * sum(
+                len(lanes) for _, lanes in read)
+            assert total("moe_expert_slots") == eng._moe_slots * len(read)
 
     @pytest.mark.parametrize("how", ["cancel", "deadline"])
-    def test_retired_with_a_step_in_flight_drops_that_token(
-            self, tiny, monkeypatch, pool, how):
-        """(e) a cancel or a deadline that falls on a lane with a step
-        in flight: the request ends with the tokens the host had read,
-        the in-flight token is dropped, the neighbour decodes on and the
-        request that takes the lane is token-identical."""
-        cfg, _, _, _, solo = tiny
+    @pytest.mark.parametrize("kind", ["dense", "paged"])
+    def test_retired_with_launches_in_flight_drops_their_tokens(
+            self, family, monkeypatch, depth, kind, how):
+        """(e) a cancel or a deadline that falls on a lane with
+        ``depth`` launches in flight: the request ends with the tokens
+        the host had read, the tokens in flight are dropped, the
+        neighbour decodes on and the request that takes the lane is
+        token-identical."""
+        fam = family(kind)
+        solo = fam.solo
         rng = np.random.default_rng(77)
-        p1, p2, p3 = (rng.integers(0, cfg.vocab_size, (L,)).tolist()
+        p1, p2, p3 = (rng.integers(0, fam.vocab, (L,)).tolist()
                       for L in (5, 6, 4))
-        w = _Watched(tiny, monkeypatch, pool, max_slots=2,
-                     eos_id=cfg.vocab_size + 1)     # never drawn
+        w = _Watched(fam, monkeypatch, depth,
+                     eos_id=fam.vocab + 1)          # never drawn
         eng = w.eng
         kw = ({"deadline": time.monotonic() + 3600.0}
               if how == "deadline" else {})
         r1 = eng.submit(p1, max_new_tokens=30, **kw)
         r2 = eng.submit(p2, max_new_tokens=12)
-        for _ in range(3):
-            w.step()                # three launches, two of them read
+        for _ in range(2 + depth):
+            w.step()                # two of the launches read
         (lane,) = [i for i, st in enumerate(eng._slots)
                    if st.req.req_id == r1]
         assert w.after[-1]["held"][r1] == dict(
-            n_generated=3, produced=3, launched=3, read=2)
+            n_generated=3, produced=3, launched=2 + depth, read=2)
         r3 = eng.submit(p3, max_new_tokens=6)
         if how == "cancel":
             assert eng.cancel(r1)
@@ -1614,3 +1800,159 @@ class TestRunsOneStepAhead:
         assert sum(sp.counts["tokens_out"] for sp in steps) == 3 + 12 + 6
         assert sum(sp.counts["overrun_lanes"] for sp in steps) == 0
         assert eng.trace_counts == {"prefill": 1, "decode": 1}
+
+    def test_a_call_that_launches_nothing_leaves_the_engine_idle(
+            self, family, monkeypatch, depth):
+        """One request alone: its last launches are handed out one a
+        call by calls that launch nothing, `step()` returns 0 for each,
+        and the call after the last finds nothing to do."""
+        fam = family("dense")
+        w = _Watched(fam, monkeypatch, depth, eos_id=fam.vocab + 1)
+        eng = w.eng
+        rid = eng.submit(fam.prompts[0], max_new_tokens=5)
+        returned = []
+        while rid not in eng.results:
+            returned.append(eng.step())
+            assert len(returned) < 20
+        # four launches, a call each; then `depth` calls that only read
+        assert returned == [1] * 4 + [0] * depth
+        assert len(w.launches) == 4 and w.read == set(range(4))
+        assert not eng._inflight and eng.n_active == 0
+        np.testing.assert_array_equal(eng.results[rid].tokens,
+                                      fam.solo(fam.prompts[0], 5))
+        mark = _span_mark()
+        assert eng.step() == 0
+        assert [sp.name for sp in _spans_since(mark)
+                if sp.name in ("serving/decode_step",
+                               "serving/read_tokens")] == []
+
+
+# the depth is not an option: it follows from the predicate the engine
+# computes at construction for its operands
+
+@pytest.mark.parametrize("hidden,depth", [(True, 1), (False, 2)],
+                         ids=["hidden", "not-hidden"])
+def test_the_depth_follows_from_launch_is_hidden(family, monkeypatch,
+                                                 hidden, depth):
+    """The engine asks `packing.launch_is_hidden` once a tree, of the
+    leaves it serves and the operands a step hands over besides, and
+    keeps one launch in flight behind the read where the answer is yes
+    (a second would buy nothing) and two where it is no."""
+    from apex1_tpu.serving import packing
+    fam = family("dense")
+    asked = []
+
+    def predicate(leaves, n_other):
+        asked.append((len(leaves), n_other))
+        return hidden
+
+    monkeypatch.setattr(packing, "launch_is_hidden", predicate)
+    eng = fam.engine(eos_id=fam.vocab + 1)
+    n_leaves = len(jax.tree_util.tree_leaves(fam.params))
+    pool = len(jax.tree_util.tree_leaves(eng.kv.cache))
+    assert asked == [(n_leaves, pool + 5)]
+    assert eng._packed.layout.hidden is hidden and eng._depth == depth
+    # a hidden launch is also handed over unpacked: one answer, both uses
+    assert (eng._packed.layout.groups == []) is hidden
+
+
+def test_off_an_accelerator_nothing_is_hidden_and_nothing_names_the_depth(
+        family):
+    """The CPU tests run two launches deep by the predicate's own answer
+    (no memory is known here); `EngineConfig` has no field for it, and
+    neither the engine nor the predicate reads the environment."""
+    import dataclasses
+    import inspect
+    from apex1_tpu.serving import engine as engine_mod
+    from apex1_tpu.serving import packing
+    fam = family("dense")
+    assert packing.launch_is_hidden(
+        jax.tree_util.tree_leaves(fam.params), 9) is False
+    assert fam.engine(eos_id=fam.vocab + 1)._depth == 2
+    names = [f.name for f in dataclasses.fields(EngineConfig)]
+    assert not [n for n in names
+                if any(w in n for w in ("depth", "ahead", "flight"))]
+    for mod in (engine_mod, packing):
+        src = inspect.getsource(mod)
+        assert "environ" not in src and "getenv" not in src
+
+
+def served_program_hashes(family) -> dict:
+    """{"<kind>/<prefill|decode>/<packed|unpacked>": sha256 of the
+    lowered text} of the two executables of the four engines above, with
+    the tree packed (the launch not hidden: two launches deep) and
+    handed over as it is (hidden: one)."""
+    import hashlib
+    from apex1_tpu.serving import packing
+    out = {}
+    i32 = jnp.zeros((), jnp.int32)
+    for kind in _KINDS:
+        fam = family(kind)
+        for hidden in (False, True):
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(packing, "launch_is_hidden",
+                           lambda leaves, n_other: hidden)
+                eng = fam.engine(eos_id=fam.vocab - 1)
+            ctl = (eng._d_toks, eng._d_idxs, eng._d_active, eng._d_seeds,
+                   eng._d_pos)
+            chunk = jnp.zeros((1, eng.cfg.prefill_chunk), jnp.int32)
+            if kind == "paged":
+                low = {
+                    "decode": eng._decode.lower(
+                        fam.params, eng.kv.pages, eng._d_bt, *ctl),
+                    "prefill": eng._prefill.lower(
+                        fam.params, eng.kv.pages, eng._d_bt, i32, chunk,
+                        i32, i32, i32)}
+            else:
+                low = {
+                    "decode": eng._decode.lower(fam.params, eng.kv.cache,
+                                                *ctl),
+                    "prefill": eng._prefill.lower(
+                        fam.params, eng.kv.cache, i32, eng.kv.zeros_lane,
+                        jnp.zeros((), jnp.bool_), chunk, i32, i32, i32)}
+            for prog, lowered in low.items():
+                tag = "unpacked" if hidden else "packed"
+                out[f"{kind}/{prog}/{tag}"] = hashlib.sha256(
+                    lowered.as_text().encode()).hexdigest()[:16]
+    return out
+
+
+#: `served_program_hashes` at b894a02, the commit before the queue (PR 45:
+#: `PYTHONPATH=<that tree> python tests/test_serving.py`, which prints
+#: them). A later PR that changes an engine's program on purpose computes
+#: them anew, and says so.
+PARENT_HASHES = {
+    "dense/decode/packed": "385364f4f3a87832",
+    "dense/decode/unpacked": "db1902ec491342a5",
+    "dense/prefill/packed": "62ed42193ab2b3d5",
+    "dense/prefill/unpacked": "e9b2e1d2b0a1456f",
+    "granite/decode/packed": "331f94d14ab5d838",
+    "granite/decode/unpacked": "09d78b65abb543c8",
+    "granite/prefill/packed": "21c4a5d7f169749c",
+    "granite/prefill/unpacked": "0df29ae05e89cf7f",
+    "lfm2/decode/packed": "0bb07c074ef67071",
+    "lfm2/decode/unpacked": "71413c6bd2601dc4",
+    "lfm2/prefill/packed": "6ecb795836abfbec",
+    "lfm2/prefill/unpacked": "8af91cca95617eef",
+    "paged/decode/packed": "8d26db7752559817",
+    "paged/decode/unpacked": "4e5ce5a9d091fdd7",
+    "paged/prefill/packed": "6c35356c23616ad3",
+    "paged/prefill/unpacked": "a14b6772567f2dfb",
+}
+
+
+def test_the_engines_programs_are_the_parents(family):
+    """What the loop launches did not change with how far ahead it
+    launches: the prefill and decode programs of the four engines lower
+    to the text they had one launch deep, packed and unpacked."""
+    got = served_program_hashes(family)
+    assert len(got) == 16
+    assert got == PARENT_HASHES
+
+
+if __name__ == "__main__":
+    import pprint
+    _one, _built = _tiny(), {}
+    pprint.pprint(served_program_hashes(
+        lambda kind: _built.get(kind) or _built.setdefault(
+            kind, _Family(kind, _one))))
