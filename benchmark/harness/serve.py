@@ -3,8 +3,10 @@ one thread driving `Engine.step()`, a replayed trace, exact intervals.
 
 - The trace's shape comes from the traffic file's `shape_seed`
   (`loadgen`); `--seed` decides the weights and the token ids only.
-- A BOUNDARY is the moment `Engine.step()` returned with that step's
-  tokens read on the host. A token's time is its boundary. The window
+- A BOUNDARY is the moment the `Engine.step()` that READ a token on the
+  host returned: since PR 33 one call after the call that launched it, since
+  PR 45 two calls after where the launch is not hidden (`gpt2m_serve_chat`).
+  A token's time is its boundary. The window
   opens at the first boundary at or after warm-up + ramp and closes at the
   first boundary at or after `--seconds` later.
 - `serve_tok_s` = tokens processed between the two boundaries (a prompt's

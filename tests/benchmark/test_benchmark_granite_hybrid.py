@@ -82,9 +82,11 @@ def root(tmp_path_factory):
 def test_the_real_cell_is_in_the_manifest_uncut():
     man = lib.mf.load_manifest(ROOT)
     lib.mf.validate(man, ROOT)
-    assert len(man["configs"]) == 2 and len(man["workloads"]) == 3
     entry = lib.mf.find(man, "configs", "granite-4.0-h-micro")
     assert entry["reduced"] == []
+    cell = lib.mf.find(man, "workloads", REAL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "granite-4.0-h-micro", "hybrid_chat_steady", 1)
     cfg = lib.mf.load_config(man, "granite-4.0-h-micro", ROOT)
     assert (cfg["num_hidden_layers"], cfg["hidden_size"],
             cfg["vocab_size"]) == (40, 2048, 100352)
