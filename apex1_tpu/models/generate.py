@@ -66,17 +66,21 @@ def init_cache(num_layers: int, batch: int, num_kv_heads: int,
 def cache_len(cache) -> int:
     """Positions a dense cache pytree (or one leaf of it) holds: read off
     a K/V leaf, since a tree may hold entries of another kind beside them
-    (a recurrent state has no positions: `granite_hybrid_decoder`)."""
+    (a recurrent state has no positions: `granite_hybrid_decoder`), and
+    off the longest of them (a ring holds a window: `afmoe_decoder`)."""
     leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
     kv = [x for path, x in leaves
           if path and getattr(path[-1], "key", None) in ("k", "v")]
-    return (kv or [x for _, x in leaves])[0].shape[1]
+    # the LONGEST: a sliding-attention layer's leaf is a ring, shorter
+    # than the positions the tree serves (`models.afmoe`)
+    return max(x.shape[1] for x in (kv or [x for _, x in leaves]))
 
 
 def cached_attention(q, k_new, v_new, cache, cache_index, *,
                      sm_scale: Optional[float] = None, bias=None,
                      segment_ids=None, valid_start=None,
-                     chunk_decode: bool = False):
+                     chunk_decode: bool = False,
+                     window: Optional[int] = None):
     """Attention through the KV cache. ``q``/``k_new``/``v_new``:
     (B, H, S, D)/(B, Hkv, S, D) for the CURRENT tokens; ``cache`` holds
     (B, S_max, Hkv * D) (`init_cache`); ``cache_index`` is the (traced) write position:
@@ -122,6 +126,14 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
     j never reaches a pad slot k > cache_index + j, and the next write
     overwrites the pad K/V before any query can see it).
 
+    ``window`` (a sliding-attention layer): query j attends the last
+    ``window`` positions up to its own alone, and the entry is a RING:
+    position ``p`` lies in row ``p mod L``, so ``L >= window + S - 1``
+    rows serve any depth (`ops.decode_attend`, `cache_attend`; an entry
+    no longer than the window holds every position and never wraps). Every
+    call then attends THROUGH the cache (``chunk_decode``'s path), a
+    prefill from an empty cache too: the flash kernels have no window.
+
     Returns (attn (B, H, S, D), new_cache_entry).
     """
     with region("attn"):
@@ -140,6 +152,18 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
                                        sm_scale=sm_scale,
                                        chunk_decode=chunk_decode)
         idx = jnp.asarray(cache_index, jnp.int32)
+        ring = {}
+        if window is not None:
+            if bias is not None or segment_ids is not None \
+                    or valid_start is not None:
+                raise ValueError("a window takes neither bias, "
+                                 "segment_ids nor valid_start")
+            if window < cache["k"].shape[1] < window + S - 1:
+                raise ValueError(
+                    f"a ring of {cache['k'].shape[1]} rows cannot take {S} "
+                    f"new positions under a window of {window}: rows still "
+                    f"attended would be overwritten")
+            ring, chunk_decode = {"window": int(window)}, True
         if (idx.ndim == 1 and use_pallas() and Hq * S <= MAX_ROWS
                 and (S == 1 or chunk_decode) and bias is None
                 and valid_start is None
@@ -149,10 +173,10 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
             # lane to its horizon and no further (`ops.decode_attend`)
             attn, k_all, v_all = decode_attend(q, k_new, v_new, cache["k"],
                                                cache["v"], idx,
-                                               sm_scale=sm_scale)
+                                               sm_scale=sm_scale, **ring)
             return attn, {"k": k_all, "v": v_all}
-        k_all = cache_write(cache["k"], k_new, idx)
-        v_all = cache_write(cache["v"], v_new, idx)
+        k_all = cache_write(cache["k"], k_new, idx, ring=bool(ring))
+        v_all = cache_write(cache["v"], v_new, idx, ring=bool(ring))
         new_entry = {"k": k_all, "v": v_all}
         if S > 1 and not chunk_decode:
             # prefill attends only over the CURRENT tokens — valid only from
@@ -172,11 +196,11 @@ def cached_attention(q, k_new, v_new, cache, cache_index, *,
                                    segment_ids=segment_ids)
             return attn, new_entry
         attn = cache_attend(q, k_all, v_all, idx, sm_scale=sm_scale,
-                            bias=bias, valid_start=valid_start)
+                            bias=bias, valid_start=valid_start, **ring)
         return attn, new_entry
 
 
-def cache_write(cache, new, cache_index):
+def cache_write(cache, new, cache_index, ring: bool = False):
     """``cache`` (B, S_max, Hkv * D) with ``new`` (B, Hkv, S, D), as a
     model hands its K or V, written at ``cache_index``; the index's
     RANK chooses how.
@@ -192,15 +216,32 @@ def cache_write(cache, new, cache_index):
     next. It rewrites every position, which is why the scalar case does
     not take it and why, where the kernels run, the engine's step does
     not either (`ops.decode_attend` writes the rows alone). A position
-    past ``S_max`` is dropped there, not clamped onto earlier rows."""
+    past ``S_max`` is dropped there, not clamped onto earlier rows.
+
+    ``ring``: position ``p`` lies in row ``p mod S_max`` (a
+    sliding-attention layer's entry), so a chunk may wrap: either rank
+    then selects by position, the chunk rolled into its rows (a ring is
+    a window long, and a prefill chunk rewrites one lane's)."""
     idx = jnp.asarray(cache_index, jnp.int32)
     B, _, S, _ = new.shape
     new = new.astype(cache.dtype).transpose(0, 2, 1, 3).reshape(B, S, -1)
+    L = cache.shape[1]
+    if ring and idx.ndim == 0:
+        if S > L:
+            raise ValueError(f"{S} new positions into a ring of {L} rows")
+        rolled = jnp.roll(jnp.pad(new, ((0, 0), (0, L - S), (0, 0))),
+                          idx % L, axis=1)
+        here = (jnp.arange(L, dtype=jnp.int32) - idx) % L < S
+        return jnp.where(here[None, :, None], rolled, cache)
     if idx.ndim == 0:
         return jax.lax.dynamic_update_slice(cache, new, (0, idx, 0))
-    pos = jnp.arange(cache.shape[1], dtype=jnp.int32)
-    rel = jnp.where(idx[:, None] >= 0, pos[None, :] - idx[:, None],
-                    -1)[:, :, None]                     # (B, S_max, 1)
+    pos = jnp.arange(L, dtype=jnp.int32)
+    if ring:
+        rel = jnp.where(idx[:, None] >= 0,
+                        (pos[None, :] - idx[:, None]) % L, -1)[:, :, None]
+    else:
+        rel = jnp.where(idx[:, None] >= 0, pos[None, :] - idx[:, None],
+                        -1)[:, :, None]                 # (B, S_max, 1)
     for j in range(S):
         cache = jnp.where(rel == j, new[:, j:j + 1], cache)
     return cache
@@ -859,6 +900,38 @@ def lfm2_moe_decoder(model):
 
     def make_cache(batch: int, max_len: int, dtype=None):
         return init_lfm2_cache(model.cfg, batch, max_len, dtype)
+
+    return apply_fn, make_cache
+
+
+def afmoe_decoder(model, *, ring_slack: int = 256):
+    """(apply_fn, make_cache) for `models.afmoe.Afmoe`: RoPE (the sliding
+    layers' alone) takes ``positions`` (None: from ``cache_index`` on); the
+    cache tree holds, by the model's own ``layer_types``, K/V leaves of TWO
+    lengths: every position of a global layer, and a RING of
+    ``sliding_window + ring_slack`` rows (whole blocks) of a sliding one,
+    which nothing older than its window is ever read from. ``ring_slack``
+    is the most one call may append to a ring (the engine's prefill chunk:
+    `serving.Engine` refuses a longer one by the leaf shapes and
+    ``apply_fn.sliding_window``). ``moe_counts`` and
+    ``apply_fn.moe_expert_slots`` as `lfm2_moe_decoder` has them; ``n_real``
+    (scalar, with a scalar ``cache_index``): the tokens of a right-padded
+    run that are routed."""
+    from apex1_tpu.models.afmoe import init_afmoe_cache
+
+    def apply_fn(params, tokens, cache, cache_index, *, positions=None,
+                 chunk_decode=False, n_real=None, moe_counts=False):
+        return model.apply({"params": params}, tokens, positions=positions,
+                           cache=cache, cache_index=cache_index,
+                           chunk_decode=chunk_decode, n_real=n_real,
+                           moe_counts=moe_counts)
+
+    apply_fn.moe_expert_slots = model.cfg.moe_expert_slots
+    apply_fn.sliding_window = model.cfg.sliding_window
+
+    def make_cache(batch: int, max_len: int, dtype=None):
+        return init_afmoe_cache(model.cfg, batch, max_len, dtype,
+                                ring_slack=ring_slack)
 
     return apply_fn, make_cache
 

@@ -23,6 +23,19 @@ layer, on the pool where it lies:
   outside a row's own head); the output is masked to each row's head
   and summed over rows by a second, 0/1, product.
 
+A WINDOW (``window=W``, a sliding-attention layer): query ``j`` attends
+positions ``idx + j - W < p <= idx + j`` alone, and the leaf is a RING:
+position ``p`` lies in row ``p mod L``, so a leaf of ``L >= W + S - 1``
+rows (whole blocks) serves a lane of any depth. The kernel walks the
+blocks of POSITIONS from the one that holds the window's first to the
+horizon's, fetches block ``a`` from ring block ``a mod (L / block)``, and
+never a block wholly below the window; the append and its aligned
+write-back wrap with the block they lie in. A row whose position is above
+the window's first has not been overwritten yet (``L >= W + S - 1``), and
+every other row is masked, so the ring block that holds both ends of the
+window is simply read twice. A lane shallower than the window reads from
+block 0, as without one. Without ``window`` nothing of this is traced.
+
 Operands enter the MXU in the pool's dtype (bfloat16 as configured)
 with float32 accumulation; the softmax statistics are float32 and the
 probabilities are rounded to the value dtype before P.V, as the
@@ -57,12 +70,14 @@ def _sublanes(dtype) -> int:
 
 
 def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
-                          dtype):
+                          dtype, window: Optional[int] = None):
     """Loud validation of a `decode_attend` geometry, at trace time and
     again by ``tools/aot_check``: whole blocks, whole tiles, and a VMEM
     frame under the shared `vmem_model` budget (a row narrower than a
-    multiple of 128 lanes is padded by the chip, not refused).
-    Returns ``(block, window, padded rows)``."""
+    multiple of 128 lanes is padded by the chip, not refused); with a
+    ``window``, a ring that holds the window and the call's new rows in
+    at least two blocks. Returns ``(block, write-back rows, padded
+    rows)``."""
     from apex1_tpu.vmem_model import CHECKS, budget_bytes
     sub = _sublanes(dtype)
     blk = min(DECODE_BLOCK, length)
@@ -76,6 +91,15 @@ def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
         raise ValueError(
             f"decode_attend appends at most {blk - sub + 1} rows a lane "
             f"to a block of {blk}, got {s}")
+    # a leaf no longer than the window cannot wrap (it would forget what
+    # is attended): it holds every position, as without a window
+    if window is not None and (window < 1 or length > window and (
+            length < window + s - 1 or length < 2 * blk)):
+        raise ValueError(
+            f"decode_attend over a ring of {length} rows: a window of "
+            f"{window} positions and {s} new rows a lane need "
+            f"{max(window + s - 1, 2 * blk)} (in whole blocks): an older "
+            f"row would be overwritten while it is still attended")
     rp = -(-rows // 16) * 16
     fits, est = CHECKS["decode_attend"](
         {"block_l": blk}, {"HD": lanes, "Rq": rp, "W": win},
@@ -90,40 +114,52 @@ def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
 def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
                           o_ref, kp_out, vp_out, kbuf, vbuf, acc, m_scr,
                           l_scr, rsem, wsem, *, scale, S, G, Hkv, D, blk,
-                          sub, win):
+                          sub, win, window=None):
     b = pl.program_id(0)
     idx = idx_ref[b]
     Rp, HD = acc.shape
     Hq = G * Hkv
+    if window is None:
+        # block i of the leaf holds positions i * blk ...
+        first = None
+        at = lambda i: i
+        base = lambda i: i * blk
+    else:
+        # the blocks of POSITIONS from the window's first; block a of
+        # them lies in the ring's block a mod n_ring
+        n_ring = kp_in.shape[1] // blk
+        first = jnp.maximum(idx - window + 1, 0) // blk
+        at = lambda i: jax.lax.rem(first + i, n_ring)
+        base = lambda i: (first + i) * blk     # block i's first position
 
     @pl.when(idx < 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
     def fetch(i, slot):
-        rows = pl.ds(pl.multiple_of(i * blk, blk), blk)
+        rows = pl.ds(pl.multiple_of(at(i) * blk, blk), blk)
         return (pltpu.make_async_copy(kp_in.at[b, rows, :], kbuf.at[slot],
                                       rsem.at[0, slot]),
                 pltpu.make_async_copy(vp_in.at[b, rows, :], vbuf.at[slot],
                                       rsem.at[1, slot]))
 
-    def window(i):
+    def new_rows(i):
         # the aligned rows of block i that hold this lane's new ones
-        r0 = jnp.maximum(idx - i * blk, 0)
+        r0 = jnp.maximum(idx - base(i), 0)
         return pl.multiple_of(jnp.minimum((r0 // sub) * sub, blk - win),
                               sub)
 
     def append(i, slot):
-        ws = window(i)
-        rows = pl.ds(pl.multiple_of(i * blk + ws, sub), win)
+        ws = new_rows(i)
+        rows = pl.ds(pl.multiple_of(at(i) * blk + ws, sub), win)
         return (pltpu.make_async_copy(kbuf.at[slot, pl.ds(ws, win), :],
                                       kp_out.at[b, rows, :], wsem.at[0]),
                 pltpu.make_async_copy(vbuf.at[slot, pl.ds(ws, win), :],
                                       vp_out.at[b, rows, :], wsem.at[1]))
 
     def patch(i, slot):
-        ws = window(i)
-        pos = i * blk + ws + jax.lax.broadcasted_iota(
+        ws = new_rows(i)
+        pos = base(i) + ws + jax.lax.broadcasted_iota(
             jnp.int32, (win, HD), 0)
         for buf, new in ((kbuf, kn_ref), (vbuf, vn_ref)):
             tile = buf[slot, pl.ds(ws, win), :]
@@ -140,7 +176,9 @@ def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # rows are (s, g, kv): query s of the chunk sees <= idx + s
-        keep = i * blk + col <= idx + row // Hq
+        keep = base(i) + col <= idx + row // Hq
+        if window is not None:
+            keep &= base(i) + col > idx + row // Hq - window
         s = jnp.where(keep, s, NEG_INF)
         m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -158,6 +196,9 @@ def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
         # blocks up to the horizon; a row past the pool's end is dropped
         n = jnp.minimum((idx + S - 1) // blk + 1, kp_in.shape[1] // blk)
         first_new = idx // blk                 # first block with a new row
+        if window is not None:                 # a ring has no end
+            n = (idx + S - 1) // blk + 1 - first
+            first_new -= first
         for c in fetch(0, 0):
             c.start()
         acc[...] = jnp.zeros_like(acc)
@@ -204,7 +245,8 @@ def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
 
 
 def decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *,
-                  sm_scale: Optional[float] = None):
+                  sm_scale: Optional[float] = None,
+                  window: Optional[int] = None):
     """Append and attend, one lane at its own depth: ``q`` (B, Hq, S,
     D) and ``k_new`` / ``v_new`` (B, Hkv, S, D) for the current tokens,
     the pool leaves (B, L, Hkv * D), ``idx`` (B,) each lane's write
@@ -212,7 +254,9 @@ def decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *,
     output rows are zero). Returns ``(attn (B, Hq, S, D), k_pool,
     v_pool)`` with rows ``idx[b] .. idx[b] + S - 1`` of every live lane
     replaced and nothing else touched; the pools are updated in place
-    where the caller donates them."""
+    where the caller donates them. ``window``: query ``j`` sees the last
+    ``window`` positions up to its own alone, and the leaves are rings
+    (position ``p`` in row ``p mod L``: the module's text)."""
     _, Hq, S, D = q.shape
     Hkv = k_new.shape[1]
     _, L, HD = k_pool.shape
@@ -220,20 +264,23 @@ def decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *,
         raise ValueError(
             f"decode_attend: Hq={Hq}, Hkv={Hkv}, D={D} do not match a "
             f"pool row of {HD} lanes")
-    geometry = check_decode_geometry(L, HD, Hq * S, S, k_pool.dtype)
+    geometry = check_decode_geometry(L, HD, Hq * S, S, k_pool.dtype,
+                                     window)
     scale = (D ** -0.5) if sm_scale is None else sm_scale
     return _decode_attend(q, k_new, v_new, k_pool, v_pool,
                           jnp.asarray(idx, jnp.int32), scale=float(scale),
-                          geometry=geometry, interpret=interpret_mode())
+                          geometry=geometry, interpret=interpret_mode(),
+                          **({} if window is None
+                             else {"window": int(window)}))
 
 
 # a program's layers call this with the same shapes: jitted, they share
 # one traced kernel and one lowering of it (24 of them took 10 s of a
 # step executable's first call: PERF.md §6, PR 29)
 @functools.partial(jax.jit, static_argnames=("scale", "geometry",
-                                             "interpret"))
+                                             "interpret", "window"))
 def _decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *, scale,
-                   geometry, interpret):
+                   geometry, interpret, window=None):
     B, Hq, S, D = q.shape
     Hkv = k_new.shape[1]
     HD = Hkv * D
@@ -276,7 +323,8 @@ def _decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *, scale,
     out, k_pool, v_pool = kernel_call(
         functools.partial(_decode_attend_kernel, scale=scale, S=S, G=G,
                           Hkv=Hkv, D=D, blk=blk,
-                          sub=_sublanes(k_pool.dtype), win=win),
+                          sub=_sublanes(k_pool.dtype), win=win,
+                          **({} if window is None else {"window": window})),
         name="decode_attend",
         grid_spec=grid_spec,
         out_shape=[out_struct((B, SGp, HD), q.dtype, qbd, k_pool, v_pool),

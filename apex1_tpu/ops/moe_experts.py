@@ -53,7 +53,10 @@ PASS_ROWS = (16, 32, 64, 128)
 #: rows behind the last group that a pass may run over
 PASS_SLACK = PASS_ROWS[-1] - ROW_TILE
 #: the kernel's VMEM: two buffers of an expert's three matrices at the
-#: published widths this repo serves (2 x 22 MB), the rows and the result
+#: published widths this repo serves (2048 x 1792, LFM2: 2 x 22 MB; 2048 x
+#: 1024, Trinity-Mini: 2 x 12.6 MB, a half of each a grid step), the rows
+#: and the result (a prefill chunk of 256 rows, top-8 over 16 held: 2432
+#: rows of 2048, 10 MB each and 20 for the float32 sums)
 _VMEM_LIMIT = 100 * 1024 * 1024
 
 

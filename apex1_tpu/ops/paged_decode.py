@@ -64,7 +64,7 @@ _TINY = np.float32(np.finfo(np.float32).tiny)
 
 def cache_attend(q, k_all, v_all, cache_index, *,
                  sm_scale: Optional[float] = None, bias=None,
-                 valid_start=None):
+                 valid_start=None, window: Optional[int] = None):
     """Masked composite attention of (B, Hq, S, D) queries against a
     FULL cache in its stored form (B, S_max, Hkv * D), one position's
     heads side by side in a row (`models.generate.init_cache`) — the
@@ -73,7 +73,14 @@ def cache_attend(q, k_all, v_all, cache_index, *,
     pages → dense → here) and token parity with the dense engine is
     bit-exact by construction. ``cache_index`` may be a scalar (the
     dense path) or a per-row (B,) vector (the paged batch path — rows
-    at different depths). Query j sees cache slots <= index + j."""
+    at different depths). Query j sees cache slots <= index + j.
+
+    ``window`` (a sliding-attention layer): query j sees the positions
+    ``index + j - window < p <= index + j`` alone, and the cache is a
+    RING: position ``p`` lies in row ``p mod S_max``, so row ``r`` holds
+    the newest position up to the call's last (``index + S - 1``) that is
+    ``r`` modulo ``S_max``. A cache that never wrapped (``S_max`` above
+    every position) is the case in which that is ``r`` itself."""
     B, Hq, S, D = q.shape
     S_max = k_all.shape[1]
     Hkv = k_all.shape[2] // D
@@ -102,7 +109,13 @@ def cache_attend(q, k_all, v_all, cache_index, *,
     else:
         horizon = (idx.reshape(B, 1, 1, 1, 1)
                    + jnp.arange(S)[None, None, None, :, None])
-    keep = pos[None, None, None, None, :] <= horizon
+    if window is not None:
+        # what each ring row holds, by the call's last position
+        last = (idx + (S - 1)).reshape(-1, 1)               # (1 or B, 1)
+        pos = (last - (last - pos[None, :]) % S_max)[:, None, None, None, :]
+        keep = (pos <= horizon) & (pos > horizon - window) & (pos >= 0)
+    else:
+        keep = pos[None, None, None, None, :] <= horizon
     if valid_start is not None:
         keep = keep & (pos[None, None, None, None, :]
                        >= valid_start.reshape(B, 1, 1, 1, 1))

@@ -186,6 +186,24 @@ read once and written once). The loop runs ahead as it does for any
 decoder: an overrun lane-step, or two, advances a state that the next
 admission zeroes, behind them in the device's order.
 
+K/V LEAVES OF TWO LENGTHS: a decoder with sliding-window layers
+(`models.generate.afmoe_decoder`) keeps, beside the leaves that hold every
+position, RINGS: a sliding layer attends its last ``apply_fn.
+sliding_window`` positions alone, so its leaf holds that many rows and one
+launch's slack, position ``p`` in row ``p mod rows``
+(`ops.decode_attend`, `generate.cached_attention`). The pool treats a ring
+like any leaf, slot on axis 0; the engine reads the lengths off the tree
+(`kv_leaf_rows`). What a ring has forgotten cannot be shared or rolled
+back, so ``prefix_cache``, ``num_draft > 0`` and ``paged`` are refused at
+construction, each by name, and so is a ``prefill_chunk`` longer than the
+ring's slack (a chunk would overwrite rows its own first queries attend).
+The step span's ``kv_blocks_read`` / ``kv_blocks_pool`` are then sums over
+the attention LAYERS, each by its own leaf (a sliding layer reads the
+blocks that hold its window, of those its ring has), and two counts stand
+beside them: ``kv_blocks_read_window``, the sliding layers' part of the
+first, and ``kv_layers``, the layers the sums run over. For a decoder
+with one length all three stay what they were: blocks a lane, no layers.
+
 EXPERTS: a decoder with sparse layers says so (``apply_fn.
 moe_expert_slots``: the experts it holds, summed over its sparse layers)
 and can be asked for a launch's routing counts. The plain step with an
@@ -354,6 +372,17 @@ def recurrent_lane_bytes(make_cache) -> int:
                if a.shape == b.shape)
 
 
+def kv_leaf_rows(make_cache, lane_len: int) -> List[int]:
+    """Rows of each attention layer's K leaf of ONE lane of ``lane_len``
+    positions, in tree order: ``lane_len`` for a leaf that holds every
+    position, fewer for a ring (a sliding-window layer's). Shapes only,
+    nothing is allocated."""
+    leaves = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda: make_cache(1, lane_len)))[0]
+    return [x.shape[1] for path, x in leaves
+            if path and getattr(path[-1], "key", None) == "k"]
+
+
 class Engine:
     """Continuous-batching engine over a ``(apply_fn, make_cache)``
     decoder pair (`models.generate.gpt2_decoder` / `llama_decoder`).
@@ -436,6 +465,39 @@ class Engine:
         slack = max(cfg.prefill_chunk, cfg.num_draft + 1) - 1
         lane_len = -(-(cfg.max_len + slack) // DECODE_BLOCK) * DECODE_BLOCK
         self._lane_blocks = lane_len // DECODE_BLOCK
+        # leaves of two lengths: a sliding-window layer's is a ring
+        self._kv_rows = kv_leaf_rows(make_cache, lane_len)
+        rings = sorted({n for n in self._kv_rows if n < lane_len})
+        self._window = (int(getattr(apply_fn, "sliding_window", 0))
+                        if rings else 0)
+        if rings:
+            if not self._window:
+                raise ValueError(
+                    f"this decoder's cache has K/V leaves of {rings} rows "
+                    f"beside {lane_len}: a ring, whose decoder names its "
+                    f"window (apply_fn.sliding_window)")
+            missing = [why for asked, why in (
+                (cfg.prefix_cache,
+                 "prefix_cache=True (a ring has forgotten the positions "
+                 "before its window, which a sharer at a shorter length "
+                 "would attend)"),
+                (cfg.num_draft > 0,
+                 "num_draft > 0 (a rejected draft's rows have overwritten "
+                 "positions that the roll-back would attend again)"),
+                (cfg.paged,
+                 "paged=True (the paged pool holds pages of every "
+                 "position and has no ring)"),
+                (cfg.prefill_chunk > rings[0] - self._window,
+                 f"prefill_chunk={cfg.prefill_chunk} (a ring of {rings[0]} "
+                 f"rows under a window of {self._window} has "
+                 f"{rings[0] - self._window} rows of slack: a longer "
+                 f"chunk overwrites rows its own queries attend)"))
+                if asked]
+            if missing:
+                raise ValueError(
+                    "this decoder's cache holds sliding-window rings, "
+                    "which the engine cannot serve with "
+                    + "; ".join(missing))
         if cache_dtype is None:
             cache_dtype = cfg.cache_dtype    # kwarg (degraded-mode
         #                                      restarts) beats config
@@ -499,6 +561,13 @@ class Engine:
              "kv_blocks_pool", "ran_ahead", "overrun_lanes"), 0)
         if self._state_lane_bytes:
             self._tally.update(state_lanes=0, state_bytes=0)
+        if self._window:
+            self._tally.update(kv_blocks_read_window=0, kv_layers=0)
+            # what a launch's counts need of the tree, once: the sliding
+            # layers, and the blocks the pool's K leaves hold
+            self._ring_layers = sum(n < lane_len for n in self._kv_rows)
+            self._pool_blocks = (cfg.max_slots * sum(self._kv_rows)
+                                 // DECODE_BLOCK)
         # eos_id=None: retirement is length-based, so step tokens are
         # only READ at retirement — the log keeps each step's (N,)
         # output (device array until first fetch memoizes it as numpy).
@@ -1106,12 +1175,29 @@ class Engine:
         """Tally what the step's attention has to move, in blocks of
         `DECODE_BLOCK` positions: each lane of the batch up to the
         horizon of its ``width`` new tokens, of the blocks the pool
-        holds. Host arithmetic on the slots' depths, no device read."""
-        self._tally["kv_blocks_pool"] += (self.cfg.max_slots
-                                          * self._lane_blocks)
-        self._tally["kv_blocks_read"] += sum(
-            -(-(st.depth + width) // DECODE_BLOCK)
-            for st in self._slots if st is not None and st.in_batch)
+        holds. Host arithmetic on the slots' depths, no device read.
+        With rings in the pool, both are sums over the attention layers:
+        a sliding layer reads the blocks that hold its window (what
+        `ops.decode_attend` walks), of those its ring has."""
+        if not self._window:
+            self._tally["kv_blocks_pool"] += (self.cfg.max_slots
+                                              * self._lane_blocks)
+            self._tally["kv_blocks_read"] += sum(
+                -(-(st.depth + width) // DECODE_BLOCK)
+                for st in self._slots if st is not None and st.in_batch)
+            return
+        depths = [st.depth for st in self._slots
+                  if st is not None and st.in_batch]
+        upto = sum(-(-(d + width) // DECODE_BLOCK) for d in depths)
+        below = sum(max(d - self._window + 1, 0) // DECODE_BLOCK
+                    for d in depths)
+        in_window = self._ring_layers * (upto - below)
+        n_layers = len(self._kv_rows)
+        self._tally["kv_blocks_pool"] += self._pool_blocks
+        self._tally["kv_blocks_read"] += (
+            (n_layers - self._ring_layers) * upto + in_window)
+        self._tally["kv_blocks_read_window"] += in_window
+        self._tally["kv_layers"] += n_layers
 
     def _launch(self) -> tuple:
         """Dispatch the step executable for the lanes in the batch and

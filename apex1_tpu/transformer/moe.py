@@ -37,7 +37,10 @@ padded to `ops.moe_experts.ROW_TILE`), runs the grouped product
 experts held elsewhere would add is LEFT OUT: on one chip the layer runs
 without its exchange, and nothing stands in for the absent chips. The sum
 of the parts that every share gives is the whole layer
-(`tests/test_moe_dropless.py`).
+(`tests/test_moe_dropless.py`). A SHARED expert (`shared_expert_mlp`: one
+that every token passes through, beside the routed ones) is not a share:
+every chip holds it whole and computes it for its own tokens, so where
+the shares' parts are summed it is counted ONCE (`tests/test_afmoe.py`).
 """
 
 from __future__ import annotations
@@ -316,3 +319,16 @@ def held_experts_mlp(x2, experts, weights, w1, w3, w2, held: range,
                 preferred_element_type=jnp.float32)
     return y.astype(x2.dtype), jnp.stack(
         [jnp.sum(counts), jnp.sum(counts > 0)]).astype(jnp.int32)
+
+
+def shared_expert_mlp(x2, w1, w3, w2):
+    """The expert every token passes through, unrouted and unweighted:
+    ``W2 (silu(W1^T x) * (W3^T x))`` over ``x2`` (T, H), ``w1`` / ``w3``
+    (H, F), ``w2`` (F, H). A layer's result is this plus the routed part
+    (`held_experts_mlp`); in an expert-parallel deployment every chip
+    holds the shared expert whole, so the sum of the chips' routed parts
+    takes it once, not once a chip."""
+    up = jax.nn.silu(jnp.dot(x2, w1, preferred_element_type=jnp.float32)) \
+        * jnp.dot(x2, w3, preferred_element_type=jnp.float32)
+    return jnp.dot(up.astype(x2.dtype), w2,
+                   preferred_element_type=jnp.float32).astype(x2.dtype)

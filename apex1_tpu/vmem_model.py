@@ -204,7 +204,9 @@ def decode_attend_check(blocks, dims, es, budget):
     in HBM), the block-diagonal query block and the output block
     (double-buffered by Pallas), the new K/V rows (each its own tile),
     fp32 (acc, m, l) scratch over ``Rq`` query rows, and the live fp32
-    (Rq, block_l) score + exp tiles and (W, HD) append window."""
+    (Rq, block_l) score + exp tiles and (W, HD) append window. A window
+    over a ring (a sliding-attention layer's leaf) changes WHICH blocks
+    the kernel walks and masks, not what it holds: the same frame."""
     bl = blocks["block_l"]
     hd, rq, w = dims["HD"], dims["Rq"], dims["W"]
     est = (2 * DB * es * bl * hd                   # k, v block buffers

@@ -342,6 +342,20 @@ def _run_checks(args) -> bool:
                            (B_d, L_d, Hkv_d * D_d), (B_d,)],
                           dtypes=[jnp.bfloat16, jnp.bfloat16, jnp.bfloat16,
                                   cdt, cdt, jnp.int32])
+        # a WINDOW over a RING (PR 48), at the `trinitymini_serve_longctx`
+        # cell's shapes: 16 lanes, rows of 512 lanes (GQA 32/4, D=128),
+        # the sliding layers' rings of 2304 rows under a window of 2048
+        # and, without a window, the global layers' leaves of 8960
+        for tag, L_w, kw in (("ring 2304 window 2048", 2304,
+                              {"window": 2048}),
+                             ("global leaf 8960", 8960, {})):
+            check(f"decode_attend trinity-mini cell {tag} "
+                  f"(16,Hq32/Hkv4,D128,L{L_w})",
+                  lambda q, kn, vn, kp, vp, ix, kw=kw: decode_attend(
+                      q, kn, vn, kp, vp, ix, **kw),
+                  [(16, 32, 1, 128), (16, 4, 1, 128), (16, 4, 1, 128),
+                   (16, L_w, 512), (16, L_w, 512), (16,)],
+                  dtypes=[jnp.bfloat16] * 5 + [jnp.int32])
         for bad_len, bad_s in ((1151, 1), (1152, 128)):
             try:
                 check_decode_geometry(bad_len, 1024, 16 * bad_s, bad_s,
@@ -353,6 +367,15 @@ def _run_checks(args) -> bool:
                 ok = False
                 print(f"  FAIL decode geometry gate: L={bad_len} S={bad_s} "
                       f"must raise ValueError", flush=True)
+        try:        # a ring shorter than its window and one call's rows
+            check_decode_geometry(2048, 512, 160, 5, jnp.bfloat16, 2046)
+        except ValueError as e:
+            print(f"  OK   decode geometry ring=2048 window=2046 S=5 "
+                  f"raises: {str(e)[:60]}", flush=True)
+        else:
+            ok = False
+            print("  FAIL decode geometry gate: a ring of 2048 under a "
+                  "window of 2046 and 5 rows must raise", flush=True)
 
         # the decode step's state update of a state-space layer (PR 34),
         # at the `granite4hm_serve_chat` cell's shapes: 48 slots of 64
@@ -377,6 +400,19 @@ def _run_checks(args) -> bool:
               dtypes=[jnp.bfloat16, jnp.float32] + [jnp.bfloat16] * 3
               + [jnp.int32] * 2,
               in_specs=(P(),) * 7)
+        # and at the `trinitymini_serve_longctx` cell's (PR 48): 16 small
+        # experts of 2048 x 1024 held, the step's 16 rows x top-8 and a
+        # prefill chunk's 256 x top-8
+        for tag, n_rows in (("step 16x8 rows", 16 * 8),
+                            ("prefill chunk 256x8 rows", 256 * 8)):
+            rows = padded_rows(n_rows, 16)
+            check(f"moe_experts trinity-mini cell ({tag}, E16, 2048x1024)",
+                  moe_experts,
+                  [(rows, 2048), (rows,), (16, 2048, 1024),
+                   (16, 2048, 1024), (16, 1024, 2048), (16,), (16,)],
+                  dtypes=[jnp.bfloat16, jnp.float32] + [jnp.bfloat16] * 3
+                  + [jnp.int32] * 2,
+                  in_specs=(P(),) * 7)
 
         # chunked preference/distill losses, fused GLU, LoRA epilogue
         # (ISSUE 19): the chunked-loss VJP recomputes per vocab chunk
