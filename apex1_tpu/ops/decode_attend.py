@@ -62,6 +62,25 @@ DECODE_BLOCK = 128
 #: query rows (``Hq * S``) the kernel takes; more go to the composite
 MAX_ROWS = 256
 _LANES = 128
+#: K and V bytes the kernel keeps in flight, one constant whatever the
+#: model: with one pair of 128 KB copies in flight (the two-buffer
+#: schedule) rows of 512 lanes stand at 47 % of the HBM stream; the time a
+#: block is flat from 0.75 MiB (three fetches) at rows of 512 lanes and
+#: from 1.5 MiB at rows of 1024, where a block's own products set the pace
+#: or the stream is full (docs/ops.md has the chip's sweep, PR 50)
+FETCH_BYTES = 2 * 1024 * 1024
+
+
+def fetch_depth(lanes: int, dtype, length: Optional[int] = None) -> int:
+    """Fetches (one K and one V block each) the kernel keeps in flight
+    over a pool whose rows are ``lanes`` wide: the blocks that make up
+    `FETCH_BYTES`, or a quarter of `vmem_model.budget_bytes` where that
+    is less, and two at least. From the row's bytes and the budget alone
+    (the engine counts `kv_fetch_ahead` with it)."""
+    from apex1_tpu.vmem_model import budget_bytes
+    blk = DECODE_BLOCK if length is None else min(DECODE_BLOCK, length)
+    pair = 2 * blk * lanes * jnp.dtype(dtype).itemsize
+    return max(2, min(FETCH_BYTES, budget_bytes() // 4) // pair)
 
 
 def _sublanes(dtype) -> int:
@@ -74,7 +93,8 @@ def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
     """Loud validation of a `decode_attend` geometry, at trace time and
     again by ``tools/aot_check``: whole blocks, whole tiles, and a VMEM
     frame under the shared `vmem_model` budget (a row narrower than a
-    multiple of 128 lanes is padded by the chip, not refused); with a
+    multiple of 128 lanes is padded by the chip, not refused; the K and V
+    buffers are `fetch_depth`'s, one pair a fetch in flight); with a
     ``window``, a ring that holds the window and the call's new rows in
     at least two blocks. Returns ``(block, write-back rows, padded
     rows)``."""
@@ -102,7 +122,8 @@ def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
             f"row would be overwritten while it is still attended")
     rp = -(-rows // 16) * 16
     fits, est = CHECKS["decode_attend"](
-        {"block_l": blk}, {"HD": lanes, "Rq": rp, "W": win},
+        {"block_l": blk, "depth": fetch_depth(lanes, dtype, length)},
+        {"HD": lanes, "Rq": rp, "W": win},
         jnp.dtype(dtype).itemsize, budget_bytes())
     if not fits:
         raise ValueError(
@@ -113,35 +134,85 @@ def check_decode_geometry(length: int, lanes: int, rows: int, s: int,
 
 def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
                           o_ref, kp_out, vp_out, kbuf, vbuf, acc, m_scr,
-                          l_scr, rsem, wsem, *, scale, S, G, Hkv, D, blk,
-                          sub, win, window=None):
+                          l_scr, rsem, wsem, cur=None, *, scale, S, G, Hkv,
+                          D, blk, sub, win, window=None):
+    """``cur`` (SMEM, three words that outlive a grid step) is the queue's
+    state: the lane and the block of the next fetch to start, and the
+    buffer of the next block to consume. Without it the schedule is the
+    two-buffer one that empties at every lane's edge."""
     b = pl.program_id(0)
     idx = idx_ref[b]
+    B = idx_ref.shape[0]
+    depth = kbuf.shape[0]
     Rp, HD = acc.shape
     Hq = G * Hkv
+    n_ring = kp_in.shape[1] // blk
+
+    def window_first(ix):
+        # the first block of POSITIONS a lane at ix walks: the one that
+        # holds its window's first (None: block 0 of a plain leaf)
+        if window is None:
+            return None
+        return jnp.maximum(ix - window + 1, 0) // blk
+
+    def blocks(ix, first):
+        # how many it walks: up to the horizon (a row past a plain
+        # pool's end is dropped; a ring has no end)
+        if window is None:
+            return jnp.minimum((ix + S - 1) // blk + 1, n_ring)
+        return (ix + S - 1) // blk + 1 - first
+
+    first = window_first(idx)
     if window is None:
         # block i of the leaf holds positions i * blk ...
-        first = None
-        at = lambda i: i
+        at = lambda i, first=None: i
         base = lambda i: i * blk
     else:
-        # the blocks of POSITIONS from the window's first; block a of
-        # them lies in the ring's block a mod n_ring
-        n_ring = kp_in.shape[1] // blk
-        first = jnp.maximum(idx - window + 1, 0) // blk
-        at = lambda i: jax.lax.rem(first + i, n_ring)
+        # block a of the blocks of positions lies in the ring's block a
+        # mod n_ring
+        at = lambda i, first=first: jax.lax.rem(first + i, n_ring)
         base = lambda i: (first + i) * blk     # block i's first position
+
+    def fetch(i, slot, lane=b, first=first):
+        rows = pl.ds(pl.multiple_of(at(i, first) * blk, blk), blk)
+        return (pltpu.make_async_copy(kp_in.at[lane, rows, :],
+                                      kbuf.at[slot], rsem.at[0, slot]),
+                pltpu.make_async_copy(vp_in.at[lane, rows, :],
+                                      vbuf.at[slot], rsem.at[1, slot]))
+
+    def next_live(c):
+        # the first lane from c on that has blocks to fetch (B: none)
+        return jax.lax.while_loop(
+            lambda c: (c < B) & (idx_ref[jnp.minimum(c, B - 1)] < 0),
+            lambda c: c + 1, c)
+
+    def issue(c, j, slot):
+        """Start the queue's next fetch, block ``j`` of lane ``c``'s walk,
+        into ``slot``, and step on: to the lane's next block, or to the
+        next live lane's first. Past the last live lane (``c == B``)
+        nothing is started. The lane, its depth and its walk are read
+        HERE, from ``c`` alone: never the consuming lane's."""
+        def start():
+            first = window_first(idx_ref[c])
+            for d in fetch(j, slot, c, first):
+                d.start()
+            last = j + 1 >= blocks(idx_ref[c], first)
+            return (jax.lax.cond(last, lambda: next_live(c + 1), lambda: c),
+                    jnp.where(last, 0, j + 1))
+        return jax.lax.cond(c < B, start, lambda: (c, j))
+
+    if cur is not None:
+        @pl.when(b == 0)
+        def _():
+            # the queue's first depth - 1 fetches, whoever's they are
+            c, j = jax.lax.fori_loop(
+                0, depth - 1, lambda k, cj: issue(*cj, k),
+                (next_live(jnp.int32(0)), jnp.int32(0)))
+            cur[0], cur[1], cur[2] = c, j, jnp.int32(0)
 
     @pl.when(idx < 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
-
-    def fetch(i, slot):
-        rows = pl.ds(pl.multiple_of(at(i) * blk, blk), blk)
-        return (pltpu.make_async_copy(kp_in.at[b, rows, :], kbuf.at[slot],
-                                      rsem.at[0, slot]),
-                pltpu.make_async_copy(vp_in.at[b, rows, :], vbuf.at[slot],
-                                      rsem.at[1, slot]))
 
     def new_rows(i):
         # the aligned rows of block i that hold this lane's new ones
@@ -193,26 +264,22 @@ def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
 
     @pl.when(idx >= 0)
     def _():
-        # blocks up to the horizon; a row past the pool's end is dropped
-        n = jnp.minimum((idx + S - 1) // blk + 1, kp_in.shape[1] // blk)
+        n = blocks(idx, first)
         first_new = idx // blk                 # first block with a new row
-        if window is not None:                 # a ring has no end
-            n = (idx + S - 1) // blk + 1 - first
+        if window is not None:
             first_new -= first
-        for c in fetch(0, 0):
-            c.start()
+        if cur is None:
+            for c in fetch(0, 0):
+                c.start()
         acc[...] = jnp.zeros_like(acc)
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-        def body(i, _):
-            slot = jax.lax.rem(i, 2)
-
-            @pl.when(i + 1 < n)
-            def _():
-                for c in fetch(i + 1, 1 - slot):
-                    c.start()
-
+        def consume(i, slot):
+            # the lane's own arithmetic, in its own order, whatever the
+            # schedule: the block lands, takes the new rows, hands their
+            # aligned window back to the pool, and is attended; its buffer
+            # is free for the queue once the write-back has left it
             for c in fetch(i, slot):
                 c.wait()
 
@@ -229,7 +296,29 @@ def _decode_attend_kernel(idx_ref, q_ref, kn_ref, vn_ref, kp_in, vp_in,
                 for c in append(i, slot):
                     c.wait()
 
-        jax.lax.fori_loop(0, n, body, None)
+        if cur is None:
+            def body(i, _):
+                slot = jax.lax.rem(i, 2)
+
+                @pl.when(i + 1 < n)
+                def _():
+                    for c in fetch(i + 1, 1 - slot):
+                        c.start()
+
+                consume(i, slot)
+
+            jax.lax.fori_loop(0, n, body, None)
+        else:
+            def body(i, carry):
+                # the fetch depth - 1 blocks on goes where the block
+                # before this one was consumed, and written back from
+                c, j, slot = carry
+                c, j = issue(c, j, jnp.where(slot == 0, depth, slot) - 1)
+                consume(i, slot)
+                return c, j, jnp.where(slot == depth - 1, 0, slot + 1)
+
+            cur[0], cur[1], cur[2] = jax.lax.fori_loop(
+                0, n, body, (cur[0], cur[1], cur[2]))
         # each row keeps the lanes of its own head; a 0/1 product then
         # sums the Hkv rows of one (s, g) into one lane-dense row
         x = acc[...] / l_scr[:, :1]
@@ -270,6 +359,7 @@ def decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *,
     return _decode_attend(q, k_new, v_new, k_pool, v_pool,
                           jnp.asarray(idx, jnp.int32), scale=float(scale),
                           geometry=geometry, interpret=interpret_mode(),
+                          depth=fetch_depth(HD, k_pool.dtype, L),
                           **({} if window is None
                              else {"window": int(window)}))
 
@@ -277,10 +367,16 @@ def decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *,
 # a program's layers call this with the same shapes: jitted, they share
 # one traced kernel and one lowering of it (24 of them took 10 s of a
 # step executable's first call: PERF.md §6, PR 29)
-@functools.partial(jax.jit, static_argnames=("scale", "geometry",
-                                             "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "geometry", "interpret", "depth", "run_on", "window"))
 def _decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *, scale,
-                   geometry, interpret, window=None):
+                   geometry, interpret, depth, run_on=True, window=None):
+    """``depth`` fetches in flight in one queue over the call's lanes.
+    ``run_on=False`` (depth 2, no caller but the parity checks): the
+    schedule before the queue, two buffers emptied at every lane's edge,
+    traced op for op as it was."""
+    if not run_on and depth != 2:
+        raise ValueError("the two-buffer schedule is two deep")
     B, Hq, S, D = q.shape
     Hkv = k_new.shape[1]
     HD = Hkv * D
@@ -312,13 +408,14 @@ def _decode_attend(q, k_new, v_new, k_pool, v_pool, idx, *, scale,
                          memory_space=pltpu.VMEM),
             any_spec, any_spec],
         scratch_shapes=[
-            pltpu.VMEM((2, blk, HD), k_pool.dtype),
-            pltpu.VMEM((2, blk, HD), v_pool.dtype),
+            pltpu.VMEM((depth, blk, HD), k_pool.dtype),
+            pltpu.VMEM((depth, blk, HD), v_pool.dtype),
             pltpu.VMEM((Rp, HD), jnp.float32),
             pltpu.VMEM((Rp, _LANES), jnp.float32),
             pltpu.VMEM((Rp, _LANES), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2,))],
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.SemaphoreType.DMA((2,)),
+            *([pltpu.SMEM((3,), jnp.int32)] if run_on else [])],
     )
     out, k_pool, v_pool = kernel_call(
         functools.partial(_decode_attend_kernel, scale=scale, S=S, G=G,

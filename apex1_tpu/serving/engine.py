@@ -151,7 +151,9 @@ device programs it launched outside the two executables, and
 ``kv_blocks_read`` of
 ``kv_blocks_pool``: the blocks of K/V positions the step's attention
 has to move, each live lane's up to its horizon, of those the pool
-holds (host arithmetic on the slots' depths). They are kept in memory,
+holds, with ``kv_fetch_ahead``: those of them that the kernel's queue
+of fetches starts from an earlier lane's grid step (host arithmetic on
+the slots' depths, all three). They are kept in memory,
 always,
 and lie in a profiler trace on the device's clock when one is taken.
 
@@ -232,7 +234,7 @@ import numpy as np
 from apex1_tpu.models.generate import (counter_sample, last_real_logits,
                                        sample_token)
 from apex1_tpu.ops._common import use_pallas
-from apex1_tpu.ops.decode_attend import DECODE_BLOCK
+from apex1_tpu.ops.decode_attend import DECODE_BLOCK, fetch_depth
 from apex1_tpu.ops.paged_decode import (PagedCache, fused_sample,
                                         gather_pages, scatter_pages)
 from apex1_tpu.resilience.retry import _mix32
@@ -372,15 +374,32 @@ def recurrent_lane_bytes(make_cache) -> int:
                if a.shape == b.shape)
 
 
+def _k_leaves(make_cache, lane_len: int, **form) -> list:
+    """Each attention layer's K leaf of ONE lane of ``lane_len`` positions
+    (its shape and dtype: nothing is allocated), in tree order."""
+    leaves = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda: make_cache(1, lane_len, **form)))[0]
+    return [x for path, x in leaves
+            if path and getattr(path[-1], "key", None) == "k"]
+
+
 def kv_leaf_rows(make_cache, lane_len: int) -> List[int]:
     """Rows of each attention layer's K leaf of ONE lane of ``lane_len``
     positions, in tree order: ``lane_len`` for a leaf that holds every
     position, fewer for a ring (a sliding-window layer's). Shapes only,
     nothing is allocated."""
-    leaves = jax.tree_util.tree_flatten_with_path(
-        jax.eval_shape(lambda: make_cache(1, lane_len)))[0]
-    return [x.shape[1] for path, x in leaves
-            if path and getattr(path[-1], "key", None) == "k"]
+    return [x.shape[1] for x in _k_leaves(make_cache, lane_len)]
+
+
+def fetch_ahead(lanes: List[int], blocks: List[int], depth: int) -> int:
+    """Of the blocks one `ops.decode_attend` call fetches (``blocks[i]``
+    for the live lane ``lanes[i]``, in lane order), those whose fetch an
+    EARLIER lane's grid step starts: the kernel keeps ``depth`` fetches
+    of the call's one sequence in flight, so a lane's first ``depth - 1``
+    blocks are started before its own step, unless it is lane 0 (whose
+    step starts the queue)."""
+    ahead = [min(n, depth - 1) for n in blocks]
+    return sum(ahead) - (ahead[0] if lanes and lanes[0] == 0 else 0)
 
 
 class Engine:
@@ -558,7 +577,14 @@ class Engine:
         self._tally = dict.fromkeys(
             ("admitted", "retired", "prefill_chunks", "prefill_tokens",
              "tokens_out", "control_dispatches", "kv_blocks_read",
-             "kv_blocks_pool", "ran_ahead", "overrun_lanes"), 0)
+             "kv_blocks_pool", "kv_fetch_ahead", "ran_ahead",
+             "overrun_lanes"), 0)
+        # the fetches the step kernel keeps in flight over this pool's
+        # rows (their bytes alone: `ops.decode_attend.fetch_depth`)
+        row = next(iter(_k_leaves(make_cache, lane_len, **(
+            {} if cache_dtype is None else {"dtype": cache_dtype}))), None)
+        self._fetch_depth = (2 if row is None else
+                             fetch_depth(row.shape[-1], row.dtype))
         if self._state_lane_bytes:
             self._tally.update(state_lanes=0, state_bytes=0)
         if self._window:
@@ -1175,28 +1201,34 @@ class Engine:
         """Tally what the step's attention has to move, in blocks of
         `DECODE_BLOCK` positions: each lane of the batch up to the
         horizon of its ``width`` new tokens, of the blocks the pool
-        holds. Host arithmetic on the slots' depths, no device read.
-        With rings in the pool, both are sums over the attention layers:
-        a sliding layer reads the blocks that hold its window (what
-        `ops.decode_attend` walks), of those its ring has."""
+        holds, and ``kv_fetch_ahead``: those of them whose fetch the
+        kernel's queue starts from an earlier lane's grid step
+        (`fetch_ahead`). Host arithmetic on the slots' depths, no device
+        read. With rings in the pool, all are sums over the attention
+        layers: a sliding layer reads the blocks that hold its window
+        (what `ops.decode_attend` walks), of those its ring has."""
+        lanes = [i for i, st in enumerate(self._slots)
+                 if st is not None and st.in_batch]
+        upto = [-(-(self._slots[i].depth + width) // DECODE_BLOCK)
+                for i in lanes]
+        ahead = fetch_ahead(lanes, upto, self._fetch_depth)
         if not self._window:
             self._tally["kv_blocks_pool"] += (self.cfg.max_slots
                                               * self._lane_blocks)
-            self._tally["kv_blocks_read"] += sum(
-                -(-(st.depth + width) // DECODE_BLOCK)
-                for st in self._slots if st is not None and st.in_batch)
+            self._tally["kv_blocks_read"] += sum(upto)
+            self._tally["kv_fetch_ahead"] += ahead
             return
-        depths = [st.depth for st in self._slots
-                  if st is not None and st.in_batch]
-        upto = sum(-(-(d + width) // DECODE_BLOCK) for d in depths)
-        below = sum(max(d - self._window + 1, 0) // DECODE_BLOCK
-                    for d in depths)
-        in_window = self._ring_layers * (upto - below)
+        held = [n - max(self._slots[i].depth - self._window + 1, 0)
+                // DECODE_BLOCK for i, n in zip(lanes, upto)]
+        in_window = self._ring_layers * sum(held)
         n_layers = len(self._kv_rows)
         self._tally["kv_blocks_pool"] += self._pool_blocks
         self._tally["kv_blocks_read"] += (
-            (n_layers - self._ring_layers) * upto + in_window)
+            (n_layers - self._ring_layers) * sum(upto) + in_window)
         self._tally["kv_blocks_read_window"] += in_window
+        self._tally["kv_fetch_ahead"] += (
+            (n_layers - self._ring_layers) * ahead + self._ring_layers
+            * fetch_ahead(lanes, held, self._fetch_depth))
         self._tally["kv_layers"] += n_layers
 
     def _launch(self) -> tuple:

@@ -199,7 +199,8 @@ def paged_decode_check(blocks, dims, es, budget):
 
 def decode_attend_check(blocks, dims, es, budget):
     """Dense-pool decode attention (`ops.decode_attend.decode_attend`):
-    the kernel's own two-slot K and V block buffers (``block_l``
+    the kernel's own K and V block buffers, one pair a fetch of its queue
+    (``depth`` of them, `ops.decode_attend.fetch_depth`; ``block_l``
     positions of ``HD`` lanes, CACHE dtype ``es``; the pool itself stays
     in HBM), the block-diagonal query block and the output block
     (double-buffered by Pallas), the new K/V rows (each its own tile),
@@ -207,9 +208,9 @@ def decode_attend_check(blocks, dims, es, budget):
     (Rq, block_l) score + exp tiles and (W, HD) append window. A window
     over a ring (a sliding-attention layer's leaf) changes WHICH blocks
     the kernel walks and masks, not what it holds: the same frame."""
-    bl = blocks["block_l"]
+    bl, depth = blocks["block_l"], blocks["depth"]
     hd, rq, w = dims["HD"], dims["Rq"], dims["W"]
-    est = (2 * DB * es * bl * hd                   # k, v block buffers
+    est = (2 * depth * es * bl * hd                # k, v block buffers
            + DB * es * rq * hd                     # q block
            + DB * es * rq * hd                     # o block (<= Rq rows)
            + 2 * DB * es * w * hd                  # new k, v rows

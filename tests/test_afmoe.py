@@ -411,9 +411,10 @@ def test_the_pool_at_the_published_lengths_and_what_a_step_reads():
     eng = types.SimpleNamespace(
         cfg=types.SimpleNamespace(max_slots=16), _window=2048,
         _kv_rows=rows, _lane_blocks=70, _ring_layers=24,
-        _pool_blocks=16 * (8 * 70 + 24 * 18),
+        _pool_blocks=16 * (8 * 70 + 24 * 18), _fetch_depth=8,
         _tally=dict.fromkeys(("kv_blocks_pool", "kv_blocks_read",
-                              "kv_blocks_read_window", "kv_layers"), 0))
+                              "kv_blocks_read_window", "kv_layers",
+                              "kv_fetch_ahead"), 0))
     for depth in (0, 100, 2047, 2048, 2049, 2175, 4096, 4223, 8703):
         before = dict(eng._tally)
         eng._slots = [types.SimpleNamespace(depth=depth, in_batch=True),
@@ -428,6 +429,8 @@ def test_the_pool_at_the_published_lengths_and_what_a_step_reads():
         assert ring <= 2048 // 128 + 2
         assert ring == depth // 128 + 1 - max(depth - 2047, 0) // 128
         assert got["kv_blocks_read"] == 8 * (depth // 128 + 1) + 24 * ring
+        # the one live lane is lane 0: its own step starts its fetches
+        assert got["kv_fetch_ahead"] == 0
 
 
 def test_the_harnesss_draw_starves_no_expert_and_the_bias_chooses():

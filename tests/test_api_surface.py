@@ -112,7 +112,7 @@ SURFACE = {
         "paged_update_attend", "sample_token", "scatter_pages"],
     "apex1_tpu.ops.decode_attend": [
         "decode_attend", "check_decode_geometry", "DECODE_BLOCK",
-        "MAX_ROWS"],
+        "MAX_ROWS", "fetch_depth", "FETCH_BYTES"],
     "apex1_tpu.models.generate": [
         "generate", "speculative_generate", "beam_search", "t5_generate",
         "init_cache", "cache_len", "cache_write", "cached_attention",

@@ -14,7 +14,16 @@ the dense pool, in interpret mode on the CPU.
   partly forgotten, lanes shallower and deeper than the window, idle
   lanes, appends across the ring's end; windows and rings got wrong are
   seen; a ring that cannot hold its window is refused; without a window
-  the kernel is traced as it was;
+  the two-buffer schedule is traced as the kernel was before its queue;
+- the QUEUE of fetches (PR 50): at every depth the serving cells derive,
+  and at 2, 3 and 8, the attention and both leaves are the two-buffer
+  schedule's bit for bit, over idle lanes inside, first and last, lanes
+  of one block, a ring at its wrap and past three windows, a ring block
+  read twice, chunks across an edge and the last lane; the order in which
+  fetches start (a model of the kernel's cursor) visits every (lane,
+  block) once, in the order they are consumed, never a block below a
+  window, never into a buffer still owned, nothing past the end; the
+  depth follows the row's bytes and the VMEM budget alone;
 - `cached_attention` takes the kernel by what it sees in its input: a
   rank-1 index, `use_pallas()` and the row count; everything else is
   the composite;
@@ -33,9 +42,10 @@ import pytest
 from apex1_tpu.models.generate import (cache_len, cache_write,
                                        cached_attention, init_cache)
 from apex1_tpu.ops import _common
-from apex1_tpu.ops.decode_attend import (DECODE_BLOCK, MAX_ROWS,
+from apex1_tpu.ops import decode_attend as da
+from apex1_tpu.ops.decode_attend import (DECODE_BLOCK, FETCH_BYTES, MAX_ROWS,
                                          check_decode_geometry,
-                                         decode_attend)
+                                         decode_attend, fetch_depth)
 from apex1_tpu.ops.paged_decode import cache_attend
 
 BLK = DECODE_BLOCK
@@ -317,22 +327,239 @@ def test_a_ring_that_cannot_hold_its_window_is_refused(why):
         cached_attention(q, q, q, cache, 0, window=2 * BLK - 20)
 
 
-def test_without_a_window_the_kernel_is_traced_as_before():
-    """No window: the call's jaxpr is the one the kernel had before it
-    knew of windows, op for op (its sha256 at 9111a1f, the commit before,
-    read there with this very call), so the three accepted serving cells'
-    step programs lower to their parents' text. A later PR that changes
-    the kernel on purpose reads it anew, and says so."""
+def _scheduled(window=None, **schedule):
+    """`decode_attend` with the inner call's schedule forced: what only
+    the parity checks do (the public call derives the depth from bytes)."""
+    def call(q, kn, vn, kp, vp, idx):
+        _, Hq, S, D = q.shape
+        _, L, HD = kp.shape
+        return da._decode_attend(
+            q, kn, vn, kp, vp, jnp.asarray(idx, jnp.int32), scale=0.2,
+            geometry=check_decode_geometry(L, HD, Hq * S, S, kp.dtype,
+                                           window),
+            interpret=True, **schedule,
+            **({} if window is None else {"window": window}))
+    return call
+
+
+def test_without_a_window_the_two_buffer_schedule_is_traced_as_before():
+    """The schedule before the queue (`run_on=False`, kept for the parity
+    checks on the chip) is the kernel the three older serving cells ran,
+    op for op: its jaxpr's sha256 is the one read at 9111a1f, before the
+    kernel knew of windows or queues. The queue's own text is read anew
+    by PR 50 (a later PR that changes the kernel on purpose reads it
+    again, and says so); a ring still costs it a `rem` a block."""
     import hashlib
     idx = jnp.asarray([3, -1], jnp.int32)
     q, kn, _, kp, _ = _operands(2, 4, 2, 64, 2 * BLK, 1, jnp.float32)
+    sha = lambda text: hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def two_buffer(*a, **kw):
+        with pytest.MonkeyPatch.context() as mp:
+            inner = da._decode_attend
+            mp.setattr(da, "_decode_attend", lambda *x, **k: inner(
+                *x, **dict(k, depth=2, run_on=False)))
+            return decode_attend(*a, **kw)
+
     with _common.force_impl("pallas"):
-        plain = str(jax.make_jaxpr(decode_attend)(q, kn, kn, kp, kp, idx))
+        before = str(jax.make_jaxpr(two_buffer)(q, kn, kn, kp, kp, idx))
+        plain = str(jax.make_jaxpr(lambda *a: decode_attend(*a))(
+            q, kn, kn, kp, kp, idx))
         ring = str(jax.make_jaxpr(lambda *a: decode_attend(
             *a, window=BLK + 8))(q, kn, kn, kp, kp, idx))
-    assert hashlib.sha256(plain.encode()).hexdigest()[:16] \
-        == "f9facd897752e3e5"
+    assert sha(before) == "f9facd897752e3e5"
+    assert sha(plain) == "c81b8ed74f54912f"
     assert ring.count(" rem ") > plain.count(" rem ")
+
+
+# -- the queue of fetches ------------------------------------------------------
+
+#: the depths the four serving cells' rows derive (GPT-2's 1024 lanes of
+#: bfloat16: 4; granite's, lfm2's and Trinity-Mini's 512: 8), and 2, 3, 8
+_DEPTHS = sorted({2, 3, 8, fetch_depth(1024, jnp.bfloat16),
+                  fetch_depth(512, jnp.bfloat16)})
+
+_QUEUE_CASES = {
+    # idle lanes inside, every edge of a block, the last row that fits
+    "ragged": lambda S: (None, 3 * BLK, _ragged(3 * BLK, S)),
+    # idle lanes first and last, one live lane among idle ones
+    "idle_ends": lambda S: (None, 3 * BLK, [-1, -1, 2 * BLK + 9, -1, -1,
+                                            -1, 7, -1]),
+    # lanes of one block, more of them than any queue is deep, then deep
+    # lanes, and a one-block lane last
+    "one_block_lanes": lambda S: (None, 3 * BLK, [3, 0, 100, BLK - S, 5, 64,
+                                                  1, 2, 77, 9, 3 * BLK - S,
+                                                  2 * BLK, 4]),
+    "all_idle": lambda S: (None, 2 * BLK, [-1, -1, -1]),
+    "one_lane": lambda S: (None, 3 * BLK, [2 * BLK + 5]),
+    # shallower than the window, at the wrap, past three windows
+    "ring": lambda S: (_WINDOW, _RING, _ring_idx(S)),
+    # a ring of two blocks under a window that spans three: the block
+    # that holds both ends of the window is read twice in one walk
+    "ring_read_twice": lambda S: (BLK + 72, 2 * BLK, [
+        2 * BLK + 70 - S, 5 * BLK + 100 - S, -1, 3, 7 * BLK + 71 - S,
+        BLK + 71]),
+}
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("case", sorted(_QUEUE_CASES))
+@pytest.mark.parametrize("depth", _DEPTHS)
+def test_the_queue_gives_the_two_buffer_schedules_bits(depth, case, s):
+    """WHEN a block is fetched moves, nothing else: the attention and
+    both leaves equal the two-buffer schedule's bit for bit, whatever
+    the other lanes hold."""
+    window, L, idx = _QUEUE_CASES[case](s)
+    if window is None:
+        q, kn, vn, kp, vp = _operands(len(idx), 8, 2, 64, L, s, jnp.bfloat16)
+    else:
+        q, kn, vn, kp, vp, _, _ = _ring_case(idx, s, L, 8, 2, 64,
+                                             jnp.bfloat16)
+    ix = jnp.asarray(idx, jnp.int32)
+    want = jax.jit(_scheduled(window, depth=2, run_on=False))(
+        q, kn, vn, kp, vp, ix)
+    got = jax.jit(_scheduled(window, depth=depth))(q, kn, vn, kp, vp, ix)
+    for name, g, w in zip(("attention", "k", "v"), got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32), name)
+    if window is None and case != "all_idle":
+        np.testing.assert_array_equal(
+            np.asarray(got[1], np.float32),
+            np.asarray(cache_write(kp, kn, ix), np.float32))
+
+
+def test_the_two_buffer_schedule_is_two_deep():
+    q, kn, vn, kp, vp = _operands(2, 2, 2, 64, 2 * BLK, 1, jnp.float32)
+    with pytest.raises(ValueError, match="two deep"):
+        _scheduled(depth=3, run_on=False)(q, kn, vn, kp, vp, [3, 4])
+
+
+def _fetch_model(idx, S, L, window, depth):
+    """The kernel's cursor, step for step, on the host: ``(started,
+    consumed)``, two lists of ``(grid step, lane, block of the lane's
+    walk, block of the leaf, buffer)`` in the order the kernel starts its
+    fetches and waits for them; a started one also says how many blocks
+    had been consumed when it was."""
+    B, n_ring = len(idx), L // BLK
+
+    def walk(ix):
+        if window is None:
+            return 0, min((ix + S - 1) // BLK + 1, n_ring)
+        first = max(ix - window + 1, 0) // BLK
+        return first, (ix + S - 1) // BLK + 1 - first
+
+    def next_live(c):
+        while c < B and idx[c] < 0:
+            c += 1
+        return c
+
+    started, consumed = [], []
+    cur = {}
+
+    def issue(step, c, j, slot):
+        if c >= B:
+            return c, j
+        first, n = walk(idx[c])
+        started.append((step, c, j, (first + j) % n_ring, slot,
+                        len(consumed)))
+        return (next_live(c + 1), 0) if j + 1 >= n else (c, j + 1)
+
+    for b in range(B):
+        if b == 0:
+            c, j = next_live(0), 0
+            for k in range(depth - 1):
+                c, j = issue(0, c, j, k)
+            cur = dict(c=c, j=j, slot=0)
+        if idx[b] < 0:
+            continue
+        first, n = walk(idx[b])
+        for i in range(n):
+            slot = cur["slot"]
+            cur["c"], cur["j"] = issue(b, cur["c"], cur["j"],
+                                       (slot or depth) - 1)
+            consumed.append((b, b, i, (first + i) % n_ring, slot))
+            cur["slot"] = 0 if slot == depth - 1 else slot + 1
+    return started, consumed
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("case", sorted(_QUEUE_CASES))
+@pytest.mark.parametrize("depth", _DEPTHS)
+def test_the_fetches_start_in_the_order_they_are_consumed(depth, case, s):
+    """Every (lane, block) of the call is fetched exactly once and into
+    the buffer it is awaited in, in the order the lanes consume them;
+    never more than ``depth`` in flight, so never into a buffer whose
+    block is not consumed yet; never a block below a window or past a
+    pool's end; nothing once the last live lane's last block is started.
+    And `serving.engine.fetch_ahead`, which the step span's
+    ``kv_fetch_ahead`` is, counts the fetches an earlier lane's grid step
+    started."""
+    from apex1_tpu.serving.engine import fetch_ahead
+    window, L, idx = _QUEUE_CASES[case](s)
+    started, consumed = _fetch_model(idx, s, L, window, depth)
+    assert [x[1:5] for x in started] == [x[1:] for x in consumed]
+    want = []
+    for b, ix in enumerate(idx):
+        if ix < 0:
+            continue
+        lo = 0 if window is None else max(ix - window + 1, 0) // BLK
+        hi = (ix + s - 1) // BLK if window is not None \
+            else min((ix + s - 1) // BLK, L // BLK - 1)
+        want += [(b, a - lo, a % (L // BLK)) for a in range(lo, hi + 1)]
+    assert [x[1:4] for x in started] == want
+    # the k-th fetch starts before the k-th block is awaited, and only
+    # once the block that had its buffer (the one depth before it) has
+    # been consumed: at most depth fetches are ever in flight
+    for k, (step, lane, _, _, slot, done) in enumerate(started):
+        assert step <= lane and k - depth < done <= k
+        assert k < depth or consumed[k - depth][4] == slot
+    live = [b for b, ix in enumerate(idx) if ix >= 0]
+    blocks = [sum(1 for x in consumed if x[1] == b) for b in live]
+    assert fetch_ahead(live, blocks, depth) == sum(
+        1 for step, lane, *_ in started if step < lane)
+    if depth > 2 and case == "one_block_lanes":
+        assert fetch_ahead(live, blocks, depth) > len(live)
+
+
+@pytest.mark.parametrize("lanes,dtype,depth", [
+    (1024, jnp.bfloat16, 4), (512, jnp.bfloat16, 8), (512, jnp.int8, 16),
+    (512, jnp.float32, 4), (4096, jnp.bfloat16, 2), (8192, jnp.float32, 2)])
+def test_the_queues_depth_follows_the_rows_bytes_and_the_budget(
+        lanes, dtype, depth, monkeypatch):
+    """`FETCH_BYTES` of K and V blocks in flight, two fetches at least, a
+    quarter of the VMEM budget at most: GPT-2's rows of 1024 lanes get
+    half the depth of the 512-lane rows of granite, lfm2 and
+    Trinity-Mini. Nothing else enters: not the lanes of the batch, the
+    heads, the window or the leaf's length (in whole blocks)."""
+    import inspect
+    from apex1_tpu import vmem_model
+    assert fetch_depth(lanes, dtype) == depth
+    assert fetch_depth(lanes, dtype, 70 * BLK) == depth
+    pair = 2 * BLK * lanes * jnp.dtype(dtype).itemsize
+    assert depth == max(2, FETCH_BYTES // pair)
+    assert list(inspect.signature(fetch_depth).parameters) == [
+        "lanes", "dtype", "length"]
+    monkeypatch.setattr(vmem_model, "budget_bytes",
+                        lambda g=None: 4 * 3 * pair)
+    assert fetch_depth(lanes, dtype) == min(depth, 3)
+    src = inspect.getsource(da)
+    assert "environ" not in src and "getenv" not in src
+
+
+def test_the_public_call_keeps_the_derived_depth_of_buffers():
+    """`decode_attend` hands the kernel `fetch_depth`'s buffers, and the
+    frame `check_decode_geometry` prices holds that many."""
+    from apex1_tpu import vmem_model
+    q, kn, _, kp, _ = _operands(2, 4, 2, 64, 2 * BLK, 1, jnp.bfloat16)
+    depth = fetch_depth(128, jnp.bfloat16)
+    with _common.force_impl("pallas"):
+        text = str(jax.make_jaxpr(lambda *a: decode_attend(*a))(
+            q, kn, kn, kp, kp, jnp.asarray([3, -1], jnp.int32)))
+    assert f"bf16[{depth},{BLK},128]" in text
+    frame = lambda d: vmem_model.CHECKS["decode_attend"](
+        {"block_l": BLK, "depth": d}, {"HD": 512, "Rq": 32, "W": 16}, 2,
+        vmem_model.budget_bytes())[1]
+    assert frame(8) - frame(2) == 6 * 2 * BLK * 512 * 2
 
 
 def _kernels_in(fn, *args):
@@ -416,3 +643,80 @@ def test_engine_serves_the_composites_tokens_through_the_kernel(
         return [eng.results[i].tokens.tolist() for i in ids]
 
     assert serve("pallas") == serve("xla")
+
+
+@pytest.mark.parametrize("cache_dtype", [None, jnp.int8],
+                         ids=["as_built", "int8"])
+def test_the_engine_counts_with_the_kernels_own_depth(tiny, cache_dtype):
+    """`kv_fetch_ahead` on the step span is reckoned with the depth the
+    kernel derives for the pool's rows: read off the K leaf's width and
+    dtype, as `decode_attend` reads them off its operand."""
+    from apex1_tpu.serving.engine import Engine, EngineConfig, fetch_ahead
+    cfg, params, apply_fn, make_cache = tiny
+    eng = Engine(apply_fn, make_cache, params, EngineConfig(
+        max_slots=3, max_len=40, prefill_chunk=4, vocab_size=cfg.vocab_size,
+        cache_dtype=cache_dtype))
+    leaf = jax.tree_util.tree_leaves(eng.kv.cache)[0]
+    assert eng._fetch_depth == fetch_depth(leaf.shape[-1], leaf.dtype)
+    assert fetch_depth(leaf.shape[-1], leaf.dtype) > 2
+    # lanes 1 and 4 live, 12 and 3 blocks, a queue of 8: seven and three
+    # are started before their lane's step; lane 0 starts its own
+    assert fetch_ahead([1, 4], [12, 3], 8) == 7 + 3
+    assert fetch_ahead([0, 4], [12, 3], 8) == 3
+    assert fetch_ahead([], [], 8) == 0
+
+
+def _hw_numerics():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "hw_numerics.py")
+    spec = importlib.util.spec_from_file_location("hw_numerics", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("cell", ["gpt2m", "granite4hm", "lfm2moe",
+                                  "trinity_ring", "trinity_global"])
+def test_the_chips_parity_check_draws_depths_onto_every_edge(cell, s):
+    """`tools/hw_numerics.py --only decode_attend` is the proof the CPU
+    cannot give (a copy completes where it starts here); what the CPU CAN
+    hold is that its depths are the cells' own geometries and sit where
+    the issue asks: idle lanes between live ones, lanes of one block, a
+    row short of and past a block's edge, new rows astride one, and over
+    a ring lanes shallower than the window, at the wrap and past three
+    windows; every depth one the kernel takes."""
+    hw = _hw_numerics()
+    assert sorted(hw.DECODE_CELLS) == ["gpt2m", "granite4hm", "lfm2moe",
+                                       "trinity_global", "trinity_ring"]
+    B, Hq, Hkv, D, L, window = hw.DECODE_CELLS[cell]
+    check_decode_geometry(L, Hkv * D, Hq * s, s, jnp.bfloat16, window)
+    seen = set()
+    for seed in range(6):
+        idx = hw.decode_cell_depths(np.random.default_rng(seed), B, L, s,
+                                    window)
+        assert idx.shape == (B,) and idx.dtype == np.int32
+        live = idx[idx >= 0]
+        assert (idx < 0).sum() >= 1 and len(live) >= B // 2
+        assert live.max() + s <= (4 * window if window else L)
+        inside = np.flatnonzero(idx < 0)
+        seen |= {"idle_inside"} if ((inside > 0) & (inside < B - 1)).any() \
+            else set()
+        seen |= {"one_block"} if (live + s <= BLK).any() else set()
+        seen |= {"short_of_edge"} if ((live + s) % BLK == 0).any() else set()
+        seen |= {"past_edge"} if ((live % BLK == 0) & (live > 0)).any() \
+            else set()
+        if s > 1:
+            seen |= {"astride"} if (live // BLK != (live + s - 1) // BLK
+                                    ).any() else set()
+        if window:
+            seen |= {"shallow"} if (live < window - 1).any() else set()
+            seen |= {"wrap"} if ((live <= L - 1) & (live + s > L - 1)
+                                 ).any() else set()
+            seen |= {"three_windows"} if (live >= 3 * window).any() else set()
+    want = {"idle_inside", "one_block", "short_of_edge", "past_edge"}
+    want |= {"astride"} if s > 1 else set()
+    want |= {"shallow", "wrap", "three_windows"} if window else set()
+    assert seen == want

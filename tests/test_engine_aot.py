@@ -369,3 +369,62 @@ def test_the_scatter_it_replaced_still_loops(topo, mosaic):
     big = _leaf_sized(compiled["kernel"], leaf_elems)
     assert [re.sub(r"\.\d+$", "", n) for n in big if "apex1" in n] == [
         "apex1_decode_attend"]
+
+
+def _hw_numerics():
+    """`tools/hw_numerics.py` as a module (its imports at the top are the
+    standard library's and numpy): the ONE table of the serving cells'
+    step-attention geometries, which the chip's parity check walks."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "hw_numerics.py")
+    spec = importlib.util.spec_from_file_location("hw_numerics", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_DECODE_CELLS = _hw_numerics().DECODE_CELLS
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_the_kernels_queue_compiles_inside_the_vmem_budget(topo, mosaic,
+                                                           cell, s):
+    """The step kernel with its queue of fetches (PR 50), at each serving
+    cell's own geometry, through Mosaic for the described v5e: the depth
+    the row's bytes derive (4 at GPT-2's rows of 1024 lanes, 8 at 512),
+    that many K and V buffers in a frame `vmem_model` prices under its
+    budget, both leaves aliased in place, and nothing leaf-sized beside
+    them. What the chip's compiler accepts, not what the chip computes:
+    `tools/hw_numerics.py --only decode_attend` is that."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from apex1_tpu import vmem_model
+    from apex1_tpu.ops.decode_attend import (check_decode_geometry,
+                                             decode_attend, fetch_depth)
+    B, Hq, Hkv, D, L, window = _DECODE_CELLS[cell]
+    HD = Hkv * D
+    depth = fetch_depth(HD, jnp.bfloat16, L)
+    assert depth == (4 if HD == 1024 else 8)
+    blk, win, rp = check_decode_geometry(L, HD, Hq * s, s, jnp.bfloat16,
+                                         window)
+    fits, est = vmem_model.CHECKS["decode_attend"](
+        {"block_l": blk, "depth": depth}, {"HD": HD, "Rq": rp, "W": win},
+        2, vmem_model.budget_bytes())
+    assert fits and est >= 2 * depth * blk * HD * 2
+    s1 = SingleDeviceSharding(topo.devices[0])
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=s1)
+    kw = {} if window is None else {"window": window}
+    compiled = jax.jit(
+        lambda q, kn, vn, kp, vp, ix: decode_attend(q, kn, vn, kp, vp, ix,
+                                                    **kw),
+        donate_argnums=(3, 4)).lower(
+            sds(B, Hq, s, D), sds(B, Hkv, s, D), sds(B, Hkv, s, D),
+            sds(B, L, HD), sds(B, L, HD),
+            jax.ShapeDtypeStruct((B,), jnp.int32, sharding=s1)).compile()
+    leaf_elems = B * L * HD
+    assert _census(compiled, leaf_elems) == (0, 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_elems // 4

@@ -89,20 +89,22 @@ def served_program_hashes() -> dict:
 #: that tree on the path: `PYTHONPATH=<tree> python -c "import sys;
 #: sys.path.insert(0, 'tests'); import test_flash_rows_program as t;
 #: print(t.served_program_hashes())"`). A later PR that changes a serving
-#: program on purpose computes them anew, and says so.
+#: program on purpose computes them anew, and says so: PR 50 read the
+#: three `decode/pallas` entries anew (the step kernel inside them keeps a
+#: queue of fetches; `ops/decode_attend.py`), the other nine are d76ddfc's.
 PARENT_HASHES = {
     "gpt2/prefill/xla": "328f7e3503aaa4ea",
     "gpt2/decode/xla": "20a95c8604206260",
     "gpt2/prefill/pallas": "251c8d9df97e4377",
-    "gpt2/decode/pallas": "73de6db6e77e97fc",
+    "gpt2/decode/pallas": "7a33415212ecc78e",
     "granite_hybrid/prefill/xla": "7b5b4026640f4ba2",
     "granite_hybrid/decode/xla": "42b168ec3a7fb121",
     "granite_hybrid/prefill/pallas": "0f097d116873aa9e",
-    "granite_hybrid/decode/pallas": "e8f4406cf53aed37",
+    "granite_hybrid/decode/pallas": "87be7b03ff7dc6ec",
     "lfm2_moe/prefill/xla": "bdd5faba9a14df1b",
     "lfm2_moe/decode/xla": "f39f47cdab8001c4",
     "lfm2_moe/prefill/pallas": "b2ad05aa6a7759a3",
-    "lfm2_moe/decode/pallas": "adb1c6e8e05d80f0",
+    "lfm2_moe/decode/pallas": "3061e50a9b12c894",
 }
 
 

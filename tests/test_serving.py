@@ -1333,6 +1333,16 @@ class TestEngineSpans:
         assert sum(sp.counts["kv_blocks_pool"]
                    for sp in steps) == decoding * 3 * lane_blocks
         assert 0 < read < decoding * 3 * lane_blocks
+        # `kv_fetch_ahead`: the part of a step's blocks that the kernel's
+        # queue starts from an earlier lane's grid step: none of lane
+        # 0's, and of a later live lane's its first `depth - 1` (the
+        # toy's cache names no K leaf, so the depth is the least, 2: one
+        # block a lane of three)
+        ahead = [sp.counts["kv_fetch_ahead"] for sp in steps]
+        assert eng._fetch_depth == 2
+        assert all(0 <= a <= min(sp.counts["kv_blocks_read"], 3 - 1)
+                   for a, sp in zip(ahead, steps))
+        assert 0 < sum(ahead) < read
 
     def test_queued_span_runs_from_submit_to_admission(self, tiny, rng):
         eng, ids, spans = self._run(tiny, rng, prefix_cache=False)

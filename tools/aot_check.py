@@ -322,15 +322,23 @@ def _run_checks(args) -> bool:
         # in one kernel over leaves stored (slots, positions, Hkv * D),
         # at the `gpt2m_serve_chat` cell's shapes (48 slots x 1152 x 16
         # heads of 64, decode and a K=4 verify) and at llama-head rows
-        # (GQA 32/8, D=128) in both cache tiers. `check_decode_geometry`
-        # runs at trace time against the registry-shared vmem model.
+        # (GQA 32/8, D=128) in both cache tiers, and at the rows of 512
+        # lanes of `granite4hm_serve_chat` (48 x 1280) and
+        # `lfm2moe_serve_rollout` (96 x 2816), whose queue of fetches is
+        # twice as deep as GPT-2's (PR 50: `fetch_depth`, 8 against 4).
+        # `check_decode_geometry` runs at trace time against the
+        # registry-shared vmem model, the queue's buffers in its frame.
         from apex1_tpu.ops.decode_attend import (check_decode_geometry,
                                                  decode_attend)
         for tag, (B_d, Hq_d, Hkv_d, D_d, L_d), tiers in (
                 ("gpt2m chat cell", (48, 16, 16, 64, 1152),
                  (("bf16", jnp.bfloat16),)),
                 ("llama heads", (8, 32, 8, 128, 2048),
-                 (("bf16", jnp.bfloat16), ("int8", jnp.int8)))):
+                 (("bf16", jnp.bfloat16), ("int8", jnp.int8))),
+                ("granite4hm chat cell", (48, 32, 8, 64, 1280),
+                 (("bf16", jnp.bfloat16),)),
+                ("lfm2moe rollout cell", (96, 32, 8, 64, 2816),
+                 (("bf16", jnp.bfloat16),))):
             for tier, cdt in tiers:
                 for S_d in (1, 5):
                     check(f"decode_attend {tag} {tier} S={S_d} "

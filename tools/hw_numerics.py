@@ -279,6 +279,141 @@ def _sweep(backend):
           (x8,))
 
     _dropout_mask_identity(rng)
+    _decode_attend_queue()
+
+
+#: the serving cells' step attention as the engine lays it out: (lanes, Hq,
+#: Hkv, D, rows a lane, window). `gpt2m_serve_chat`, `granite4hm_serve_chat`,
+#: `lfm2moe_serve_rollout`, and `trinitymini_serve_longctx`'s two kinds of
+#: leaf (a sliding layer's ring, a global layer's whole lane)
+DECODE_CELLS = {
+    "gpt2m": (48, 16, 16, 64, 1152, None),
+    "granite4hm": (48, 32, 8, 64, 1280, None),
+    "lfm2moe": (96, 32, 8, 64, 2816, None),
+    "trinity_ring": (16, 32, 4, 128, 2304, 2048),
+    "trinity_global": (16, 32, 4, 128, 8960, None),
+}
+
+
+def decode_cell_depths(rng, B, L, S, window, blk=128):
+    """Per-lane depths over one cell's geometry that sit where a queue of
+    fetches that crosses lanes can go wrong: idle lanes between live ones
+    (and first, and last), lanes of one block, lanes one row short of and
+    one row past a block's edge, new rows astride an edge, the last row
+    that fits; over a ring, lanes shallower than the window, at the wrap,
+    and past three windows. The rest are drawn over the whole lane."""
+    top = (4 * window if window else L) - S
+    idx = rng.integers(0, top + 1, size=B)
+    edge = blk * int(rng.integers(1, min(L, top) // blk))
+    special = [0, blk - S, blk - 1, blk, edge - 1, edge, edge + 1,
+               edge - S + 1 if S > 1 else edge - 2, top]
+    if window:
+        special += [window - 1, window, L - 1, L, L - S + 1 if S > 1 else L + 1,
+                    3 * window + 77, int(rng.integers(3 * window, top + 1))]
+    at = rng.permutation(B)
+    for lane, v in zip(at, special):
+        idx[lane] = max(min(int(v), top), 0)
+    idle = rng.permutation(B)[:max(2, B // 6)]
+    idx[idle] = -1
+    idx[[0, B - 1]] = rng.choice([-1, 3, edge], size=2)
+    return idx.astype(np.int32)
+
+
+def _decode_attend_queue(rounds=3, steps=12):
+    """`ops.decode_attend`'s queue of fetches THROUGH MOSAIC at the four
+    serving cells' own geometries: the attention and both pool leaves
+    BITWISE against the two-buffer schedule it replaced (`run_on=False`
+    of the same jitted call: the parent's kernel, op for op), and the
+    attention against the composite within the tests' tolerance. Each
+    round draws new depths and runs ``steps`` calls as the engine does,
+    pools donated and every live lane one launch deeper each call, so a
+    fetch that passes a write-back, a buffer refilled too early or a
+    block fetched by another lane's arithmetic shows where it happens one
+    time in ten. Interpret mode cannot stand in for this: there a copy is
+    complete where it is started (docs/ops.md)."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex1_tpu.models.generate import cache_write
+    from apex1_tpu.ops import decode_attend as da
+    from apex1_tpu.ops._common import interpret_mode
+    from apex1_tpu.ops.paged_decode import cache_attend
+
+    for cell, (B, Hq, Hkv, D, L, window) in DECODE_CELLS.items():
+        for S in (1, 5):
+            name = f"decode_attend_{cell}_S{S}"
+            if ONLY is not None and not any(s in name for s in ONLY):
+                continue
+            t0 = time.time()
+            try:
+                HD = Hkv * D
+                depth = da.fetch_depth(HD, jnp.bfloat16, L)
+                geometry = da.check_decode_geometry(L, HD, Hq * S, S,
+                                                    jnp.bfloat16, window)
+                kw = dict(scale=float(D ** -0.5), geometry=geometry,
+                          interpret=interpret_mode(),
+                          **({} if window is None else {"window": window}))
+
+                def make(**schedule):
+                    return jax.jit(lambda q, kn, vn, kp, vp, ix:
+                                   da._decode_attend(q, kn, vn, kp, vp, ix,
+                                                     **kw, **schedule),
+                                   donate_argnums=(3, 4))
+
+                queue, two = make(depth=depth), make(depth=2, run_on=False)
+                same = jax.jit(lambda a, b: jnp.stack(
+                    [jnp.array_equal(x, y) for x, y in zip(a, b)]))
+
+                def composite(q, kn, vn, kp, vp, ix):
+                    ring = {} if window is None else {"ring": True}
+                    return cache_attend(
+                        q, cache_write(kp, kn, ix, **ring),
+                        cache_write(vp, vn, ix, **ring), ix,
+                        **({} if window is None else {"window": window}))
+
+                rng = np.random.default_rng(50 + S)
+                calls, bad, err = 0, [], 0.0
+                # tests/test_decode_attend.py's: an output step of bfloat16
+                # against the composite, two over a ring
+                tol = 2 ** -6 if window is None else 2 ** -5
+                for r in range(rounds):
+                    ks = jax.random.split(jax.random.key(100 * r + S), 5)
+                    draw = lambda i, *shape: jax.random.normal(
+                        ks[i], shape, jnp.bfloat16)
+                    q, kn, vn = (draw(0, B, Hq, S, D), draw(1, B, Hkv, S, D),
+                                 draw(2, B, Hkv, S, D))
+                    kp, vp = draw(3, B, L, HD), draw(4, B, L, HD)
+                    idx = decode_cell_depths(rng, B, L, S, window)
+                    live = idx >= 0
+                    want = np.asarray(jax.jit(composite)(
+                        q, kn, vn, kp, vp, jnp.asarray(idx)), np.float32)
+                    a, b = (kp, vp), (kp + 0, vp + 0)
+                    for t in range(steps):
+                        # a live lane one launch deeper each call; one
+                        # that would pass its lane's end starts over
+                        ix = idx + np.where(live, t * S, 0)
+                        if window is None:
+                            ix = np.where(ix > L - S, ix % S, ix)
+                        ix = jnp.asarray(ix.astype(np.int32))
+                        oa, *a = queue(q, kn, vn, *a, ix)
+                        ob, *b = two(q, kn, vn, *b, ix)
+                        ok3 = np.asarray(same((oa, *a), (ob, *b)))
+                        calls += 1
+                        if not ok3.all():
+                            bad.append((r, t, ok3.tolist()))
+                        if t == 0:
+                            got = np.asarray(oa, np.float32)
+                            err = max(err, float(np.abs(
+                                got[live] - want[live]).max()))
+                            if got[~live].any():
+                                bad.append((r, "idle lane not zero"))
+                _record(name, not bad and err <= tol,
+                        f"depth={depth} {calls} calls bitwise vs two-buffer "
+                        f"(out,k,v): {'all equal' if not bad else bad[:3]}; "
+                        f"vs composite max|d|={err:.2e} tol={tol:.1e}",
+                        t0)
+            except Exception as e:
+                _record(name, False, f"{type(e).__name__}: {e}", t0)
 
 
 def _dropout_mask_identity(rng):
