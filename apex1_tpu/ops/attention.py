@@ -21,7 +21,14 @@ the TPU-native design is a flash/online-softmax kernel with NO seqlen cap:
   (`_key_tiles`, `_query_tiles`; counted by `tile_plan`) stop at the
   causal diagonal: a tile above it is neither fetched nor stepped over,
   a tile wholly under it runs a body with no mask (INTERIOR), and only
-  the tiles the diagonal or a padded edge crosses build one. GRID — where
+  the tiles the diagonal or a padded edge crosses build one (MASKED).
+  A whole square tile whose corner the diagonal passes through (what a
+  causal call in aligned tiles crosses; `_on_diagonal`, a predicate on
+  the tile indices and the offsets in SMEM) runs the DIAGONAL body
+  instead: ``bq // DIAG_SUB`` static steps, each a trapezoid row of
+  ``DIAG_SUB``-wide squares that stops at the diagonal, masked on its one
+  diagonal square by a constant triangle; the squares above the diagonal,
+  whose scores are all dead, are not computed. GRID — where
   the row does not fit VMEM, or with an additive bias (a bias tile is per
   (qi, ki) by nature), the other axis stays the innermost grid axis
   (TPU grid iteration is sequential, so scratch persists) and every
@@ -152,12 +159,26 @@ def _auto_blocks(D, block_q, block_k, dtype=jnp.bfloat16, seq=128):
     the step from 1024 halves peak usage for one extra grid level.
 
     The same tile is the resident form's (the loop inside the kernel,
-    module docstring): a smaller one wastes less of the causal triangle
-    ((1 + 1/n_q)/2 of the square: 0.75 at 512 of S = 1024, 0.625 at 256)
-    but every tile costs a fixed ~0.4 us of loop and pipeline fill, and
-    on a v5e at 8 x 16 x 1024 x 64 bf16 the three kernels together take
-    1.82 ms at 512x512, 2.06 at 1024x1024 (one masked tile, no loop),
-    2.28 at 256x512 and 2.45 at 256x256 (PERF.md §6, PR 39): 512 stays.
+    module docstring). A causal pass visits n_q·(n_q + 1)/2 of its n_q²
+    tiles, (1 + 1/n_q)/2 of the square (0.75 at 512 of S = 1024, 0.625
+    at 256), n_q of them on the diagonal; since PR 51 a diagonal tile
+    COMPUTES (1 + 128/bq)/2 of itself (`DIAG_SUB`; 10 of 16 squares at
+    512), so a 512 tile pass computes 0.5625 of the square. Smaller
+    tiles waste less and run slower: every tile costs a fixed ~0.4 us
+    of loop and pipeline fill, and every 128-row step of a diagonal tile
+    a fixed cost of its own. On a v5e at 8 x 16 x 1024 x 64 bf16 the three
+    kernels of a layer together take, rows layout (PERF.md §6, PR 51;
+    in brackets the MASKED body on the diagonal tiles, what ran before):
+    **1.63 ms at 512x512 (1.88)**, 2.30 at 256x256 (2.26: two steps of
+    128 rows save a quarter of a small tile and cost as much), 2.15 at
+    1024x1024 (the GRID form, one masked tile a head and no loop: the
+    resident form's MASKED fallback holds four live (1024, 1024) fp32
+    tiles a head and does not fit VMEM, so this tile has no diagonal
+    body to take); heads layout 1.67 at 512x512 (1.82; PR 39: 2.06 at
+    1024x1024, 2.28 at 256x512, 2.45 at 256x256): 512 stays. By the
+    square of 128: an interior one ~0.025 ms a layer, a masked one
+    ~0.031, a diagonal tile's ten 0.38 together where its sixteen
+    masked were 0.50 (a step's fixed cost is what keeps it from 0.275).
     Whether the resident form is taken is NOT decided here but per call,
     from the padded lengths, the GQA group and the operands
     (`_kv_resident`, `_q_resident` over `vmem_model.flash_kv_row_check` /
@@ -261,17 +282,54 @@ def _query_tiles(ki, bq, bk, true_sq, true_sk, q_off, k_off, causal):
     return lo, a, _mx(a, n_full), n_q
 
 
+# The three bodies a tile runs. DIAGONAL is MASKED's special case, taken
+# where the call allows it statically (`_diag_sub`) and the tile at run
+# time (`_on_diagonal`): both from what the kernel can see, no option.
+INTERIOR, MASKED, DIAGONAL = "interior", "masked", "diagonal"
+
+# Width of the squares the DIAGONAL body cuts its tile into: one lane
+# tile. On a v5e a layer's three kernels at 8 x 16 x 1024 x 64 bf16 in
+# 512 x 512 tiles take 1.632 ms at 128 and 1.647 at 256 (1.876 with no
+# such body; the forward and dq a hair faster at 256, dk/dv at 128:
+# docs/ops.md has the sweep, PERF.md §6, PR 51, the runs).
+DIAG_SUB = 128
+
+
+def _diag_sub(bq, bk, causal, plain=True):
+    """The DIAGONAL body's square width for a call, 0 where it has none:
+    causal, square tiles of whole ``DIAG_SUB`` squares, and ``plain`` (no
+    segment ids, dropout or bias: operands that are per tile by nature
+    keep the MASKED body)."""
+    return DIAG_SUB if (causal and plain and bq == bk
+                        and bq % DIAG_SUB == 0) else 0
+
+
+def _on_diagonal(qi, ki, bq, bk, true_sq, true_sk, q_off, k_off):
+    """Whether tile (qi, ki) is whole (no padded row or column) and the
+    causal diagonal passes through its corner: row r of the tile sees
+    columns 0..r of it, whatever the indices. Python ints (`tile_plan`)
+    or the kernel's traced scalars, as `_key_tiles`."""
+    return ((qi * bq + q_off - k_off == ki * bk)
+            & ((qi + 1) * bq <= true_sq) & ((ki + 1) * bk <= true_sk))
+
+
 def tile_plan(Sq, Sk, bq, bk, q_off=0, k_off=0, causal=True):
-    """``(interior, masked, never_visited)`` score tiles of one (batch,
-    head) at true lengths ``Sq`` x ``Sk`` in ``bq`` x ``bk`` tiles: what
-    the resident kernels' loops run without a mask, with one, and not at
-    all. Static counterpart of the in-kernel bounds (same functions)."""
-    interior = masked = 0
+    """``(interior, masked, diagonal, never_visited)`` score tiles of one
+    (batch, head) at true lengths ``Sq`` x ``Sk`` in ``bq`` x ``bk``
+    tiles: what the resident kernels' loops run without a mask, with
+    one, how many OF THE MASKED take the DIAGONAL body (`_diag_sub`,
+    `_on_diagonal`), and what they do not run at all. Static counterpart
+    of the in-kernel bounds and predicate (same functions)."""
+    interior = masked = diagonal = 0
+    diag = _diag_sub(bq, bk, causal) > 0
     for qi in range(-(-Sq // bq)):
         n_int, n_vis = _key_tiles(qi, bq, bk, Sq, Sk, q_off, k_off, causal)
         interior += n_int
         masked += n_vis - n_int
-    return interior, masked, \
+        diagonal += sum(
+            bool(diag and _on_diagonal(qi, ki, bq, bk, Sq, Sk, q_off, k_off))
+            for ki in range(n_int, n_vis))
+    return interior, masked, diagonal, \
         -(-Sq // bq) * -(-Sk // bk) - interior - masked
 
 
@@ -393,23 +451,63 @@ def _own_lanes(xs):
     return out
 
 
+def _edge_tile(tile, on):
+    """Run ``tile(body)`` for a tile of a masked run: MASKED, or, where
+    the call has a DIAGONAL body and the kernel sees the tile on the
+    diagonal (``on``: `_on_diagonal` of traced scalars; None where the
+    call has no such body), that."""
+    if on is None:
+        return tile(MASKED)
+    jax.lax.cond(on, lambda: tile(DIAGONAL), lambda: tile(MASKED))
+
+
+def _triangle(g, transposed=False):
+    """The live scores of a ``g`` x ``g`` square that the diagonal halves,
+    corner to corner: key <= query. One constant pattern whatever the
+    tile, (queries, keys) or ``transposed``."""
+    q, k = (1, 0) if transposed else (0, 1)
+    return (jax.lax.broadcasted_iota(jnp.int32, (g, g), k)
+            <= jax.lax.broadcasted_iota(jnp.int32, (g, g), q))
+
+
+def _fill_dead(x, tri, fill, axis, last):
+    """A DIAGONAL step's trapezoid row ``x``, whole ``g``-squares along
+    ``axis``, with the dead scores of its ONE diagonal square (the
+    ``last`` along the axis, or the first) set to ``fill``; the other
+    squares are all live and pass as they are."""
+    g = tri.shape[0]
+    if x.shape[axis] == g:
+        return jnp.where(tri, x, fill)
+    a, b = jnp.split(x, [x.shape[axis] - g if last else g], axis)
+    if last:
+        b = jnp.where(tri, b, fill)
+    else:
+        a = jnp.where(tri, a, fill)
+    return jnp.concatenate([a, b], axis)
+
+
 def _over_key_tiles(tile, row, init, finish, *, block_k, qi, bq, bk, n_k,
                     true_sq, true_sk, q_off, k_off, causal, mask_all):
-    """Drive ``tile(ki, masked, *row())`` over query tile ``qi``'s key
+    """Drive ``tile(ki, body, *row())`` over query tile ``qi``'s key
     tiles for the forward and dq kernels. RESIDENT (``block_k`` given):
     two loops in the kernel, the interior run and the masked one
     (`_key_tiles`; ``mask_all``: segment ids or dropout, operands that
-    are per tile by nature, mask every tile). GRID: this grid step's
-    one tile, skipped when it lies wholly above the diagonal."""
+    are per tile by nature, mask every tile), a tile of the masked run
+    taking the DIAGONAL body where it can (`_edge_tile`). GRID: this grid
+    step's one tile, skipped when it lies wholly above the diagonal."""
     if block_k is not None:
         n_int, n_vis = _key_tiles(qi, bq, bk, true_sq, true_sk, q_off,
                                   k_off, causal)
         if mask_all:
             n_int = 0
+        diag = _diag_sub(bq, bk, causal, not mask_all) > 0
         init()
         ops = row()
-        _loop(0, n_int, lambda ki: tile(ki, False, *ops))
-        _loop(n_int, n_vis, lambda ki: tile(ki, True, *ops))
+        _loop(0, n_int, lambda ki: tile(ki, INTERIOR, *ops))
+        _loop(n_int, n_vis, lambda ki: _edge_tile(
+            lambda body: tile(ki, body, *ops),
+            _on_diagonal(qi, ki, bq, bk, true_sq, true_sk, q_off, k_off)
+            if diag else None))
         finish()
         return
     ki = pl.program_id(3)
@@ -418,14 +516,14 @@ def _over_key_tiles(tile, row, init, finish, *, block_k, qi, bq, bk, n_k,
         # skip blocks entirely above the diagonal (no valid positions):
         # saves the strictly-upper-triangular ~half of the MXU work
         pl.when((ki * bk + k_off) <= (qi * bq + bq - 1 + q_off))(
-            lambda: tile(ki, True, *row()))
+            lambda: tile(ki, MASKED, *row()))
     else:
-        tile(ki, True, *row())
+        tile(ki, MASKED, *row())
     pl.when(ki == n_k - 1)(finish)
 
 
 def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
-                   mask=None, keep=None, dropout_p=0.0, own=None):
+                   mask=None, keep=None, dropout_p=0.0, own=None, tri=None):
     """Fold one score tile into the running (outᵀ, max, sum) of the
     online softmax. The tile is TRANSPOSED, (bk, bq) with the keys down
     the sublanes: a query's max and sum then run over sublanes and vregs
@@ -437,6 +535,9 @@ def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
     ``scale=None`` means the caller folded it into ``q``. ``own``: the
     rows of ``vᵀ·eᵀ`` that are this head's (the ROWS layout, where ``v``
     holds several heads' lanes and ``acc`` is this head's rows of outᵀ).
+    ``tri``: a DIAGONAL step (`_attend_diagonal`), the interior body on a
+    trapezoid row whose last square alone holds dead scores; every query
+    of it has a live key, so their ``e`` is exp's exact 0.0 unaided.
     The forward kernel and `ops.fused_collective._agf_kernel` both run
     THIS function, which is what keeps the fused ring equal to the
     decomposed one."""
@@ -453,6 +554,8 @@ def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
         s = s + bias.astype(jnp.float32).T
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
+    if tri is not None:
+        s = _fill_dead(s, tri, NEG_INF, 0, last=True)
     m_prev = m_scr[...]                                       # (1, bq)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
@@ -472,6 +575,19 @@ def _attend_tile(q, k, v, acc, m_scr, l_scr, *, scale=None, bias=None,
         preferred_element_type=jnp.float32)
     acc[...] = old + (pv if own is None else pv[own])
     m_scr[...] = m_new
+
+
+def _attend_diagonal(q, k, v, acc, m_scr, l_scr, *, tri, **kw):
+    """`_attend_tile` for a tile on the diagonal (`_on_diagonal`), in
+    static steps: query sub-block ``j`` (``g`` lanes of the transposed
+    tile, of ``acc``, ``m_scr``, ``l_scr``) against the keys 0..(j+1)·g
+    it can see and no others. A query's state is its lane's alone, so
+    the steps are independent and none rescales another's."""
+    g = tri.shape[0]
+    for j in range(q.shape[0] // g):
+        at, w = slice(j * g, (j + 1) * g), (j + 1) * g
+        _attend_tile(q[at], k[:w], v[:w], acc.at[:, at], m_scr.at[:, at],
+                     l_scr.at[:, at], tri=tri, **kw)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
@@ -517,17 +633,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, *rest,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    def tile(ki, masked, qs, qseg):
+    def tile(ki, body, qs, qseg):
         if resident:
             k, v = _tile_of(k_ref, ki, bk), _tile_of(v_ref, ki, bk)
             kseg = kseg_ref[0, _rows(ki, bk), :] if has_segs else None
         else:
             k, v = k_ref[0, 0], v_ref[0, 0]
             kseg = kseg_ref[0] if has_segs else None          # (bk, 1)
+        if body is DIAGONAL:
+            tri = _triangle(DIAG_SUB, transposed=True)
+            for t in range(n):
+                _attend_diagonal(qs[t], k, v, *state[t], tri=tri,
+                                 scale=None if fold else scale, own=own[t])
+            return
         mask = _mask_for(qi, ki, bq, bk, causal=causal, true_sq=true_sq,
                          true_sk=true_sk, q_off=q_off, k_off=k_off,
                          qseg=qseg, kseg=kseg, transposed=True) \
-            if masked else None
+            if body is MASKED else None
         for t in range(n):
             keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b,
                               h * n + t if rows else h,
@@ -616,50 +738,69 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     def init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def tile(ki, masked, qs, dos, lses, dds, qseg):
+    def tile(ki, body, qs, dos, lses, dds, qseg):
         if resident:
             k, v = _tile_of(k_ref, ki, bk), _tile_of(v_ref, ki, bk)
             kseg = kseg_ref[0, ki] if has_segs else None
         else:
             k, v = k_ref[0, 0], v_ref[0, 0]
             kseg = kseg_ref[0, 0] if has_segs else None
-        mask, dqs = None, []
-        for t in range(n):
-            s = jax.lax.dot_general(qs[t], k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if not fold:
-                s = s * scale
-            if has_bias:
-                s = s + bias_ref[0, 0].astype(jnp.float32)
-            p = jnp.exp(s - lses[t])
-            if masked:
-                if mask is None:   # one mask a tile, whatever the head
-                    mask = _mask_for(qi, ki, bq, bk, causal=causal,
-                                     true_sq=true_sq, true_sk=true_sk,
-                                     q_off=q_off, k_off=k_off, qseg=qseg,
-                                     kseg=kseg)
-                p = jnp.where(mask, p, 0.0)
-            dp = jax.lax.dot_general(dos[t], v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            if dropout_p > 0.0:
-                # out = Σ drop∘softmax(s)·v with drop a CONSTANT mask ⇒
-                # ds = p·(drop·dp − δ + dlse): the recomputed mask scales
-                # only the dp term (δ already carries the dropped weights
-                # through do·out); dd is δ − dlse
-                keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk,
-                                  b, h * n + t if rows else h,
-                                  dropout_p=dropout_p, n_h=n_h,
-                                  interp=interp)
-                dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
-            ds = p * (dp - dds[t])
-            if not fold:
-                ds = ds * scale
-            if t == n - 1:
-                acc = dq_acc[...]
-            dqs.append(jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-        dq_acc[...] = acc + _own_lanes(dqs)
+
+        def part(at, k, v, live):
+            # query rows ``at`` of the tile against the keys ``k``, ``v``;
+            # ``live``: what zeroes the dead scores' p, None where none is
+            dqs = []
+            for t in range(n):
+                s = jax.lax.dot_general(qs[t][at], k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                if not fold:
+                    s = s * scale
+                if has_bias:
+                    s = s + bias_ref[0, 0].astype(jnp.float32)
+                p = jnp.exp(s - lses[t][at])
+                if live is not None:
+                    p = live(p)
+                dp = jax.lax.dot_general(dos[t][at], v,
+                                         (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                if dropout_p > 0.0:
+                    # out = Σ drop∘softmax(s)·v with drop a CONSTANT mask ⇒
+                    # ds = p·(drop·dp − δ + dlse): the recomputed mask
+                    # scales only the dp term (δ already carries the dropped
+                    # weights through do·out); dd is δ − dlse
+                    keep = _keep_tile(sd_ref, qo_ref, ko_ref, qi, ki, bq, bk,
+                                      b, h * n + t if rows else h,
+                                      dropout_p=dropout_p, n_h=n_h,
+                                      interp=interp)
+                    dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
+                ds = p * (dp - dds[t][at])
+                if not fold:
+                    ds = ds * scale
+                if t == n - 1:
+                    acc = dq_acc[at]
+                dqs.append(jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            dq_acc[at] = acc + _own_lanes(dqs)
+
+        if body is DIAGONAL:
+            # query rows j against the keys 0..(j+1)·g they can see
+            g = DIAG_SUB
+            tri = _triangle(g)
+            for j in range(bq // g):
+                w = (j + 1) * g
+                part(slice(j * g, w), k[:w], v[:w],
+                     lambda p: _fill_dead(p, tri, 0.0, 1, last=True))
+            return
+
+        @functools.cache   # one mask a tile, built where a head first asks
+        def mask():
+            return _mask_for(qi, ki, bq, bk, causal=causal, true_sq=true_sq,
+                             true_sk=true_sk, q_off=q_off, k_off=k_off,
+                             qseg=qseg, kseg=kseg)
+
+        part(..., k, v, (lambda p: jnp.where(mask(), p, 0.0))
+             if body is MASKED else None)
 
     def finish():
         dq = dq_acc[...] * scale if fold else dq_acc[...]
@@ -729,7 +870,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def tile(gi, qi, masked, ks, vs, kseg):
+    def tile(gi, qi, body, ks, vs, kseg):
         if rows:
             at = (0, _rows(qi, bq), slice(None))
         elif resident:
@@ -741,55 +882,75 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         else:
             qseg = qseg_ref[0, 0] if has_segs else None
         q, do = q_ref[at], do_ref[at]
-        mask, dvs, dks = None, [], []
-        for t in range(n):
-            # head t's statistics: its (1, bq) rows
-            stat = (0, t if rows else gi, qi) if resident else (0, 0, 0)
-            s = jax.lax.dot_general(ks[t], q, nt,
-                                    preferred_element_type=jnp.float32)
-            if not fold:
-                s = s * scale
-            if has_bias:
-                s = s + bias_ref[0, 0].astype(jnp.float32).T
-            p = jnp.exp(s - lse_ref[stat])
-            if masked:
-                if mask is None:   # one mask a tile, whatever the head
-                    mask = _mask_for(qi, ki, bq, bk, causal=causal,
-                                     true_sq=true_sq, true_sk=true_sk,
-                                     q_off=q_off, k_off=k_off, qseg=qseg,
-                                     kseg=kseg, transposed=True)
-                p = jnp.where(mask, p, 0.0)
-            dp = jax.lax.dot_general(vs[t], do, nt,
-                                     preferred_element_type=jnp.float32)
-            p_av = p
-            if dropout_p > 0.0:
-                # q head hkv·group + gi (ROWS: the block's head t) — the
-                # SAME salt, and the same (bq, bk) draw turned over, the
-                # forward used for this (b, h, qi, ki)
-                keep = _keep_tile(
-                    sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b,
-                    hkv * n + t if rows else hkv * group + gi,
-                    dropout_p=dropout_p, n_h=n_h, interp=interp,
-                    transposed=True)
-                inv = 1.0 / (1.0 - dropout_p)
-                p_av = jnp.where(keep, p * inv, 0.0)  # dv sees DROPPED probs
-                dp = jnp.where(keep, dp * inv, 0.0)
-            if t == n - 1:
-                dv = dv_acc[...]
-            dvs.append(jax.lax.dot_general(                  # p_avᵀ · do
-                p_av.astype(do.dtype), do, nn,
-                preferred_element_type=jnp.float32))
-            if t == n - 1:
-                dv_acc[...] = dv + _own_lanes(dvs)
-            ds = p * (dp - dd_ref[stat])                      # δ − dlse
-            if not fold:
-                ds = ds * scale
-            if t == n - 1:
-                dk = dk_acc[...]
-            dks.append(jax.lax.dot_general(                  # dsᵀ · q
-                ds.astype(q.dtype), q, nn,
-                preferred_element_type=jnp.float32))
-        dk_acc[...] = dk + _own_lanes(dks)
+
+        def part(keys, qrs, q, do, live):
+            # key rows ``keys`` of the tile against its queries ``qrs``
+            # (where their statistics lie in a (1, bq) row; ``q``, ``do``
+            # theirs: the columns of the transposed tile);
+            # ``live``: what zeroes the dead scores' p, None where none is
+            dvs, dks = [], []
+            for t in range(n):
+                # head t's statistics: its (1, bq) rows
+                stat = (0, t if rows else gi, qi) if resident else (0, 0, 0)
+                s = jax.lax.dot_general(ks[t][keys], q, nt,
+                                        preferred_element_type=jnp.float32)
+                if not fold:
+                    s = s * scale
+                if has_bias:
+                    s = s + bias_ref[0, 0].astype(jnp.float32).T
+                p = jnp.exp(s - lse_ref[stat + qrs])
+                if live is not None:
+                    p = live(p)
+                dp = jax.lax.dot_general(vs[t][keys], do, nt,
+                                         preferred_element_type=jnp.float32)
+                p_av = p
+                if dropout_p > 0.0:
+                    # q head hkv·group + gi (ROWS: the block's head t) — the
+                    # SAME salt, and the same (bq, bk) draw turned over, the
+                    # forward used for this (b, h, qi, ki)
+                    keep = _keep_tile(
+                        sd_ref, qo_ref, ko_ref, qi, ki, bq, bk, b,
+                        hkv * n + t if rows else hkv * group + gi,
+                        dropout_p=dropout_p, n_h=n_h, interp=interp,
+                        transposed=True)
+                    inv = 1.0 / (1.0 - dropout_p)
+                    p_av = jnp.where(keep, p * inv, 0.0)  # dv: DROPPED probs
+                    dp = jnp.where(keep, dp * inv, 0.0)
+                if t == n - 1:
+                    dv = dv_acc[keys]
+                dvs.append(jax.lax.dot_general(              # p_avᵀ · do
+                    p_av.astype(do.dtype), do, nn,
+                    preferred_element_type=jnp.float32))
+                if t == n - 1:
+                    dv_acc[keys] = dv + _own_lanes(dvs)
+                ds = p * (dp - dd_ref[stat + qrs])              # δ − dlse
+                if not fold:
+                    ds = ds * scale
+                if t == n - 1:
+                    dk = dk_acc[keys]
+                dks.append(jax.lax.dot_general(              # dsᵀ · q
+                    ds.astype(q.dtype), q, nn,
+                    preferred_element_type=jnp.float32))
+            dk_acc[keys] = dk + _own_lanes(dks)
+
+        if body is DIAGONAL:
+            # key rows i against the queries i·g.. that can see them
+            g = DIAG_SUB
+            tri = _triangle(g, transposed=True)
+            for i in range(bk // g):
+                part(slice(i * g, (i + 1) * g),
+                     (slice(None), slice(i * g, bq)), q[i * g:], do[i * g:],
+                     lambda p: _fill_dead(p, tri, 0.0, 1, last=False))
+            return
+
+        @functools.cache   # one mask a tile, built where a head first asks
+        def mask():
+            return _mask_for(qi, ki, bq, bk, causal=causal, true_sq=true_sq,
+                             true_sk=true_sk, q_off=q_off, k_off=k_off,
+                             qseg=qseg, kseg=kseg, transposed=True)
+
+        part(..., (), q, do, (lambda p: jnp.where(mask(), p, 0.0))
+             if body is MASKED else None)
 
     def finish():
         dk = dk_acc[...] * scale if fold else dk_acc[...]
@@ -803,15 +964,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         def run():
             lo, a, b_, hi = _query_tiles(ki, bq, bk, true_sq, true_sk,
                                          q_off, k_off, causal)
-            if has_segs or dropout_p > 0.0:
+            mask_all = has_segs or dropout_p > 0.0
+            if mask_all:
                 a = b_ = hi   # those operands are per tile by nature
+            diag = _diag_sub(bq, bk, causal, not mask_all) > 0
             init()
             ops = col()
 
             def head(gi):
-                _loop(lo, a, lambda qi: tile(gi, qi, True, *ops))
-                _loop(a, b_, lambda qi: tile(gi, qi, False, *ops))
-                _loop(b_, hi, lambda qi: tile(gi, qi, True, *ops))
+                # the diagonal crosses [lo, a): its corner tile DIAGONAL
+                _loop(lo, a, lambda qi: _edge_tile(
+                    lambda body: tile(gi, qi, body, *ops),
+                    _on_diagonal(qi, ki, bq, bk, true_sq, true_sk, q_off,
+                                 k_off) if diag else None))
+                _loop(a, b_, lambda qi: tile(gi, qi, INTERIOR, *ops))
+                _loop(b_, hi, lambda qi: tile(gi, qi, MASKED, *ops))
 
             _loop(0, group, head)
             finish()
@@ -829,9 +996,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     pl.when((gi == 0) & (qi == 0))(init)
     if causal:
         pl.when((qi * bq + bq - 1 + q_off) >= (ki * bk + k_off))(
-            lambda: tile(gi, qi, True, *col()))
+            lambda: tile(gi, qi, MASKED, *col()))
     else:
-        tile(gi, qi, True, *col())
+        tile(gi, qi, MASKED, *col())
     pl.when((gi == group - 1) & (qi == n_q - 1))(finish)
 
 
@@ -1179,15 +1346,25 @@ _STATIC = ("scale", "causal", "has_segs", "block_q", "block_k",
            "dropout_p", "resident", "interpret", "heads")
 
 
-def _emit_form(g, resident):
+def _emit_form(g, resident, causal, plain):
     """The form a call took, said once where it is traced (these calls
-    are jitted: once a shape and form) on the `obs` spine."""
+    are jitted: once a shape and form) on the `obs` spine; with it the
+    tiles a head's resident loop runs by body (`tile_plan` at offsets 0;
+    not ``plain``, segment ids or dropout: every visited tile MASKED) and
+    the DIAGONAL body's square width, 0 where the call has none."""
     from apex1_tpu.obs import spine
+    interior, masked, diagonal, _ = tile_plan(
+        g["Sq"], g["Sk"], g["bq"], g["bk"], causal=causal)
+    sub = _diag_sub(g["bq"], g["bk"], causal, plain) if resident[0] else 0
+    if not plain:
+        interior, masked = 0, interior + masked
     spine.emit("counter", "flash/form", value=1,
                layout="rows" if g["heads"] else "heads",
                heads_per_block=g["per_block"],
                resident_kv=bool(resident[0]), resident_q=bool(resident[1]),
-               block_q=g["bq"], block_k=g["bk"])
+               block_q=g["bq"], block_k=g["bk"], diag_sub=sub,
+               tiles_interior=interior, tiles_masked=masked,
+               tiles_diagonal=diagonal if sub else 0)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -1200,7 +1377,8 @@ def _fwd_call(q, k, v, qseg, kseg, q_off, k_off, seed, bias, *, scale,
     qp, kp, vp, qs, ks, g = _prep(q, k, v, qseg, kseg, has_segs,
                                   block_q, block_k, heads)
     has_bias = bias is not None
-    _emit_form(g, (resident, _q_resident(g, has_bias)))
+    _emit_form(g, (resident, _q_resident(g, has_bias)), causal,
+               not (has_segs or has_bias or dropout_p > 0.0))
     q_spec, kv_spec, stat_spec, off_spec, qseg_spec, kseg_spec = \
         _common_specs(g, resident, transposed=True)
     k_spec, v_spec = kv_spec if heads else (kv_spec, kv_spec)

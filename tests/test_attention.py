@@ -356,33 +356,42 @@ def test_resident_tile_classes(rng, monkeypatch, case, q_off, k_off):
                              [4, 4, 5] if grid_form else [3, 3, 3])
 
 
-def _brute_tiles(Sq, Sk, bq, bk, q_off, k_off, causal):
-    """{(qi, ki): 'interior' | 'masked' | 'never'} from the mask itself."""
+def _brute_tiles(Sq, Sk, bq, bk, q_off, k_off, causal, diagonal=False):
+    """{(qi, ki): 'interior' | 'masked' | 'never'} from the mask itself;
+    ``diagonal``: instead the set of tiles whose mask IS the lower
+    triangle, corner to corner (what the DIAGONAL body assumes)."""
     row = np.arange(-(-Sq // bq) * bq)[:, None]
     col = np.arange(-(-Sk // bk) * bk)[None, :]
     live = (row < Sq) & (col < Sk)
     if causal:
         live &= (col + k_off) <= (row + q_off)
-    out = {}
+    out, tril = {}, set()
     for qi in range(live.shape[0] // bq):
         for ki in range(live.shape[1] // bk):
             t = live[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
             out[qi, ki] = ("interior" if t.all() else
                            "masked" if t.any() else "never")
-    return out
+            if bq == bk and (t == np.tri(bq, dtype=bool)).all():
+                tril.add((qi, ki))
+    return tril if diagonal else out
 
 
-@pytest.mark.parametrize("q_off,k_off", _OFFSETS + [(0, 200), (200, 0)])
+@pytest.mark.parametrize("q_off,k_off", _OFFSETS + [
+    (0, 200), (200, 0), (256, 256), (512, 0), (576, 512)])
 @pytest.mark.parametrize("Sq,Sk,bq,bk,causal", [
     (80, 80, 16, 32, True), (72, 96, 16, 32, True), (80, 80, 32, 16, True),
     (48, 112, 16, 32, True), (80, 80, 16, 32, False),
     (1024, 1024, 256, 256, True), (1024, 1024, 512, 512, True),
-    (100, 60, 48, 16, True)])
+    (100, 60, 48, 16, True), (900, 900, 256, 256, True),
+    (1024, 1024, 256, 256, False)])
 def test_tile_plan_is_the_mask(Sq, Sk, bq, bk, causal, q_off, k_off):
     """The loop bounds the resident kernels run are exactly the tiles
     whose mask has a live element, and the unmasked ones exactly those
-    whose mask is all live: by brute force from the mask."""
-    from apex1_tpu.ops.attention import (_key_tiles, _query_tiles,
+    whose mask is all live: by brute force from the mask. And the tiles
+    `_on_diagonal` hands to the DIAGONAL body (in tiles of whole
+    `DIAG_SUB` squares) are exactly those whose mask is the triangle."""
+    from apex1_tpu.ops.attention import (DIAG_SUB, _key_tiles,
+                                         _on_diagonal, _query_tiles,
                                          tile_plan)
     want = _brute_tiles(Sq, Sk, bq, bk, q_off, k_off, causal)
     n_q, n_k = -(-Sq // bq), -(-Sk // bk)
@@ -403,17 +412,157 @@ def test_tile_plan_is_the_mask(Sq, Sk, bq, bk, causal, q_off, k_off):
     assert by_q == want
     assert by_k == want
     count = lambda cls: sum(1 for c in want.values() if c == cls)
+    tril = _brute_tiles(Sq, Sk, bq, bk, q_off, k_off, causal, diagonal=True)
+    assert tril <= {t for t, c in want.items() if c == "masked"}
+    if bq == bk:    # the predicate is asked of square tiles only
+        assert {t for t in want if causal and _on_diagonal(
+            *t, bq, bk, Sq, Sk, q_off, k_off)} == tril
     assert tile_plan(Sq, Sk, bq, bk, q_off, k_off, causal) == (
-        count("interior"), count("masked"), count("never"))
+        count("interior"), count("masked"),
+        len(tril) if bq == bk and bq % DIAG_SUB == 0 else 0, count("never"))
 
 
 def test_tile_plan_of_the_training_cell():
     """GPT-2 medium's call (S = 1024): the share of the square visited is
-    (1 + 1/n_q) / 2, and most visited tiles need no mask."""
+    (1 + 1/n_q) / 2, most visited tiles need no mask, and every masked
+    one lies on the diagonal: (interior, masked, of which diagonal,
+    never visited)."""
     from apex1_tpu.ops.attention import tile_plan
-    assert tile_plan(1024, 1024, 512, 512) == (1, 2, 1)
-    assert tile_plan(1024, 1024, 256, 256) == (6, 4, 6)
-    assert tile_plan(1024, 1024, 128, 128) == (28, 8, 28)
+    assert tile_plan(1024, 1024, 512, 512) == (1, 2, 2, 1)
+    assert tile_plan(1024, 1024, 256, 256) == (6, 4, 4, 6)
+    assert tile_plan(1024, 1024, 128, 128) == (28, 8, 8, 28)
+    assert tile_plan(1024, 1024, 512, 256) == (2, 4, 0, 2)
+
+
+# ---------------------------------------------------------------------------
+# the DIAGONAL body: a whole tile whose corner the diagonal passes through
+# runs as a trapezoid of static 128-wide squares, masked on its diagonal ones
+# ---------------------------------------------------------------------------
+
+# what a case hands over, and ``takes``: whether the tiles of its masked
+# runs take the DIAGONAL body ("all": every one; "none": not one at run
+# time; "some": the whole ones do, the padded one not; "never": the call
+# has no such body, statically)
+_DIAG_CASES = {
+    "rows_256": dict(layout="rows", b=256, S=512, takes="all"),
+    "rows_512": dict(layout="rows", b=512, S=1024, takes="all"),
+    "heads_256_gqa": dict(layout="heads", b=256, S=512, Hq=4, Hkv=2,
+                          takes="all"),
+    "heads_512_gqa": dict(layout="heads", b=512, S=1024, Hq=2, Hkv=1,
+                          takes="all"),
+    # a ring shard that attends itself: offsets equal, a tile's multiple
+    "rows_256_aligned_offset": dict(layout="rows", b=256, S=512, q_off=256,
+                                    k_off=256, takes="all"),
+    "heads_256_aligned_offset": dict(layout="heads", b=256, S=512, Hq=2,
+                                     Hkv=1, q_off=256, k_off=256,
+                                     takes="all"),
+    # the next shard's keys: a tile further down, still corner to corner
+    "heads_256_offset_a_tile": dict(layout="heads", b=256, S=512, Hq=2,
+                                    Hkv=1, q_off=256, k_off=0, takes="all"),
+    "rows_256_misaligned_offset": dict(layout="rows", b=256, S=512,
+                                       q_off=256 + 64, k_off=256,
+                                       takes="none"),
+    "heads_256_misaligned_offset": dict(layout="heads", b=256, S=512, Hq=2,
+                                        Hkv=1, q_off=256 + 64, k_off=256,
+                                        takes="none"),
+    "rows_256_padded": dict(layout="rows", b=256, S=448, takes="some"),
+    "heads_256_padded": dict(layout="heads", b=256, S=448, Hq=2, Hkv=1,
+                             takes="some"),
+    "rows_256_segments": dict(layout="rows", b=256, S=512, segs=True,
+                              takes="never"),
+    "heads_256_dropout": dict(layout="heads", b=256, S=512, Hq=2, Hkv=1,
+                              dropout_p=0.25, takes="never"),
+    "heads_256_wide_k": dict(layout="heads", b=256, bk=512, S=512, Hq=2,
+                             Hkv=1, takes="never"),
+}
+
+
+def _conds_in_kernels(fn, *args):
+    """How many `cond`s sit inside the pallas_calls under ``fn``."""
+    found = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            kernel = inside or eqn.primitive.name == "pallas_call"
+            found.append(kernel and eqn.primitive.name == "cond")
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, kernel)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return sum(found)
+
+
+@pytest.mark.parametrize("case", sorted(_DIAG_CASES))
+def test_diagonal_body(rng, monkeypatch, case):
+    """The DIAGONAL body against the MASKED body on the same inputs and
+    both against the XLA composite, forward and every gradient; and WHICH
+    of the two a tile ran, by breaking the other: with `_mask_for` saying
+    "nothing is live" a call whose masked runs are all diagonal tiles is
+    still right, with `_fill_dead` killing the whole row a call none of
+    whose tiles is on the diagonal is."""
+    from apex1_tpu.ops import attention as A
+    c = dict(_DIAG_CASES[case])
+    layout, b, S, takes = c.pop("layout"), c.pop("b"), c.pop("S"), \
+        c.pop("takes")
+    kw = dict(causal=True, block_q=b, block_k=c.pop("bk", b),
+              q_offset=c.pop("q_off", 0), k_offset=c.pop("k_off", 0))
+    if c.pop("segs", False):
+        kw["segment_ids"] = jnp.asarray(np.arange(S) // 200)[None]
+    if "dropout_p" in c:
+        kw.update(dropout_p=c.pop("dropout_p"), dropout_seed=7)
+    if layout == "rows":
+        args = (_packed(rng, 1, S, 2, 64),)
+        call = lambda x: fmha(x, **kw)
+        assert A.flash_form(2, 2, S, S, 64, packed=True, block_q=b,
+                            block_k=b, dtype=jnp.float32)["layout"] == "rows"
+    else:
+        args = _qkv(rng, B=1, Hq=c.pop("Hq"), Hkv=c.pop("Hkv"), Sq=S, D=64)
+        call = lambda q, k, v: flash_attention(q, k, v, **kw)
+    assert not c
+    with force_impl("xla"):
+        w = jnp.asarray(rng.normal(size=jax.eval_shape(call, *args).shape),
+                        jnp.float32)
+
+    def run(impl, **broken):
+        jax.clear_caches()      # the launches are jitted: a patched body
+        with monkeypatch.context() as m:        # needs its own trace
+            for name, fn in broken.items():
+                m.setattr(A, name, fn)
+            with force_impl(impl):
+                out = jax.value_and_grad(
+                    lambda *a: jnp.sum(call(*a).astype(jnp.float32) * w),
+                    argnums=tuple(range(len(args))))(*args)
+        jax.clear_caches()
+        return out
+
+    def same(a, b, rtol, atol):
+        np.testing.assert_allclose(a[0], b[0], rtol=rtol)
+        for x, y in zip(a[1], b[1]):
+            np.testing.assert_allclose(x, y, rtol=rtol, atol=atol)
+
+    got, gold = run("pallas"), run("xla")
+    same(got, gold, 1e-4, 5e-5)
+    # the parent's program: no call has a DIAGONAL body
+    same(got, run("pallas", _diag_sub=lambda *a, **k: 0), 1e-5, 2e-6)
+    plan = A.tile_plan(S, S, kw["block_q"], kw["block_k"], kw["q_offset"],
+                       kw["k_offset"])
+    with force_impl("pallas"):
+        conds = _conds_in_kernels(jax.grad(
+            lambda *a: jnp.sum(call(*a)), argnums=0), *args)
+    # the rows layout's dk/dv has two of its own (its two steps a tile)
+    conds -= 2 if layout == "rows" else 0
+    if takes == "never":
+        assert conds == 0
+        return
+    # one choice a masked run: the forward's, dq's and dk/dv's
+    assert conds == 3
+    assert plan[2] == dict(all=plan[1], none=0, some=1)[takes]
+    if takes == "all":
+        same(got, run("pallas", _mask_for=lambda *a, transposed=False, **k:
+                      jnp.zeros((b, b), bool)), 1e-5, 2e-6)
+    if takes == "none":
+        same(got, run("pallas", _fill_dead=lambda x, tri, fill, *a, **k:
+                      jnp.full_like(x, fill)), 1e-5, 2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -574,7 +723,9 @@ def test_flash_form_of_the_training_cell():
 def test_flash_form_is_said_on_the_spine(rng, tmp_path):
     """A traced call says the form it took, once, as the counter
     `flash/form`: the rows layout for the packed array, the heads layout
-    for (B, H, S, D)."""
+    for (B, H, S, D); and the tiles a head's loop runs by body: "2 of 3
+    on the diagonal at squares of 128" for a causal call in aligned
+    tiles, none (every tile masked) for one with segment ids."""
     from apex1_tpu.obs import spine
     run = spine.ObsRun(str(tmp_path), component="test")
     old = spine.default_run()
@@ -587,6 +738,11 @@ def test_flash_form_is_said_on_the_spine(rng, tmp_path):
                 _packed(rng, 1, 40, 2, 64))
             q, k, v = _qkv(rng, B=1, Hq=3, Sq=40, D=16)
             flash_attention(q, k, v, block_q=16, block_k=16)
+            q, k, v = _qkv(rng, B=1, Hq=1, Sq=512, D=16)
+            kw = dict(causal=True, block_q=256, block_k=256)
+            flash_attention(q, k, v, **kw)
+            flash_attention(q, k, v, segment_ids=jnp.zeros((1, 512), int),
+                            **kw)
     finally:
         spine.set_default_run(old)
         run.close()
@@ -594,7 +750,14 @@ def test_flash_form_is_said_on_the_spine(rng, tmp_path):
             if e["name"] == "flash/form"]
     assert [(e["layout"], e["heads_per_block"], e["resident_kv"],
              e["resident_q"], e["block_q"], e["block_k"]) for e in said] == [
-        ("rows", 2, True, True, 16, 16), ("heads", 1, True, True, 16, 16)]
+        ("rows", 2, True, True, 16, 16), ("heads", 1, True, True, 16, 16),
+        ("heads", 1, True, True, 256, 256),
+        ("heads", 1, True, True, 256, 256)]
+    assert [(e["diag_sub"], e["tiles_interior"], e["tiles_masked"],
+             e["tiles_diagonal"]) for e in said] == [
+        # 40 rows in tiles of 16, the last one padded: causal, then not
+        (0, 1, 5, 0), (0, 4, 5, 0),
+        (128, 1, 2, 2), (0, 0, 3, 0)]
 
 
 def test_gpt2_training_path_takes_the_rows_layout(rng):

@@ -190,6 +190,13 @@ def _sweep(backend):
     check("flash_fwd_bwd_causal_cell",
           lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
           (qc, kc, vc), grad_argnums=(0, 1, 2))
+    # the same call AS THE MODEL MAKES IT: the packed array in the rows
+    # layout (PR 41); a head's two masked tiles lie on the diagonal and
+    # run the DIAGONAL body (PR 51)
+    check("fmha_rows_fwd_bwd_causal_cell",
+          lambda x: ops.fmha(x, causal=True),
+          (bf(8, 1024, 3, 16, D),), grad_argnums=(0,))
+    _flash_diagonal_vs_masked(bf)
     kg, vg = bf(B, 2, S, D), bf(B, 2, S, D)
     check("flash_fwd_bwd_gqa",
           lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
@@ -317,6 +324,60 @@ def decode_cell_depths(rng, B, L, S, window, blk=128):
     idx[idle] = -1
     idx[[0, B - 1]] = rng.choice([-1, 3, edge], size=2)
     return idx.astype(np.int32)
+
+
+def _flash_diagonal_vs_masked(bf):
+    """The flash kernels' DIAGONAL body THROUGH MOSAIC against the MASKED
+    body on the same inputs (`ops.attention._diag_sub` answering 0: the
+    program a call ran before PR 51), forward and every gradient, in both
+    layouts: the training cell's packed call, and (B, H, S, D) operands
+    under GQA with a ring shard's aligned offset. The two differ by exact
+    zeros left out of sums (a reduction over fewer sublanes may round a
+    last bit of float32 another way): held to a bfloat16 output step."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex1_tpu import ops
+    from apex1_tpu.ops import attention, force_impl
+
+    cases = {
+        "rows_cell": (lambda x: ops.fmha(x, causal=True),
+                      (bf(8, 1024, 3, 16, 64),)),
+        "heads_gqa_offset": (
+            lambda q, k, v: ops.flash_attention(
+                q, k, v, causal=True, q_offset=1024, k_offset=1024),
+            (bf(2, 8, 1024, 128), bf(2, 4, 1024, 128), bf(2, 4, 1024, 128))),
+    }
+    for case, (fn, args) in cases.items():
+        name = f"flash_diagonal_vs_masked_{case}"
+        if ONLY is not None and not any(s in name for s in ONLY):
+            continue
+        t0 = time.time()
+
+        def run():
+            with force_impl("pallas"):
+                out, vjp = jax.vjp(fn, *args)
+                return (out, *vjp(jnp.ones_like(out)))
+
+        sub = attention._diag_sub
+        try:
+            got = [np.asarray(x, np.float32) for x in run()]
+            attention._diag_sub = lambda *a, **k: 0
+            jax.clear_caches()      # the launches are jitted
+            want = [np.asarray(x, np.float32) for x in run()]
+            errs = [float(np.max(np.abs(g - w) / np.maximum(np.abs(w), 1.0)))
+                    for g, w in zip(got, want)]
+            differ = sum(int(np.sum(g != w)) for g, w in zip(got, want))
+            _record(name, max(errs) <= 2 ** -7 and all(
+                np.isfinite(g).all() for g in got),
+                f"sub={sub(512, 512, True)} max relerr (out, grads)="
+                f"{max(errs):.2e} tol={2 ** -7:.1e}; {differ} of "
+                f"{sum(g.size for g in got)} elements differ", t0)
+        except Exception as e:  # keep sweeping
+            _record(name, False, f"{type(e).__name__}: {e}", t0)
+        finally:
+            attention._diag_sub = sub
+            jax.clear_caches()
 
 
 def _decode_attend_queue(rounds=3, steps=12):
