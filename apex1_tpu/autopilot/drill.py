@@ -1,7 +1,6 @@
-"""The headline autopilot drill — one scenario, three consumers
+"""The headline autopilot drill — one scenario, two consumers
 (tier-1 `tests/test_autopilot.py`, ``python -m apex1_tpu.autopilot
---smoke``, `tools/bench_autopilot.py`), so the claim every surface
-makes is the SAME claim.
+--smoke``), so the claim every surface makes is the SAME claim.
 
 THE CLAIM (ROADMAP item 4's "done" line): on a replayed
 adversarial-overload trace whose guaranteed-class demand alone exceeds

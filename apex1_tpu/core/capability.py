@@ -183,9 +183,9 @@ def ici_link_gbps(generation: str | None = None) -> float:
     """Conservative per-neighbor ICI rate (GB/s): the aggregate per-chip
     spec figure split across the torus's ``2 * ici_axes`` links. This is
     the rate a ring ppermute hop (ONE neighbor transfer) sees — the
-    denominator of the roofline comms term (`tools/predict_perf.py`,
-    bench.py's ``ici_exposed_bytes`` pricing). 0.0 when the generation
-    row carries no ICI figure."""
+    denominator of the roofline comms term (`apex1_tpu.perf_model`'s
+    ``ici_exposed_bytes`` pricing). 0.0 when the generation row carries
+    no ICI figure."""
     cap = get_capability(generation)
     if not cap.ici_gbps:
         return 0.0

@@ -23,7 +23,7 @@
   named it), idle gaps put down to the innermost program span over their
   midpoint, and one module's device time by the program's regions,
   forward and backward (`by_region`: the path of an op comes from the
-  program the profiler stored in the trace). ``tools/trace_report.py``
+  program the profiler stored in the trace). `xspace.format_report`
   prints it.
 - `calibrate` — fits correction factors from banked (predicted,
   measured) pairs; the repo ships no corpus, so every consumer prices

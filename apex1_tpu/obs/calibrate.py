@@ -1,18 +1,18 @@
 """Calibration — fit (predicted → measured) correction factors from the
 banked corpus, and feed them back to the predictors.
 
-`tools/predict_perf.py` prices every bench config and kernel with an
-analytic roofline; uncorrected against measurement, every planner
-decision would inherit its errors. This module closes the loop (the
-repo ships NO corpus today — the planner prices "uncalibrated" until
-chip records exist):
+`apex1_tpu.perf_model` prices a step or a kernel with an analytic
+roofline; uncorrected against measurement, every planner decision
+would inherit its errors. This module closes the loop (the repo ships
+NO corpus today, and since PR 52 nothing in it writes the
+``bench_*.log`` records or ``predicted_*.json`` tables the step pairs
+are joined from — the planner prices "uncalibrated"; ROADMAP D7):
 
 - **pairs** — every banked measurement that can be joined to its own
   prediction: on-silicon ``perf_results/bench_*.log`` records against
-  the newest ``predicted_*.json`` step rows (the
-  `tools/measured_vs_predicted.py` join, generalized), and tuning-table
+  the newest ``predicted_*.json`` step rows, and tuning-table
   entries that carry the per-sweep analytic ``predicted.ms``
-  `tools/tune_kernels.py` now banks beside each ``time_ms``.
+  `tools/tune_kernels.py` banks beside each ``time_ms``.
 - **factors** — per key (``step:<config>`` / ``kernel:<name>``), the
   geometric-mean SLOWDOWN ``predicted_rate / measured_rate`` (equiv.
   ``measured_time / predicted_time``; > 1 = slower than the roofline).
@@ -20,17 +20,13 @@ chip records exist):
   fitted too but land in ``proxy_factors`` and are NEVER applied to
   on-silicon predictions — interpret-mode time is plumbing evidence,
   not silicon (docs/observability.md, "What CPU-proxy numbers mean").
-- **feedback** — ``bench._attach_roofline`` stamps
-  ``calibrated_predicted`` / ``calibrated_ratio`` on measured records
-  (a calibrated ratio near 1.0 = performing as banked history says;
-  the RAW ``roofline_ratio`` keeps its absolute-localizer meaning),
-  and ``tools/predict_perf.py`` tables the factors beside its
-  predictions. `step_slowdown` / `kernel_slowdown` are the lookup API.
+- **feedback** — `step_slowdown` / `kernel_slowdown` are the lookup
+  API (`apex1_tpu.planner.cost` reads them).
 
 Exclusions are explicit and banked: the decode configs' predictions
 are known-garbage (the HLO cost model counts a scanned loop's weight
-buffers once, not once per decode step — see predict_perf's
-"SCANNED-LOOP BLIND SPOT"), so they are excluded with that reason
+buffers once, not once per decode step), so they are excluded with
+that reason
 rather than silently fitted into a meaningless factor.
 
 The table (``perf_results/calibration.json``,
@@ -62,7 +58,7 @@ CAL_NAME = "calibration.json"
 #: excluded from fitting WITH the reason banked in the table
 EXCLUDED_STEP_CONFIGS = {
     "decode": "scanned-loop blind spot: cost model counts streamed "
-              "weights once, not per decode step (predict_perf.py)",
+              "weights once, not per decode step",
     "decode_int8": "scanned-loop blind spot (see decode)",
 }
 
@@ -109,11 +105,9 @@ def roofline_ms(flops: float, nbytes: float,
 
 def newest_prediction_path(results_dir: Optional[str] = None
                            ) -> Optional[str]:
-    """Newest banked ``predicted_*.json`` by mtime — the same rule
-    ``bench._predicted_row`` applies (lexicographic order breaks at
-    r10 vs r9). `tools/measured_vs_predicted.py` resolves through this
-    too, so a new prediction round can never be silently scored against
-    a stale table."""
+    """Newest banked ``predicted_*.json`` by mtime (lexicographic
+    order breaks at r10 vs r9), so a new prediction round can never be
+    silently scored against a stale table."""
     d = results_dir or default_results_dir()
     paths = glob.glob(os.path.join(d, "predicted_*.json"))
     if not paths:
@@ -182,8 +176,7 @@ class Pair:
 
 def json_lines(path: str) -> list[dict]:
     """Lenient JSON-record scan of a bench queue log: every parseable
-    one-line {...} object, in order; unreadable file -> []. The ONE
-    scanner for queue logs (tools/trace_report.py shares it)."""
+    one-line {...} object, in order; unreadable file -> []."""
     out = []
     try:
         with open(path) as f:
@@ -213,8 +206,7 @@ def collect_step_pairs(results_dir: Optional[str] = None,
     tolerates batch-size overrides to first order — flops and time
     both scale ~linearly with B, so bench_gpt2_b24's record pairs
     fairly with the B=16 prediction row. A step_ms-based join would
-    NOT (that is measured_vs_predicted.py's per-shape constraint on
-    its LOG_FOR_CONFIG table)."""
+    NOT."""
     d = results_dir or default_results_dir()
     pred = newest_prediction(d)
     rows = ({r.get("name"): r for r in pred.get("steps", [])

@@ -1,7 +1,7 @@
 """Telemetry spine — one run-scoped event schema for every subsystem.
 
 Before this module, each measuring subsystem invented its own JSON
-shape (`bench.py` records, `tune_kernels` sweep logs, serving lifecycle
+shape (bench records, `tune_kernels` sweep logs, serving lifecycle
 events, sentinel diagnostics), so nothing could be joined across a run.
 The spine fixes the SCHEMA and the SINK:
 

@@ -3,8 +3,7 @@
 ``jax.profiler.trace`` banks ``*.xplane.pb`` files — XSpace protobufs.
 The stock decoders (``tensorflow.tsl...xplane_pb2`` et al.) drag a
 multi-second TensorFlow import through import-location roulette that
-differs per image (`tools/profile_step.py` shipped a three-way probe
-for exactly this). The XSpace wire format itself is tiny, so this
+differs per image. The XSpace wire format itself is tiny, so this
 module reads it directly: a ~100-line protobuf wire-format walker over
 the four message types we need, validated field-for-field against the
 ``xplane_pb2`` parse on this image (PR 10). No imports beyond stdlib —
@@ -818,7 +817,7 @@ def write_report(trace_dir: str | os.PathLike, *,
 
 
 def format_report(report: dict, top: int = 25) -> str:
-    """Human-readable rendering (the trace_report/profile_step CLIs)."""
+    """Human-readable rendering."""
     lines = [f"plane class: {report['plane_class']}   "
              f"total op time: {report['total_op_ms']:.3f} ms"
              + (f"   ({report['per_step_ms']:.3f} ms/step x "

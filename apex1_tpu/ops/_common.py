@@ -183,9 +183,8 @@ def row_block(lanes: int, *, rows: int | None = None,
 
     Tiny fixed blocks make the grid huge and per-step DMA/launch overheads
     dominate (round-1 on-device profile attributed ~5× to small tiles on
-    GPT-2 shapes — BASELINE.md "Round 1 measurements"; the raw trace was
-    not retained, block-sweep re-measurement queued in
-    tools/bench_kernels.py); this targets ``budget_bytes``
+    GPT-2 shapes; the raw trace was not retained and the block sweep
+    was never re-measured); this targets ``budget_bytes``
     of fp32 per row-block operand (keep it ≤1 MiB — Pallas double-buffers
     every operand and bwd kernels carry 3+ row blocks), clamped to
     [``lo``, ``hi``] and — when ``rows`` is given — to the actual row

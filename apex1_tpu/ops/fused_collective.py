@@ -59,8 +59,8 @@ Every executable form here keeps a bitwise-parity pin against its
 decomposed PR 4 counterpart on the CPU mesh (interpret AND
 XLA-composite paths, `tests/test_fused_collective.py`), a dependence-
 mode `testing.hlo_probe` pin in tier-1, and an async-mode probe +
-Mosaic-lowering gate in `tools/aot_check.py`. `tools/bench_fused_comm.py`
-is the wall-clock A/B (never yet run on chips).
+Mosaic-lowering gate in `tools/aot_check.py`. No wall-clock A/B has
+run on chips (ROADMAP D4, S10).
 """
 
 from __future__ import annotations
@@ -373,8 +373,8 @@ def fused_all_gather_matmul_serial(x, w, axis_name=AXIS_TP, seq_dim=0,
     consumes the permute issued in the same step, so ALL n−1 transfers
     are exposed. Retained as the falsifiable negative control for the
     overlap probes (dependence mode in tier-1, async mode in the AOT
-    gate) and as the A/B floor in tools/bench_fused_comm.py. Numerics
-    match the overlapped form (same dots, same placement order)."""
+    gate). Numerics match the overlapped form (same dots, same
+    placement order)."""
     return _fused_agm_loop(x, w, axis_name, seq_dim, block_m, block_n,
                            serialize=True)
 
@@ -667,11 +667,13 @@ def fused_vocab_parallel_merge(stats, axis_name=AXIS_TP):
     sumx]``, emitted by the kernel's final vocab tile in one output
     stream instead of four): ONE pmax for the global max, then ONE psum
     of the (T, 3) pack ``[l·exp(m − gmax), tgt, sumx]`` — two
-    collective rendezvous where the decomposed `_vp_merge` ladder pays
-    four. Bitwise equal to the decomposed merge: an all-reduce sums
-    each lane independently, so packing changes neither the reduction
-    order nor a single bit (pinned by test_fused_collective +
-    the hlo_probe collective-count check). Returns (lse, tgt, sumx)."""
+    collectives emitted where the decomposed `_vp_merge` ladder emits
+    four (XLA's all-reduce combiner then merges the ladder's three
+    psums into one variadic all-reduce: both forms COMPILE to two, on
+    the CPU and for a described v5e — ROADMAP D4). Bitwise equal to
+    the decomposed merge: an all-reduce sums each lane independently,
+    so packing changes neither the reduction order nor a single bit
+    (pinned by test_fused_collective). Returns (lse, tgt, sumx)."""
     m = stats[:, 0]
     gmax = jax.lax.pmax(m, axis_name)
     packed = jnp.stack([stats[:, 1] * jnp.exp(m - gmax),

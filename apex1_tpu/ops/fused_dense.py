@@ -9,9 +9,8 @@ torch launches matmul/bias/activation as separate kernels. Under XLA the
 matmul lands on the MXU and the bias/GELU/ReLU epilogues are fused into its
 output stage by the compiler — a hand-written Pallas GEMM would have to beat
 XLA's own matmul emitter to win, which is expected not to happen for plain
-dense shapes. The confirming roofline A/B (``tools/bench_kernels.py dense``,
-achieved-TFLOPs vs MXU peak) is queued in the hardware revival queue and has
-NOT yet run (docs/perf_playbook.md §2) — the decision currently rests on the
+dense shapes. No roofline A/B (achieved TFLOP/s against the MXU peak) has
+run — the decision rests on the
 architecture argument plus AOT lowering checks, not a measurement. So these
 are thin modules with the reference's API over ``jnp`` compute, with fp32
 MXU accumulation (``preferred_element_type``) matching the reference's

@@ -42,8 +42,7 @@ to fall back to XLA's transpose of the forward scan (the pre-overlap
 behavior for the backward; forward stays double-buffered).
 
 `ring_attention_serial` retains the original rotate-first-then-attend
-schedule (every transfer exposed) for A/B timing
-(``tools/bench_ring_ab.py``) and as the parity anchor in tests.
+schedule (every transfer exposed) as the parity anchor in tests.
 
 Use inside ``jax.shard_map`` with the sequence dimension sharded over
 ``axis_name``.
@@ -93,7 +92,7 @@ def _ring_fwd_loop(q, k, v, qseg, axis_name, causal, sm_scale, has_segs,
     drop identical weights). ``seed`` must be replicated over the ring.
     ``skip_masked=False`` disables the causal lax.cond shard skip (the
     fully-masked attend runs and merges a NEG_INF partial — numerically
-    identical); kept for the A/B timing in tools/bench_cond_elision.py.
+    identical); a path with no caller but its parity test (ROADMAP D4).
     """
     n = _axis_size(axis_name)
     B, Hq, Sq, _ = q.shape
@@ -447,7 +446,7 @@ def ring_attention(q, k, v, axis_name, *, causal: bool = False,
     counter-based mask keys on each shard's global k-offset, so shards
     draw disjoint streams and serial/overlapped schedules drop
     identical weights. ``skip_masked=False`` disables the causal
-    lax.cond shard skip (A/B knob for tools/bench_cond_elision.py;
+    lax.cond shard skip (no caller but its parity test, ROADMAP D4;
     numerics identical).
     """
     sm_scale = None if sm_scale is None else float(sm_scale)
@@ -478,9 +477,8 @@ def ring_attention_serial(q, k, v, axis_name, *, causal: bool = False,
                           skip_masked: bool = True):
     """The ORIGINAL serialized schedule — rotate first, then attend, so
     every one of the n−1 ICI transfers is exposed (the attend consumes
-    the permute it just issued). Retained as the A/B baseline
-    (``tools/bench_ring_ab.py``), the parity anchor for the
-    double-buffered rewrite, and the hlo_probe negative control (this
+    the permute it just issued). Retained as the parity anchor for the
+    double-buffered rewrite and the hlo_probe negative control (this
     loop body must FAIL the overlap probe). Backward is XLA's transpose
     of the scan. Numerics are identical to `ring_attention` (same
     attend/merge order)."""

@@ -1,27 +1,19 @@
 """The ONE analytic performance-pricing library — rooflines, per-kernel
 cost formulas, and ICI comms exposure models.
 
-History: these formulas grew up inside ``tools/predict_perf.py``
-(`_roofline`, `_kernel_cases`, `predict_comms`, `predict_comms_fused`)
-where only the CLI could reach them. ROADMAP item 1's planner must price
-thousands of candidate layouts per search — shelling out to a CLI per
-layout, or re-implementing the roofline, would either be absurd or
-guarantee formula drift (exactly the divergence ``vmem_model`` exists
-to prevent for the VMEM formulas). This module is the same
-deduplication for TIME: ``tools/predict_perf.py`` now imports every
-pricing ingredient from here (its CLI behavior and banked
-``predicted_*.json`` output are byte-stable across the refactor —
-pinned by the planner test suite re-deriving its table rows), and
-``apex1_tpu.planner.cost`` prices candidate layouts through the same
-functions.
+The planner must price thousands of candidate layouts per search, so
+the formulas live in one importable library (as ``vmem_model`` holds
+the VMEM formulas): ``apex1_tpu.planner.cost`` prices candidate layouts
+through these functions, and `tools/tune_kernels.py` banks their
+per-kernel figure beside each sweep. Fitted to nothing on the chip
+(ROADMAP D7).
 
 Everything here is jax-free at import (``core.capability`` is too):
 the planner's legality/pricing path must run in light tools and the
 ``tools/lint.py``-style stub environments. The honesty contract on
-every number is ``tools/predict_perf.py``'s module docstring — these
-are UPPER bounds on throughput (no bandwidth derating, no scheduler
-gaps); calibration (``obs.calibrate``) is what corrects them against
-banked silicon history.
+every number: these are UPPER bounds on throughput (no bandwidth
+derating, no scheduler gaps); calibration (``obs.calibrate``) is what
+would correct them against banked silicon history.
 """
 
 from __future__ import annotations
@@ -65,8 +57,7 @@ def roofline(flops, nbytes, cap, ici_exposed_bytes=0.0):
 def flash_flops_bytes(B, Hq, Hkv, S, D, causal=True, grad=False):
     """Analytic (flops, min HBM bytes) for one flash-attention call —
     the formula block shared by `kernel_cases` and the planner's
-    attention pricing (docstring of the factors: predict_perf
-    "_kernel_cases")."""
+    attention pricing (the factors: `kernel_cases`' docstring)."""
     f = 4 * B * Hq * S * S * D * (0.5 if causal else 1.0)
     if grad:
         # fwd (2 matmuls) + the SHIPPED two-pass backward: dq pass
@@ -95,8 +86,7 @@ def elemwise_flops_bytes(n_elem, passes, itemsize, fpe):
 
 def kernel_cases():
     """ANALYTIC (flops, min HBM bytes) per Pallas kernel at its bench
-    shape — shapes mirror tools/aot_check.py's kernel gate, so each row
-    lines up with what tools/bench_kernels.py measures on silicon.
+    shape — shapes mirror tools/aot_check.py's kernel gate.
 
     Formulas (all counts: multiply-add = 2 flops; bytes = each operand
     and result crossing HBM once — the kernels are designed to touch
@@ -166,9 +156,8 @@ def ring_attention_comms(generation: str, n: int, *,
     K/V shard transfer either serializes against the attend (the
     pre-overlap schedule) or hides behind it (the double-buffered
     schedule, hlo_probe-pinned). Returns None when the capability row
-    carries no ICI figure. Values in the dict are exactly what
-    predict_perf's comms table prints; the planner prices candidate cp
-    degrees through the same math at its model's shape."""
+    carries no ICI figure. The planner prices candidate cp degrees
+    through this math at its model's shape."""
     from apex1_tpu.core.capability import get_capability, ici_link_gbps
 
     cap = get_capability(generation)
@@ -312,8 +301,7 @@ def speculative_speedup(accept_rate: float, num_draft: int,
     is weight-streaming-bound: the same weights stream either way);
     ``draft_cost`` is the per-draft-token proposal cost (0 for the
     host-side n-gram default). An UPPER bound, like every number in
-    this module — the banked accept rates (`bench_serving`) are what
-    calibrate it."""
+    this module — a measured accept rate is what calibrates it."""
     if not 0.0 <= accept_rate <= 1.0:
         raise ValueError(f"accept_rate must be in [0, 1], "
                          f"got {accept_rate}")

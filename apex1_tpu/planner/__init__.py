@@ -13,9 +13,8 @@ the SP-boundary kernel flags.
 
 The repo's first subsystem that CHOOSES configurations instead of
 measuring ones a human chose. Consumers: ``examples/llama_3d.py
---plan auto``, ``bench.py --config llama_3d``,
-``tools/bench_planner_ab.py`` (the hardware A/B), and
-``tools/aot_check.py``'s planner gate (AOT HBM truth for the pick).
+--plan auto`` and ``tools/aot_check.py``'s planner gate (AOT HBM truth
+for the pick).
 
 No module under this package imports jax at module level — the whole
 legality / memory / pricing path runs under a ``tools/lint.py``-style

@@ -3,10 +3,9 @@
 Prices one (ModelShape, Layout) pair in milliseconds per optimizer
 step, through exactly the machinery the repo already trusts:
 
-- compute + HBM terms ride `apex1_tpu.perf_model.roofline` (the SAME
-  function `tools/predict_perf.py` tables — the AMP-style planner of
-  arXiv 2210.07297 is only as good as its cost model, and this repo's
-  cost model is the one its bench history has already scored);
+- compute + HBM terms ride `apex1_tpu.perf_model.roofline` (the
+  AMP-style planner of arXiv 2210.07297 is only as good as its cost
+  model; this one is fitted to nothing on the chip, ROADMAP D7);
 - attention flops come from `perf_model.flash_flops_bytes` with the
   shipped two-pass-backward factor, the LM-head CE from
   `perf_model.linear_xent_flops`;

@@ -171,9 +171,9 @@ PLAN_MODEL_KEYS = ("num_layers", "hidden_size", "ffn_size", "seq_len",
 
 def check_plan_model(plan: dict, shape: ModelShape) -> list:
     """Mismatches between a plan's banked model dims and the model a
-    consumer is about to drive with it — the ONE validation both
-    ``examples/llama_3d.py --plan`` and ``bench.py --config llama_3d
-    --plan`` apply (empty list = safe to consume)."""
+    consumer is about to drive with it — the validation
+    ``examples/llama_3d.py --plan`` applies (empty list = safe to
+    consume)."""
     pm = plan.get("model", {})
     return [f"{k}: plan={pm.get(k)} model={getattr(shape, k)}"
             for k in PLAN_MODEL_KEYS
@@ -236,8 +236,8 @@ def llama3d_config_from_plan(plan: dict, model_cfg,
                              learning_rate: float = 1e-4,
                              ignore_zero: bool = False):
     """The plan as a runnable `models.llama_3d.Llama3DConfig` — the
-    bridge `examples/llama_3d.py --plan` and `bench.py --config
-    llama_3d` drive end-to-end. ``model_cfg`` is the LlamaConfig the
+    bridge `examples/llama_3d.py --plan` drives end-to-end.
+    ``model_cfg`` is the LlamaConfig the
     plan's ModelShape was derived from (the plan carries dims, not
     weights-level config like the precision policy).
 

@@ -82,8 +82,8 @@ class ModelShape:
 
 #: The bench shapes the acceptance contract prices (ISSUE 12): names
 #: are the calibration keys a banked perf_results/calibration.json
-#: would carry (step:gpt2, step:llama_longctx), dims match the exact
-#: bench.py configs (`bench_gpt2` B=16 S=1024 on v5e; `bench_llama_longctx`
+#: would carry (step:gpt2, step:llama_longctx), dims match
+#: `tools/aot_steps.py` (`bench_gpt2` B=16 S=1024 on v5e; `bench_llama_longctx`
 #: 16-layer 0.8B at 16k) and the 8B projection matches
 #: `tools/aot_check.py --flagship`'s Llama-3-8B step (dp2 pp2 tp4,
 #: M=4, mb=1 -> global batch 8).

@@ -10,9 +10,8 @@ supplied (the same sink the training loop uses, so one log carries
 both), mirror into the telemetry spine's run file when
 ``APEX1_OBS_DIR`` is set (``serving.request`` / ``serving.transition``
 events — docs/observability.md), and always accumulate in memory for
-`summary()` — the
-offered-load sweep in ``tools/bench_serving.py`` reads tokens/sec,
-p50/p99 time-to-first-token, and mean slot occupancy from it.
+`summary()`: tokens/sec, p50/p99 time-to-first-token and mean slot
+occupancy.
 
 Schema (`docs/serving.md` § Engine): every event line is
 ``{"event", "req", "t", **fields}``; per-step samples are
@@ -292,8 +291,7 @@ class ServingMetrics:
         `Engine.pop_result`. The occupancy/step aggregates and the
         wall clock in `summary()` are LIFETIME values and do not reset
         — for a fresh measurement window, swap in a new
-        `ServingMetrics` (what `tools/bench_serving.py` does between
-        reps)."""
+        `ServingMetrics`."""
         with self._lock:
             gone = {k: r for k, r in self.records.items()
                     if r.status in TERMINAL}

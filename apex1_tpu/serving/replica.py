@@ -103,7 +103,7 @@ class ReplicaConfig:
     In pump mode the iteration that builds a fresh engine (and pays
     its first-call XLA compiles) is exempt; in threaded mode there is
     no such grace — size the deadline above the first step's compile
-    (what `tools/bench_serving.py` does) or pre-warm before `start`.
+    or pre-warm before `start`.
     """
 
     watchdog_s: float = 5.0       # no-progress deadline before declared

@@ -56,7 +56,7 @@ _MIN_COMPILE_SECS = 0.1
 
 def enable_persistent_compilation_cache() -> str:
     """The one copy of the compile-cache policy (``chip_smoke.py``,
-    ``bench.py``, ``tests/conftest.py`` and the tools all call this):
+    ``tests/conftest.py`` and the tools all call this):
     where ``JAX_COMPILATION_CACHE_DIR`` is exported JAX already reads it
     and NO directory is set in code (exported empty = the operator
     disabling the cache); otherwise the cache is `REPO_CACHE_DIR`.

@@ -269,10 +269,11 @@ def count_collectives(hlo_text, prefixes=("all-reduce",)):
     """Count instructions whose opcode starts with any of ``prefixes``
     across every computation (async pairs count once via their -start).
     The structural pin for fusions that REDUCE the collective count
-    rather than overlap it — e.g. the fused vocab-parallel linear_xent
-    merge (2 all-reduces: one pmax + one packed psum) against its
-    decomposed 4-collective ladder (the falsifiable negative control:
-    the decomposed program must count higher)."""
+    rather than overlap it. No caller since PR 52: its one pin, the
+    fused vocab-parallel linear_xent merge (one pmax + one packed psum)
+    against its decomposed 4-collective ladder, read 2 and 2 in
+    optimised HLO — XLA's combiner merges the ladder's psums (ROADMAP
+    D4)."""
     comps = parse_computations(hlo_text)
     n = 0
     for instrs in comps.values():

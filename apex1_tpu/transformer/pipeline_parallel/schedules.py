@@ -30,9 +30,9 @@ Schedule math:
 Bubble ticks (fraction (P−1)/(VM+P−1)) SKIP the stage compute via a
 per-tick ``lax.cond`` — like 1F1B, the schedule does no redundant work;
 bubble ranks idle through the tick and forward zeros to the ring permute
-(quantified via XLA cost analysis: tools/pipeline_cost.py, docs/parallel.md
-"Pipeline cost model" — whether lax.cond actually elides the branch on real
-TPU hardware is still unmeasured; tools/cond_elision_probe.py is queued).
+(docs/parallel.md "Pipeline cost model"; the v5e executable keeps the
+``conditional``, `perf_results/cond_elision_aot_r4.log` — what that saves
+in wall-clock on real TPU hardware is not measured).
 """
 
 from __future__ import annotations
@@ -214,12 +214,10 @@ def pipeline_apply(
         # subgroups), so group-scoped collectives (psum / all_gather /
         # reduce_scatter / all_to_all) inside `stage_fn` are safe: peers
         # share (s, t), take the same branch, and each replica_group
-        # rendezvouses independently (verified mask-vs-skip exact-match,
-        # tools/pipeline_cost.py repro). ``ppermute`` is NOT safe — see
+        # rendezvouses independently (verified mask-vs-skip exact-match).
+        # ``ppermute`` is NOT safe — see
         # the ``skip_bubbles`` contract in the docstring.
-        # (``skip_bubbles=False`` keeps the old mask-only path — the A/B
-        # lever tools/pipeline_cost.py times, since static cost_analysis
-        # prices a conditional's branches whether or not they execute.)
+        # (``skip_bubbles=False`` keeps the old mask-only path.)
         zero_aux = jnp.zeros([], jnp.float32)
 
         def run(ops):
@@ -250,7 +248,7 @@ def pipeline_apply(
             jnp.zeros([], jnp.float32))
     # scan_unroll > 1 lets XLA software-pipeline the tick loop (overlap a
     # tick's ppermute with the next tick's compute); True also makes every
-    # tick visible to cost_analysis (tools/pipeline_cost.py)
+    # tick visible to cost_analysis
     (x_recv, fifo, outs, aux_sum), _ = jax.lax.scan(
         tick, init, jnp.arange(T), unroll=scan_unroll)
 

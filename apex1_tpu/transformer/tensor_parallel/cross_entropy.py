@@ -252,9 +252,9 @@ def vocab_parallel_linear_cross_entropy(x, w_shard, labels, *,
     (`ops.linear_xent.shard_stats_packed`) and the pmax/psum ladder
     collapsed to TWO collectives
     (`ops.fused_collective.fused_vocab_parallel_merge`). Bitwise the
-    same loss as ``fused=False`` (pinned by test_fused_collective;
-    structural 2-vs-4 collective count pinned via
-    `testing.hlo_probe.count_collectives`).
+    same loss as ``fused=False`` (pinned by test_fused_collective).
+    Two collectives EMITTED against four; XLA's combiner merges the
+    ladder's three psums, so both forms compile to two (ROADMAP D4).
     """
     from apex1_tpu.transformer.tensor_parallel.mappings import (
         copy_to_tensor_model_parallel_region)

@@ -733,7 +733,7 @@ class TestDriftGate:
 
     def test_tolerates_spec_serving_record_fields(self, tmp_path,
                                                   drift_mod):
-        """ISSUE 15 satellite: the new bench_serving record shape
+        """ISSUE 15 satellite: a serving record's shape
         (multiplier_sweep rows with prefix_hit_rate / accept_rate /
         goodput + the int8 capacity block) banked into the corpus dir
         must not move the gate — serving benches join no

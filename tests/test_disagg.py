@@ -259,8 +259,7 @@ class TestFleetsimDisagg:
         assert max(by_qos["best_effort"] + by_qos["sheddable"]) >= 18
 
     def test_preexisting_trace_fingerprints_unchanged(self):
-        """The exact trace fingerprints banked in
-        perf_results/bench_autopilot_cpu.json BEFORE the two-tier model
+        """The exact trace fingerprints from BEFORE the two-tier model
         landed — the new trace kind and knobs must not perturb the
         shared rng call order."""
         assert synthetic_trace("bursty", seed=20260804, horizon_s=6.0,

@@ -17,9 +17,10 @@ The pins, per form:
   GQA group-sum, segments, and cp=2, plus the dependence probe (the
   serialized ring is the shared negative control).
 - fused vocab-parallel linear CE merge: BITWISE loss AND grads vs the
-  decomposed 4-collective ladder on both paths, plus the structural
-  2-vs-4 all-reduce count via `hlo_probe.count_collectives` (the
-  decomposed program is the falsifiable high-count control).
+  decomposed 4-collective ladder on both paths. (No count of compiled
+  all-reduces: XLA's combiner merges the ladder's three psums into one
+  variadic all-reduce, so both forms compile to two, on the CPU and
+  for a described v5e alike.)
 """
 
 import jax
@@ -35,7 +36,7 @@ from apex1_tpu.ops import fused_collective as fc
 from apex1_tpu.ops._common import force_impl
 from apex1_tpu.testing.hlo_probe import (assert_collective_overlap,
                                          check_collective_overlap,
-                                         count_collectives, optimized_hlo)
+                                         optimized_hlo)
 from apex1_tpu.transformer import tensor_parallel as tp
 
 
@@ -407,19 +408,6 @@ class TestFusedVocabParallelCE:
 
             for a, b in zip(grads(True), grads(False)):
                 _bitwise(a, b)
-
-    def test_collective_count_2_vs_4(self, mesh, rng):
-        """The structural pin: the fused merge compiles to exactly TWO
-        all-reduces; the decomposed ladder's FOUR is the falsifiable
-        negative control (if packing regressed, the counts converge)."""
-        x, w, t = self._arrs(rng)
-        with force_impl("xla"):
-            nf = count_collectives(
-                optimized_hlo(self._fn(mesh, True), x, w, t))
-            nd = count_collectives(
-                optimized_hlo(self._fn(mesh, False), x, w, t))
-        assert nf == 2, f"fused form must run 2 all-reduces, saw {nf}"
-        assert nd == 4, f"decomposed control must run 4, saw {nd}"
 
     def test_packed_stats_bitwise(self, rng):
         """shard_stats_packed columns == shard_stats outputs (the same

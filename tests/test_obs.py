@@ -7,7 +7,6 @@ hardware attached.
 """
 
 import gzip
-import importlib.util
 import json
 import os
 import pathlib
@@ -774,111 +773,3 @@ class TestCalibrate:
         code it described): every lookup degrades to 'uncalibrated'."""
         assert calibrate.load_calibration() is None
         assert calibrate.step_slowdown("gpt2") is None
-
-
-# ==========================================================================
-# feedback into bench records
-# ==========================================================================
-
-@pytest.fixture(scope="module")
-def bench_mod():
-    spec = importlib.util.spec_from_file_location("_bench_for_obs2",
-                                                  _REPO / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchCalibrationFeedback:
-    def _results_with_calibration(self, tmp_path, slowdown=2.0):
-        res = tmp_path / "perf_results"
-        _write(res / "predicted_r9.json", {
-            "steps": [{"name": "gpt2", "units_per_step": 1000,
-                       "flops": 1e12, "bytes": 1e9}]})
-        _write(res / "calibration.json", {
-            "schema": calibrate.SCHEMA,
-            "factors": {"step:gpt2": {"slowdown": slowdown, "n": 3,
-                                      "backend": "tpu"}},
-            "proxy_factors": {}, "excluded": [], "pairs": []})
-        return str(res)
-
-    def test_calibrated_fields_attached(self, bench_mod, tmp_path):
-        res = self._results_with_calibration(tmp_path, slowdown=2.0)
-        rec = {"metric": "m [tpu]", "value": 4000.0}
-        out = bench_mod._attach_roofline(dict(rec), "gpt2", res, "v5e")
-        assert out["predicted"] > 0
-        assert out["calibrated_predicted"] == pytest.approx(
-            out["predicted"] / 2.0, rel=1e-3)
-        assert out["calibrated_ratio"] == pytest.approx(
-            out["value"] / out["calibrated_predicted"], rel=1e-3)
-        assert out["calibration"] == {"slowdown": 2.0, "n": 3}
-        # raw localizer untouched
-        assert out["roofline_ratio"] == pytest.approx(
-            out["value"] / out["predicted"], rel=1e-3)
-
-    def test_no_calibration_no_fields(self, bench_mod, tmp_path):
-        res = self._results_with_calibration(tmp_path)
-        os.remove(os.path.join(res, "calibration.json"))
-        out = bench_mod._attach_roofline(
-            {"metric": "m [tpu]", "value": 4000.0}, "gpt2", res, "v5e")
-        assert "predicted" in out
-        assert "calibrated_predicted" not in out
-
-    def test_corrupt_calibration_never_breaks_record(self, bench_mod,
-                                                     tmp_path):
-        res = self._results_with_calibration(tmp_path)
-        with open(os.path.join(res, "calibration.json"), "w") as f:
-            f.write("!! not json")
-        out = bench_mod._attach_roofline(
-            {"metric": "m [tpu]", "value": 4000.0}, "gpt2", res, "v5e")
-        assert out["value"] == 4000.0 and "predicted" in out
-        assert "calibrated_predicted" not in out
-
-    def test_cpu_records_never_calibrated(self, bench_mod, tmp_path):
-        res = self._results_with_calibration(tmp_path)
-        out = bench_mod._attach_roofline(
-            {"metric": "m [cpu]", "value": 10.0}, "gpt2", res)
-        assert "predicted" not in out
-        assert "calibrated_predicted" not in out
-
-
-# ==========================================================================
-# measured_vs_predicted: newest-table resolution (satellite fix)
-# ==========================================================================
-
-class TestMeasuredVsPredicted:
-    @pytest.fixture()
-    def mvp_main(self, monkeypatch):
-        spec = importlib.util.spec_from_file_location(
-            "_mvp_for_obs", _REPO / "tools" / "measured_vs_predicted.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_resolves_newest_and_derives_out_name(self, mvp_main,
-                                                  tmp_path,
-                                                  monkeypatch, capsys):
-        res = tmp_path / "perf_results"
-        _write(res / "predicted_r5.json", {"steps": []})
-        _write(res / "predicted_r12.json", {"steps": [
-            {"name": "gpt2", "units_per_step": 1000, "flops": 1e12,
-             "bytes": 1e9}]})
-        os.utime(res / "predicted_r5.json", (1e9, 1e9))
-        os.utime(res / "predicted_r12.json", (2e9, 2e9))
-        monkeypatch.setattr(
-            "sys.argv", ["measured_vs_predicted.py",
-                         "--results", str(res)])
-        mvp_main.main()
-        out = res / "measured_r12.md"
-        assert out.exists(), "out name must follow the resolved table"
-        text = out.read_text()
-        assert "predicted_r12.json" in text
-        assert "predicted_r5.json" not in text
-
-    def test_exits_loud_when_no_table(self, mvp_main, tmp_path,
-                                      monkeypatch):
-        monkeypatch.setattr(
-            "sys.argv", ["measured_vs_predicted.py",
-                         "--results", str(tmp_path)])
-        with pytest.raises(SystemExit):
-            mvp_main.main()

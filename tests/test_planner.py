@@ -126,8 +126,8 @@ class TestLegality:
 
 class TestMemory:
     def test_prefilter_reproduces_banked_aot_verdicts(self):
-        # the llama_longctx sizing episode (bench.py docstring + the
-        # banked aot logs): 16-layer 0.8B fits a v5e (~14.4 GiB
+        # the llama_longctx sizing episode (tools/aot_steps.py's comment
+        # on `bench_llama_longctx`): 16-layer 0.8B fits a v5e (~14.4 GiB
         # measured), the 22-layer variant does not (18.7 GiB > 15.75)
         import dataclasses
         s16 = planner.BANKED_SHAPES["llama_longctx"]
@@ -392,7 +392,7 @@ class TestPlan:
 
 
 # ---------------------------------------------------------------------------
-# perf_model (the refactored pricing library predict_perf rides)
+# perf_model (the pricing library the planner's cost engine rides)
 # ---------------------------------------------------------------------------
 
 class TestPerfModel:
@@ -416,8 +416,8 @@ class TestPerfModel:
         assert t2 == pytest.approx(3.0) and bound2 == "ICI"
 
     def test_kernel_cases_formulas_stable(self):
-        # the values predict_perf banked pre-refactor — the flash gpt2
-        # fwd row and the linear_xent row, recomputed by hand
+        # the flash gpt2 fwd row and the linear_xent row, recomputed
+        # by hand
         cases = {name: (f, b) for name, f, b
                  in perf_model.kernel_cases()}
         f, b = cases["flash gpt2 (16,12,1024,64) fwd"]
@@ -429,8 +429,7 @@ class TestPerfModel:
         assert len(cases) == 11
 
     def test_sp_boundary_comms_matches_predict_comms_fused(self):
-        # the exact arithmetic predict_perf.predict_comms_fused
-        # printed before the refactor, recomputed inline
+        # the SP-boundary comms arithmetic, recomputed inline
         from apex1_tpu.core.capability import (get_capability,
                                                ici_link_gbps)
         S, hid, ffn, n, gen = 8192, 4096, 14336, 4, "v5e"

@@ -155,7 +155,16 @@ class TestOverlappedSchedule:
             np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
 
     def test_segment_grads_ride_the_bwd_ring(self, rng, devices):
-        mesh = make_mesh(cp=SP, dp=1, devices=devices[:SP])
+        # A ring of TWO. With segment ids the backward ring has five
+        # collective permutes in flight a step (dk, dv, k, v, kseg);
+        # XLA:CPU runs each as a thunk that BLOCKS a thread in its
+        # rendezvous, and has a thread a device plus one pool thread a
+        # core. A ring of four can park 5 x 3 threads at permutes whose
+        # fourth partner then finds none left (12 on 8 cores): the
+        # rendezvous aborts the process after 40 s, ~1 run in 4 under
+        # load. A ring of two parks at most 5. Several hops without
+        # segments (four permutes, 4 x 3 <= 12) are the test above.
+        mesh = make_mesh(cp=2, dp=1, devices=devices[:2])
         q, k, v = _mk(rng)
         seg = jnp.sort(jnp.asarray(rng.integers(0, 3, size=(B, S)),
                                    jnp.int32), axis=1)
@@ -406,8 +415,7 @@ class TestRingDropout:
         # and dropout actually happened
         plain = flash_attention(q, k, v, causal=True)
         assert not np.allclose(o_ov, plain, atol=1e-3)
-        # causal-skip cond off (tools/bench_cond_elision.py's A/B arm):
-        # numerics identical
+        # causal-skip cond off: numerics identical
         o_ns = self._run(mesh, ring_attention, q, k, v,
                          skip_masked=False)
         np.testing.assert_allclose(o_ns, o_ov, rtol=1e-6, atol=1e-7)

@@ -10,9 +10,8 @@ Usage: python tools/aot_check.py [--topology v5e:2x2]
 - Kernel checks shard the batch over a dp mesh (Mosaic kernels are not
   auto-partitionable), sized so PER-DEVICE shapes equal the single-chip
   bench shapes.
-- Step checks compile the ACTUAL `bench.py` train steps single-device
-  with donated state and report the HBM breakdown — these are the
-  numbers the bench.py batch/layer comments cite.
+- Step checks compile `tools/aot_steps.py`'s train and decode steps
+  single-device with donated state and report the HBM breakdown.
 - Collectives checks compile the distributed shard_map programs (ring
   attention, Ulysses, MoE double-all_to_all, scan+ppermute pipeline)
   against the multi-chip topology — ICI collective lowering + Mosaic
@@ -482,9 +481,9 @@ def _run_checks(args) -> bool:
                       f"ValueError", flush=True)
 
     if args.steps:
-        print(f"== full bench train steps (single device, exactly what "
-              f"bench.py runs), {args.topology} ==", flush=True)
-        import bench as bench_mod
+        print(f"== full bench train steps (single device, "
+              f"tools/aot_steps.py), {args.topology} ==", flush=True)
+        import aot_steps
 
         s1 = SingleDeviceSharding(topo.devices[0])
 
@@ -494,14 +493,9 @@ def _run_checks(args) -> bool:
                                                jnp.asarray(x).dtype,
                                                sharding=s1), tree)
 
-        # planner-driven configs (PLANNED_BENCHES) build their mesh
-        # from the live device count — not single-device-lowerable
-        # here; the planner's own pick is AOT-gated in the flagship
-        # section below
-        for cfg_name in sorted(set(bench_mod.BENCHES)
-                               - bench_mod.PLANNED_BENCHES):
+        for cfg_name in sorted(aot_steps.BENCHES):
             def run(cfg_name=cfg_name):
-                state, step, batch, *_ = bench_mod.BENCHES[cfg_name](True)
+                state, step, batch, *_ = aot_steps.BENCHES[cfg_name](True)
                 return jax.jit(step, donate_argnums=0).lower(
                     to_shape(state), *to_shape(batch))
 
@@ -719,8 +713,7 @@ def _run_checks(args) -> bool:
         # execute in interpret mode. Positive/negative pairs per the
         # probe-falsifiability rule. The RDMA kernel below has NO
         # XLA collective at all — its gate is the compile itself
-        # (numerics UNVERIFIED until the hardware window runs
-        # tools/bench_fused_comm.py --rdma).
+        # (numerics UNVERIFIED on hardware).
         from apex1_tpu.ops.fused_collective import (
             all_gather_flash_attention, fused_all_gather_matmul,
             fused_all_gather_matmul_serial, fused_matmul_reduce_scatter,

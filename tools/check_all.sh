@@ -62,8 +62,6 @@ echo "== paged parity drill (Pallas paged-attention + fused sampler vs the XLA-c
 JAX_PLATFORMS=cpu python -m apex1_tpu.ops.paged_decode --drill
 echo "== multi-tenant LoRA parity drill (adapter-page store lifecycle + one batch mixing two adapters and an adapterless control bitwise vs per-tenant solo runs, dense and paged-kernel epilogues; CPU interpret, real Mosaic on TPU) =="
 JAX_PLATFORMS=cpu python -m apex1_tpu.serving.lora
-echo "== serving engine smoke (CPU: correctness + two-executable gate + radix-hit/speculative goodput-multiplier rows with token parity + paged A/B with per-phase attribution + single- vs multi-tenant LoRA A/B) =="
-python tools/bench_serving.py --smoke --lora-tenants 2 > /dev/null
 echo "== hlo overlap probe (ring fwd+bwd vs serialized, CPU-compiled) =="
 python -m apex1_tpu.testing.hlo_probe
 echo "== AOT Mosaic + HBM checks (v5e; incl. async overlap probes) =="

@@ -20,8 +20,7 @@ exactly. So ALL of these fail loud instead of rotting silently:
 
 - a new banked record (hardware window, bad merge) whose
   calibrated_ratio says the fleet got slower/faster than banked
-  history — the regression signal `bench._attach_roofline` stamps,
-  enforced at CI time instead of eyeballed;
+  history, enforced at CI time instead of eyeballed;
 - an edited/corrupted log shifting a fitted factor;
 - re-swept tuning tables or new logs without a calibration re-fit
   (run ``python -m apex1_tpu.obs.calibrate`` and commit);

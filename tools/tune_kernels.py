@@ -41,10 +41,8 @@ import os
 import sys
 from typing import Callable, Sequence
 
-_TOOLS = os.path.dirname(os.path.abspath(__file__))
-_REPO = os.path.dirname(_TOOLS)
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
-sys.path.insert(0, _TOOLS)   # for bench_kernels (shared timeit)
 
 
 @dataclasses.dataclass
@@ -52,7 +50,7 @@ class Case:
     """One kernel sweep: candidates (dicts of block params) + a factory
     returning (timed_fn, args) for a candidate. ``flops``/``nbytes``
     are the ANALYTIC cost of one timed invocation at the sweep shape
-    (formulas mirror tools/predict_perf.py::_kernel_cases) — banked
+    (formulas mirror `apex1_tpu.perf_model.kernel_cases`) — banked
     beside the winner as ``predicted.ms`` so `apex1_tpu.obs.calibrate`
     can pair every measured sweep against its own roofline. None =
     unpriced (the entry then never feeds calibration)."""
@@ -68,7 +66,7 @@ class Case:
 
 def _flash_cost(B, Hq, Hkv, S, D, causal=True, grad=False):
     """Analytic (flops, min HBM bytes) for one flash invocation —
-    predict_perf's formula, incl. the 4.5x fwd+bwd factor for the
+    `apex1_tpu.perf_model`'s formula, incl. the 4.5x fwd+bwd factor for the
     SHIPPED two-pass backward (7 bwd matmuls, not the fused-5)."""
     f = 4 * B * Hq * S * S * D * (0.5 if causal else 1.0)
     if grad:
@@ -99,8 +97,7 @@ def _grad_of_sum(f, argnums):
 
 # --------------------------------------------------------------------------
 # sweep cases — shapes auto-shrink on CPU (interpret mode validates the
-# plumbing; tpu shapes mirror tools/bench_kernels.py so winners line up
-# with the banked A/B numbers)
+# plumbing)
 # --------------------------------------------------------------------------
 
 def _attention_case(B, Hq, Hkv, S, D, cands):
@@ -652,6 +649,39 @@ def sweep_one(name, iters, say, write=True):
     return winners, problems
 
 
+def timeit(fn, *args, iters=20):
+    """Seconds/call with the loop in ONE dispatch.
+
+    Each iteration's inputs depend on the previous output (a 0-valued
+    scalar tap added to every float arg) so XLA cannot hoist the
+    loop-invariant call out of the fori_loop."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    fn2 = jax.jit(fn)
+
+    def many(n, args):
+        def body(_, carry):
+            cargs, out = carry
+            eps = jax.tree.leaves(out)[0].ravel()[0] * 0
+            cargs = jax.tree.map(
+                lambda a: (a + eps.astype(a.dtype)
+                           if jnp.issubdtype(a.dtype, jnp.floating) else a),
+                cargs)
+            return cargs, fn2(*cargs)
+        return jax.lax.fori_loop(0, n, body, (args, fn2(*args)))[1]
+
+    manyj = jax.jit(many, static_argnums=0)
+    out = manyj(iters, args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    out = manyj(iters, args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / (iters + 1)
+
+
 def _sweep_case(case, iters, say, write):
     import jax
     import numpy as np
@@ -689,10 +719,6 @@ def _sweep_case(case, iters, say, write):
     if len(runnable) < 2:
         say(f"  SKIP {case.kernel}: <2 runnable candidates")
         return None, [f"{case.kernel}: <2 runnable candidates"]
-
-    # shared single-dispatch timing methodology (the eps-tap fori loop):
-    # lazy import so jax initializes only after --backend takes effect
-    from bench_kernels import timeit
 
     # analytic roofline for ONE timed invocation at the sweep shape —
     # banked as `predicted.ms` beside the winner so obs.calibrate can
